@@ -1,0 +1,357 @@
+"""The quickest proof that the system still starts on the chip.
+
+    python chip_smoke.py
+
+One process, from the root of a copy of the repo (no git, no network),
+on a machine with at least one TPU chip.  It drives the main path once
+through the entry points a user calls, at the full width of ResNet-50:
+
+  1. device     the chip, the versions, the peak table, the compile cache
+  2. resnet50   vision.resnet50_v1 (NHWC, bf16, 224^2, batch 256) through
+                parallel.make_mesh(dp=1) -> parallel.SPMDTrainer.step
+  3. gluon      the README's front door: initialize(ctx=mx.tpu(0)),
+                hybridize(), gluon.Trainer, record/backward/step
+  4. attention  ops/pallas_attention._attend compiled by Mosaic, against
+                the XLA reference, at BERT-base and NMT head shapes
+  5. multichip  (only when more than one chip is visible) the step of 2
+                under make_mesh(dp=n) at batch 256*n
+
+No phase failure is caught: any exception is a non-zero exit with no
+result line.  Without an accelerator it says what it found and exits 2 -
+there is no CPU run under this script's name.  The last line of stdout is
+one JSON object with exactly these keys, the device as JAX reports it:
+
+    {"ok": true, "device": {"platform": "tpu", "kind": "...", "count": 1}}
+
+The line before it, `[summary] {...}`, is JSON too: versions, per-phase
+`ok` and seconds, total seconds.
+
+The phases are functions of a size so tests/test_chip_smoke.py can run
+them at toy size on the CPU backend (Pallas in interpret mode); `main`
+runs only the full size.  Any img/s printed here is information for the
+reader, not a benchmark metric.
+"""
+from __future__ import annotations
+
+import json
+import sys
+import time
+
+import numpy as np
+
+# BERT-base heads (batch 32 x 12 heads, D=64) at S=128 and S=512 with a
+# validity mask; transformer-base NMT heads (batch 64 x 8 heads, D=64)
+# at S=64, causal.  (batch*heads, seq, head_dim, causal)
+ATTENTION_SHAPES = ((384, 128, 64, False), (384, 512, 64, False),
+                    (512, 64, 64, True))
+
+
+def _require(cond, message) -> None:
+    """A check that survives `python -O`, unlike `assert`."""
+    if not cond:
+        raise AssertionError(message)
+
+
+def device_phase(dev=None) -> dict:
+    """What JAX sees (`dev` defaults to its first device), and that the
+    peak table knows this device_kind."""
+    import jax
+    import jaxlib
+
+    from mxnet_tpu.telemetry.mxprof import costs
+
+    dev = dev or jax.devices()[0]
+    try:
+        import libtpu
+        libtpu_version = libtpu.__version__
+    except ImportError:
+        libtpu_version = None
+    peak, source = costs.peak_flops(dev.device_kind)
+    info = {"platform": dev.platform, "kind": dev.device_kind,
+            "count": len(jax.devices()), "jax": jax.__version__,
+            "jaxlib": jaxlib.__version__, "libtpu": libtpu_version,
+            "peak_flops": peak, "peak_source": source}
+    print(f"[device] {info}", flush=True)
+    if dev.platform != "cpu" and source != "table":
+        raise RuntimeError(
+            f"device_kind {dev.device_kind!r} is not in "
+            "telemetry/mxprof/costs.py:_PEAK_BY_KIND: every MFU on this "
+            "device would be null")
+    return info
+
+
+def resnet_phase(cache, *, n_dev=1, batch=256, image=224, warmup=3,
+                 steps=10, dtype="bfloat16", model="resnet50_v1",
+                 classes=1000, tile=1) -> dict:
+    """bench.py's construction (the one examples/imagenet_train.py uses):
+    initialise on cpu(), place onto a dp mesh, run SPMDTrainer.step on a
+    fixed batch.  `tile` repeats a batch/tile base batch `tile` times, so
+    that BatchNorm statistics and the mean loss of the first step equal
+    those of the base batch on one device."""
+    import jax
+
+    import bench
+    import mxnet_tpu as mx
+    from mxnet_tpu.parallel.spmd import step_compile_stats
+
+    platform = jax.devices()[0].platform
+    np.random.seed(0)
+    mx.random.seed(0)
+    before = step_compile_stats()
+    jax_before = cache.counts()
+    trainer = bench.resnet_trainer(model=model, classes=classes,
+                                   dtype=dtype, n_dev=n_dev)
+
+    rng = np.random.RandomState(0)
+    base = batch // tile
+    images = np.tile(rng.rand(base, image, image, 3).astype(dtype),
+                     (tile, 1, 1, 1))
+    labels = np.tile(rng.randint(0, classes, size=(base,)).astype(np.int32),
+                     tile)
+
+    # the batch lives on the device, split over dp by shard_batch
+    images = trainer._place(images, None)
+    labels = trainer._place(labels, None)
+    shard_devices = {s.device for s in images.addressable_shards}
+    _require(len(shard_devices) == n_dev and all(
+        s.data.shape[0] == batch // n_dev
+        for s in images.addressable_shards),
+        f"batch not split {n_dev} ways: {images.sharding}")
+    for name, v in trainer.params.items():
+        devs = {s.device for s in v.addressable_shards}
+        _require(len(devs) == n_dev and all(
+            d.platform == platform for d in devs),
+            f"{name} lives on {devs}, wanted {n_dev} {platform} devices")
+
+    donated = next(iter(trainer.params.values()))
+    t0 = time.perf_counter()
+    loss = trainer.step(images, labels)
+    first_loss = float(loss.asnumpy())
+    compile_s = time.perf_counter() - t0
+    _require(donated.is_deleted(),
+             "the step did not donate its parameter buffers")
+    for _ in range(warmup - 1):
+        loss = trainer.step(images, labels)
+    loss.asnumpy()
+    warm = step_compile_stats()
+
+    t0 = time.perf_counter()
+    for _ in range(steps):
+        loss = trainer.step(images, labels)
+    last_loss = float(loss.asnumpy())   # blocks: the whole chain ran
+    dt = time.perf_counter() - t0
+
+    after = step_compile_stats()
+    jax_after = cache.counts()
+    built = (warm["count"] + warm["cache_loads"]
+             - before["count"] - before["cache_loads"])
+    _require(built == 1, f"{built} step programs built during warm-up")
+    _require(after["count"] + after["cache_loads"]
+             == warm["count"] + warm["cache_loads"],
+             "a step program was built inside the timed window")
+    _require(np.isfinite(first_loss) and np.isfinite(last_loss),
+             f"non-finite loss: {first_loss} -> {last_loss}")
+    _require(abs(last_loss - first_loss) > 1e-3,
+             f"loss did not move on a fixed batch: {first_loss} -> "
+             f"{last_loss}")
+    for name, v in trainer.params.items():
+        _require(all(d.platform == platform for d in v.devices()),
+                 f"{name} left the {platform} devices: {v.devices()}")
+
+    hits = jax_after["hits"] - jax_before["hits"]
+    misses = jax_after["misses"] - jax_before["misses"]
+    stats = jax.devices()[0].memory_stats()
+    (step_fn, _cost), = trainer._step_fns.values()
+    program = step_fn.memory_analysis()
+    out = {
+        "n_dev": n_dev, "batch": batch, "first_loss": first_loss,
+        "last_loss": last_loss,
+        "first_step_s": round(compile_s, 2),
+        "step_compile_s": round(after["seconds_total"]
+                                - before["seconds_total"], 2),
+        "jax_cache": "hit" if hits and not misses else "miss",
+        "jax_cache_counts": {"hits": hits, "misses": misses},
+        "ms_per_step": round(1000 * dt / steps, 2),
+        "img_per_s": round(batch * steps / dt, 1),
+        "peak_bytes_in_use": stats.get("peak_bytes_in_use")
+        if stats else "memory_stats() not reported by this backend",
+        # what the compiler planned for the step program, to read the
+        # allocator's peak against
+        "step_program_bytes": {
+            "arguments": program.argument_size_in_bytes,
+            "temporaries": program.temp_size_in_bytes},
+    }
+    print(f"[resnet dp={n_dev}] {out}", flush=True)
+    return out
+
+
+def gluon_phase(ctx, *, batch=512, steps=20) -> dict:
+    """The README's front door on the 784-128-64-10 MLP: parameters
+    initialised on `ctx`, hybridize, gluon.Trainer, record/backward/step."""
+    import mxnet_tpu as mx
+    from mxnet_tpu import autograd, nd
+    from mxnet_tpu.gluon import Trainer, loss, nn
+    from mxnet_tpu.optimizer import fused
+
+    np.random.seed(0)
+    mx.random.seed(0)
+    net = nn.HybridSequential()
+    net.add(nn.Dense(128, activation="relu"),
+            nn.Dense(64, activation="relu"), nn.Dense(10))
+    net.initialize(mx.initializer.Xavier(), ctx=ctx)
+    net.hybridize()
+    trainer = Trainer(net.collect_params(), "adam", {"learning_rate": 1e-3})
+    loss_fn = loss.SoftmaxCrossEntropyLoss()
+
+    rng = np.random.RandomState(0)
+    x = nd.array(rng.rand(batch, 784).astype(np.float32), ctx=ctx)
+    y = nd.array(rng.randint(0, 10, (batch,)).astype(np.float32), ctx=ctx)
+
+    fused_before = fused.compile_stats()
+    losses, donated = [], None
+    t0 = time.perf_counter()
+    for i in range(steps):
+        with autograd.record():
+            l = loss_fn(net(x), y)
+        l.backward()
+        if i == 1:  # past the first step's deferred initialisation
+            donated = next(iter(net.collect_params().values())).data().data
+        trainer.step(batch)
+        losses.append(float(l.mean().asnumpy()))
+    dt = time.perf_counter() - t0
+
+    _require(all(np.isfinite(losses)), f"non-finite loss: {losses}")
+    _require(losses[-1] < losses[0], f"loss did not fall: {losses}")
+    for name, p in net.collect_params().items():
+        _require(p.data().ctx == ctx and p.grad().ctx == ctx,
+                 f"{name}: data on {p.data().ctx}, grad on {p.grad().ctx}")
+        _require(ctx.jax_device in p.data().data.devices(),
+                 f"{name} is not on {ctx.jax_device}")
+
+    fused_built = (fused.compile_stats()["count"]
+                   - fused_before["count"])
+    if trainer._spmd_updater is not None:
+        tail = "spmd"
+    elif fused_built and trainer._fuse_update_ok:
+        tail = "fused"
+    else:
+        tail = "eager"
+    if tail == "fused":
+        # optimizer/fused.py donates the weights wherever the device is
+        # not the CPU, which tier-1 therefore never runs
+        _require(donated.is_deleted() == (ctx.jax_device.platform != "cpu"),
+                 f"fused update donation on {ctx}: {donated.is_deleted()}")
+    out = {"ctx": str(ctx), "tail": tail,
+           "donated": bool(donated.is_deleted()),
+           "first_loss": losses[0], "last_loss": losses[-1],
+           "seconds": round(dt, 2)}
+    print(f"[gluon] {out}", flush=True)
+    return out
+
+
+def attention_phase(shapes=ATTENTION_SHAPES, *, dtype="bfloat16",
+                    expect_mosaic=True) -> dict:
+    """`_attend` against `dot_product_attention_ref` at bf16 tolerance.
+    The op is called directly: BERT's default dropout=0.1 routes a
+    training step to `_attention_with_prob_dropout`, which never reaches
+    the kernel.  With `expect_mosaic` the lowered program must hold the
+    Mosaic custom call, so the XLA reference cannot pass in its place."""
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu.ops import pallas_attention as pa
+
+    out = {}
+    for bh, seq, dim, causal in shapes:
+        rng = np.random.RandomState(seq)
+        q, k, v = (jnp.asarray(rng.randn(bh, seq, dim), dtype)
+                   for _ in range(3))
+        # validity mask: each row keeps a prefix of at least seq/2 keys
+        valid = rng.randint(seq // 2, seq + 1, size=(bh,))
+        mask = jnp.asarray(np.arange(seq)[None, :] < valid[:, None], dtype)
+        scale = 1.0 / np.sqrt(dim)
+        kernel = jax.jit(lambda q, k, v, m, c=causal:
+                         pa._attend(q, k, v, m, scale, c))
+        if expect_mosaic:
+            text = kernel.lower(q, k, v, mask).as_text()
+            _require("tpu_custom_call" in text,
+                     f"no Mosaic custom call in the lowered program for "
+                     f"S={seq}")
+        t0 = time.perf_counter()
+        got = np.asarray(kernel(q, k, v, mask), np.float32)
+        seconds = time.perf_counter() - t0
+        ref = np.asarray(jax.jit(
+            lambda q, k, v, m, c=causal: pa.dot_product_attention_ref(
+                q, k, v, m, scale, c))(q, k, v, mask), np.float32)
+        _require(np.isfinite(got).all(), f"non-finite output at S={seq}")
+        err = float(np.abs(got - ref).max())
+        np.testing.assert_allclose(got, ref, rtol=2e-2, atol=2e-2)
+        name = f"bh{bh}_s{seq}_d{dim}" + ("_causal" if causal else "")
+        out[name] = {"max_abs_err": round(err, 5),
+                     "first_call_s": round(seconds, 2)}
+    print(f"[attention] {out}", flush=True)
+    return out
+
+
+def result_line(devices) -> str:
+    """The last line of stdout: exactly `ok` and `device`, and `device`
+    exactly `platform`, `kind`, `count`.  Only a run in which every phase
+    passed gets here - a failed phase has already raised."""
+    return json.dumps({
+        "ok": True,
+        "device": {"platform": devices[0].platform,
+                   "kind": devices[0].device_kind,
+                   "count": len(devices)}})
+
+
+def main() -> int:
+    t_start = time.perf_counter()
+    from mxnet_tpu.compile_cache import jax_cache
+
+    cache = jax_cache.configure()
+    import jax
+
+    devices = jax.devices()
+    if devices[0].platform != "tpu":
+        print(f"chip_smoke: no TPU: jax.devices() is {devices} (platform "
+              f"{devices[0].platform!r}); this script does not run on "
+              "another platform", file=sys.stderr)
+        return 2
+    import mxnet_tpu as mx
+
+    print(f"[cache] jax compilation cache at {cache.directory}", flush=True)
+    phases = {}
+
+    def run(name, fn, *a, **kw):
+        t0 = time.perf_counter()
+        result = fn(*a, **kw)
+        phases[name] = {"ok": True,
+                        "seconds": round(time.perf_counter() - t0, 1),
+                        **{k: result[k] for k in
+                           ("n_dev", "first_loss", "jax_cache",
+                            "ms_per_step", "tail", "peak_bytes_in_use")
+                           if k in result}}
+        return result
+
+    info = run("device", device_phase)
+    one = run("resnet50", resnet_phase, cache)
+    run("gluon", gluon_phase, mx.tpu(0))
+    run("attention", attention_phase)
+    n = len(devices)
+    if n > 1:
+        many = run("multichip", resnet_phase, cache, n_dev=n,
+                   batch=256 * n, tile=n)
+        # same seed, the base batch repeated on every chip: the first
+        # step's loss is the one-chip loss up to bf16 reduction order
+        np.testing.assert_allclose(many["first_loss"], one["first_loss"],
+                                   rtol=2e-2)
+    summary = {"versions": {k: info[k] for k in ("jax", "jaxlib", "libtpu")},
+               "phases": phases,
+               "seconds": round(time.perf_counter() - t_start, 1)}
+    print(f"[summary] {json.dumps(summary)}", flush=True)
+    print(result_line(devices), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

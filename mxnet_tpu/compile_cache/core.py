@@ -69,9 +69,16 @@ def _encode_executable(compiled: Any,
         from jax.experimental import serialize_executable as _se
 
         payload, in_tree, out_tree = _se.serialize(compiled)
+        # the device assignment rides with the payload: jax's loader
+        # defaults execution_devices to EVERY device of the backend, so
+        # a one-device program reloaded on a four-chip host would
+        # demand four shards
+        device_ids = [d.id for d in
+                      compiled._executable._unloaded_executable.device_list]
         return "exec", pickle.dumps(
             {"payload": payload, "in_tree": in_tree,
-             "out_tree": out_tree}, protocol=pickle.HIGHEST_PROTOCOL)
+             "out_tree": out_tree, "device_ids": device_ids},
+            protocol=pickle.HIGHEST_PROTOCOL)
     except Exception:  # noqa: BLE001 — backend/runtime may not support it
         if program_text is not None:
             return "stablehlo", program_text.encode()
@@ -82,9 +89,13 @@ def _decode_executable(payload: bytes) -> Any:
     """Rehydrate an ``exec``-tier payload into a callable executable."""
     from jax.experimental import serialize_executable as _se
 
+    import jax
+
     d = pickle.loads(payload)
-    return _se.deserialize_and_load(d["payload"], d["in_tree"],
-                                    d["out_tree"])
+    by_id = {dev.id: dev for dev in jax.devices()}
+    return _se.deserialize_and_load(
+        d["payload"], d["in_tree"], d["out_tree"],
+        execution_devices=[by_id[i] for i in d["device_ids"]])
 
 
 class CompileCache:

@@ -1,0 +1,66 @@
+"""Where JAX's own persistent compilation cache lives, decided once.
+
+The ``.mxcc`` store beside this module persists the framework's AOT
+executables and is off unless ``MXNET_COMPILE_CACHE_DIR`` is set; this
+module is about the cache JAX itself keeps for everything ``jit`` and
+``lower().compile()`` build.  The measurement entry points
+(``chip_smoke.py``, ``bench.py``, ``bench_all.py``'s children, the
+on-chip test lane) call :func:`configure` before their first compile:
+
+  * ``JAX_COMPILATION_CACHE_DIR`` set: JAX reads it on its own and
+    nothing is set in code, so whoever placed the cache from outside
+    (a machine that keeps one across runs) finds it used;
+  * unset: a FIXED directory inside the checkout, ``<repo>/.jax_cache``
+    (git-ignored).  Never a temporary directory, a pid or a timestamp: a
+    cache that moves is never hit again.
+"""
+from __future__ import annotations
+
+import os
+import threading
+from typing import Dict
+
+__all__ = ["JaxCache", "configure", "DEFAULT_DIR"]
+
+_REPO = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+DEFAULT_DIR = os.path.join(_REPO, ".jax_cache")
+
+_HIT = "/jax/compilation_cache/cache_hits"
+_MISS = "/jax/compilation_cache/cache_misses"
+
+
+class JaxCache:
+    """The cache directory in effect, and how often JAX has hit and
+    missed it in this process since this object was made."""
+
+    def __init__(self, directory: str):
+        import jax
+
+        self.directory = directory
+        self._lock = threading.Lock()
+        self._counts = {"hits": 0, "misses": 0}
+        jax.monitoring.register_event_listener(self._on_event)
+
+    def _on_event(self, event: str, **_kw) -> None:
+        if event == _HIT or event == _MISS:
+            with self._lock:
+                self._counts["hits" if event == _HIT else "misses"] += 1
+
+    def counts(self) -> Dict[str, int]:
+        """{"hits": executables served from the persistent cache,
+        "misses": executables compiled and written there}.  Programs
+        under JAX's own thresholds (compile time, entry size) are
+        neither."""
+        with self._lock:
+            return dict(self._counts)
+
+
+def configure() -> JaxCache:
+    """Place the cache (see the module docstring) and start counting.
+    Call it once per process, before the first compile."""
+    import jax
+
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update("jax_compilation_cache_dir", DEFAULT_DIR)
+    return JaxCache(jax.config.jax_compilation_cache_dir)

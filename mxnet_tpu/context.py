@@ -12,6 +12,7 @@ on a TPU host).
 """
 from __future__ import annotations
 
+import os
 import threading
 from typing import Optional
 
@@ -78,7 +79,7 @@ class Context:
         # each worker addresses only its own devices — ctx cpu(0)/tpu(0)
         # must never resolve to another process's buffer space
         if self.device_type in ("cpu", "cpu_pinned", "cpu_shared"):
-            devs = jax.local_devices(backend="cpu")
+            devs = _cpu_devices()
         else:
             devs = _accelerator_devices()
             if not devs:
@@ -94,13 +95,28 @@ class Context:
 
 
 def _accelerator_devices():
+    """Local non-CPU devices.  A backend that fails to initialise (the
+    chip held by another process, say) raises here: turning that into
+    "no accelerator" would silently move the default context to cpu(0)."""
+    import jax
+
+    return [d for d in jax.local_devices() if d.platform != "cpu"]
+
+
+def _cpu_devices():
+    """The host backend's local devices.  Every model builder in the repo
+    initialises on cpu() and then places onto the mesh, so a process
+    whose JAX_PLATFORMS hides the CPU backend cannot run them."""
     import jax
 
     try:
-        devs = jax.local_devices()
-    except RuntimeError:
-        return []
-    return [d for d in devs if d.platform != "cpu"]
+        return jax.local_devices(backend="cpu")
+    except RuntimeError as e:
+        raise MXNetError(
+            "context cpu() needs JAX's CPU backend next to the accelerator, "
+            f"and it is not available (JAX_PLATFORMS="
+            f"{os.environ.get('JAX_PLATFORMS')!r}): list cpu there, e.g. "
+            "JAX_PLATFORMS=tpu,cpu, or leave it unset") from e
 
 
 def cpu(device_id: int = 0) -> Context:

@@ -327,10 +327,19 @@ class ServedModel:
         return leaves[0] if len(leaves) == 1 else leaves
 
     def set_params(self, params: dict) -> None:
-        """Validated atomically: a bad set leaves the old weights."""
+        """Validated atomically: a bad set leaves the old weights.
+
+        The weights are placed on the current context's device, and the
+        model serves there: a weights file loads onto cpu() (nd.load's
+        contract), while on a TPU host the default context, the inputs
+        and the serving executables are on tpu(0)."""
+        import jax
+
         missing = [n for n in self._order if n not in params]
         if missing:
             raise MXNetError(f"artifact params missing {missing[:5]}")
+        ctx = current_context()
+        dev = ctx.jax_device
         new = []
         for n in self._order:
             v = params[n].data if isinstance(params[n], NDArray) \
@@ -344,8 +353,9 @@ class ServedModel:
             if want_d is not None and str(v.dtype) != want_d:
                 raise MXNetError(
                     f"param {n}: dtype {v.dtype} != exported {want_d}")
-            new.append(v)
+            new.append(jax.device_put(v, dev))
         self._pvals = tuple(new)
+        self._ctx = ctx
 
     def __call__(self, *inputs, seed: int = 0):
         import jax
@@ -355,8 +365,8 @@ class ServedModel:
         if len(inputs) != len(want):
             raise MXNetError(
                 f"artifact takes {len(want)} inputs, got {len(inputs)}")
-        ctx = next((x.ctx for x in inputs if isinstance(x, NDArray)),
-                   None) or current_context()
+        ctx = self._ctx
+        dev = ctx.jax_device
         xs = []
         for x, w in zip(inputs, want):
             v = x.data if isinstance(x, NDArray) else jnp.asarray(x)
@@ -372,14 +382,14 @@ class ServedModel:
             if str(v.dtype) != w["dtype"]:
                 raise MXNetError(
                     f"input dtype {v.dtype} != exported {w['dtype']}")
-            xs.append(v)
+            xs.append(jax.device_put(v, dev))
         if self._meta.get("dynamic_batch"):
             sizes = {x.shape[0] for x in xs if x.ndim >= 1}
             if len(sizes) > 1:
                 raise MXNetError(
                     f"dynamic-batch artifact: all inputs must share one "
                     f"batch size, got {sorted(sizes)}")
-        key = jax.random.PRNGKey(seed)
+        key = jax.device_put(jax.random.PRNGKey(seed), dev)
         outs = self.exported.call(self._pvals, key, *xs)
         nds = [NDArray(o, ctx=ctx) for o in outs]
         # the structure the block's forward documents (dict/tuple/

@@ -12,6 +12,7 @@ toolchain exists, and pure-Python paths take over.
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import sys
@@ -49,7 +50,7 @@ def _img_sources() -> List[str]:
 
 
 class _NativeLib:
-    """One build-on-demand ctypes library: mtime staleness check, g++
+    """One build-on-demand ctypes library: source-hash staleness check, g++
     fallback build, env gate, double-checked-lock load, error ring."""
 
     def __init__(self, so_name: str, sources_fn, extra_flags: List[str],
@@ -62,24 +63,40 @@ class _NativeLib:
         self._lib: Optional[ctypes.CDLL] = None
         self._tried = False
 
-    def _needs_build(self) -> bool:
-        if not os.path.exists(self.so_path):
-            return True
-        mtime = os.path.getmtime(self.so_path)
-        deps = self._sources_fn() + [
+    def _fingerprint(self) -> str:
+        """sha256 over the flags and every source/header's CONTENT: a
+        fresh copy or checkout scrambles mtimes, bytes it cannot."""
+        deps = self._sources_fn() + sorted(
             os.path.join(_SRC, f) for f in os.listdir(_SRC)
-            if f.endswith(".h")]
-        return any(os.path.getmtime(p) > mtime for p in deps)
+            if f.endswith(".h"))
+        h = hashlib.sha256(" ".join(self._flags).encode())
+        for p in deps:
+            with open(p, "rb") as f:
+                h.update(f.read())
+        return h.hexdigest()
+
+    def _needs_build(self) -> bool:
+        stamp = self.so_path + ".src"
+        if not (os.path.exists(self.so_path) and os.path.exists(stamp)):
+            return True
+        with open(stamp) as f:
+            return f.read() != self._fingerprint()
 
     def _build(self) -> None:
         os.makedirs(_BUILD, exist_ok=True)
+        # build beside the target and rename: a concurrent process never
+        # dlopens a half-written library
+        tmp = f"{self.so_path}.{os.getpid()}.tmp"
         cmd = (["g++", "-std=c++17", "-O2", "-shared", "-fPIC", "-pthread",
-                "-Wall", "-o", self.so_path] + self._sources_fn() +
+                "-Wall", "-o", tmp] + self._sources_fn() +
                self._flags)
         proc = subprocess.run(cmd, capture_output=True, text=True)
         if proc.returncode != 0:
             raise MXNetError(f"{self._what} build failed:\n"
                              f"{' '.join(cmd)}\n{proc.stderr[-4000:]}")
+        os.replace(tmp, self.so_path)
+        with open(self.so_path + ".src", "w") as f:
+            f.write(self._fingerprint())
 
     def load(self) -> Optional[ctypes.CDLL]:
         if self._lib is not None or self._tried:

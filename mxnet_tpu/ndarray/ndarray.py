@@ -196,14 +196,17 @@ def _ctx_of_jax(arr) -> Context:
         return current_context()
     # Context.device_id is a LOCAL (per-process) position, matching
     # Context.jax_device's local_devices indexing — dev.id is a GLOBAL id
-    # and the two differ on non-zero workers of a multi-process job
-    if dev.platform == "cpu":
-        local = jax.local_devices(backend="cpu")
-        return cpu(next((i for i, d in enumerate(local) if d == dev), 0))
-    from ..context import tpu
+    # and the two differ on non-zero workers of a multi-process job.
+    # Which devices count as accelerators is context.py's one answer.
+    from .. import context as _context
 
-    local = [d for d in jax.local_devices() if d.platform != "cpu"]
-    return tpu(next((i for i, d in enumerate(local) if d == dev), 0))
+    accel = _context._accelerator_devices()
+    if dev in accel:
+        return _context.tpu(accel.index(dev))
+    if dev.platform != "cpu":   # another process's accelerator
+        return _context.tpu(0)
+    local = _context._cpu_devices()
+    return cpu(next((i for i, d in enumerate(local) if d == dev), 0))
 
 
 class NDArray:

@@ -1,4 +1,4 @@
-"""Fused scaled-dot-product attention: Pallas TPU kernel + XLA fallback.
+"""Fused scaled-dot-product attention: Pallas TPU kernel + XLA reference.
 
 The reference's counterpart is the fused attention path in later-1.x
 contrib (ref: src/operator/contrib/transformer.cc —
@@ -15,74 +15,23 @@ Design:
   * Backward = recompute-from-inputs via jax.vjp of the reference
     (XLA) math under custom_vjp — XLA fuses it; activation memory stays
     O(S·D) not O(S²).
-  * CPU backend (tests) and any Pallas lowering failure fall back to the
-    pure-XLA path with identical semantics; MXNET_USE_PALLAS=0 forces the
-    fallback.
+  * A program lowered for CPU has no Mosaic and takes the pure-XLA
+    path with identical semantics (or the Pallas interpreter under
+    MXNET_PALLAS_INTERPRET=1); MXNET_USE_PALLAS=0 selects the XLA path
+    anywhere.  Lowered for TPU, a lowering or compile failure raises.
 """
 from __future__ import annotations
 
 import functools
-import threading
-from typing import Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..analysis import sanitizer as _mxsan
 from ..util import env
 from .registry import register_op
 
 __all__ = ["dot_product_attention_ref"]
-
-# resolved lazily; None = undecided.  mxsan: lock-free reads are the
-# double-checked idiom; writes hold _PALLAS_LOCK
-_PALLAS_STATE = _mxsan.track({"enabled": None},
-                             "ops.pallas_attention._PALLAS_STATE",
-                             reads="unlocked-ok")
-_PALLAS_LOCK = threading.Lock()  # first attention call races from serving threads (mxlint MX004)
-
-
-def _pallas_wanted() -> bool:
-    """Decide once whether the Pallas path is usable: platform is not CPU
-    AND a tiny probe kernel COMPILES (catches Mosaic/backend rejections,
-    not just trace-time errors — a failure here permanently selects the
-    XLA fallback instead of breaking every attention call)."""
-    if _PALLAS_STATE["enabled"] is None:
-        with _PALLAS_LOCK:
-            if _PALLAS_STATE["enabled"] is None:
-                _PALLAS_STATE["enabled"] = _decide_pallas()
-    return _PALLAS_STATE["enabled"]
-
-
-def _decide_pallas() -> bool:
-    """One-time probe behind _pallas_wanted (caller holds _PALLAS_LOCK)."""
-    if not env.get_bool("MXNET_USE_PALLAS"):
-        return False
-    try:
-        backend = jax.default_backend()
-    except Exception:
-        backend = "cpu"
-    if backend == "cpu" and not env.get_bool("MXNET_PALLAS_INTERPRET"):
-        return False
-    try:
-        # representative shapes: head_dim 64 (BERT-style), one q block;
-        # probe BOTH variants — the causal path lowers extra iota/mask
-        # ops that Mosaic could reject independently
-        q = jnp.zeros((2, 128, 64), jnp.float32)
-        m = jnp.ones((2, 128), jnp.float32)
-        probe = jax.jit(_attention_pallas, static_argnums=(4, 5))
-        jax.block_until_ready(probe(q, q, q, m, 1.0, False))
-        jax.block_until_ready(probe(q, q, q, m, 1.0, True))
-        return True
-    except Exception as e:  # lowering OR compile failure
-        import logging
-
-        logging.warning(
-            "Pallas attention probe failed (%s: %s); using the XLA "
-            "fallback. Set MXNET_USE_PALLAS=0 to silence.",
-            type(e).__name__, e)
-        return False
 
 
 def dot_product_attention_ref(q, k, v, mask, scale, causal=False):
@@ -128,8 +77,10 @@ def _attention_pallas(q, k, v, mask, scale, causal=False):
         sc = jax.lax.dot_general(
             qb, kb, dimension_numbers=(((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)            # (bq, Sk)
-        valid = m_ref[0, 0] > 0                            # (Sk,)
-        sc = jnp.where(valid[None, :], sc, -1e30)
+        # the mask stays 2-D: Mosaic (libtpu 0.0.34) aborts the process
+        # in vector-layout inference on a 1-D bf16 vector
+        valid = m_ref[0].astype(jnp.float32) > 0           # (1, Sk)
+        sc = jnp.where(valid, sc, -1e30)
         if causal:
             qi = pl.program_id(1)
             qpos = qi * bq + jax.lax.broadcasted_iota(
@@ -162,13 +113,22 @@ def _attention_pallas(q, k, v, mask, scale, causal=False):
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
 def _attend(q, k, v, mask, scale, causal=False):
-    if _pallas_wanted():
-        try:
-            return _attention_pallas(q, k, v, mask, scale, causal)
-        except Exception:  # trace-time failure → permanent fallback
-            with _PALLAS_LOCK:
-                _PALLAS_STATE["enabled"] = False
-    return dot_product_attention_ref(q, k, v, mask, scale, causal)
+    """Kernel or reference, chosen from what the code can observe: the
+    platform the enclosing program is LOWERED for (a cpu()-resident warm
+    pass on a TPU host lowers for CPU and takes the reference; the same
+    call inside the TPU step takes the kernel).  Nothing is probed and
+    nothing latches: when the program lowers for TPU the kernel runs or
+    the call raises with Mosaic's own message."""
+    if not env.get_bool("MXNET_USE_PALLAS"):
+        return dot_product_attention_ref(q, k, v, mask, scale, causal)
+    if env.get_bool("MXNET_PALLAS_INTERPRET"):
+        return _attention_pallas(q, k, v, mask, scale, causal)
+    return jax.lax.platform_dependent(
+        q, k, v, mask,
+        tpu=functools.partial(_attention_pallas, scale=scale,
+                              causal=causal),
+        default=functools.partial(dot_product_attention_ref, scale=scale,
+                                  causal=causal))
 
 
 def _attend_fwd(q, k, v, mask, scale, causal):

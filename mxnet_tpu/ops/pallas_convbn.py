@@ -35,12 +35,15 @@ input-affine/ReLU backward recomputed elementwise from x.  Residuals are
 have stored anyway, so fusion adds no activation memory.
 
 The Pallas path needs layout NHWC (channels on the 128-lane axis) and a
-TPU backend; everything else (CPU tests, NCHW, probe failure,
-MXNET_USE_PALLAS=0) takes the XLA fallback with identical semantics.
+TPU backend; everything else (CPU tests, NCHW, MXNET_USE_PALLAS=0, a
+shape the compiler refuses — logged once per shape at WARNING) takes
+the XLA fallback with identical semantics, and `unit_counts()` says how
+many units ran on each path.
 """
 from __future__ import annotations
 
 import functools
+import logging
 import threading
 
 import jax
@@ -54,12 +57,9 @@ from .registry import register_op
 
 __all__ = ["fused_conv_unit"]
 
-# mxsan: the enable latch is read lock-free (double-checked idiom);
-# writes must hold _PROBE_LOCK
-_STATE = _mxsan.track({"enabled": None}, "ops.pallas_convbn._STATE",
-                      reads="unlocked-ok")
-#: guards _STATE plus the probe cache/budget below — serving threads and
-#: the training loop race the first conv dispatch (mxlint MX004)
+#: guards the per-shape probe cache and the unit counts below — serving
+#: threads and the training loop race the first conv dispatch (mxlint
+#: MX004)
 _PROBE_LOCK = threading.Lock()
 
 # VMEM working-set budget for choosing the per-program batch tile
@@ -70,47 +70,14 @@ _COLS_BUDGET_BYTES = 8 * 1024 * 1024
 
 
 def _pallas_wanted() -> bool:
-    """Pallas usable?  Decided once: not on CPU (unless interpret mode is
-    forced for tests) and only if a probe kernel actually compiles.
-    Double-checked under _PROBE_LOCK: the first conv can arrive from
-    several serving threads at once, and an unguarded decide would race
-    the probe compile."""
-    if _STATE["enabled"] is None:
-        with _PROBE_LOCK:
-            if _STATE["enabled"] is None:
-                _STATE["enabled"] = _decide_pallas()
-    return _STATE["enabled"]
-
-
-def _decide_pallas() -> bool:
-    """The one-time probe behind _pallas_wanted (caller holds
-    _PROBE_LOCK)."""
+    """Pallas usable?  MXNET_USE_PALLAS and a backend that can run it:
+    Mosaic on an accelerator, the interpreter (MXNET_PALLAS_INTERPRET,
+    tests) on CPU.  Whether Mosaic accepts a given shape is decided per
+    shape by `_probe_ok`, which says so when it does not."""
     if not env.get_bool("MXNET_USE_PALLAS"):
         return False
-    try:
-        backend = jax.default_backend()
-    except Exception:
-        backend = "cpu"
-    interp = env.get_bool("MXNET_PALLAS_INTERPRET")
-    if backend == "cpu" and not interp:
-        return False
-    try:
-        x = jnp.zeros((2, 8, 8, 128), jnp.bfloat16)
-        w = jnp.zeros((128, 128, 3, 3), jnp.bfloat16)
-        sc = jnp.ones((128,), jnp.float32)
-        sh = jnp.zeros((128,), jnp.float32)
-        jax.eval_shape(functools.partial(
-            _pallas_unit, kernel=(3, 3), stride=(1, 1), pad=(1, 1),
-            act_in=True, want_stats=True), x, w, sc, sc, sh)
-        if interp:
-            return True
-        jax.jit(functools.partial(
-            _pallas_unit, kernel=(3, 3), stride=(1, 1), pad=(1, 1),
-            act_in=True, want_stats=True)).lower(x, w, sc, sc, sh) \
-            .compile()
-        return True
-    except Exception:
-        return False
+    return (jax.default_backend() != "cpu"
+            or env.get_bool("MXNET_PALLAS_INTERPRET"))
 
 
 def _batch_tile(n, h, w, ci, ho, wo, co, itemsize=2, pad=(1, 1)):
@@ -504,27 +471,29 @@ def _xla_unit(x, w, in_scale, in_bias, shift, *, kernel, stride, pad,
 # pinned to the XLA fallback.
 _SHAPE_OK: dict = _mxsan.track({}, "ops.pallas_convbn._SHAPE_OK",
                                reads="unlocked-ok")
-# cumulative probe-compile seconds; every access holds _PROBE_LOCK
-_PROBE_SPENT = _mxsan.track([0.0], "ops.pallas_convbn._PROBE_SPENT")
+# fused units traced per path since import, forward and backward
+# alike; every access holds _PROBE_LOCK.  A "fused" measurement whose
+# units all ran XLA measured the restructured graph, not the kernel.
+_UNIT_COUNTS = _mxsan.track({"pallas": 0, "xla": 0},
+                            "ops.pallas_convbn._UNIT_COUNTS")
 
 
-def _probe_budget() -> float:
-    """Default probe-compile budget, scaled for the backward knob:
-    MXNET_FUSED_CONVBN_BWD=1 roughly doubles the number of distinct
-    configurations to probe (~20 fwd + ~20 bwd at 3-17s each on-chip),
-    so the default must grow with it — at the library layer, not per
-    launcher."""
-    dflt = 600.0 if env.get_bool("MXNET_FUSED_CONVBN_BWD") \
-        else 300.0
-    return env.get_float("MXNET_PALLAS_PROBE_BUDGET", default=dflt)
+def unit_counts() -> dict:
+    """{"pallas": n, "xla": m}: fused-unit dispatches traced on each path
+    in this process (bench.py prints it beside the fused variant)."""
+    with _PROBE_LOCK:
+        return dict(_UNIT_COUNTS)
+
+
+def _count(path: str) -> None:
+    with _PROBE_LOCK:
+        _UNIT_COUNTS[path] += 1
 
 
 def _probe_ok(key, fn, arg_structs) -> bool:
-    """Shared probe/budget/cache mechanism for fwd and bwd kernels.
-
-    Budget-exhausted is deliberately NOT cached: 'never probed' must
-    stay distinguishable from 'Mosaic rejected' so a later call with
-    budget headroom can still probe this configuration."""
+    """Probe-compile one configuration once and cache the answer.  A
+    refusal is reported once, at WARNING, with the compiler's message;
+    the unit then takes the XLA path and `unit_counts` shows it."""
     # the interpret flag is part of the key: interpreter-mode ok=True
     # says nothing about Mosaic, so a later non-interpret call in the
     # same process must re-probe instead of reusing it (ADVICE round 5)
@@ -536,21 +505,18 @@ def _probe_ok(key, fn, arg_structs) -> bool:
     with _PROBE_LOCK:
         ok = _SHAPE_OK.get(key)
         if ok is None:
-            import time as _time
-
             if interpret:
                 ok = True  # interpreter mode has no Mosaic stage
-            elif _PROBE_SPENT[0] >= _probe_budget():
-                return False
             else:
-                _t0 = _time.perf_counter()
                 try:
                     jax.jit(fn).lower(*arg_structs).compile()
                     ok = True
-                except Exception:
+                except Exception as e:  # noqa: BLE001 — Mosaic/XLA refusal
+                    logging.warning(
+                        "fused Conv+BN: the compiler refused the Pallas "
+                        "kernel for %s; this unit runs the XLA path. %s: %s",
+                        key[0], type(e).__name__, e)
                     ok = False
-                finally:
-                    _PROBE_SPENT[0] += _time.perf_counter() - _t0
             _SHAPE_OK[key] = ok
     return ok
 
@@ -653,21 +619,18 @@ def _unit(x, w, in_scale, in_bias, shift, kernel, stride, pad, act_in,
                                             act_in, want_stats)
         kind, mesh, axes = _dispatch_plan(x, probe)
         if kind == "single" and probe(x):
-            try:
-                return _pallas_unit(x, w, in_scale, in_bias, shift,
-                                    kernel=kernel, stride=stride,
-                                    pad=pad, act_in=act_in,
-                                    want_stats=want_stats)
-            except Exception:
-                pass
-        elif kind == "sharded":
-            try:
-                return _pallas_unit_sharded(
-                    x, w, in_scale, in_bias, shift, mesh=mesh,
-                    axes=axes, kernel=kernel, stride=stride, pad=pad,
-                    act_in=act_in, want_stats=want_stats)
-            except Exception:
-                pass
+            _count("pallas")
+            return _pallas_unit(x, w, in_scale, in_bias, shift,
+                                kernel=kernel, stride=stride,
+                                pad=pad, act_in=act_in,
+                                want_stats=want_stats)
+        if kind == "sharded":
+            _count("pallas")
+            return _pallas_unit_sharded(
+                x, w, in_scale, in_bias, shift, mesh=mesh,
+                axes=axes, kernel=kernel, stride=stride, pad=pad,
+                act_in=act_in, want_stats=want_stats)
+    _count("xla")
     return _xla_unit(x, w, in_scale, in_bias, shift, kernel=kernel,
                      stride=stride, pad=pad, act_in=act_in,
                      want_stats=want_stats)
@@ -689,24 +652,21 @@ def _unit_bwd(kernel, stride, pad, act_in, want_stats, res, cots):
                                                 pad, act_in, want_stats)
         kind, mesh, axes = _dispatch_plan(x, probe)
         if kind == "single" and probe(x):
-            try:
-                gx, dw, gscale, gbias = _pallas_unit_bwd(
-                    x, w, in_scale, in_bias, shift, y, gy, gs1, gs2,
-                    kernel=kernel, stride=stride, pad=pad,
-                    act_in=act_in, want_stats=want_stats)
-                return gx, dw, gscale, gbias, jnp.zeros_like(shift)
-            except Exception:
-                pass
-        elif kind == "sharded":
-            try:
-                gx, dw, gscale, gbias = _pallas_unit_bwd_sharded(
-                    x, w, in_scale, in_bias, shift, y, gy, gs1, gs2,
-                    mesh=mesh, axes=axes, kernel=kernel,
-                    stride=stride, pad=pad, act_in=act_in,
-                    want_stats=want_stats)
-                return gx, dw, gscale, gbias, jnp.zeros_like(shift)
-            except Exception:
-                pass
+            _count("pallas")
+            gx, dw, gscale, gbias = _pallas_unit_bwd(
+                x, w, in_scale, in_bias, shift, y, gy, gs1, gs2,
+                kernel=kernel, stride=stride, pad=pad,
+                act_in=act_in, want_stats=want_stats)
+            return gx, dw, gscale, gbias, jnp.zeros_like(shift)
+        if kind == "sharded":
+            _count("pallas")
+            gx, dw, gscale, gbias = _pallas_unit_bwd_sharded(
+                x, w, in_scale, in_bias, shift, y, gy, gs1, gs2,
+                mesh=mesh, axes=axes, kernel=kernel,
+                stride=stride, pad=pad, act_in=act_in,
+                want_stats=want_stats)
+            return gx, dw, gscale, gbias, jnp.zeros_like(shift)
+        _count("xla")
     if want_stats:
         # fold the BN-stat cotangents into dy: d(s1)/dy = 1,
         # d(s2)/dy = 2(y - shift); all C-sized broadcasts, XLA fuses

@@ -1,17 +1,12 @@
-"""jax version compatibility shims for the parallel package."""
+"""shard_map as the parallel package calls it."""
 from __future__ import annotations
 
-try:
-    from jax import shard_map as _shard_map
-    _UNCHECKED_KW = "check_vma"
-except ImportError:  # older jax: experimental API with check_rep
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _UNCHECKED_KW = "check_rep"
+from jax import shard_map as _shard_map
 
 
 def shard_map_unchecked(fn, *, mesh, in_specs, out_specs):
-    """shard_map with replication/varying-axis checking disabled — the body
-    functions here mix replicated accumulators with axis-varying data, which
-    the checker (check_rep in older jax, check_vma in newer) rejects."""
+    """shard_map with varying-axis checking disabled — the body functions
+    here mix replicated accumulators with axis-varying data, which
+    check_vma rejects."""
     return _shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      **{_UNCHECKED_KW: False})
+                      check_vma=False)

@@ -703,6 +703,7 @@ class SPMDTrainer:
 
             plist = self._plist
             block = self.block
+            trainer = self
 
             def fwd(params, ivals, key):
                 trace = ActiveTrace({id(p): params[n] for n, p in plist},

@@ -489,14 +489,11 @@ def build_info():
         import jax
 
         jaxver = jax.__version__
-        try:
-            initialized = bool(jax._src.xla_bridge._backends)
-        except Exception:
-            # can't tell -> assume DOWN: the wrong guess here would
-            # make a Prometheus scrape initialize the TPU backend as a
-            # side effect (labels stay 'uninitialized' instead)
-            initialized = False
-        if initialized:
+        from jax._src import xla_bridge
+
+        # a Prometheus scrape must not initialize the TPU backend as a
+        # side effect: labels stay 'uninitialized' until it is up
+        if xla_bridge.backends_are_initialized():
             dev = jax.devices()[0]
             platform, kind = dev.platform, dev.device_kind
         else:

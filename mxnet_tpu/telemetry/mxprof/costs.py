@@ -56,9 +56,13 @@ def executable_cost(compiled) -> Optional[Cost]:
 
 # peak dense FLOP/s per chip by device-kind substring (bf16 MXU peak,
 # public TPU specs); matched case-insensitively, first hit wins.  CPU
-# and unknown accelerators resolve to None.
+# and unknown accelerators resolve to None.  "v5 lite" is the
+# device_kind the v5e reports under jax 0.9.0 / libtpu 0.0.34
+# ("TPU v5 lite", read on the chip in PR 21); the "v5e"/"v5litepod"
+# spellings stay for runtimes that name it differently.
 _PEAK_BY_KIND: Tuple[Tuple[str, float], ...] = (
     ("v5p", 459e12),
+    ("v5 lite", 197e12),
     ("v5e", 197e12),
     ("v5litepod", 197e12),
     ("v6e", 918e12),
@@ -72,12 +76,9 @@ def backend_initialized() -> bool:
     """Whether a jax backend is up — an 'unknown' peak answered while
     the backend is still down is provisional (the device kind could
     not be read yet), not final."""
-    try:
-        import jax
+    from jax._src import xla_bridge
 
-        return bool(getattr(jax._src.xla_bridge, "_backends", None))
-    except Exception:  # noqa: BLE001
-        return False
+    return xla_bridge.backends_are_initialized()
 
 
 def peak_flops(device_kind: Optional[str] = None
@@ -90,15 +91,12 @@ def peak_flops(device_kind: Optional[str] = None
     if v:
         return float(v), "env"
     if device_kind is None:
-        try:
-            import jax
-
-            if not getattr(jax._src.xla_bridge, "_backends", None):
-                return None, "unknown"
-            device_kind = jax.devices()[0].device_kind
-        except Exception:  # noqa: BLE001
+        if not backend_initialized():
             return None, "unknown"
-    kind = (device_kind or "").lower()
+        import jax
+
+        device_kind = jax.devices()[0].device_kind
+    kind = device_kind.lower()
     for sub, peak in _PEAK_BY_KIND:
         if sub in kind:
             return peak, "table"

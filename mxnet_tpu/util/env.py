@@ -13,7 +13,7 @@ populated at import time, so documentation can never trail the code.
 
 Declared defaults are what the accessor returns when the variable is
 unset; a call site may pass ``default=`` to override — used by knobs
-whose default is computed (worker counts, probe budgets), which declare
+whose default is computed (worker counts), which declare
 ``default=None`` and document the dynamic rule.
 """
 from __future__ import annotations
@@ -383,15 +383,10 @@ declare("MXNET_FUSED_CONVBN", bool, False,
         "Conv+BN+ReLU kernels when tracing in NHWC layout.")
 declare("MXNET_FUSED_CONVBN_BWD", bool, False,
         "Opt-in Pallas backward for the fused Conv+BN units (roughly "
-        "doubles the probe-compile surface; see "
-        "MXNET_PALLAS_PROBE_BUDGET).")
+        "doubles the probe-compile surface).")
 declare("MXNET_PALLAS_INTERPRET", bool, False,
         "Run Pallas kernels in interpreter mode (CPU testing): no "
         "Mosaic compile, bit-accurate reference semantics.")
-declare("MXNET_PALLAS_PROBE_BUDGET", float, None,
-        "Cumulative seconds of probe-compiles allowed when deciding "
-        "whether a Pallas kernel supports a shape. Default is computed: "
-        "600 when MXNET_FUSED_CONVBN_BWD=1, else 300.")
 declare("MXNET_USE_PALLAS", bool, True,
         "Master switch for Pallas kernels (flash attention, fused "
         "Conv+BN). 0 selects the XLA fallbacks with identical "

@@ -35,6 +35,36 @@ class _Failing:
         return np.zeros(2, np.float32)
 
 
+class _PlatformAtUnpickle:
+    """Records, when a worker unpickles it, what JAX_PLATFORMS was."""
+
+    def __init__(self):
+        self.seen = None
+
+    def __len__(self):
+        return 4
+
+    def __getitem__(self, i):
+        return np.array([i if self.seen == "cpu" else -1], np.float32)
+
+    def __setstate__(self, state):
+        import os
+
+        self.seen = os.environ.get("JAX_PLATFORMS")
+
+
+def test_dataset_unpickled_after_cpu_pin(monkeypatch):
+    """Workers are CPU-only and the parent holds the chip: a pickled
+    NDArray rebuilds its jax array on the default device, so the dataset
+    must be unpickled only after _mp_init has pinned the CPU backend —
+    whatever platform list the parent's environment names."""
+    monkeypatch.setenv("JAX_PLATFORMS", "tpu,cpu")
+    dl = DataLoader(_PlatformAtUnpickle(), batch_size=4, num_workers=1,
+                    worker_pool="process")
+    (batch,) = list(dl)
+    np.testing.assert_array_equal(batch.asnumpy()[:, 0], [0, 1, 2, 3])
+
+
 def test_process_pool_order_and_reuse():
     x = np.arange(80, dtype=np.float32).reshape(20, 4)
     y = np.arange(20, dtype=np.float32)

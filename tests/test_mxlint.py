@@ -129,61 +129,10 @@ class TestLintDrivenHardening:
     PR: module caches shared with serving/dataloader threads now take
     the double-checked-lock path."""
 
-    def test_pallas_convbn_decides_once_under_contention(self, monkeypatch):
-        from mxnet_tpu.ops import pallas_convbn as pc
-
-        calls = []
-
-        def slow_decide():
-            calls.append(1)
-            time.sleep(0.05)
-            return False
-
-        monkeypatch.setattr(pc, "_decide_pallas", slow_decide)
-        # swap the whole latch dict (not setitem): under MXNET_SAN the
-        # module dict is lockset-tracked, and monkeypatch's unlocked
-        # teardown write would read as a seeded race
-        monkeypatch.setattr(pc, "_STATE", {"enabled": None})
-        results = []
-        threads = [threading.Thread(
-            target=lambda: results.append(pc._pallas_wanted()))
-            for _ in range(8)]
-        for t in threads:
-            t.start()
-        for t in threads:
-            t.join()
-        assert len(calls) == 1, "probe ran once despite 8 racing threads"
-        assert results == [False] * 8
-
-    def test_pallas_attention_decides_once_under_contention(
-            self, monkeypatch):
-        from mxnet_tpu.ops import pallas_attention as pa
-
-        calls = []
-
-        def slow_decide():
-            calls.append(1)
-            time.sleep(0.05)
-            return False
-
-        monkeypatch.setattr(pa, "_decide_pallas", slow_decide)
-        # setattr, not setitem — see the convbn twin above
-        monkeypatch.setattr(pa, "_PALLAS_STATE", {"enabled": None})
-        results = []
-        threads = [threading.Thread(
-            target=lambda: results.append(pa._pallas_wanted()))
-            for _ in range(8)]
-        for t in threads:
-            t.start()
-        for t in threads:
-            t.join()
-        assert len(calls) == 1 and results == [False] * 8
-
     def test_probe_cache_single_probe_per_key(self, monkeypatch):
         from mxnet_tpu.ops import pallas_convbn as pc
 
         monkeypatch.setattr(pc, "_SHAPE_OK", {})
-        monkeypatch.setattr(pc, "_PROBE_SPENT", [0.0])
         monkeypatch.setattr(pc.env, "get_bool",
                             lambda name, default=None: False)
         compiles = []

@@ -171,7 +171,6 @@ def test_multi_device_mesh_selects_sharded_pallas(monkeypatch):
     from mxnet_tpu import parallel
 
     monkeypatch.setenv("MXNET_PALLAS_INTERPRET", "1")
-    monkeypatch.setitem(pcb._STATE, "enabled", None)
 
     calls = {"pallas": 0, "sharded": 0}
     real = pcb._pallas_unit
@@ -263,12 +262,10 @@ def test_sharded_pallas_matches_fallback_full(axes, monkeypatch):
         return y, s1, s2, g
 
     monkeypatch.setenv("MXNET_USE_PALLAS", "0")
-    monkeypatch.setitem(pcb._STATE, "enabled", None)
     yr, s1r, s2r, gr = all_outputs()
 
     monkeypatch.setenv("MXNET_USE_PALLAS", "1")
     monkeypatch.setenv("MXNET_PALLAS_INTERPRET", "1")
-    monkeypatch.setitem(pcb._STATE, "enabled", None)
     with parallel.make_mesh(**axes):
         yf, s1f, s2f, gf = all_outputs()
 
@@ -309,7 +306,6 @@ def test_pallas_bwd_matches_xla_bwd(case, monkeypatch):
                 + (s1 * s1).sum() * 1e-3 + s2.sum() * 1e-3)
 
     monkeypatch.setenv("MXNET_PALLAS_INTERPRET", "1")
-    monkeypatch.setitem(pcb._STATE, "enabled", None)
 
     monkeypatch.setenv("MXNET_FUSED_CONVBN_BWD", "0")
     ref = jax.grad(loss, argnums=(0, 1, 2, 3))(x, w, sc, bi)
@@ -337,7 +333,6 @@ def test_pallas_bwd_strided_falls_back(monkeypatch):
     dgrad of a strided conv needs interior-dilated pads)."""
     monkeypatch.setenv("MXNET_PALLAS_INTERPRET", "1")
     monkeypatch.setenv("MXNET_FUSED_CONVBN_BWD", "1")
-    monkeypatch.setitem(pcb._STATE, "enabled", None)
     calls = {"bwd": 0}
     real = pcb._pallas_unit_bwd
 
@@ -367,7 +362,6 @@ def test_pallas_bwd_multi_program_accumulation(monkeypatch):
     one program, which would leave that path untested."""
     monkeypatch.setenv("MXNET_PALLAS_INTERPRET", "1")
     monkeypatch.setenv("MXNET_FUSED_CONVBN_BWD", "1")
-    monkeypatch.setitem(pcb._STATE, "enabled", None)
     monkeypatch.setattr(pcb, "_COLS_BUDGET_BYTES", 1)  # nb floor = 1
 
     shape, co, kernel, pad = (4, 6, 6, 8), 8, (3, 3), (1, 1)
@@ -417,7 +411,6 @@ def test_sharded_pallas_bwd_matches_fallback(axes, monkeypatch):
                 + (s1 * s1).sum() * 1e-3 + s2.sum() * 1e-3)
 
     monkeypatch.setenv("MXNET_USE_PALLAS", "0")
-    monkeypatch.setitem(pcb._STATE, "enabled", None)
     ref = jax.grad(loss, argnums=(0, 1, 2, 3))(x, w, sc, bi)
 
     calls = {"sharded_bwd": 0}
@@ -431,7 +424,6 @@ def test_sharded_pallas_bwd_matches_fallback(axes, monkeypatch):
     monkeypatch.setenv("MXNET_USE_PALLAS", "1")
     monkeypatch.setenv("MXNET_PALLAS_INTERPRET", "1")
     monkeypatch.setenv("MXNET_FUSED_CONVBN_BWD", "1")
-    monkeypatch.setitem(pcb._STATE, "enabled", None)
     with parallel.make_mesh(**axes):
         got = jax.grad(loss, argnums=(0, 1, 2, 3))(x, w, sc, bi)
     assert calls["sharded_bwd"] == 1
@@ -440,3 +432,33 @@ def test_sharded_pallas_bwd_matches_fallback(axes, monkeypatch):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=1e-4, atol=1e-4,
                                    err_msg=f"{name}")
+
+
+def test_refused_shape_warns_once_and_is_counted(monkeypatch, caplog):
+    """A shape the compiler refuses runs the XLA path — said once per
+    shape at WARNING with the compiler's own message, and visible in
+    unit_counts() (a "fused" number whose units all ran XLA measured the
+    restructured graph, not the kernel)."""
+    import logging
+
+    class _Refuses:
+        def lower(self, *a):
+            raise RuntimeError("scoped vmem limit exceeded by 5.23M")
+
+    monkeypatch.setattr(pcb, "_SHAPE_OK", {})
+    monkeypatch.setattr(pcb, "_pallas_wanted", lambda: True)
+    monkeypatch.setattr(pcb.jax, "jit", lambda fn: _Refuses())
+    x = jnp.asarray(_rand((2, 8, 8, 8)))
+    w = jnp.asarray(_rand((8, 8, 3, 3), scale=0.2))
+    before = pcb.unit_counts()
+    with caplog.at_level(logging.WARNING):
+        for _ in range(2):
+            y, _s1, _s2 = pcb.fused_conv_unit(x, w, kernel=(3, 3),
+                                              pad=(1, 1))
+    assert y.shape == (2, 8, 8, 8)
+    refusals = [r for r in caplog.records if "refused" in r.getMessage()]
+    assert len(refusals) == 1
+    assert "scoped vmem limit exceeded by 5.23M" in refusals[0].getMessage()
+    after = pcb.unit_counts()
+    assert after["xla"] - before["xla"] == 2
+    assert after["pallas"] == before["pallas"]

@@ -28,6 +28,12 @@ Launchers (the dmlc tracker family):
 
 `--dry-run` prints the commands instead of executing (used by tests and
 for copy-paste into other schedulers).
+
+One process per chip: the launcher itself never imports jax, and every
+worker inherits its environment.  Several `local` workers on one machine
+cannot share its chips, so that mode is a CPU substrate (tests and the
+README run it under JAX_PLATFORMS=cpu); on a pod it is one worker per
+host.
 """
 from __future__ import annotations
 

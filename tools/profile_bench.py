@@ -23,6 +23,8 @@ import sys
 import time
 from collections import defaultdict
 
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
 
 def capture(args) -> str:
     import numpy as np
