@@ -1,0 +1,8 @@
+"""Layer: models.  Device time per step on chip 0 of the forward ops:
+those whose scope in the step program (block and op scopes, read through
+parallel.spmd.step_programs()) lies under `jvp(` and not `transpose(`."""
+from harness import scope_time
+
+
+def read(run):
+    return scope_time.phase_ms(run, "forward")

@@ -161,8 +161,7 @@ def resnet_phase(cache, *, n_dev=1, batch=256, image=224, warmup=3,
     hits = jax_after["hits"] - jax_before["hits"]
     misses = jax_after["misses"] - jax_before["misses"]
     stats = jax.devices()[0].memory_stats()
-    (step_fn, _cost), = trainer._step_fns.values()
-    program = step_fn.memory_analysis()
+    program = trainer.step_executable().memory_analysis()
     out = {
         "n_dev": n_dev, "batch": batch, "first_loss": first_loss,
         "last_loss": last_loss,

@@ -20,7 +20,7 @@ import os
 import threading
 from typing import Dict
 
-__all__ = ["JaxCache", "configure", "DEFAULT_DIR"]
+__all__ = ["JaxCache", "configure", "counts", "DEFAULT_DIR"]
 
 _REPO = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
@@ -30,30 +30,46 @@ _HIT = "/jax/compilation_cache/cache_hits"
 _MISS = "/jax/compilation_cache/cache_misses"
 
 
+_lock = threading.Lock()
+_counts = {"hits": 0, "misses": 0}
+_listening = False
+
+
+def _on_event(event: str, **_kw) -> None:
+    if event == _HIT or event == _MISS:
+        with _lock:
+            _counts["hits" if event == _HIT else "misses"] += 1
+
+
+def counts() -> Dict[str, int]:
+    """{"hits": executables JAX served from its persistent cache,
+    "misses": executables compiled and written there} in this process,
+    counted from the first call (which starts listening).  Programs
+    under JAX's own thresholds (compile time, entry size) are neither.
+    A rise in "hits" across one ``lower().compile()`` is the only sign
+    that the executable was loaded and not built."""
+    global _listening
+    with _lock:
+        if not _listening:
+            import jax
+
+            jax.monitoring.register_event_listener(_on_event)
+            _listening = True
+        return dict(_counts)
+
+
 class JaxCache:
     """The cache directory in effect, and how often JAX has hit and
     missed it in this process since this object was made."""
 
     def __init__(self, directory: str):
-        import jax
-
         self.directory = directory
-        self._lock = threading.Lock()
-        self._counts = {"hits": 0, "misses": 0}
-        jax.monitoring.register_event_listener(self._on_event)
-
-    def _on_event(self, event: str, **_kw) -> None:
-        if event == _HIT or event == _MISS:
-            with self._lock:
-                self._counts["hits" if event == _HIT else "misses"] += 1
+        self._base = counts()
 
     def counts(self) -> Dict[str, int]:
-        """{"hits": executables served from the persistent cache,
-        "misses": executables compiled and written there}.  Programs
-        under JAX's own thresholds (compile time, entry size) are
-        neither."""
-        with self._lock:
-            return dict(self._counts)
+        """:func:`counts` since this object was made."""
+        now = counts()
+        return {k: now[k] - self._base[k] for k in now}
 
 
 def configure() -> JaxCache:

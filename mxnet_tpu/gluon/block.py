@@ -22,6 +22,7 @@ writes.
 """
 from __future__ import annotations
 
+import contextlib
 import json
 import re
 import threading
@@ -106,9 +107,30 @@ class TraceScope(threading.local):
 class _TraceState(threading.local):
     def __init__(self):
         self.scope: Optional["ActiveTrace"] = None
+        self.block_prefix = ""    # prefix of the enclosing traced block
 
 
 _TRACE = _TraceState()
+
+
+@contextlib.contextmanager
+def _block_scope(block: "Block"):
+    """``jax.named_scope`` of one traced block, so the compiled program's
+    ``op_name`` metadata (and the profile viewer) carries the block path
+    ``bertmodel0/encoder/layers/layer3/attn/...``.  Gluon names repeat
+    the parents' prefixes, so a component is the block's name less the
+    enclosing traced block's prefix where it starts with it, the whole
+    name otherwise.  Trace time only: nothing here runs per step."""
+    outer = _TRACE.block_prefix
+    name = block._name
+    if outer and name.startswith(outer):
+        name = name[len(outer):]
+    _TRACE.block_prefix = block._prefix
+    try:
+        with jax.named_scope(name or type(block).__name__):
+            yield
+    finally:
+        _TRACE.block_prefix = outer
 
 
 class ActiveTrace:
@@ -610,37 +632,38 @@ class HybridBlock(Block):
                     params[name] = ts.value_of(p)
                 else:
                     params[name] = p.data().data
-            if (ts is not None and getattr(ts, "mirror", False)
-                    and self._reg_params
-                    and all(hasattr(a, "dtype") for a in args)):
-                # gradient mirroring: each PARAM-BEARING sub-block is a
-                # remat SEGMENT — the backward recomputes its activations
-                # from its inputs instead of keeping them live across the
-                # whole program.  Param-less containers are NOT wrapped
-                # (an outer whole-function checkpoint would only add a
-                # redundant full recompute), and blocks with non-array
-                # extra args are left unwrapped.  Aux updates (BatchNorm
-                # stats) made inside the segment are returned THROUGH the
-                # checkpoint boundary and replayed onto the outer trace —
-                # letting the inner tracers escape via the side channel
-                # would be an UnexpectedTracerError.
-                outer = ts
-                aux_params_cell = [()]
+            with _block_scope(self):
+                if (ts is not None and getattr(ts, "mirror", False)
+                        and self._reg_params
+                        and all(hasattr(a, "dtype") for a in args)):
+                    # gradient mirroring: each PARAM-BEARING sub-block is a
+                    # remat SEGMENT — the backward recomputes its activations
+                    # from its inputs instead of keeping them live across the
+                    # whole program.  Param-less containers are NOT wrapped
+                    # (an outer whole-function checkpoint would only add a
+                    # redundant full recompute), and blocks with non-array
+                    # extra args are left unwrapped.  Aux updates (BatchNorm
+                    # stats) made inside the segment are returned THROUGH the
+                    # checkpoint boundary and replayed onto the outer trace —
+                    # letting the inner tracers escape via the side channel
+                    # would be an UnexpectedTracerError.
+                    outer = ts
+                    aux_params_cell = [()]
 
-                def seg(xx, pp, *targs):
-                    inner = ActiveTrace(outer.param_values, outer.train)
-                    inner.mirror = True
-                    with inner:
-                        out = self.hybrid_forward(F_PURE, xx, *targs,
-                                                  **pp)
-                    aux_params_cell[0] = tuple(inner.aux_params)
-                    return out, tuple(inner.aux_values)
+                    def seg(xx, pp, *targs):
+                        inner = ActiveTrace(outer.param_values, outer.train)
+                        inner.mirror = True
+                        with inner:
+                            out = self.hybrid_forward(F_PURE, xx, *targs,
+                                                      **pp)
+                        aux_params_cell[0] = tuple(inner.aux_params)
+                        return out, tuple(inner.aux_values)
 
-                out, aux_vals = jax.checkpoint(seg)(x, params, *args)
-                for p, v in zip(aux_params_cell[0], aux_vals):
-                    ts.add_aux_update(p, v)
-                return out
-            return self.hybrid_forward(F_PURE, x, *args, **params)
+                    out, aux_vals = jax.checkpoint(seg)(x, params, *args)
+                    for p, v in zip(aux_params_cell[0], aux_vals):
+                        ts.add_aux_update(p, v)
+                    return out
+                return self.hybrid_forward(F_PURE, x, *args, **params)
 
         if self._active:
             if self._cached_op is None:

@@ -107,6 +107,7 @@ def _attention_pallas(q, k, v, mask, scale, causal=False):
         out_specs=pl.BlockSpec((1, bq, d), lambda b, i: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, s_pad, d), q.dtype),
         interpret=env.get_bool("MXNET_PALLAS_INTERPRET"),
+        name="mx_attention_fwd",    # the kernel's name in a device profile
     )(q, k, v, mask[:, None, :])
     return out[:, :s]
 

@@ -472,8 +472,16 @@ def grad_fn(op: Operator, attrs_key: Tuple, argnums: Tuple[int, ...]) -> Callabl
 
 def apply_pure(name: str, *arrays, **attrs):
     """Run op on raw jax values — the path used inside traced (hybridized)
-    programs, where inputs are jax tracers and no wrapping happens."""
-    return get_op(name).fn(*arrays, **attrs)
+    programs, where inputs are jax tracers and no wrapping happens.
+
+    The op's work is traced under ``jax.named_scope`` of its registered
+    name (``BatchNorm``, ``Convolution``, ``dot_product_attention``...):
+    the vocabulary that ``parallel.spmd.step_programs()`` and a device
+    profile key on.  It does not change when XLA renames a fusion or a
+    user renames a block."""
+    op = get_op(name)
+    with jax.named_scope(op.name):
+        return op.fn(*arrays, **attrs)
 
 
 # --------------------------------------------------------------------------

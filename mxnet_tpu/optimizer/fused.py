@@ -52,6 +52,7 @@ from ..telemetry.mxprof import costs as _costs
 from ..util import env as _env
 from .. import compile_cache as _cc
 from ..compile_cache import audit as _ir_audit
+from ..compile_cache import jax_cache as _jax_cache
 from .optimizer import Optimizer, Updater
 
 __all__ = ["FusedUpdater", "FusedUnsupported", "ExecutableCache",
@@ -74,15 +75,21 @@ class _Entry:
     once at insert time for fresh builds AND persistent-cache loads
     alike — a warm restart keeps its cost metadata.  ``fingerprint``
     is the HLO-module identity riding beside it (mxtriage regression
-    attribution: "did the compiled program change")."""
+    attribution: "did the compiled program change").  ``origin`` is
+    "compiled" where XLA built it in this process and "cache" where it
+    was loaded (the ``.mxcc`` store or JAX's persistent cache).
+    ``program`` is the executable's scope table, built on the first
+    call of ``parallel.spmd.step_programs()`` and never before."""
 
-    __slots__ = ("fn", "tick", "cost", "fingerprint")
+    __slots__ = ("fn", "tick", "cost", "fingerprint", "origin", "program")
 
-    def __init__(self, fn, cost=None, fingerprint=None):
+    def __init__(self, fn, cost=None, fingerprint=None, origin="compiled"):
         self.fn = fn
         self.tick = next(_TICKS)
         self.cost = cost
         self.fingerprint = fingerprint
+        self.origin = origin
+        self.program = None
 
 
 class ExecutableCache:
@@ -159,6 +166,7 @@ class ExecutableCache:
         the mxir program auditor so MX014 can verify the lowered
         module actually aliases something."""
         t0 = time.perf_counter()
+        jax_hits = _jax_cache.counts()["hits"]
         cell = {}
 
         def text():
@@ -209,7 +217,10 @@ class ExecutableCache:
             prior = self.data.get(sig)
             if prior is not None:
                 return prior.fn
-            self.data[sig] = _Entry(compiled, cost, fp)
+            loaded = origin != "compiled" \
+                or _jax_cache.counts()["hits"] > jax_hits
+            self.data[sig] = _Entry(compiled, cost, fp,
+                                    "cache" if loaded else "compiled")
             if origin == "compiled":
                 self.compiles += 1
                 self.seconds += dt

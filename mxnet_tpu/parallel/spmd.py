@@ -18,6 +18,7 @@ math to KVStore('nccl') push/pull in the reference, one fused program here.
 from __future__ import annotations
 
 import functools
+import re
 from collections import OrderedDict
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
@@ -40,7 +41,7 @@ from .sharding import (ShardingRules, DEFAULT_RULES, shard_batch,
                        zero_state_spec)
 
 __all__ = ["SPMDTrainer", "functional_optimizer", "FunctionalOptimizer",
-           "step_compile_stats"]
+           "step_compile_stats", "step_programs"]
 
 # mesh-wide fwd+bwd+update executables: routed through the persistent
 # compile cache (PR 7) so a same-topology restart warm-starts the step
@@ -55,6 +56,73 @@ def step_compile_stats():
     """SPMDTrainer step-executable builds/loads in this process (same
     shape as optimizer.fused.compile_stats)."""
     return _STEP_CACHE.stats()
+
+
+_HLO_OP_NAME = re.compile(r'op_name="([^"]*)"')
+_HLO_CALLS = re.compile(r"\bcalls=%?([\w.\-]+)")
+_HLO_MATMUL = re.compile(r"(?<![%\w.\-])(?:convolution|dot)\(")
+_HLO_FUSION = re.compile(r"(?<![%\w.\-])fusion\(")
+
+
+def program_table(hlo_text: str) -> Dict[str, Any]:
+    """``{"module", "scoped", "ops"}`` of one compiled program's text
+    (``Compiled.as_text()``): ``ops`` maps every instruction outside a
+    fused computation, by its name without ``%``, to its ``op_name``
+    (the name stack it was traced under: ``jit(mx_train_step)/
+    jvp(net0)/dense0/FullyConnected/dot_general``).
+
+    XLA keeps ONE instruction's metadata for a fusion, as a rule its
+    root's, and an optimizer update rides in the epilogue of the weight-
+    gradient convolution: by the root, whole convolutions would be
+    booked to ``mx.update``.  So a fusion whose computation holds a
+    convolution or a dot takes THAT instruction's ``op_name``, and its
+    own only where it holds none.
+
+    ``scoped`` is false where no ``op_name`` in the text (fused
+    instructions included) holds ``mx.update``: an executable from
+    before the scopes existed (they are not in JAX's compile-cache key)
+    says so instead of reading as zeros."""
+    module, comp, scoped = "", None, False
+    matmul_of: Dict[str, str] = {}      # fused computation -> op_name
+    rows = []       # (computation, instruction, op_name, fusion's callee)
+    for line in hlo_text.splitlines():
+        if line.startswith("HloModule "):
+            module = line[len("HloModule "):].split(",")[0].strip()
+        elif line[:1] not in (" ", "}", "") and line.endswith("{"):
+            comp = line.split(" (")[0].replace("ENTRY ", "").lstrip("%")
+        elif " = " in line and line.startswith("  "):
+            lhs, rest = line.split(" = ", 1)
+            m = _HLO_OP_NAME.search(rest)
+            own = m.group(1) if m else ""
+            scoped = scoped or "mx.update" in own
+            if own and comp not in matmul_of and _HLO_MATMUL.search(rest):
+                matmul_of[comp] = own
+            fusion = _HLO_FUSION.search(rest) and _HLO_CALLS.search(rest)
+            rows.append((comp, lhs.split()[-1].lstrip("%"), own,
+                         fusion.group(1) if fusion else None))
+    fused = {callee for _c, _n, _o, callee in rows if callee}
+    ops = {name: matmul_of.get(callee, own)
+           for comp_, name, own, callee in rows if comp_ not in fused}
+    return {"module": module, "scoped": scoped, "ops": ops}
+
+
+def step_programs() -> List[Dict[str, Any]]:
+    """The scope table of every step executable built or loaded in this
+    process and still cached, oldest first: ``{"module", "origin":
+    "compiled" | "cache", "scoped", "ops": {instruction: op_name}}``
+    (see :func:`program_table`).  A device trace names instructions
+    (``%fusion.14``) and not scopes; this is the program's own join
+    from the one to the other, for whoever reads a profile.
+
+    Built on the first call and memoised per executable: the text of a
+    ResNet-50 step is megabytes, so nothing here runs in ``step()`` or
+    in set-up."""
+    out = []
+    for ent in list(_STEP_CACHE.data.values()):
+        if ent.program is None:
+            ent.program = program_table(ent.fn.as_text())
+        out.append({"origin": ent.origin, **ent.program})
+    return out
 
 
 # class qualname + param names + avals do NOT pin the model's forward
@@ -428,7 +496,7 @@ class SPMDTrainer:
 
         name_of = {id(p): n for n, p in plist}
 
-        def pure_step(params, opt_state, inputs, labels, key, lr, t):
+        def mx_train_step(params, opt_state, inputs, labels, key, lr, t):
             def loss_fn(pv):
                 trace = ActiveTrace(
                     {id(p): pv[n] for n, p in plist}, train=True)
@@ -443,7 +511,8 @@ class SPMDTrainer:
                         rnd.key_provider(rnd.KeyProvider(key)):
                     out = block.forward(*inputs)
                     outs = out if isinstance(out, (list, tuple)) else (out,)
-                    l = loss(outs[0], *labels)
+                    with jax.named_scope("mx.loss"):
+                        l = loss(outs[0], *labels)
                 lval = jnp.mean(l if not isinstance(l, (list, tuple))
                                 else l[0])
                 # aux (BatchNorm moving stats) keyed BY NAME in the traced
@@ -480,34 +549,35 @@ class SPMDTrainer:
                 return nw.astype(w.dtype), tuple(
                     sv.astype(state[i].dtype) for i, sv in enumerate(ns))
 
-            for names, lm, wm in trainer._flat_groups:
-                # concat in NATIVE dtypes — upcasts happen in-register
-                # inside the one fused update kernel, never materialized
-                n_st = len(opt_state[names[0]])
-                fw = jnp.concatenate(
-                    [params[n].reshape(-1) for n in names])
-                fg = jnp.concatenate(
-                    [grads[n].reshape(-1) for n in names])
-                fs = tuple(
-                    jnp.concatenate(
-                        [opt_state[n][i].reshape(-1) for n in names])
-                    for i in range(n_st))
-                nw, ns = apply_one(names[0], fw, fg, fs, lm, wm)
-                off = 0
-                for n in names:
-                    p = params[n]
-                    sz = int(np.prod(p.shape)) if p.shape else 1
-                    sl = lax.slice(nw, (off,), (off + sz,))
-                    new_params[n] = sl.reshape(p.shape).astype(p.dtype)
-                    new_state[n] = tuple(
-                        lax.slice(s, (off,), (off + sz,))
-                        .reshape(p.shape).astype(opt_state[n][i].dtype)
-                        for i, s in enumerate(ns))
-                    off += sz
-            for n in trainer._per_param:
-                lm, wm = mults[n]
-                new_params[n], new_state[n] = apply_one(
-                    n, params[n], grads[n], opt_state[n], lm, wm)
+            with jax.named_scope("mx.update"):
+                for names, lm, wm in trainer._flat_groups:
+                    # concat in NATIVE dtypes — upcasts happen in-register
+                    # inside the one fused update kernel, never materialized
+                    n_st = len(opt_state[names[0]])
+                    fw = jnp.concatenate(
+                        [params[n].reshape(-1) for n in names])
+                    fg = jnp.concatenate(
+                        [grads[n].reshape(-1) for n in names])
+                    fs = tuple(
+                        jnp.concatenate(
+                            [opt_state[n][i].reshape(-1) for n in names])
+                        for i in range(n_st))
+                    nw, ns = apply_one(names[0], fw, fg, fs, lm, wm)
+                    off = 0
+                    for n in names:
+                        p = params[n]
+                        sz = int(np.prod(p.shape)) if p.shape else 1
+                        sl = lax.slice(nw, (off,), (off + sz,))
+                        new_params[n] = sl.reshape(p.shape).astype(p.dtype)
+                        new_state[n] = tuple(
+                            lax.slice(s, (off,), (off + sz,))
+                            .reshape(p.shape).astype(opt_state[n][i].dtype)
+                            for i, s in enumerate(ns))
+                        off += sz
+                for n in trainer._per_param:
+                    lm, wm = mults[n]
+                    new_params[n], new_state[n] = apply_one(
+                        n, params[n], grads[n], opt_state[n], lm, wm)
             # aux state (BatchNorm moving stats) accumulates across steps:
             # fold the traced updates back into the param dict so the next
             # step's trace reads them (stop_gradient — not a learnable path)
@@ -515,7 +585,7 @@ class SPMDTrainer:
                 new_params[n] = lax.stop_gradient(v).astype(params[n].dtype)
             return new_params, new_state, lval, aux
 
-        return pure_step
+        return mx_train_step
 
     def _opt_static_fingerprint(self) -> Tuple:
         """Hashable fingerprint of the optimizer attrs BAKED into the
@@ -607,66 +677,92 @@ class SPMDTrainer:
     def step(self, *args) -> NDArray:
         """Run one training step on a global batch; returns the loss
         (async — only .asnumpy() blocks).  The last ``n_labels`` args are
-        labels, the rest model inputs."""
+        labels, the rest model inputs.
+
+        The call and its five phases are host spans in a ``jax.profiler``
+        trace (``mx.step`` and ``mx.step.place`` / ``.scalars`` /
+        ``.get_step`` / ``.dispatch`` / ``.rebind``, each carrying the
+        step number), on the clock the device lines are on; outside a
+        profiler session an annotation costs under a microsecond."""
         if _goodput._ACTIVE:
             # first post-resume step entry closes the goodput
             # preemption-recovery window (one falsy check when off)
             _goodput.on_step_entry()
-        n_lab = self.n_labels
-        if n_lab == 0:
-            inputs, labels = args, ()
-        else:
-            inputs, labels = args[:-n_lab], args[-n_lab:]
-        bspecs = self._batch_spec or [None] * len(inputs)
-        lspecs = self._label_spec or [None] * len(labels)
-        ivals = tuple(self._place(x, s) for x, s in zip(inputs, bspecs))
-        lvals = tuple(self._place(x, s) for x, s in zip(labels, lspecs))
-        self._t += 1
-        self._optimizer._update_count(0)
-        lr = jnp.asarray(self._optimizer.learning_rate, jnp.float32)
-        t = jnp.asarray(self._t, jnp.int32)
-        key = rnd.next_key()
-        args = (self.params, self.opt_state, ivals, lvals, key, lr, t)
-        ikey = tuple((tuple(v.shape), str(v.dtype))
-                     for v in ivals + lvals)
-        step, step_cost = self._get_step(args, ikey)
-        if not _tracing.active():
-            out = step(*args)
-        else:
-            if _tracing._ENABLED:
-                for ax, size in self.mesh.axis_sizes.items():
-                    _ins.step_layout_axis_size(ax).set(size)
-                factor = 1
-                if self._zero:
-                    for ax in ("dp", "fsdp"):
-                        factor *= self.mesh.size(ax)
-                _ins.step_state_shard_factor().set(factor)
-            with _tracing.span("spmd-step", cat="training",
-                               metric=_ins.training_phase_seconds(
-                                   "spmd-step")
-                               if _tracing._ENABLED else None):
-                out = step(*args)
-            snk = _tracing._SINK
-            if snk is not None and step_cost is not None:
-                # whole-step program: forward+backward+update FLOPs in
-                # one executable — the gspmd path's MFU counts
-                # everything.  AFTER the span: this step's record only
-                # closes when the NEXT spmd-step span arrives, so flops
-                # reported before the span would land one record early
-                # (and double the first closed record's MFU).
-                snk.on_flops(_STEP_CACHE.site, step_cost)
-        self.params, self.opt_state, lval, aux = out
-        # rebind aux state (BatchNorm moving stats) by parameter NAME
-        for n, v in aux.items():
-            self._param_by_name[n].data()._data = v
-        if _mxhealth._ACTIVE:
-            # loss-spike detection feed: the device scalar is handed
-            # off as-is; the monitor's fetch thread syncs it, the step
-            # path never does
-            _mxhealth.observe_loss(lval)
-        from ..context import current_context
+        span, n = _tracing.annotation, self._t + 1
+        with span("mx.step", step=n):
+            with span("mx.step.place", step=n):
+                n_lab = self.n_labels
+                if n_lab == 0:
+                    inputs, labels = args, ()
+                else:
+                    inputs, labels = args[:-n_lab], args[-n_lab:]
+                bspecs = self._batch_spec or [None] * len(inputs)
+                lspecs = self._label_spec or [None] * len(labels)
+                # one line each: MXLINT_BASELINE.json knows them by their text
+                ivals = tuple(self._place(x, s) for x, s in zip(inputs, bspecs))
+                lvals = tuple(self._place(x, s) for x, s in zip(labels, lspecs))
+            with span("mx.step.scalars", step=n):
+                self._t += 1
+                self._optimizer._update_count(0)
+                lr = jnp.asarray(self._optimizer.learning_rate, jnp.float32)
+                t = jnp.asarray(self._t, jnp.int32)
+                key = rnd.next_key()
+            with span("mx.step.get_step", step=n):
+                args = (self.params, self.opt_state, ivals, lvals, key, lr, t)
+                ikey = tuple((tuple(v.shape), str(v.dtype))
+                             for v in ivals + lvals)
+                step, step_cost = self._get_step(args, ikey)
+            with span("mx.step.dispatch", step=n):
+                out = self._dispatch(step, step_cost, args)
+            with span("mx.step.rebind", step=n):
+                self.params, self.opt_state, lval, aux = out
+                # rebind aux state (BatchNorm moving stats) by parameter NAME
+                for name, v in aux.items():
+                    self._param_by_name[name].data()._data = v
+                if _mxhealth._ACTIVE:
+                    # loss-spike detection feed: the device scalar is handed
+                    # off as-is; the monitor's fetch thread syncs it, the
+                    # step path never does
+                    _mxhealth.observe_loss(lval)
+                from ..context import current_context
 
-        return NDArray(lval, ctx=current_context())
+                return NDArray(lval, ctx=current_context())
+
+    def _dispatch(self, step, step_cost, args):
+        """The executable call, inside the telemetry that times it."""
+        if not _tracing.active():
+            return step(*args)
+        if _tracing._ENABLED:
+            for ax, size in self.mesh.axis_sizes.items():
+                _ins.step_layout_axis_size(ax).set(size)
+            factor = 1
+            if self._zero:
+                for ax in ("dp", "fsdp"):
+                    factor *= self.mesh.size(ax)
+            _ins.step_state_shard_factor().set(factor)
+        with _tracing.span("spmd-step", cat="training",
+                           metric=_ins.training_phase_seconds(
+                               "spmd-step")
+                           if _tracing._ENABLED else None):
+            out = step(*args)
+        snk = _tracing._SINK
+        if snk is not None and step_cost is not None:
+            # whole-step program: forward+backward+update FLOPs in
+            # one executable — the gspmd path's MFU counts
+            # everything.  AFTER the span: this step's record only
+            # closes when the NEXT spmd-step span arrives, so flops
+            # reported before the span would land one record early
+            # (and double the first closed record's MFU).
+            snk.on_flops(_STEP_CACHE.site, step_cost)
+        return out
+
+    def step_executable(self):
+        """The compiled step program of the input shapes stepped last (a
+        ``jax.stages.Compiled``: ``memory_analysis()``, ``as_text()``,
+        ``cost_analysis()``), or None before the first step."""
+        if not self._step_fns:
+            return None
+        return next(reversed(self._step_fns.values()))[0]
 
     @property
     def learning_rate(self):
