@@ -12,7 +12,10 @@ through the entry points a user calls, at the full width of ResNet-50:
   3. gluon      the README's front door: initialize(ctx=mx.tpu(0)),
                 hybridize(), gluon.Trainer, record/backward/step
   4. attention  ops/pallas_attention._attend compiled by Mosaic, against
-                the XLA reference, at BERT-base and NMT head shapes
+                the XLA reference, at BERT-base and NMT head shapes; then
+                the training route (dropout on the probabilities): value
+                and gradients of the fused kernels against the XLA path
+                under the identical hash mask, at the benchmark's shapes
   5. multichip  (only when more than one chip is visible) the step of 2
                 under make_mesh(dp=n) at batch 256*n
 
@@ -44,6 +47,8 @@ import numpy as np
 # at S=64, causal.  (batch*heads, seq, head_dim, causal)
 ATTENTION_SHAPES = ((384, 128, 64, False), (384, 512, 64, False),
                     (512, 64, 64, True))
+# the two BERT-base benchmark cells: (batch, heads, seq, head_dim)
+ATTENTION_TRAIN_SHAPES = ((40, 12, 512, 64), (264, 12, 128, 64))
 
 
 def _require(cond, message) -> None:
@@ -249,11 +254,11 @@ def gluon_phase(ctx, *, batch=512, steps=20) -> dict:
 
 
 def attention_phase(shapes=ATTENTION_SHAPES, *, dtype="bfloat16",
-                    expect_mosaic=True) -> dict:
-    """`_attend` against `dot_product_attention_ref` at bf16 tolerance.
-    The op is called directly: BERT's default dropout=0.1 routes a
-    training step to `_attention_with_prob_dropout`, which never reaches
-    the kernel.  With `expect_mosaic` the lowered program must hold the
+                    expect_mosaic=True,
+                    train_shapes=ATTENTION_TRAIN_SHAPES) -> dict:
+    """`_attend` against `dot_product_attention_ref` at bf16 tolerance
+    (the dropout-free call, `_attend` called directly), then the training
+    stage below.  With `expect_mosaic` the lowered program must hold the
     Mosaic custom call, so the XLA reference cannot pass in its place."""
     import jax
     import jax.numpy as jnp
@@ -288,7 +293,104 @@ def attention_phase(shapes=ATTENTION_SHAPES, *, dtype="bfloat16",
         name = f"bh{bh}_s{seq}_d{dim}" + ("_causal" if causal else "")
         out[name] = {"max_abs_err": round(err, 5),
                      "first_call_s": round(seconds, 2)}
+    out.update(attention_train_stage(train_shapes, dtype=dtype,
+                                     expect_mosaic=expect_mosaic))
     print(f"[attention] {out}", flush=True)
+    return out
+
+
+def attention_train_stage(shapes, *, dtype="bfloat16", expect_mosaic=True,
+                          dropout=0.1, n_dev=1) -> dict:
+    """Value and `jax.grad` of `dot_product_attention` in training with
+    dropout on the probabilities, through the op's own route, against
+    `_attention_with_prob_dropout` (XLA, heads split off in HBM) under the
+    IDENTICAL mask: both take it from the same hash of (key, b*h, q, k).
+    Prefix-valid masks include lengths 2 and 3, so whole key blocks are
+    masked for some rows.  With `expect_mosaic` the lowered gradient must
+    hold the forward and the backward kernel and must have counted
+    `fused_train`.  With `n_dev` > 1 the batch is `n_dev` times the
+    shape's, split over a dp mesh, and the route is traced inside the
+    mesh's scope as `SPMDTrainer` traces a step: GSPMD cannot partition a
+    Mosaic call, so the route runs one call a chip, and the mask, indexed
+    by GLOBAL batch row, must still be the one-program reference's."""
+    import contextlib
+
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import NamedSharding, PartitionSpec
+
+    from mxnet_tpu import parallel
+    from mxnet_tpu.ops import pallas_attention as pa
+
+    out = {}
+    mesh = parallel.make_mesh(dp=n_dev) if n_dev > 1 else None
+    for batch, heads, seq, dim in shapes:
+        batch *= n_dev
+        rng = np.random.RandomState(seq)
+        # packed (B, S, H*D), as the q/k/v projections hand it over
+        q, k, v, ct = (jnp.asarray(rng.randn(batch, seq, heads * dim), dtype)
+                       for _ in range(4))
+        valid = rng.randint(2, seq + 1, size=(batch,))
+        valid[:3] = (2, 3, seq)
+        mask = jnp.asarray(np.arange(seq)[None, :] < valid[:, None], dtype)
+        key = jax.random.PRNGKey(seq)
+        scale = 1.0 / np.sqrt(dim)
+
+        # everything an argument: a closed-over array is a constant of
+        # the program (a 220 MB executable at these shapes)
+        def weighed(o, ct):
+            return (o.astype(jnp.float32) * ct.astype(jnp.float32)).sum(), o
+
+        def route(q, k, v, ct, mask, key):
+            with mesh or contextlib.nullcontext():
+                return weighed(pa._dot_product_attention(
+                    q, k, v, mask, key, num_heads=heads, dropout=dropout,
+                    _train=True), ct)
+
+        def reference(q, k, v, ct, mask, key):
+            return weighed(pa._train_xla(
+                q, k, v, mask, pa._seed(pa._key_words(key)), heads, scale,
+                1.0 - dropout), ct)
+
+        operands = (q, k, v, ct, mask, key)
+        if mesh is not None:
+            rows = NamedSharding(mesh.mesh, PartitionSpec("dp"))
+            operands = (*(jax.device_put(x, rows) for x in operands[:5]),
+                        jax.device_put(key, NamedSharding(
+                            mesh.mesh, PartitionSpec())))
+        grad = jax.jit(jax.grad(route, argnums=(0, 1, 2), has_aux=True))
+        if expect_mosaic:
+            before = pa.route_counts()["fused_train"]
+            text = grad.lower(*operands).as_text()
+            _require(pa.route_counts()["fused_train"] == before + 1,
+                     f"the training call at S={seq} did not take the "
+                     f"fused route: {pa.route_counts()}")
+            for name in ("mx_attention_train_fwd", "mx_attention_train_bwd"):
+                _require(name in text and "tpu_custom_call" in text,
+                         f"no Mosaic call {name} in the lowered gradient "
+                         f"at S={seq}")
+        t0 = time.perf_counter()
+        got = jax.block_until_ready(grad(*operands))
+        seconds = time.perf_counter() - t0
+        ref = jax.jit(jax.grad(reference, argnums=(0, 1, 2),
+                               has_aux=True))(*operands)
+        errs = {}
+        for name, a, b in zip(("dq", "dk", "dv", "o"),
+                              (*got[0], got[1]), (*ref[0], ref[1])):
+            a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+            _require(np.isfinite(a).all(),
+                     f"non-finite {name} from the training route at S={seq}")
+            # bf16 operands on both sides: hold the error to the size of
+            # the tensor, as rounding of a sum over S keys is
+            errs[name] = float(np.abs(a - b).max() / np.abs(b).max())
+            _require(errs[name] < 2e-2,
+                     f"{name} of the training route at S={seq} is off the "
+                     f"reference under the same mask by {errs[name]:.4f} of "
+                     f"its largest value")
+        out[f"train_b{batch}_h{heads}_s{seq}_d{dim}" + (
+                f"_dp{n_dev}" if mesh is not None else "")] = {
+            "max_rel_err": {n: round(e, 5) for n, e in errs.items()},
+            "first_call_s": round(seconds, 2)}
     return out
 
 
@@ -338,6 +440,8 @@ def main() -> int:
     run("attention", attention_phase)
     n = len(devices)
     if n > 1:
+        run("attention_dp", attention_train_stage, ATTENTION_TRAIN_SHAPES,
+            n_dev=n)
         many = run("multichip", resnet_phase, cache, n_dev=n,
                    batch=256 * n, tile=n)
         # same seed, the base batch repeated on every chip: the first

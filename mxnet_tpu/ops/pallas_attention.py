@@ -6,32 +6,60 @@ _contrib_interleaved_matmul_selfatt_* used by GluonNLP BERT); this is the
 TPU-native equivalent per SURVEY.md §7 ("fused cells (RNN/attention) …
 in Pallas").
 
-Design:
-  * One Pallas kernel per (batch*head, q-block): the query block lives in
-    VMEM, keys/values for the whole sequence stream in as one block
-    (BERT-scale S·D fits VMEM easily; long-context goes through
-    parallel.ring instead), scores are computed on the MXU in fp32 and
-    never materialized in HBM — the flash-attention memory win.
-  * Backward = recompute-from-inputs via jax.vjp of the reference
-    (XLA) math under custom_vjp — XLA fuses it; activation memory stays
-    O(S·D) not O(S²).
-  * A program lowered for CPU has no Mosaic and takes the pure-XLA
-    path with identical semantics (or the Pallas interpreter under
-    MXNET_PALLAS_INTERPRET=1); MXNET_USE_PALLAS=0 selects the XLA path
-    anywhere.  Lowered for TPU, a lowering or compile failure raises.
+Which call takes which route (`route_counts()`; chosen at trace time from
+what the op can observe: shape, train mode, the platform the program is
+lowered for; no switch of its own):
+
+  * Dropout-free calls (inference, p=0): `_attend`.  One Pallas kernel per
+    (batch*head, q-block), the query block in VMEM, keys/values for the
+    whole sequence as one block, scores on the MXU in fp32 and never in
+    HBM; backward = recompute through jax.vjp of the XLA reference under
+    custom_vjp.  Counted `kernel_infer` (`reference` under
+    MXNET_USE_PALLAS=0).
+  * Training with dropout on the probabilities, self-attention shaped as
+    BERT's (not causal, sq == sk, a multiple of 128 up to 1024, heads of
+    64, 128 or 256 filling whole 128-lane blocks): `_attend_train`, two
+    fused kernels under one custom_vjp (`mx_attention_train_fwd`;
+    `mx_attention_train_bwd` = dQ, dK and dV together), adapted from
+    jax.experimental.pallas.ops.tpu.flash_attention.  They work in the
+    packed (B, S, H*D) layout: no S x S tensor and no head-split copy
+    reaches HBM; the dropout mask is regenerated in each kernel.  Under a
+    mesh of several devices whose only splitting axes are the batch's
+    (dp / fsdp) the kernels run per batch shard inside a shard_map (GSPMD
+    cannot partition a Mosaic call), the mask still indexed globally;
+    under a mesh that splits anything else the call takes the next route.
+    Counted `fused_train`.
+  * Every other training call with dropout (causal, cross, ragged
+    lengths: the NMT model, toy shapes; tensor- or sequence-parallel
+    meshes): `_attention_with_prob_dropout`, XLA, probabilities saved for
+    the backward.  Counted `xla_dropout`.
+
+The dropout mask is `dropout_keep_mask`: an integer hash of (key words,
+b*h, q, k) by GLOBAL index, the same bits in Mosaic, the interpreter, XLA
+and numpy.  Both training routes take it from there (one generator), which
+is why the dropout STREAM differs from the threefry `bernoulli` this op
+used before PR 26: same distribution, other draws, so a loss pinned under
+attention dropout moved once.
+
+A program lowered for CPU has no Mosaic and takes the pure-XLA path of
+the same function (or the Pallas interpreter under
+MXNET_PALLAS_INTERPRET=1); MXNET_USE_PALLAS=0 selects the XLA paths
+anywhere.  Lowered for TPU, a lowering or compile failure raises.
 """
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..telemetry import instruments as _instruments
 from ..util import env
 from .registry import register_op
 
-__all__ = ["dot_product_attention_ref"]
+__all__ = ["dot_product_attention_ref", "dropout_keep_mask", "route_counts"]
 
 
 def dot_product_attention_ref(q, k, v, mask, scale, causal=False):
@@ -150,12 +178,89 @@ def _attend_bwd(scale, causal, res, ct):
 _attend.defvjp(_attend_fwd, _attend_bwd)
 
 
+# ---------------------------------------------------------------------------
+# Training with dropout on the attention probabilities
+# ---------------------------------------------------------------------------
+# One mask generator for every route: an integer hash of (key words, b*h,
+# q, k) in plain uint32 arithmetic.  Being a pure function of GLOBAL
+# indices it gives the same bits inside a Mosaic kernel, in the Pallas
+# interpreter, in XLA and in numpy, whatever the block sizes; that is what
+# lets tier-1 hold the kernels' outputs AND gradients to a reference under
+# the identical mask (pltpu.prng_random_bits depends on the block layout
+# and never could).
+
+_M1 = np.uint32(0x7FEB352D)
+_M2 = np.uint32(0x846CA68B)
+_GOLDEN = np.uint32(0x9E3779B9)
+_S15 = np.uint32(15)
+_S16 = np.uint32(16)
+
+
+def _hash_qk(q_idx, k_idx, seq_k, key0):
+    """First round, shared by every head: (q, k) and the key's first word.
+    uint32 operands (numpy, jnp or kernel values); q*seq_k + k cannot wrap
+    below 65536 keys."""
+    x = (q_idx * np.uint32(seq_k) + k_idx) ^ key0
+    x = x ^ (x >> _S16)
+    x = x * _M1
+    return x ^ (x >> _S15)
+
+
+def _hash_head(bh_idx, key1):
+    """Per-head word: b*h and the key's second word through a full
+    two-multiply mix.  b*h enters here, not through bh*S*S, so no shape
+    can wrap 32 bits; in a kernel this is scalar work."""
+    x = bh_idx * _GOLDEN + key1
+    x = x ^ (x >> _S16)
+    x = x * _M1
+    x = x ^ (x >> _S15)
+    x = x * _M2
+    return x ^ (x >> _S16)
+
+
+def _hash_bits(h_qk, h_head):
+    """Second round, per (b*h, q, k): a word whose HIGH bits are uniform.
+    The threshold compare reads the high bits, which the closing
+    xor-shift of a full round would not change, so there is none."""
+    x = h_qk + h_head
+    x = x ^ (x >> _S16)
+    return x * _M2
+
+
+def _keep_threshold(keep):
+    """An element is kept when its bits are below this; the rounding of
+    keep*2^32 (2^-32) is far below bf16."""
+    return np.uint32(min(int(keep * 2.0 ** 32), 2 ** 32 - 1))
+
+
+def dropout_keep_mask(key_words, bh, seq_q, seq_k, keep, xp=jnp,
+                      first_head=0):
+    """The (bh, seq_q, seq_k) boolean kept-mask of attention dropout for
+    the two uint32 `key_words`; `xp` is jnp or numpy.  `first_head` is the
+    GLOBAL b*h index of row 0, for a caller that holds one batch shard."""
+    u32 = lambda n, shape: xp.arange(n, dtype=xp.uint32).reshape(shape)
+    h_qk = _hash_qk(u32(seq_q, (1, seq_q, 1)), u32(seq_k, (1, 1, seq_k)),
+                    seq_k, key_words[0])
+    h_head = _hash_head(
+        u32(bh, (bh, 1, 1)) + xp.asarray(first_head, dtype=xp.uint32),
+        key_words[1])
+    return _hash_bits(h_qk, h_head) < _keep_threshold(keep)
+
+
+def _key_words(rng_key):
+    """The first two uint32 words of a PRNG key, typed or raw."""
+    if jnp.issubdtype(rng_key.dtype, jax.dtypes.prng_key):
+        rng_key = jax.random.key_data(rng_key)
+    return rng_key.reshape(-1)[:2].astype(jnp.uint32)
+
+
 def _attention_with_prob_dropout(q, k, v, mask, scale, p, rng_key,
-                                 causal=False):
+                                 causal=False, first_head=0):
     """XLA path with dropout on the attention probabilities — the BERT /
-    reference training semantics (dropout on softmax(QK^T)).  Used when
-    dropout is active; XLA fuses it just as well, and the fused Pallas
-    kernel serves the dropout-free (inference / p=0) case."""
+    reference training semantics (dropout on softmax(QK^T)), the mask from
+    `dropout_keep_mask`.  Training calls the fused kernels do not cover
+    (causal, cross, ragged lengths) run this, and so does a fused-route
+    call in a program lowered for another platform than the TPU."""
     s = jnp.einsum("bqd,bkd->bqk", q, k,
                    preferred_element_type=jnp.float32) * scale
     if mask is not None:
@@ -166,9 +271,507 @@ def _attention_with_prob_dropout(q, k, v, mask, scale, p, rng_key,
         s = jnp.where(qpos >= jnp.arange(sk)[None, :], s, -1e30)
     p_attn = jax.nn.softmax(s, axis=-1).astype(v.dtype)
     keep = 1.0 - p
-    drop_mask = jax.random.bernoulli(rng_key, keep, p_attn.shape)
+    drop_mask = dropout_keep_mask(_key_words(rng_key), *s.shape, keep,
+                                  first_head=first_head)
     p_attn = p_attn * drop_mask.astype(p_attn.dtype) / keep
     return jnp.einsum("bqk,bkd->bqd", p_attn, v)
+
+
+# The fused route: two kernels under one custom_vjp, adapted from
+# jax.experimental.pallas.ops.tpu.flash_attention (saved row statistics,
+# bf16 operands with f32 accumulation, several heads per grid step), cut
+# to what BERT-shaped self-attention needs and extended by the dropout
+# hash and the key-validity mask.  A whole row of keys is one block
+# (S*D and a (block_q, S) f32 tile fit VMEM up to _FUSED_MAX_SEQ), so
+# the softmax is not tiled, the backward is ONE kernel (dQ, dK and dV
+# from a single recomputation of the probabilities and the mask), and no
+# S x S tensor is ever written to HBM.
+#
+#   forward   O_i = (sum_j D_ij P~_ij V_j) / (keep * l_i),
+#             P~ = exp(S - m), l_i = sum_j P~_ij over ALL j;
+#             saves lse_i = m_i + log l_i as (B, H, 1, S) f32
+#   backward  delta_i = rowsum(dO_i * O_i); P = exp(S - lse);
+#             dV = (P * D / keep)^T dO; dP = D * (dO V^T) / keep;
+#             dS = P * (dP - delta); dQ = scale dS K; dK = scale dS^T Q
+#             (in the kernel keep * dS = P * (D * dO V^T - keep * delta),
+#             and scale / keep multiplies the (S, D) results)
+#
+# The kernels read q, k, v and write o and the gradients in the PACKED
+# (B, S, H*D) layout the projections produce: a block is 128 lanes wide,
+# two heads of 64 side by side, and a head is taken out of it by zeroing
+# the other head's lanes in ONE operand of each matmul (a contraction
+# over 64 fills half the MXU's depth either way).  No head is ever split
+# off or merged back in HBM, and nothing is padded from 64 to 128 lanes.
+# The backward works on the TRANSPOSED tile (keys on sublanes, queries on
+# lanes): lse and delta then broadcast as rows, and dV and dK need no
+# transposed operand.
+
+_FUSED_MAX_SEQ = 1024     # a (block_q, S) f32 tile: at most 2 MiB
+_NT = (((1,), (1,)), ((), ()))      # A @ B^T
+_TN = (((0,), (0,)), ((), ()))      # A^T @ B
+
+
+def _train_blocks(batch, seq, width=128):
+    """(batch rows per grid step, query rows per block, rows unrolled in
+    the kernel's loop), from the shape alone.  The query block is the
+    largest multiple of 128 up to 512 that DIVIDES S (512 at S=512 and
+    1024, 384 at 768, 128 at 640 and 896): the grid runs S / block_q query
+    blocks, so a block that does not divide S would leave rows unwritten.
+    A key block of some 4096 rows at 128 lanes (1 MiB of bf16; 8 rows at
+    S=512, 24 of 264 at S=128; half as many rows at 256 lanes) buries the
+    ~0.35 us a grid step costs.  Small tiles are unrolled, some 2^17 score
+    elements a head (8 rows at S=128, none at S=512), so that one row's
+    matmuls overlap another's vector work; each unrolled row costs ~0.12 s
+    of tracing and lowering at every start of the process (all 24 rows at
+    S=128: 1% of the step won, 5.6 s of `setup_s` lost).  Swept on the v5e in PR 26 (PERF.md section 6);
+    forward + backward ms a layer: S=512, B=40: (8, 512, 1) 0.59 + 1.02,
+    (8, 512, 2) 0.58 + 0.99, (8, 256, 2) 0.59 + 1.30, (8, 128, 1) 0.80 +
+    1.89; S=128, B=264: (24, 128, 1) 0.77 + 1.41, (24, 128, 6) 0.55 + 0.80,
+    (24, 128, 12) 0.50 + 0.73, (24, 128, 24) 0.47 + 0.71."""
+    bq = max(r for r in range(128, min(seq, 512) + 1, 128) if seq % r == 0)
+    divisor = lambda n, most: max(d for d in range(1, max(1, min(most, n)) + 1)
+                                  if n % d == 0)
+    bb = divisor(batch, 4096 * 128 // (width * seq))
+    return bb, bq, divisor(bb, (1 << 17) // (bq * seq))
+
+
+def _train_vmem_bytes(bb, bq, unroll, seq, width, itemsize):
+    """The scoped VMEM to ask Mosaic for, from the tiles the backward (the
+    larger kernel) holds: its eight operand and result blocks, double
+    buffered; the two f32 accumulators when there is more than one query
+    block; some eight (block_q, S) f32 tiles (scores, probabilities, hash
+    bits, dP, dS and their temporaries) for each unrolled row; twice that
+    for what Mosaic allocates itself.  48 MiB at (8, 512, 1) on S=512 and
+    32 MiB at (24, 128, 8) on S=128, bf16; a chip with less VMEM than a
+    shape asks for refuses it at compile time, with Mosaic's message."""
+    blocks = 2 * 4 * bb * (bq + seq) * width * itemsize
+    scratch = 2 * bb * seq * width * 4 if seq > bq else 0
+    tiles = 8 * unroll * bq * seq * 4
+    return 2 * (blocks + scratch + tiles)
+
+
+def _for_each_entry(n, unroll, body):
+    """body(i) for the n batch entries of a block, `unroll` at a time in
+    one basic block, so that the scheduler overlaps one entry's matmuls
+    with another's vector work (Mosaic's own loops unroll all or nothing)."""
+    def group(g, carry):
+        for u in range(unroll):
+            body(g * unroll + u)
+        return carry
+
+    jax.lax.fori_loop(0, n // unroll, group, 0)
+
+
+def _u32_iota(shape, dim):
+    return jax.lax.broadcasted_iota(jnp.int32, shape, dim).astype(jnp.uint32)
+
+
+def _first_head(row0, i, j, heads, per_block):
+    """Global b*h index (uint32) of the first head of lane block `j`, for
+    entry `i` of a batch block whose first entry is global row `row0`."""
+    u32 = lambda x: jnp.asarray(x).astype(jnp.uint32)
+    return (row0 + u32(i)) * np.uint32(heads) + u32(j) * np.uint32(per_block)
+
+
+def _scaled_scores(a, b, scale):
+    """scale * a @ b^T in f32.  A power-of-two scale (1/8 at D=64) goes
+    into the first operand, exactly; any other is applied to the scores,
+    as the reference applies it."""
+    if math.frexp(scale)[0] == 0.5:
+        return jax.lax.dot_general(a * jnp.asarray(scale, a.dtype), b, _NT,
+                                   preferred_element_type=jnp.float32)
+    return jax.lax.dot_general(
+        a, b, _NT, preferred_element_type=jnp.float32) * scale
+
+
+class _HeadLanes:
+    """The heads that share one lane block of width `w`: head `h` of the
+    block owns lanes [h*d, (h+1)*d)."""
+
+    def __init__(self, d):
+        self.d, self.w = d, max(128, d)
+        self.n = self.w // d
+
+    def mine(self, h):
+        lane = jax.lax.broadcasted_iota(jnp.int32, (1, self.w), 1)
+        return (lane >= h * self.d) & (lane < (h + 1) * self.d)
+
+    def only(self, h, x):
+        """`x` with the other heads' lanes zeroed."""
+        return x if self.n == 1 else jnp.where(self.mine(h), x,
+                                               jnp.zeros_like(x))
+
+    def put(self, h, new, into):
+        """`into` with head h's lanes taken from `new`."""
+        return new if into is None else jnp.where(self.mine(h), new, into)
+
+
+def _train_specs(pl, batch, heads, seq, d, blocks):
+    """Grid and the BlockSpecs both kernels share."""
+    lanes = _HeadLanes(d)
+    bb, bq, unroll = blocks or _train_blocks(batch, seq, lanes.w)
+    # a block that does not divide its axis leaves rows unwritten
+    assert batch % bb == 0 and seq % bq == 0 and bb % unroll == 0, (
+        batch, seq, (bb, bq, unroll))
+    assert heads % lanes.n == 0 and bq % 128 == 0, (heads, d, bq)
+    grid = (batch // bb, heads // lanes.n, seq // bq)
+    return lanes, bb, bq, unroll, grid, {
+        # (bb, bq, w) of q / o / do / dq; (bb, S, w) of k / v / dk / dv
+        "q": pl.BlockSpec((bb, bq, lanes.w), lambda b, j, i, key: (b, i, j)),
+        "kv": pl.BlockSpec((bb, seq, lanes.w), lambda b, j, i, key: (b, 0, j)),
+        # the (B, 1, S) key-validity mask, one row a batch entry
+        "mask": pl.BlockSpec((bb, 1, seq), lambda b, j, i, key: (b, 0, 0)),
+        # the (B, H, 1, S) row statistic: lse
+        "row": pl.BlockSpec((bb, lanes.n, 1, bq),
+                            lambda b, j, i, key: (b, j, 0, i)),
+    }
+
+
+_STATICS = ("heads", "scale", "keep")
+
+
+def _shared_kernel(fn):
+    """`fn` jitted, so that the twelve layers of a step trace and lower
+    each kernel ONCE: a Pallas kernel costs ~0.15 s to trace and lower, on
+    every start of the process, with or without a compile-cache hit (7 s
+    of `setup_s` in PR 26 before this).  The interpreter switch is read
+    per call and is part of the jit's key."""
+    jitted = jax.jit(fn, static_argnames=_STATICS + ("blocks", "interpret"))
+
+    @functools.wraps(fn)
+    def call(*operands, **statics):
+        return jitted(*operands, **statics,
+                      interpret=env.get_bool("MXNET_PALLAS_INTERPRET"))
+    return call
+
+
+@_shared_kernel
+def _attention_train_fwd_pallas(q, k, v, mask, seed, heads, scale, keep,
+                                blocks=None, interpret=False):
+    """Forward kernel: q, k, v (B, S, H*D), mask (B, S), seed uint32[3]
+    (`_seed`).  Grid (B/bb, H / heads-per-lane-block, S/bq).  Returns
+    (o, lse)."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    batch, seq, units = q.shape
+    d = units // heads
+    lanes, bb, bq, unroll, grid, spec = _train_specs(pl, batch, heads, seq, d,
+                                                     blocks)
+    threshold = _keep_threshold(keep)
+
+    def kernel(key_ref, q_ref, k_ref, v_ref, m_ref, o_ref, lse_ref):
+        j, qi = pl.program_id(1), pl.program_id(2)
+        # the GLOBAL batch row of the block's first entry
+        b0 = key_ref[2] + (pl.program_id(0) * bb).astype(jnp.uint32)
+        q_idx = _u32_iota((bq, seq), 0) + (qi * bq).astype(jnp.uint32)
+        h_qk = _hash_qk(q_idx, _u32_iota((bq, seq), 1), seq, key_ref[0])
+
+        def entry(i):
+            qb, kb, vb = q_ref[i], k_ref[i], v_ref[i]
+            valid = m_ref[i] > 0                               # (1, S)
+            head0 = _first_head(b0, i, j, heads, lanes.n)
+            o = None
+            for h in range(lanes.n):
+                s = _scaled_scores(lanes.only(h, qb), kb, scale)   # (bq, S)
+                s = jnp.where(valid, s, -1e30)
+                m = jnp.max(s, axis=1, keepdims=True)
+                p = jnp.exp(s - m)
+                l = jnp.sum(p, axis=1, keepdims=True)          # over ALL keys
+                kept = _hash_bits(h_qk, _hash_head(
+                    head0 + np.uint32(h), key_ref[1])) < threshold
+                pv = jnp.dot(jnp.where(kept, p, 0.0).astype(vb.dtype), vb,
+                             preferred_element_type=jnp.float32)   # (bq, w)
+                o = lanes.put(h, pv / (l * keep), o)
+                # the row statistics leave as a ROW: (bq, 1) -> (1, bq)
+                lse = jnp.broadcast_to(m + jnp.log(l), (bq, 128))
+                lse_ref[i, h] = lse.T[:1]
+            o_ref[i] = o.astype(o_ref.dtype)
+
+        _for_each_entry(bb, unroll, entry)
+
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=grid,
+            in_specs=[spec["q"], spec["kv"], spec["kv"], spec["mask"]],
+            out_specs=[spec["q"], spec["row"]]),
+        out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
+                   jax.ShapeDtypeStruct((batch, heads, 1, seq), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel"),
+            vmem_limit_bytes=_train_vmem_bytes(
+                bb, bq, unroll, seq, lanes.w, q.dtype.itemsize)),
+        interpret=interpret,
+        name="mx_attention_train_fwd",
+    )(seed, q, k, v, mask.astype(jnp.float32)[:, None, :])
+
+
+@_shared_kernel
+def _attention_train_bwd_pallas(q, k, v, mask, seed, o, lse, do, heads,
+                                scale, keep, blocks=None, interpret=False):
+    """Backward kernel, same grid; dK and dV accumulate over the query
+    blocks in f32 scratch.  Returns (dq, dk, dv)."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    batch, seq, units = q.shape
+    d = units // heads
+    lanes, bb, bq, unroll, grid, spec = _train_specs(pl, batch, heads, seq, d,
+                                                     blocks)
+    nq = grid[2]
+    threshold = _keep_threshold(keep)
+
+    def kernel(key_ref, q_ref, k_ref, v_ref, m_ref, o_ref, do_ref, lse_ref,
+               dq_ref, dk_ref, dv_ref, *acc):
+        j, qi = pl.program_id(1), pl.program_id(2)
+        # the GLOBAL batch row of the block's first entry
+        b0 = key_ref[2] + (pl.program_id(0) * bb).astype(jnp.uint32)
+        q_idx = _u32_iota((seq, bq), 1) + (qi * bq).astype(jnp.uint32)
+        h_qk = _hash_qk(q_idx, _u32_iota((seq, bq), 0), seq, key_ref[0])
+
+        def entry(i):
+            qb, kb, vb, dob = q_ref[i], k_ref[i], v_ref[i], do_ref[i]
+            # keys on sublanes: the (1, S) validity row becomes a column
+            valid = jnp.broadcast_to(m_ref[i], (128, seq)).T[:, :1] > 0
+            # rowsum(dO * O) is exact under dropout: O already holds the mask
+            o_do = o_ref[i].astype(jnp.float32) * dob.astype(jnp.float32)
+            dq = dk = dv = None
+            head0 = _first_head(b0, i, j, heads, lanes.n)
+            for h in range(lanes.n):
+                delta = keep * jnp.sum(lanes.only(h, o_do), axis=1,
+                                       keepdims=True)          # (bq, 1)
+                delta = jnp.broadcast_to(delta, (bq, 128)).T[:1]   # (1, bq)
+                st = _scaled_scores(lanes.only(h, kb), qb, scale)  # (S, bq)
+                st = jnp.where(valid, st, -1e30)
+                pt = jnp.exp(st - lse_ref[i, h])
+                kept = _hash_bits(h_qk, _hash_head(
+                    head0 + np.uint32(h), key_ref[1])) < threshold
+                # 1/keep and the scale leave the S x S tile: they multiply
+                # the (S, w) and (bq, w) results, delta is taken times keep
+                dv = lanes.put(h, jnp.dot(
+                    jnp.where(kept, pt, 0.0).astype(dob.dtype), dob,
+                    preferred_element_type=jnp.float32), dv)
+                dpt = jax.lax.dot_general(
+                    lanes.only(h, vb), dob, _NT,
+                    preferred_element_type=jnp.float32)
+                dst = (pt * (jnp.where(kept, dpt, 0.0) - delta)
+                       ).astype(qb.dtype)
+                dk = lanes.put(h, jnp.dot(
+                    dst, qb, preferred_element_type=jnp.float32), dk)
+                dq = lanes.put(h, jax.lax.dot_general(
+                    dst, kb, _TN, preferred_element_type=jnp.float32), dq)
+            dq_ref[i] = (dq * (scale / keep)).astype(dq_ref.dtype)
+            dk, dv = dk * (scale / keep), dv * (1.0 / keep)
+            if nq == 1:
+                dk_ref[i] = dk.astype(dk_ref.dtype)
+                dv_ref[i] = dv.astype(dv_ref.dtype)
+            else:
+                dk_acc, dv_acc = acc
+
+                @pl.when(qi == 0)
+                def _():
+                    dk_acc[i] = dk
+                    dv_acc[i] = dv
+
+                @pl.when(qi > 0)
+                def _():
+                    dk_acc[i] += dk
+                    dv_acc[i] += dv
+
+                @pl.when(qi == nq - 1)
+                def _():
+                    dk_ref[i] = dk_acc[i].astype(dk_ref.dtype)
+                    dv_ref[i] = dv_acc[i].astype(dv_ref.dtype)
+
+        _for_each_entry(bb, unroll, entry)
+
+    acc = pltpu.VMEM((bb, seq, lanes.w), jnp.float32)
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=grid,
+            in_specs=[spec["q"], spec["kv"], spec["kv"], spec["mask"],
+                      spec["q"], spec["q"], spec["row"]],
+            out_specs=[spec["q"], spec["kv"], spec["kv"]],
+            scratch_shapes=[acc, acc] if nq > 1 else []),
+        out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype) for x in (q, k, v)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=_train_vmem_bytes(
+                bb, bq, unroll, seq, lanes.w, q.dtype.itemsize)),
+        interpret=interpret,
+        name="mx_attention_train_bwd",
+    )(seed, q, k, v, mask.astype(jnp.float32)[:, None, :], o, do, lse)
+
+
+def _split_heads(x, heads):
+    """(B, S, H*D) -> (B*H, S, D)."""
+    b, s, u = x.shape
+    return x.reshape(b, s, heads, u // heads).transpose(0, 2, 1, 3).reshape(
+        b * heads, s, u // heads)
+
+
+def _merge_heads(x, heads):
+    """(B*H, S, D) -> (B, S, H*D)."""
+    bh, s, d = x.shape
+    return x.reshape(bh // heads, heads, s, d).transpose(0, 2, 1, 3).reshape(
+        bh // heads, s, heads * d)
+
+
+def _seed(key_words, first_row=0):
+    """uint32[3], the kernels' scalar operand: the two key words and the
+    GLOBAL index of the operands' first batch row (0 unless the caller
+    holds one batch shard), so the mask is a function of global indices."""
+    return jnp.concatenate(
+        [key_words, jnp.asarray(first_row, jnp.uint32).reshape(1)])
+
+
+def _train_xla(q, k, v, mask, seed, heads, scale, keep):
+    """The function the kernels compute, in XLA, packed in and out."""
+    return _merge_heads(_attention_with_prob_dropout(
+        _split_heads(q, heads), _split_heads(k, heads),
+        _split_heads(v, heads), jnp.repeat(mask, heads, axis=0), scale,
+        1.0 - keep, seed[:2], first_head=seed[2] * np.uint32(heads)), heads)
+
+
+@functools.partial(jax.jit, static_argnames=_STATICS)
+def _train_fwd_xla(q, k, v, mask, seed, heads, scale, keep):
+    """What the forward kernel returns, in XLA: (o, lse)."""
+    s = jnp.einsum("bqd,bkd->bqk", _split_heads(q, heads),
+                   _split_heads(k, heads),
+                   preferred_element_type=jnp.float32) * scale
+    s = jnp.where(jnp.repeat(mask, heads, axis=0)[:, None, :] > 0, s, -1e30)
+    lse = jax.nn.logsumexp(s, axis=-1).reshape(q.shape[0], heads, 1, -1)
+    return _train_xla(q, k, v, mask, seed, heads, scale, keep), lse
+
+
+@functools.partial(jax.jit, static_argnames=_STATICS)
+def _train_bwd_xla(q, k, v, mask, seed, o, lse, do, heads, scale, keep):
+    """What the backward kernel returns, in XLA (recomputed from the
+    inputs; the saved o and lse are the kernels' residuals)."""
+    del o, lse
+    _, vjp = jax.vjp(lambda q_, k_, v_: _train_xla(
+        q_, k_, v_, mask, seed, heads, scale, keep), q, k, v)
+    return vjp(do)
+
+
+def _on_tpu_else(kernel, reference, shard, q, k, v, mask, key_words, *rest):
+    """The kernel in a program lowered for the TPU (or everywhere under
+    the interpreter), the XLA reference of the same function elsewhere —
+    as `_attend` chooses, from the platform the program is lowered for.
+    Every operand and result but `key_words` leads with the batch.  With
+    `shard` = (mesh, batch axes) from `_mesh_batch_axes` each device runs
+    this on its batch shard inside a shard_map, as `pallas_convbn` does:
+    GSPMD cannot partition a Mosaic call.  The shard's first global row
+    goes into the seed, so the mask does not depend on the mesh."""
+    def run(first_row, q, k, v, mask, key_words, *rest):
+        operands = (q, k, v, mask, _seed(key_words, first_row), *rest)
+        if env.get_bool("MXNET_PALLAS_INTERPRET"):
+            return kernel(*operands)
+        return jax.lax.platform_dependent(*operands, tpu=kernel,
+                                          default=reference)
+
+    if shard is None:
+        return run(0, q, k, v, mask, key_words, *rest)
+    from jax.sharding import PartitionSpec as P
+
+    from ..parallel._compat import shard_map_unchecked
+
+    mesh, axes = shard
+    rows = P(axes)
+
+    def per_shard(q, *more):
+        return run(jax.lax.axis_index(axes) * q.shape[0], q, *more)
+
+    return shard_map_unchecked(
+        per_shard, mesh=mesh,
+        in_specs=(rows,) * 4 + (P(),) + (rows,) * len(rest),
+        out_specs=rows)(q, k, v, mask, key_words, *rest)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8))
+def _attend_train(q, k, v, mask, key_words, heads, scale, keep, shard=None):
+    """Self-attention with dropout on the probabilities through the fused
+    kernels: q, k, v (B, S, H*D); mask (B, S); key_words uint32[2]; shard
+    from `_mesh_batch_axes`."""
+    return _attend_train_fwd(q, k, v, mask, key_words, heads, scale, keep,
+                             shard)[0]
+
+
+def _attend_train_fwd(q, k, v, mask, key_words, heads, scale, keep, shard):
+    statics = dict(heads=heads, scale=scale, keep=keep)
+    o, lse = _on_tpu_else(
+        functools.partial(_attention_train_fwd_pallas, **statics),
+        functools.partial(_train_fwd_xla, **statics),
+        shard, q, k, v, mask, key_words)
+    return o, (q, k, v, mask, key_words, o, lse)
+
+
+def _attend_train_bwd(heads, scale, keep, shard, res, do):
+    q, k, v, mask, key_words, o, lse = res
+    statics = dict(heads=heads, scale=scale, keep=keep)
+    dq, dk, dv = _on_tpu_else(
+        functools.partial(_attention_train_bwd_pallas, **statics),
+        functools.partial(_train_bwd_xla, **statics),
+        shard, q, k, v, mask, key_words, o, lse, do)
+    return (dq, dk, dv, jnp.zeros_like(mask),
+            np.zeros(key_words.shape, jax.dtypes.float0))
+
+
+_attend_train.defvjp(_attend_train_fwd, _attend_train_bwd)
+
+
+def _mesh_batch_axes(batch):
+    """How the fused kernels run under the active mesh (`with mesh:`, as
+    `SPMDTrainer` holds it around the traced step): None where no mesh of
+    several devices is active (one bare call); (jax mesh, batch axes)
+    where dp / fsdp are the only axes that split anything and split
+    `batch` evenly (one call a batch shard, `_on_tpu_else`); False where
+    the mesh splits otherwise (tp, sp, ...: the heads or the sequence may
+    be sharded, and the XLA route is the one GSPMD can partition)."""
+    from ..parallel.mesh import current_mesh
+
+    m = current_mesh()
+    if m is None or m.mesh.size == 1:
+        return None
+    axes = tuple(a for a in ("dp", "fsdp") if m.axis_sizes.get(a, 1) > 1)
+    shards = math.prod(m.axis_sizes[a] for a in axes)
+    if shards != m.mesh.size or batch % shards:
+        return False
+    return m.mesh, axes
+
+
+def _fused_train_shape(heads, sq, sk, d, causal):
+    """The calls the fused kernels cover: self-attention shaped as BERT's
+    is, the heads filling whole 128-lane blocks.  Everything else in
+    training (causal, cross, ragged lengths, other head sizes) stays on
+    `_attention_with_prob_dropout`."""
+    return (not causal and sq == sk and sq % 128 == 0
+            and sq <= _FUSED_MAX_SEQ and d in (64, 128, 256)
+            and (heads * d) % 128 == 0)
+
+
+# Routes CHOSEN, counted where the branch is chosen: at TRACE time (once a
+# compiled program, never per step), not kernels run: a `fused_train` or
+# `kernel_infer` call in a program lowered for the CPU runs the XLA twin of
+# the same function.  The share of training calls that engaged the fused
+# route is fused_train / (fused_train + xla_dropout).  This dict is the
+# store; the telemetry counter `mx_attention_route_total{route}` is its
+# export and counts only while telemetry is enabled.
+ROUTES = ("fused_train", "xla_dropout", "kernel_infer", "reference")
+_route_counts = dict.fromkeys(ROUTES, 0)
+
+
+def route_counts():
+    """{route: calls traced through it} since import: routes chosen at
+    trace time, not kernels run.  Read it before and after to count."""
+    return dict(_route_counts)
+
+
+def _count_route(route):
+    _route_counts[route] += 1
+    _instruments.attention_route_total(route).inc()
 
 
 @register_op("dot_product_attention",
@@ -189,16 +792,34 @@ def _dot_product_attention(query, key, value, valid_mask=None, rng_key=None,
         b, sq, u = query.shape
         h = num_heads
         d = u // h
+        sk = key.shape[1]
+    else:
+        b, h, sq, d = query.shape
+        sk = key.shape[2]
+    if scale is None:
+        scale = 1.0 / np.sqrt(d)
+    dropping = _train and dropout > 0.0 and rng_key is not None
+    shard = _mesh_batch_axes(b) if dropping else None
+    if (dropping and env.get_bool("MXNET_USE_PALLAS") and shard is not False
+            and _fused_train_shape(h, sq, sk, d, causal)):
+        # the kernels work in the packed layout: no head is split off
+        _count_route("fused_train")
+        pack = (lambda x: x) if packed else (
+            lambda x: x.transpose(0, 2, 1, 3).reshape(b, -1, h * d))
+        mask = (jnp.ones((b, sk), query.dtype) if valid_mask is None
+                else valid_mask.astype(query.dtype))
+        out = _attend_train(pack(query), pack(key), pack(value), mask,
+                            _key_words(rng_key), h, float(scale),
+                            1.0 - float(dropout), shard)
+        return out if packed else out.reshape(b, sq, h, d).transpose(
+            0, 2, 1, 3)
+    if packed:
         def split(x):
             bs, s, _ = x.shape
             return x.reshape(bs, s, h, d).transpose(0, 2, 1, 3)
         qh, kh, vh = split(query), split(key), split(value)
     else:
         qh, kh, vh = query, key, value
-        b, h, sq, d = qh.shape
-    sk = kh.shape[2]
-    if scale is None:
-        scale = 1.0 / np.sqrt(d)
     qf = qh.reshape(b * h, sq, d)
     kf = kh.reshape(b * h, sk, d)
     vf = vh.reshape(b * h, sk, d)
@@ -206,11 +827,14 @@ def _dot_product_attention(query, key, value, valid_mask=None, rng_key=None,
         maskf = jnp.ones((b * h, sk), qf.dtype)
     else:
         maskf = jnp.repeat(valid_mask.astype(qf.dtype), h, axis=0)
-    if _train and dropout > 0.0 and rng_key is not None:
+    if dropping:
+        _count_route("xla_dropout")
         of = _attention_with_prob_dropout(qf, kf, vf, maskf, float(scale),
                                           float(dropout), rng_key,
                                           causal=causal)
     else:
+        _count_route("kernel_infer" if env.get_bool("MXNET_USE_PALLAS")
+                     else "reference")
         of = _attend(qf, kf, vf, maskf, float(scale), bool(causal))
     oh = of.reshape(b, h, sq, d)
     if packed:

@@ -30,7 +30,7 @@ from typing import Dict, NamedTuple, Tuple
 from .metrics import MetricFamily, get_registry
 
 __all__ = [
-    "op_dispatch_total",
+    "op_dispatch_total", "attention_route_total",
     "training_phase_seconds", "training_steps_total",
     "fused_step_total", "fused_compile_seconds",
     "spmd_step_total", "spmd_compile_seconds",
@@ -136,6 +136,19 @@ _spec("mx_op_dispatch_total", "counter",
 
 def op_dispatch_total(op_name: str):
     return _child("mx_op_dispatch_total", (op_name,))
+
+
+_spec("mx_attention_route_total", "counter",
+      "dot_product_attention calls TRACED through each route "
+      "(fused_train = the fused training kernels, xla_dropout = the XLA "
+      "path with saved probabilities, kernel_infer / reference = the "
+      "dropout-free call): counted once a compiled program, never per "
+      "step. fused_train over fused_train + xla_dropout is the share of "
+      "training attention that engaged the kernels.", ("route",))
+
+
+def attention_route_total(route: str):
+    return _child("mx_attention_route_total", (route,))
 
 
 # ---- training ---------------------------------------------------------

@@ -388,9 +388,10 @@ declare("MXNET_PALLAS_INTERPRET", bool, False,
         "Run Pallas kernels in interpreter mode (CPU testing): no "
         "Mosaic compile, bit-accurate reference semantics.")
 declare("MXNET_USE_PALLAS", bool, True,
-        "Master switch for Pallas kernels (flash attention, fused "
-        "Conv+BN). 0 selects the XLA fallbacks with identical "
-        "semantics.")
+        "Master switch for Pallas kernels (flash attention in inference "
+        "and in training, fused Conv+BN). 0 selects the XLA fallbacks "
+        "with identical semantics (attention dropout keeps its mask: "
+        "every route takes it from the same hash).")
 
 # -- compile cache ----------------------------------------------------------
 declare("MXNET_COMPILE_CACHE_BYTES", int, 0,

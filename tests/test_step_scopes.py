@@ -128,6 +128,68 @@ def test_attention_step_names_the_attention_core_apart_from_its_matmuls():
     assert _holds(names, "jit(mx_train_step)/mx.update/adam_update/")
 
 
+class BertShaped(HybridBlock):
+    """One encoder layer at a shape the fused training route takes: two
+    heads of 64 over 128 positions."""
+
+    def __init__(self, **kw):
+        super().__init__(**kw)
+        with self.name_scope():
+            self.layer0 = BERTEncoderCell(128, 256, 2, dropout=0.1,
+                                          prefix="layer0_")
+        self.head = nn.Dense(4, prefix="classifier_")
+
+    def hybrid_forward(self, F, x, mask):
+        return self.head(self.layer0(x, mask))
+
+
+def fused_attention_net():
+    np.random.seed(0)
+    mx.random.seed(0)
+    net = BertShaped(prefix="tiny_")
+    net.initialize(mx.initializer.Xavier(), ctx=mx.cpu())
+    with mx.autograd.pause():
+        net(mx.nd.zeros((1, 128, 128), ctx=mx.cpu()),
+            mx.nd.ones((1, 128), ctx=mx.cpu()))
+    trainer = parallel.SPMDTrainer(
+        net, gloss.SoftmaxCrossEntropyLoss(), "adam",
+        {"learning_rate": 1e-3}, mesh=parallel.make_mesh(dp=1))
+    rng = np.random.RandomState(0)
+    return trainer, (rng.rand(2, 128, 128).astype("float32"),
+                     np.ones((2, 128), "float32"),
+                     rng.randint(0, 4, 2).astype(np.int32))
+
+
+@pytest.mark.parametrize("interpret", ["0", "1"])
+def test_fused_attention_backward_is_booked_to_the_backward_and_the_op(
+        monkeypatch, interpret):
+    """The fused training route (PR 26) runs its backward from a
+    custom_vjp rule.  Its ops (the XLA reference a CPU program lowers to,
+    and the kernel's own body under the interpreter) must keep both
+    `transpose(` and the op scope, or `attention_device_ms` would fall for
+    the wrong reason and `scope_unattributed_pct` would rise."""
+    from mxnet_tpu.ops import pallas_attention as pa
+    monkeypatch.setenv("MXNET_PALLAS_INTERPRET", interpret)
+    before = pa.route_counts()
+    program, names = _table_after_one_step(fused_attention_net)
+    after = pa.route_counts()
+    assert after["fused_train"] == before["fused_train"] + 1
+    assert after["xla_dropout"] == before["xla_dropout"]
+    assert program["scoped"]
+    inside = [n for n in names if "attn/dot_product_attention/" in n
+              and ("dot_general" in n or "exp" in n)]
+    fwd = [n for n in inside if "/jvp(tiny)/" in n]
+    bwd = [n for n in inside if "/transpose(jvp(tiny))/" in n]
+    # matmuls and the exponential of both passes, nothing outside either
+    assert fwd and bwd and len(fwd) + len(bwd) == len(inside)
+    assert any("dot_general" in n for n in bwd)
+    if interpret == "1":
+        assert _holds(names, "/transpose(jvp(tiny))/",
+                      "dot_product_attention/", "mx_attention_train_bwd")
+        assert _holds(names, "/jvp(tiny)/", "dot_product_attention/",
+                      "mx_attention_train_fwd")
+
+
 _HLO = '''HloModule jit_mx_train_step, is_scheduled=true
 
 %region_0.1 (a: f32[], b: f32[]) -> f32[] {
@@ -180,11 +242,13 @@ def test_a_fusion_holding_a_dot_is_booked_to_the_dot_not_to_its_root():
     assert stale["module"] == "jit_old_step" and stale["scoped"] is False
 
 
-# first-step losses of the two seeded nets at the parent commit (a225b38,
-# float32 on this CPU backend, before any scope existed): a scope is
-# metadata and changes no arithmetic
+# first-step losses of the two seeded nets before any scope existed
+# (float32 on this CPU backend): a scope is metadata and changes no
+# arithmetic.  The convolution net's is a225b38's; the attention net's is
+# PR 26's, whose dropout mask on the attention probabilities comes from
+# a hash and no longer from threefry (1.447582721710205 before)
 @pytest.mark.parametrize("make, parent_loss", [
-    (conv_net, 1.3157033920288086), (attention_net, 1.447582721710205)])
+    (conv_net, 1.3157033920288086), (attention_net, 1.7128827571868896)])
 def test_step_builds_no_table_and_scopes_change_no_arithmetic(
         make, parent_loss):
     before = set(map(id, spmd._STEP_CACHE.data.values()))
