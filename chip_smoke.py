@@ -394,6 +394,103 @@ def attention_train_stage(shapes, *, dtype="bfloat16", expect_mosaic=True,
     return out
 
 
+def decoder_phase(*, seq=1024, heads=8, kv_heads=2, dim=128, tokens=2048,
+                  experts=32, held=8, top_k=4, latent=256, width=384,
+                  dtype="bfloat16", expect_mosaic=True) -> dict:
+    """Value and gradients of the hybrid decoder's two kernel routes
+    against plain XLA on the same operands: causal grouped-query attention
+    through the op (upstream's flash kernels) against the S x S reference,
+    and the held experts' grouped products (the grouped-matmul kernel,
+    most of whose rows are the empty tail) against every held expert
+    applied densely to every token.  The benchmark's reference check sees
+    only their forward."""
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu.ops import pallas_attention as pa
+    from mxnet_tpu.parallel import moe
+
+    rng = np.random.RandomState(0)
+
+    def weighed(o, ct):
+        return (o.astype(jnp.float32) * ct.astype(jnp.float32)).sum(), o
+
+    def compare(name, route, plain, operands, out):
+        grad = jax.jit(jax.grad(route, argnums=(0, 1, 2), has_aux=True))
+        if expect_mosaic:
+            _require("tpu_custom_call" in grad.lower(*operands).as_text(),
+                     f"no Mosaic call in the lowered gradient of {name}")
+        got = jax.block_until_ready(grad(*operands))
+        ref = jax.jit(jax.grad(plain, argnums=(0, 1, 2),
+                               has_aux=True))(*operands)
+        errs = {}
+        for i, (a, b) in enumerate(zip((*got[0], got[1]),
+                                       (*ref[0], ref[1]))):
+            a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+            _require(np.isfinite(a).all(), f"non-finite result from {name}")
+            errs[i] = float(np.abs(a - b).max() / np.abs(b).max())
+            _require(errs[i] < 2e-2,
+                     f"{name}: result {i} is off plain XLA by "
+                     f"{errs[i]:.4f} of its largest value")
+        out[name] = {"max_rel_err": round(max(errs.values()), 5)}
+
+    out = {}
+    q, ct = (jnp.asarray(rng.randn(1, seq, heads * dim), dtype)
+             for _ in range(2))
+    k, v = (jnp.asarray(rng.randn(1, seq, kv_heads * dim), dtype)
+            for _ in range(2))
+    before = pa.route_counts()["flash_causal"]
+
+    def attention(q, k, v, ct):
+        return weighed(pa._dot_product_attention(
+            q, k, v, None, None, num_heads=heads, num_kv_heads=kv_heads,
+            causal=True, _train=True), ct)
+
+    def attention_plain(q, k, v, ct):
+        def split(x, n):
+            return x.reshape(1, seq, n, dim).transpose(0, 2, 1, 3)
+        o = pa._causal_xla(split(q, heads), split(k, kv_heads),
+                           split(v, kv_heads), dim ** -0.5)
+        return weighed(o.transpose(0, 2, 1, 3).reshape(q.shape), ct)
+
+    compare(f"causal_gqa_h{heads}_kv{kv_heads}_s{seq}_d{dim}", attention,
+            attention_plain, (q, k, v, ct), out)
+    _require(pa.route_counts()["flash_causal"] > before,
+             f"the causal call did not take the flash route: "
+             f"{pa.route_counts()}")
+
+    x = jnp.asarray(rng.randn(tokens, 64), jnp.float32)
+    plan = moe.route(x, jnp.asarray(rng.randn(experts, 64), jnp.float32),
+                     jnp.zeros((experts,), jnp.float32), top_k=top_k,
+                     scale=2.5, first_expert=0, n_local=held)
+    _require(int(plan.dropped) == 0 and int(plan.group_sizes.sum()) > 0,
+             "the router dropped assignments or placed none")
+    u, ct = (jnp.asarray(rng.randn(tokens, latent), dtype) for _ in range(2))
+    w1 = jnp.asarray(rng.randn(held, latent, width) * 0.1, dtype)
+    w2 = jnp.asarray(rng.randn(held, width, latent) * 0.1, dtype)
+
+    def grouped(u, w1, w2, ct, token, weight, sizes):
+        return weighed(moe.experts(
+            u, moe.RoutePlan(token, weight, sizes, plan.dropped), w1, w2), ct)
+
+    def dense(u, w1, w2, ct, token, weight, sizes):
+        # (tokens, held) combine weights back from the rows' layout
+        expert = jnp.searchsorted(jnp.cumsum(sizes), jnp.arange(
+            token.shape[0]), side="right")
+        table = jnp.zeros((tokens, held + 1), jnp.float32).at[
+            token, jnp.minimum(expert, held)].add(weight, mode="drop")
+        total = jnp.zeros((tokens, latent), jnp.float32)
+        for e in range(held):
+            hidden = jnp.square(jnp.maximum(u @ w1[e], 0))
+            total += table[:, e, None] * (hidden @ w2[e]).astype(jnp.float32)
+        return weighed(total.astype(u.dtype), ct)
+
+    compare(f"experts_t{tokens}_held{held}_k{latent}_n{width}", grouped,
+            dense, (u, w1, w2, ct, plan.token, plan.weight,
+                    plan.group_sizes), out)
+    return out
+
+
 def result_line(devices) -> str:
     """The last line of stdout: exactly `ok` and `device`, and `device`
     exactly `platform`, `kind`, `count`.  Only a run in which every phase
@@ -438,6 +535,7 @@ def main() -> int:
     one = run("resnet50", resnet_phase, cache)
     run("gluon", gluon_phase, mx.tpu(0))
     run("attention", attention_phase)
+    run("decoder", decoder_phase)
     n = len(devices)
     if n > 1:
         run("attention_dp", attention_train_stage, ATTENTION_TRAIN_SHAPES,

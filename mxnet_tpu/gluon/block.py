@@ -223,21 +223,18 @@ class _PureNamespace:
 
     def dot_product_attention(self, query, key, value, valid_mask=None,
                               num_heads=1, scale=None, dropout=0.0,
-                              causal=False, **kw):
-        """Fused attention — key + train flag threaded from the trace."""
-        import jax.numpy as jnp
-
+                              causal=False, num_kv_heads=0, **kw):
+        """Fused attention — key + train flag threaded from the trace.
+        No `valid_mask` reaches the op as None: it is what lets a causal
+        call take the op's blocked route."""
         from ..ops.registry import apply_pure
 
         ts = current_trace()
         train = ts.train if ts is not None else ag.is_training()
-        if valid_mask is None:
-            sk = key.shape[1] if key.ndim == 3 else key.shape[2]
-            valid_mask = jnp.ones((key.shape[0], sk), jnp.float32)
         return apply_pure("dot_product_attention", query, key, value,
                           valid_mask, rnd.next_key(), num_heads=num_heads,
                           scale=scale, dropout=dropout, causal=causal,
-                          _train=train)
+                          num_kv_heads=num_kv_heads, _train=train)
 
     FusedAttention = dot_product_attention
 

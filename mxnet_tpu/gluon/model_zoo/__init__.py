@@ -1,5 +1,6 @@
 """Gluon model zoo (ref: python/mxnet/gluon/model_zoo/__init__.py)."""
 from . import vision
+from . import nemotron_h
 from .vision import get_model
 
-__all__ = ["vision", "get_model"]
+__all__ = ["vision", "nemotron_h", "get_model"]

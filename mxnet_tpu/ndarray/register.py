@@ -109,7 +109,8 @@ def BatchNorm(data, gamma, beta, moving_mean, moving_var, eps=1e-5,
 
 
 def dot_product_attention(query, key, value, valid_mask=None, num_heads=1,
-                          scale=None, dropout=0.0, causal=False, **kw):
+                          scale=None, dropout=0.0, causal=False,
+                          num_kv_heads=0, **kw):
     """Fused attention frontend — threads the PRNG key + train flag for
     attention-probability dropout (ref: BERT dropout-on-softmax)."""
     if valid_mask is None:
@@ -122,7 +123,7 @@ def dot_product_attention(query, key, value, valid_mask=None, num_heads=1,
                             ctx=key.ctx)
     return invoke("dot_product_attention", query, key, value, valid_mask,
                   _random.next_key(), num_heads=num_heads, scale=scale,
-                  dropout=dropout, causal=causal,
+                  dropout=dropout, causal=causal, num_kv_heads=num_kv_heads,
                   _train=autograd.is_training())
 
 

@@ -339,9 +339,13 @@ def _group_norm(data, gamma, beta, num_groups=1, eps=1e-5):
 @register_op("RMSNorm", aliases=("rms_norm",))
 def _rms_norm(data, gamma, axis=-1, eps=1e-6):
     """RMS normalization over ``axis``: scale by 1/RMS and gamma, no
-    mean subtraction."""
-    ms = jnp.mean(jnp.square(data), axis=axis, keepdims=True)
-    return data * lax.rsqrt(ms + eps) * gamma
+    mean subtraction.  Computed in float32 whatever the input's dtype (a
+    bfloat16 mean of squares over thousands of features is not a mean),
+    returned in the input's."""
+    x = data.astype(jnp.float32)
+    ms = jnp.mean(jnp.square(x), axis=axis, keepdims=True)
+    return (x * lax.rsqrt(ms + eps)
+            * gamma.astype(jnp.float32)).astype(data.dtype)
 
 
 # ---------------------------------------------------------------------------

@@ -16,6 +16,18 @@ lowered for; no switch of its own):
     HBM; backward = recompute through jax.vjp of the XLA reference under
     custom_vjp.  Counted `kernel_infer` (`reference` under
     MXNET_USE_PALLAS=0).
+  * Causal self-attention with no dropout and no key mask, heads of 128
+    (or a multiple), sq == sk a multiple of 128, training or inference,
+    query heads a multiple of the key/value heads (grouped-query):
+    `_attend_causal`, upstream's blocked flash kernels
+    (jax.experimental.pallas.ops.tpu.flash_attention: forward, dK/dV and
+    dQ, blocks above the diagonal skipped), differentiated by their own
+    custom_vjp.  O(S) memory: the only route that fits a decoder at
+    S = 8192, where `_attend`'s backward would hold 32 x 8192^2 scores.
+    The key/value heads are repeated to the query heads in HBM first (a
+    few MB at 2 of 32 heads; autodiff sums their gradient back).  Not
+    under a mesh of several devices (a bare Mosaic call), where the call
+    takes the dropout-free route below.  Counted `flash_causal`.
   * Training with dropout on the probabilities, self-attention shaped as
     BERT's (not causal, sq == sk, a multiple of 128 up to 1024, heads of
     64, 128 or 256 filling whole 128-lane blocks): `_attend_train`, two
@@ -752,6 +764,54 @@ def _fused_train_shape(heads, sq, sk, d, causal):
             and (heads * d) % 128 == 0)
 
 
+# ---------------------------------------------------------------------------
+# Causal self-attention without dropout: a decoder's layers
+# ---------------------------------------------------------------------------
+
+def _repeat_kv(x, heads):
+    """(B, Hkv, S, D) -> (B, heads, S, D): each key/value head serves
+    heads // Hkv consecutive query heads."""
+    groups = heads // x.shape[1]
+    return x if groups == 1 else jnp.repeat(x, groups, axis=1)
+
+
+def _causal_xla(q, k, v, scale):
+    b, h, s, d = q.shape
+    flat = [x.reshape(b * h, s, d)
+            for x in (q, _repeat_kv(k, h), _repeat_kv(v, h))]
+    return dot_product_attention_ref(*flat, None, scale,
+                                     causal=True).reshape(b, h, s, d)
+
+
+def _causal_flash(q, k, v, scale):
+    from jax.experimental.pallas.ops.tpu import flash_attention as fa
+
+    h, s = q.shape[1], q.shape[2]
+    blk = next(n for n in (512, 256, 128) if s % n == 0)
+    sizes = fa.BlockSizes(
+        block_q=blk, block_k_major=blk, block_k=blk, block_b=1,
+        block_q_major_dkv=blk, block_k_major_dkv=blk, block_k_dkv=blk,
+        block_q_dkv=blk, block_k_major_dq=blk, block_k_dq=blk,
+        block_q_dq=blk)
+    return fa.flash_attention(q, _repeat_kv(k, h), _repeat_kv(v, h),
+                              causal=True, sm_scale=scale,
+                              block_sizes=sizes)
+
+
+def _attend_causal(q, k, v, scale):
+    """q (B, H, S, D), k and v (B, Hkv, S, D): the flash kernels in a
+    program lowered for the TPU, the XLA reference elsewhere, as
+    `_attend` chooses; autodiff goes through the chosen branch."""
+    return jax.lax.platform_dependent(
+        q, k, v, tpu=functools.partial(_causal_flash, scale=scale),
+        default=functools.partial(_causal_xla, scale=scale))
+
+
+def _causal_flash_shape(heads, kv_heads, sq, sk, d):
+    return (sq == sk and sq % 128 == 0 and d % 128 == 0
+            and heads % kv_heads == 0)
+
+
 # Routes CHOSEN, counted where the branch is chosen: at TRACE time (once a
 # compiled program, never per step), not kernels run: a `fused_train` or
 # `kernel_infer` call in a program lowered for the CPU runs the XLA twin of
@@ -759,7 +819,8 @@ def _fused_train_shape(heads, sq, sk, d, causal):
 # route is fused_train / (fused_train + xla_dropout).  This dict is the
 # store; the telemetry counter `mx_attention_route_total{route}` is its
 # export and counts only while telemetry is enabled.
-ROUTES = ("fused_train", "xla_dropout", "kernel_infer", "reference")
+ROUTES = ("fused_train", "xla_dropout", "kernel_infer", "reference",
+          "flash_causal")
 _route_counts = dict.fromkeys(ROUTES, 0)
 
 
@@ -778,11 +839,13 @@ def _count_route(route):
              aliases=("FusedAttention", "_contrib_dot_product_attention"))
 def _dot_product_attention(query, key, value, valid_mask=None, rng_key=None,
                            num_heads=1, scale=None, dropout=0.0,
-                           causal=False, _train=False):
+                           causal=False, num_kv_heads=0, _train=False):
     """Multi-head scaled-dot-product attention.
 
     query/key/value: (B, S, U) with U = num_heads * head_dim, or already
-    head-split (B, H, S, D).  valid_mask: (B, S_k) 1/0 key-validity mask
+    head-split (B, H, S, D).  num_kv_heads (0: as num_heads): key and
+    value carry that many heads, each serving num_heads // num_kv_heads
+    query heads.  valid_mask: (B, S_k) 1/0 key-validity mask
     (sequence lengths), or None.  dropout: rate applied to the attention
     probabilities in train mode (key auto-threaded by the frontend).
     Returns the same layout as the input.
@@ -793,15 +856,34 @@ def _dot_product_attention(query, key, value, valid_mask=None, rng_key=None,
         h = num_heads
         d = u // h
         sk = key.shape[1]
+        h_kv = num_kv_heads or h
     else:
         b, h, sq, d = query.shape
         sk = key.shape[2]
+        h_kv = key.shape[1]
+    if h % h_kv:
+        raise ValueError(f"dot_product_attention: {h} query heads over "
+                         f"{h_kv} key/value heads")
     if scale is None:
         scale = 1.0 / np.sqrt(d)
     dropping = _train and dropout > 0.0 and rng_key is not None
+    if (causal and not dropping and valid_mask is None
+            and env.get_bool("MXNET_USE_PALLAS")
+            and _causal_flash_shape(h, h_kv, sq, sk, d)
+            and _mesh_batch_axes(b) is None):
+        _count_route("flash_causal")
+        if packed:
+            qh, kh, vh = (x.reshape(b, sq, n, d).transpose(0, 2, 1, 3)
+                          for x, n in ((query, h), (key, h_kv),
+                                       (value, h_kv)))
+        else:
+            qh, kh, vh = query, key, value
+        oh = _attend_causal(qh, kh, vh, float(scale))
+        return oh.transpose(0, 2, 1, 3).reshape(b, sq, h * d) if packed \
+            else oh
     shard = _mesh_batch_axes(b) if dropping else None
     if (dropping and env.get_bool("MXNET_USE_PALLAS") and shard is not False
-            and _fused_train_shape(h, sq, sk, d, causal)):
+            and h_kv == h and _fused_train_shape(h, sq, sk, d, causal)):
         # the kernels work in the packed layout: no head is split off
         _count_route("fused_train")
         pack = (lambda x: x) if packed else (
@@ -814,12 +896,13 @@ def _dot_product_attention(query, key, value, valid_mask=None, rng_key=None,
         return out if packed else out.reshape(b, sq, h, d).transpose(
             0, 2, 1, 3)
     if packed:
-        def split(x):
+        def split(x, n):
             bs, s, _ = x.shape
-            return x.reshape(bs, s, h, d).transpose(0, 2, 1, 3)
-        qh, kh, vh = split(query), split(key), split(value)
+            return x.reshape(bs, s, n, d).transpose(0, 2, 1, 3)
+        qh, kh, vh = split(query, h), split(key, h_kv), split(value, h_kv)
     else:
         qh, kh, vh = query, key, value
+    kh, vh = _repeat_kv(kh, h), _repeat_kv(vh, h)
     qf = qh.reshape(b * h, sq, d)
     kf = kh.reshape(b * h, sk, d)
     vf = vh.reshape(b * h, sk, d)

@@ -20,7 +20,9 @@ collectives over ICI within a slice, DCN across slices — under explicit
               reference launcher's DMLC_* env contract, DCN allreduce,
               barrier
   ulysses   — all-to-all sequence parallelism (DeepSpeed-Ulysses layout)
-  moe       — expert-parallel top-1 MoE over 'ep' (GShard dense dispatch)
+  moe       — mixture of experts that holds a share of its experts:
+              sigmoid top-k routing without drops, grouped products,
+              expert parallelism over 'ep'
   ring      — ring attention: sequence/context parallelism over the 'sp'
               mesh axis via shard_map + ppermute (beyond-reference)
   pipeline  — pipeline parallelism over the 'pp' axis (beyond-reference)

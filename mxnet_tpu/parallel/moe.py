@@ -1,106 +1,203 @@
-"""Mixture-of-Experts with expert parallelism over the 'ep' mesh axis.
+"""Mixture of experts: a share of the experts, told which share it is.
 
-Beyond-reference capability (SURVEY §2d lists EP as absent upstream; the
-mesh has carried the 'ep' axis since round 2 — this gives it a real
-consumer).  The formulation is the dense-dispatch one (Mesh-TensorFlow /
-GShard): top-1 routing with a fixed per-expert capacity produces
-one-hot dispatch/combine tensors, expert inputs form by einsum, the
-stacked expert parameters shard their leading dim over 'ep', and each
-device runs a vmap over ITS experts inside shard_map.  The dispatch
-einsums stay static-shaped (XLA-friendly: no dynamic token counts —
-over-capacity tokens are dropped with zero output, the GShard
-convention), and GSPMD inserts the all-to-all-equivalent collectives
-for the [T,D] -> [E,C,D] resharding.
+An expert layer here HOLDS `n_local` of the `E` experts the router scores
+(those with ids `first_expert .. first_expert + n_local - 1`), routes every
+token over all `E`, and computes the part of the result its own experts
+give.  That is what expert parallelism asks of a layer: over the 'ep' mesh
+axis (`moe_apply`) each device holds one share and the parts are summed;
+on one chip of a larger deployment the layer runs alone, and the parts of
+the absent experts are simply not there.  With `n_local == E` it is the
+whole layer.
 
-    y, aux = moe_apply(expert_fn, stacked_params, x, gate_logits)
-    # aux: {"gate_probs": [T,E] router probabilities,
-    #       "dropped_frac": scalar} for load-balance losses
+No token is ever dropped: a token lands on at most `min(top_k, n_local)`
+held experts, so `rows = T * min(top_k, n_local)` (rounded up to the row
+tile) holds every assignment under any imbalance; rows beyond the
+assignments made carry weight 0 and an out-of-range token.  The rows are
+laid out expert by expert (`group_sizes`), which is what a grouped matrix
+product wants.
+
+    route(x, w_router, bias, ...)   -> RoutePlan for the held experts
+    experts(u, plan, w1, w2)        -> sum over held experts e of
+                                        weight_e * (relu(u W1_e)^2 W2_e)
+    moe_apply(...)                  -> the same over the 'ep' axis
+
+Routing is the sigmoid-score form of DeepSeek-V3 / Nemotron-H: scores
+s = sigmoid(x W_r^T) in float32, the top_k of s + bias chosen (the bias
+moves selection only), weights scale * s / sum of the chosen s.
+
+The two grouped products run through the TPU's grouped-matmul kernel
+(`jax.experimental.pallas.ops.tpu.megablox`, which skips the empty tail)
+in a program lowered for the TPU and through `jax.lax.ragged_dot`
+elsewhere; `route_counts()` says at trace time which was asked for.
 """
 from __future__ import annotations
 
-import functools
-import math
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 from jax.sharding import PartitionSpec as P
 
 from ..base import MXNetError
+from ..util import env
 from ._compat import shard_map_unchecked
 from .mesh import DeviceMesh, current_mesh
 
-__all__ = ["top1_dispatch", "moe_apply"]
+__all__ = ["RoutePlan", "route", "experts", "moe_apply", "plan_rows",
+           "route_counts"]
+
+ROW_TILE = 512      # rows a grouped-product tile takes: `rows` is a multiple
+
+ROUTES = ("grouped_kernel", "ragged_dot")
+_route_counts = dict.fromkeys(ROUTES, 0)
 
 
-def top1_dispatch(gate_logits, capacity):
-    """[T, E] logits -> (dispatch [T,E,C] one-hot, combine [T,E,C]
-    gate-weighted, dropped_frac scalar, gate probs [T,E] fp32).  Top-1
-    routing; each expert accepts its first `capacity` tokens in order,
-    later ones drop."""
-    t, e = gate_logits.shape
-    probs = jax.nn.softmax(gate_logits.astype(jnp.float32), axis=-1)
-    expert = jnp.argmax(probs, axis=-1)                       # [T]
-    gate = jnp.max(probs, axis=-1)                            # [T]
-    onehot_e = jax.nn.one_hot(expert, e, dtype=jnp.float32)   # [T, E]
-    # position of each token within its expert's queue
-    pos = jnp.cumsum(onehot_e, axis=0) * onehot_e - onehot_e  # [T, E]
-    pos_t = jnp.sum(pos, axis=-1)                             # [T]
-    keep = pos_t < capacity
-    onehot_c = jax.nn.one_hot(pos_t.astype(jnp.int32), capacity,
-                              dtype=jnp.float32)              # [T, C]
-    dispatch = (onehot_e[:, :, None] * onehot_c[:, None, :]
-                * keep[:, None, None].astype(jnp.float32))
-    combine = dispatch * gate[:, None, None]
-    dropped = 1.0 - jnp.sum(dispatch) / t
-    return dispatch, combine, dropped, probs
+def route_counts():
+    """{route: `experts` calls traced through it} since import.  As for
+    attention, `grouped_kernel` in a program lowered for the CPU runs its
+    `ragged_dot` twin."""
+    return dict(_route_counts)
 
 
-def moe_apply(expert_fn, stacked_params, x, gate_logits, *,
-              capacity_factor: float = 1.25,
-              mesh: Optional[DeviceMesh] = None, axis_name: str = "ep"):
-    """Apply a top-1 MoE layer.
+class RoutePlan(NamedTuple):
+    """Where the held experts' work is, rows laid out expert by expert."""
+    token: jax.Array        # (rows,) int32: the row's token; T where unused
+    weight: jax.Array       # (rows,) float32: its combine weight; 0 unused
+    group_sizes: jax.Array  # (n_local,) int32: rows of each held expert
+    dropped: jax.Array      # () int32: assignments that found no row (0)
 
-    expert_fn(params_i, tokens [C, D]) -> [C, D'] — ONE expert's
-    computation; stacked_params: pytree with leading expert dim E
-    (sharded over 'ep' when present); x [T, D]; gate_logits [T, E].
-    Returns (y [T, D'], aux dict with 'gate_probs' [T,E] fp32 and
-    'dropped_frac' scalar — feed them to a load-balance loss).
-    """
-    t, _d = x.shape
-    e = gate_logits.shape[-1]
-    first = jax.tree_util.tree_leaves(stacked_params)[0]
-    if first.shape[0] != e:
-        raise MXNetError(
-            f"stacked expert dim {first.shape[0]} != gate width {e}")
-    capacity = max(1, math.ceil(t / e * capacity_factor))
-    dispatch, combine, dropped, probs = top1_dispatch(gate_logits,
-                                                      capacity)
-    ex_in = jnp.einsum("tec,td->ecd", dispatch,
-                       x.astype(jnp.float32)).astype(x.dtype)
 
+def plan_rows(tokens: int, top_k: int, n_local: int) -> int:
+    """Rows that hold every assignment onto `n_local` held experts."""
+    rows = tokens * min(top_k, n_local)
+    return -(-rows // ROW_TILE) * ROW_TILE
+
+
+def route(x, w_router, bias, *, top_k: int, scale: float = 1.0,
+          first_expert=0, n_local: Optional[int] = None) -> RoutePlan:
+    """Score `x` (T, D) against all E rows of `w_router` (E, D) in
+    float32, choose `top_k` experts a token by score + `bias` (E,), and
+    lay out the assignments that land on experts `first_expert ..
+    first_expert + n_local - 1` (`first_expert` may be traced, as under a
+    shard_map)."""
+    t, e = x.shape[0], w_router.shape[0]
+    n_local = e if n_local is None else n_local
+    if not 0 < top_k <= e or not 0 < n_local <= e:
+        raise MXNetError(f"route: top_k {top_k} and held experts {n_local} "
+                         f"must lie in 1..{e}")
+    scores = jax.nn.sigmoid(jnp.einsum(
+        "td,ed->te", x.astype(jnp.float32), w_router.astype(jnp.float32),
+        precision=lax.Precision.HIGHEST))
+    _, chosen = lax.top_k(scores + lax.stop_gradient(
+        bias.astype(jnp.float32)), top_k)                       # (T, k)
+    picked = jnp.take_along_axis(scores, chosen, axis=1)
+    weights = scale * picked / (picked.sum(-1, keepdims=True) + 1e-20)
+
+    # (T, n_local) tables of the assignments that land here; column
+    # n_local collects the others and is cut off
+    local = chosen - first_expert
+    col = jnp.where((local >= 0) & (local < n_local), local, n_local)
+    row_of = jnp.arange(t)[:, None]
+    hit = jnp.zeros((t, n_local + 1), jnp.int32).at[row_of, col].add(
+        1)[:, :n_local]
+    wt = jnp.zeros((t, n_local + 1), jnp.float32).at[row_of, col].add(
+        weights)[:, :n_local]
+    group_sizes = hit.sum(0)
+    offsets = jnp.cumsum(group_sizes) - group_sizes
+    rows = plan_rows(t, top_k, n_local)
+    # a token's row: its expert's offset + how many earlier tokens chose it
+    pos = jnp.where(hit > 0, offsets + jnp.cumsum(hit, axis=0) - hit, rows)
+    token = jnp.full((rows,), t, jnp.int32).at[pos].set(
+        jnp.broadcast_to(row_of, pos.shape).astype(jnp.int32), mode="drop")
+    weight = jnp.zeros((rows,), jnp.float32).at[pos].set(wt, mode="drop")
+    dropped = group_sizes.sum() - (token < t).sum().astype(jnp.int32)
+    return RoutePlan(token, weight, group_sizes, dropped)
+
+
+def _ragged(lhs, rhs, group_sizes):
+    return lax.ragged_dot(lhs, rhs, group_sizes,
+                          preferred_element_type=lhs.dtype)
+
+
+def _tile(dim: int, cap: int) -> int:
+    """The largest multiple of 128 up to `cap` that divides `dim`, or
+    `dim` itself where there is none."""
+    return next((t for t in range(cap - cap % 128, 0, -128)
+                 if dim % t == 0), dim)
+
+
+def _gmm(lhs, rhs, group_sizes):
+    from jax.experimental.pallas.ops.tpu.megablox import gmm
+
+    return gmm(lhs, rhs, group_sizes, preferred_element_type=lhs.dtype,
+               tiling=(ROW_TILE, _tile(lhs.shape[1], 1024),
+                       _tile(rhs.shape[2], 512)))
+
+
+def _grouped(lhs, rhs, group_sizes):
+    """lhs (rows, K) @ rhs[g] (K, N) for the rows of group g.  Rows past
+    the last group come back undefined from the kernel: the caller masks
+    them."""
+    if not env.get_bool("MXNET_USE_PALLAS"):
+        _route_counts["ragged_dot"] += 1
+        return _ragged(lhs, rhs, group_sizes)
+    _route_counts["grouped_kernel"] += 1
+    return lax.platform_dependent(lhs, rhs, group_sizes, tpu=_gmm,
+                                  default=_ragged)
+
+
+def experts(u, plan: RoutePlan, w1, w2):
+    """The held experts' part of the layer: u (T, K) tokens, w1
+    (n_local, K, N), w2 (n_local, N, K); returns (T, K) in u's dtype:
+    sum over a token's held experts of weight * relu(u W1_e)^2 W2_e."""
+    t = u.shape[0]
+    rows = jnp.take(u, plan.token, axis=0, mode="fill", fill_value=0)
+    hidden = _grouped(rows, w1, plan.group_sizes)
+    hidden = jnp.square(jnp.maximum(hidden, 0))
+    out = _grouped(hidden, w2, plan.group_sizes)
+    used = (plan.token < t)[:, None]
+    out = jnp.where(used, out, 0).astype(jnp.float32) * plan.weight[:, None]
+    return jnp.zeros((t, u.shape[1]), jnp.float32).at[plan.token].add(
+        out, mode="drop").astype(u.dtype)
+
+
+def moe_apply(x, u, w_router, bias, w1, w2, *, top_k: int,
+              scale: float = 1.0, mesh: Optional[DeviceMesh] = None,
+              axis_name: str = "ep"):
+    """The routed part of one expert layer over every expert in `w1` /
+    `w2` (E, ...): tokens x (T, D) are scored, their latents u (T, K) go
+    through the chosen experts.  Under a mesh with an `axis_name` axis of
+    several devices the stacked experts are split over it, each device
+    routes over all E and computes its own share (tokens replicated in
+    the group, as the weights outside the experts are), and the shares
+    are summed; without one the layer runs in one piece.  Returns ((T, K)
+    result, dropped assignments: always 0)."""
+    e = w1.shape[0]
+    if w_router.shape[0] != e:
+        raise MXNetError(f"stacked experts {e} != router width "
+                         f"{w_router.shape[0]}")
     mesh = mesh or current_mesh()
+    n = mesh.size(axis_name) if mesh is not None and axis_name in mesh else 1
+    if e % n:
+        raise MXNetError(f"experts ({e}) must divide over '{axis_name}' "
+                         f"({n})")
 
-    def run_local(params, xin):
-        return jax.vmap(expert_fn)(params, xin)
+    def share(first, x, u, w_router, bias, w1, w2):
+        plan = route(x, w_router, bias, top_k=top_k, scale=scale,
+                     first_expert=first, n_local=w1.shape[0])
+        return experts(u, plan, w1, w2), plan.dropped
 
-    if mesh is not None and axis_name in mesh \
-            and mesh.size(axis_name) > 1:
-        if e % mesh.size(axis_name):
-            raise MXNetError(
-                f"experts ({e}) must divide over '{axis_name}' "
-                f"({mesh.size(axis_name)})")
-        p_spec = jax.tree_util.tree_map(
-            lambda a: P(axis_name, *([None] * (a.ndim - 1))),
-            stacked_params)
-        fn = shard_map_unchecked(
-            run_local, mesh=mesh.mesh,
-            in_specs=(p_spec, P(axis_name, None, None)),
-            out_specs=P(axis_name, None, None))
-        ex_out = fn(stacked_params, ex_in)
-    else:
-        ex_out = run_local(stacked_params, ex_in)
+    if n == 1:
+        return share(0, x, u, w_router, bias, w1, w2)
 
-    y = jnp.einsum("tec,ecd->td", combine,
-                   ex_out.astype(jnp.float32)).astype(x.dtype)
-    return y, {"gate_probs": probs, "dropped_frac": dropped}
+    def per_device(x, u, w_router, bias, w1, w2):
+        first = lax.axis_index(axis_name) * w1.shape[0]
+        part, dropped = share(first, x, u, w_router, bias, w1, w2)
+        return lax.psum(part, axis_name), lax.psum(dropped, axis_name)
+
+    held = P(axis_name, None, None)
+    return shard_map_unchecked(
+        per_device, mesh=mesh.mesh,
+        in_specs=(P(), P(), P(), P(), held, held),
+        out_specs=(P(), P()))(x, u, w_router, bias, w1, w2)

@@ -101,6 +101,24 @@ def test_attention_train_stage_toy_interpret(monkeypatch, dtype, n_dev):
     assert max(errs.values()) < (1e-4 if dtype == "float32" else 2e-2)
 
 
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_decoder_phase_toy(dtype):
+    """The decoder stage's two comparisons at toy sizes, lowered for the
+    CPU (the routes' XLA twins against the stage's plain oracles)."""
+    out = chip_smoke.decoder_phase(seq=128, heads=4, tokens=64, experts=8,
+                                   held=2, top_k=3, latent=16, width=24,
+                                   dtype=dtype, expect_mosaic=False)
+    assert len(out) == 2
+    assert max(v["max_rel_err"] for v in out.values()) < (
+        1e-4 if dtype == "float32" else 2e-2)
+
+
+def test_decoder_phase_rejects_the_reference_in_the_kernels_place():
+    with pytest.raises(AssertionError, match="no Mosaic call"):
+        chip_smoke.decoder_phase(seq=128, heads=4, tokens=64, experts=8,
+                                 held=2, top_k=3, latent=16, width=24)
+
+
 def test_attention_train_stage_rejects_the_reference_in_the_kernels_place():
     """Lowered for the CPU the route takes the XLA reference: counted as
     `fused_train`, but no Mosaic call, and the smoke must say so."""
@@ -259,6 +277,73 @@ print("AOT_OK")
 """
 
 
+# the decoder's two kernel routes at the nemotron3_super_s8192 cell's
+# shapes: jax.grad through the causal grouped-query attention route (32
+# query heads over 2 key/value heads of 128, S = 8192) and through the
+# held experts' grouped products (8192 tokens, 8 experts held of a top-22
+# router: 65536 rows, latent 1024, expert 2688)
+_AOT_DECODER = r"""
+import re, sys
+import jax, jax.numpy as jnp
+from jax.experimental import topologies
+from jax.sharding import SingleDeviceSharding
+try:
+    topo = topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
+except Exception as e:
+    print("NO_TPU_COMPILER", type(e).__name__, e)
+    sys.exit(0)
+from mxnet_tpu.ops import pallas_attention as pa
+from mxnet_tpu.parallel import moe
+sh = SingleDeviceSharding(topo.devices[0])
+arg = lambda shape, dt=jnp.bfloat16: jax.ShapeDtypeStruct(
+    shape, dt, sharding=sh)
+
+def mosaic_names(fn, *args):
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    calls = [ln for ln in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln]
+    return [re.search(r'op_name="([^"]*)"', ln).group(1) for ln in calls]
+
+s, h, kv, d = 8192, 32, 2, 128
+def attention_loss(q, k, v):
+    with jax.named_scope("dot_product_attention"):
+        o = pa._dot_product_attention(q, k, v, None, None, num_heads=h,
+                                      num_kv_heads=kv, causal=True,
+                                      _train=True)
+    return o.astype(jnp.float32).sum()
+names = mosaic_names(jax.grad(attention_loss, argnums=(0, 1, 2)),
+                     arg((1, s, h * d)), arg((1, s, kv * d)),
+                     arg((1, s, kv * d)))
+print("MOSAIC attention", names)
+assert len(names) == 3, names       # forward, dK/dV, dQ
+assert all("dot_product_attention" in n for n in names), names
+assert sum("transpose(" in n for n in names) == 2, names
+assert pa.route_counts()["flash_causal"] == 1, pa.route_counts()
+
+t, held, top_k, latent, width = 8192, 8, 22, 1024, 2688
+rows = moe.plan_rows(t, top_k, held)
+assert rows == t * held
+def experts_loss(u, w1, w2, token, weight, sizes):
+    plan = moe.RoutePlan(token, weight, sizes, jnp.zeros((), jnp.int32))
+    with jax.named_scope("moe_experts"):
+        return moe.experts(u, plan, w1, w2).astype(jnp.float32).sum()
+names = mosaic_names(
+    jax.grad(experts_loss, argnums=(0, 1, 2)), arg((t, latent)),
+    arg((held, latent, width)), arg((held, width, latent)),
+    arg((rows,), jnp.int32), arg((rows,), jnp.float32),
+    arg((held,), jnp.int32))
+print("MOSAIC experts", names)
+# the gradient of a sum needs the first product's forward (the second's
+# output is dead) and, for each product, dlhs and the transposed drhs
+assert all("moe_experts" in n for n in names), names
+assert sum("transpose(" not in n for n in names) == 1, names
+assert sum("transpose(" in n and "gmm" in n for n in names) == 4, names
+assert moe.route_counts()["grouped_kernel"] == 2, moe.route_counts()
+print("AOT_OK")
+"""
+
+
 def test_attention_kernel_compiles_for_v5e_ahead_of_time():
     """Mosaic runs inside libtpu's compiler, which works without a chip.
     The kernel's old one-shot probe compiled fp32 (2,128,64) and passed
@@ -295,6 +380,20 @@ def test_attention_training_route_compiles_beyond_the_benchmark_shapes(mode):
     divide, S=1024, 128- and 256-wide heads and f32 operands fit the VMEM
     the code asks for."""
     p = _run(["-c", _AOT_TRAIN_MORE, mode], timeout=300)
+    if "NO_TPU_COMPILER" in p.stdout:
+        pytest.skip(p.stdout.strip()[:200])
+    assert p.returncode == 0 and "AOT_OK" in p.stdout, \
+        p.stdout[-2000:] + p.stderr[-3000:]
+
+
+def test_decoder_kernel_routes_compile_for_v5e_ahead_of_time():
+    """Causal grouped-query training attention through the O(S) flash
+    route, and the expert layer's grouped products through the grouped-
+    matmul kernel, at the hybrid decoder cell's shapes: Mosaic takes
+    them, and every call keeps its op scope and, in the backward,
+    `transpose(`: what `causal_attention_device_ms` and `moe_device_ms`
+    are read by."""
+    p = _run(["-c", _AOT_DECODER], timeout=300)
     if "NO_TPU_COMPILER" in p.stdout:
         pytest.skip(p.stdout.strip()[:200])
     assert p.returncode == 0 and "AOT_OK" in p.stdout, \

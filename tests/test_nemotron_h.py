@@ -1,0 +1,404 @@
+"""The hybrid decoder (PR 27): the Mamba-2 scan and convolution, the
+latent mixture of experts that holds a share of its experts, causal
+grouped-query training attention, and the zoo's Nemotron-H stack against
+the benchmark's plain reference."""
+import importlib.util
+import json
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import mxnet_tpu as mx
+from mxnet_tpu.gluon.block import ActiveTrace
+from mxnet_tpu.gluon.model_zoo import nemotron_h as zoo
+from mxnet_tpu.ops import pallas_attention as pa
+from mxnet_tpu.ops import ssm
+from mxnet_tpu.ops.registry import apply_pure
+from mxnet_tpu.parallel import moe, spmd
+
+_REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+_CONFIG_DIR = os.path.join(_REPO, "benchmark", "configs",
+                           "nemotron3_super_120b")
+
+
+def _load(name):
+    spec = importlib.util.spec_from_file_location(
+        "nemotron3_" + name, os.path.join(_CONFIG_DIR, name + ".py"))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+@pytest.fixture(scope="module")
+def reference():
+    return _load("reference")
+
+
+@pytest.fixture(scope="module")
+def small_config():
+    with open(os.path.join(_CONFIG_DIR, "config.json")) as f:
+        config = json.load(f)
+    config.update(config["rehearsal"]["model"])
+    return config
+
+
+# ---- the scan and the convolution -----------------------------------------
+
+def _scan_inputs(rng, s, dtype, b=2, h=4, p=8, g=2, n=16):
+    x = rng.randn(b, s, h, p).astype(np.float32)
+    dt = rng.randn(b, s, h).astype(np.float32)
+    a_log = np.log(rng.uniform(1, 16, h)).astype(np.float32)
+    bm = rng.randn(b, s, g, n).astype(np.float32) * 0.5
+    cm = rng.randn(b, s, g, n).astype(np.float32) * 0.5
+    d = rng.randn(h).astype(np.float32)
+    dt_bias = rng.randn(h).astype(np.float32) - 3.0
+    cast = [jnp.asarray(v, dtype) for v in (x, dt)]
+    return (cast[0], cast[1], jnp.asarray(a_log), jnp.asarray(bm, dtype),
+            jnp.asarray(cm, dtype), jnp.asarray(d), jnp.asarray(dt_bias))
+
+
+@pytest.mark.parametrize("s,chunk", [(64, 16), (48, 8), (32, 32), (8, 128)])
+def test_chunked_scan_matches_the_recurrence_values_and_gradients(s, chunk):
+    args = _scan_inputs(np.random.RandomState(s + chunk), s, jnp.float32)
+    before = ssm.route_counts()["chunked_xla"]
+
+    def chunked(*a):
+        return apply_pure("ssd_scan", *a, chunk=chunk)
+
+    weights = jnp.asarray(np.random.RandomState(1).randn(*args[0].shape),
+                          jnp.float32)
+
+    def value_and_gradients(f):
+        def weighted(*a):
+            y = f(*a)
+            return (y * weights).sum(), y
+        return jax.jit(jax.value_and_grad(
+            weighted, argnums=tuple(range(7)), has_aux=True))(*args)
+
+    (_, got), got_grads = value_and_gradients(chunked)
+    assert ssm.route_counts()["chunked_xla"] == before + 1
+    (_, want), want_grads = value_and_gradients(ssm.ssd_scan_sequential)
+    np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-5)
+    for g, w in zip(got_grads, want_grads):
+        np.testing.assert_allclose(g, w, rtol=2e-3, atol=2e-4)
+
+
+def test_chunked_scan_keeps_its_decays_in_float32_under_bfloat16_inputs():
+    """bfloat16 x, B, C and dt: the products take bfloat16 operands, but
+    the decays and the state carried over 32 chunks stay float32.  A
+    bfloat16 cumulative decay alone would be off by a few percent."""
+    args = _scan_inputs(np.random.RandomState(7), 512, jnp.bfloat16)
+    got = jax.jit(lambda *a: apply_pure("ssd_scan", *a, chunk=16))(*args)
+    assert got.dtype == jnp.bfloat16
+    want = jax.jit(ssm.ssd_scan_sequential)(
+        *(a.astype(jnp.float32) for a in args))
+    err = np.linalg.norm(np.asarray(got, np.float32) - want) \
+        / np.linalg.norm(want)
+    assert err < 0.012, err
+
+
+def test_scan_refuses_a_sequence_its_chunk_does_not_divide():
+    args = _scan_inputs(np.random.RandomState(0), 24, jnp.float32)
+    with pytest.raises(mx.MXNetError, match="multiple of the chunk"):
+        apply_pure("ssd_scan", *args, chunk=16)
+
+
+def test_causal_conv1d_is_causal_and_depthwise():
+    rng = np.random.RandomState(0)
+    x = rng.randn(2, 10, 6).astype(np.float32)
+    w = rng.randn(6, 4).astype(np.float32)
+    bias = rng.randn(6).astype(np.float32)
+    want = np.zeros_like(x)
+    for t in range(10):
+        for j in range(4):
+            if t - 3 + j >= 0:
+                want[:, t] += w[:, j] * x[:, t - 3 + j]
+    want += bias
+    got = apply_pure("causal_conv1d", jnp.asarray(x), jnp.asarray(w),
+                     jnp.asarray(bias))
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
+    later = x.copy()
+    later[:, 5:] += 1.0                 # the past does not see the future
+    again = apply_pure("causal_conv1d", jnp.asarray(later), jnp.asarray(w),
+                       jnp.asarray(bias))
+    np.testing.assert_array_equal(np.asarray(again)[:, :5],
+                                  np.asarray(got)[:, :5])
+
+
+# ---- the router and the experts --------------------------------------------
+
+def _moe_weights(rng, t=40, d=16, k=8, n=12, e=8):
+    return dict(x=rng.randn(t, d).astype(np.float32),
+                u=rng.randn(t, k).astype(np.float32),
+                wr=rng.randn(e, d).astype(np.float32) * 0.5,
+                b=np.zeros(e, np.float32),
+                w1=rng.randn(e, k, n).astype(np.float32) * 0.3,
+                w2=rng.randn(e, n, k).astype(np.float32) * 0.3)
+
+
+def _routed_oracle(w, top_k, scale, held):
+    """The routed part, token by token, over the experts in `held`."""
+    score = 1.0 / (1.0 + np.exp(-(w["x"] @ w["wr"].T)))
+    out = np.zeros_like(w["u"])
+    for t in range(len(score)):
+        chosen = np.argsort(-(score[t] + w["b"]), kind="stable")[:top_k]
+        total = score[t, chosen].sum() + 1e-20
+        for e in chosen:
+            if e in held:
+                hidden = np.maximum(w["u"][t] @ w["w1"][e], 0) ** 2
+                out[t] += scale * score[t, e] / total * (hidden @ w["w2"][e])
+    return out
+
+
+def test_router_top_k_normalisation_scale_and_bias():
+    rng = np.random.RandomState(3)
+    w = _moe_weights(rng)
+    x, wr = jnp.asarray(w["x"]), jnp.asarray(w["wr"])
+    score = 1.0 / (1.0 + np.exp(-(w["x"] @ w["wr"].T)))
+
+    def table(plan):
+        """(T, E) combine weights from a plan over all experts."""
+        out = np.zeros((len(score), 8), np.float32)
+        sizes = np.asarray(plan.group_sizes)
+        expert = np.repeat(np.arange(8), sizes)
+        rows = sizes.sum()
+        out[np.asarray(plan.token)[:rows], expert] = \
+            np.asarray(plan.weight)[:rows]
+        return out
+
+    plan = moe.route(x, wr, jnp.zeros(8), top_k=3, scale=5.0)
+    got = table(plan)
+    assert int(plan.dropped) == 0 and int(plan.group_sizes.sum()) == 40 * 3
+    for t in range(40):
+        chosen = set(np.argsort(-score[t])[:3])
+        assert set(np.nonzero(got[t])[0]) == chosen
+        np.testing.assert_allclose(got[t].sum(), 5.0, rtol=1e-5)
+        for e in chosen:
+            np.testing.assert_allclose(
+                got[t, e], 5.0 * score[t, e] / score[t, list(chosen)].sum(),
+                rtol=1e-5)
+    # a bias on expert 7 moves the selection towards it, and the weights
+    # still come from the scores alone
+    bias = np.zeros(8, np.float32)
+    bias[7] = 10.0
+    biased = table(moe.route(x, wr, jnp.asarray(bias), top_k=3, scale=5.0))
+    assert (biased[:, 7] > 0).all()
+    for t in range(40):
+        chosen = np.nonzero(biased[t])[0]
+        np.testing.assert_allclose(
+            biased[t, 7], 5.0 * score[t, 7] / score[t, chosen].sum(),
+            rtol=1e-5)
+
+
+def test_every_token_on_one_held_expert_nothing_dropped():
+    """The worst imbalance: a bias sends every token to expert 2 (and
+    its two next choices elsewhere); the layer holds experts 2 and 3."""
+    w = _moe_weights(np.random.RandomState(4))
+    w["b"][2] = 10.0
+    plan = moe.route(jnp.asarray(w["x"]), jnp.asarray(w["wr"]),
+                     jnp.asarray(w["b"]), top_k=3, scale=2.0,
+                     first_expert=2, n_local=2)
+    assert int(plan.group_sizes[0]) == 40 and int(plan.dropped) == 0
+    got = moe.experts(jnp.asarray(w["u"]), plan, jnp.asarray(w["w1"][2:4]),
+                      jnp.asarray(w["w2"][2:4]))
+    np.testing.assert_allclose(got, _routed_oracle(w, 3, 2.0, {2, 3}),
+                               rtol=2e-5, atol=2e-5)
+
+
+def test_expert_products_count_their_route(monkeypatch):
+    w = _moe_weights(np.random.RandomState(5))
+    args = [jnp.asarray(w[k]) for k in ("x", "u", "wr", "b", "w1", "w2")]
+    before = moe.route_counts()
+    moe.moe_apply(*args, top_k=2)
+    monkeypatch.setenv("MXNET_USE_PALLAS", "0")
+    moe.moe_apply(*args, top_k=2)
+    after = moe.route_counts()
+    assert after["grouped_kernel"] == before["grouped_kernel"] + 2
+    assert after["ragged_dot"] == before["ragged_dot"] + 2
+
+
+def _layer(held=None, first=0, **kw):
+    layer = zoo.LatentMoELayer(16, 8, 3, 8, 12, 24, 2.5, 1e-5,
+                               experts_held=held, first_expert=first, **kw)
+    layer.initialize(mx.initializer.Normal(0.3), ctx=mx.cpu())
+    return layer
+
+
+def _apply(layer, x, values=None):
+    """layer(x) traced on raw values, its parameters from `values` (by
+    the names hybrid_forward takes them) where given."""
+    params = {id(p): jnp.asarray(values[n]) if values else p.data().data
+              for n, p in layer._reg_params.items()}
+    with ActiveTrace(params, train=False):
+        out, stats = layer.forward(jnp.asarray(x))
+    return np.asarray(out), np.asarray(stats)
+
+
+def test_the_shares_add_up_to_the_uncut_layer(reference):
+    """Over all disjoint shares of the experts, the routed parts plus the
+    shared expert counted once equal the uncut layer, which equals the
+    plain reference's layer."""
+    np.random.seed(11)
+    whole = _layer(prefix="whole_")
+    values = {n: np.asarray(p.data().data)
+              for n, p in whole._reg_params.items()}
+    x = np.random.RandomState(0).randn(2, 20, 16).astype(np.float32)
+    full, stats = _apply(whole, x)
+    assert stats[:-1].sum() == 40 * 3 and stats[-1] == 0
+
+    cfg = {"num_experts_per_tok": 3, "routed_scaling_factor": 2.5}
+    normed = reference.rms_norm(jnp.asarray(x.reshape(40, 16)),
+                                values["norm_weight"], 1e-5)
+    flat = {"l_" + n: jnp.asarray(v) for n, v in values.items()}
+    want = x.reshape(40, 16) + np.asarray(
+        reference.moe(flat, "l_", normed, cfg))
+    np.testing.assert_allclose(full.reshape(40, 16), want, rtol=2e-4,
+                               atol=2e-5)
+
+    shared = np.asarray(
+        reference.relu2(normed @ values["shared_up_weight"].T)
+        @ values["shared_down_weight"].T).reshape(x.shape)
+    total = np.zeros_like(full)
+    for first in (0, 2, 4, 6):
+        share = _layer(held=2, first=first, prefix=f"share{first}_")
+        cut = dict(values,
+                   experts_w1=values["experts_w1"][first:first + 2],
+                   experts_w2=values["experts_w2"][first:first + 2])
+        part, part_stats = _apply(share, x, cut)
+        assert part_stats[-1] == 0
+        np.testing.assert_array_equal(part_stats[:2],
+                                      stats[first:first + 2])
+        total += part - x - shared
+    np.testing.assert_allclose(total + x + shared, full, rtol=2e-4,
+                               atol=2e-5)
+
+
+# ---- causal grouped-query attention -----------------------------------------
+
+def test_causal_grouped_query_attention_values_gradients_and_route():
+    rng = np.random.RandomState(2)
+    b, h, kv, s, d = 2, 4, 2, 128, 128
+    q = jnp.asarray(rng.randn(b, s, h * d), jnp.float32)
+    k = jnp.asarray(rng.randn(b, s, kv * d), jnp.float32)
+    v = jnp.asarray(rng.randn(b, s, kv * d), jnp.float32)
+    ct = jnp.asarray(rng.randn(b, s, h * d), jnp.float32)
+
+    def op(q, k, v, train):
+        return apply_pure("dot_product_attention", q, k, v, None, None,
+                          num_heads=h, num_kv_heads=kv, causal=True,
+                          _train=train)
+
+    def plain(q, k, v):
+        def heads(x, n):
+            return x.reshape(b, s, n, d).transpose(0, 2, 1, 3)
+        kh, vh = (jnp.repeat(heads(x, kv), h // kv, axis=1) for x in (k, v))
+        out = pa.dot_product_attention_ref(
+            heads(q, h).reshape(b * h, s, d), kh.reshape(b * h, s, d),
+            vh.reshape(b * h, s, d), None, d ** -0.5, causal=True)
+        return out.reshape(b, h, s, d).transpose(0, 2, 1, 3).reshape(
+            b, s, h * d)
+
+    before = pa.route_counts()
+    got = [op(q, k, v, train) for train in (True, False)]
+    after = pa.route_counts()
+    assert after["flash_causal"] == before["flash_causal"] + 2
+    assert after["kernel_infer"] == before["kernel_infer"]
+    for o in got:
+        np.testing.assert_allclose(o, plain(q, k, v), rtol=2e-5, atol=2e-5)
+    grads = [jax.grad(lambda *a: (f(*a) * ct).sum(), argnums=(0, 1, 2))(
+        q, k, v) for f in (lambda *a: op(*a, True), plain)]
+    for g, w in zip(*grads):
+        np.testing.assert_allclose(g, w, rtol=2e-4, atol=2e-4)
+    # a key mask keeps its old route
+    after = pa.route_counts()
+    apply_pure("dot_product_attention", q, k, v, jnp.ones((b, s)), None,
+               num_heads=h, num_kv_heads=kv, causal=True)
+    assert pa.route_counts()["flash_causal"] == after["flash_causal"]
+    assert pa.route_counts()["kernel_infer"] == after["kernel_infer"] + 1
+
+
+# ---- the whole model ---------------------------------------------------------
+
+def _small_model(config, model_py):
+    np.random.seed(5)
+    mx.random.seed(5)
+    step = model_py._step_block(config)
+    step.initialize(mx.initializer.Normal(0.02), ctx=mx.cpu())
+    return step
+
+
+def test_model_matches_the_plain_reference_logits_loss_and_gradients(
+        reference, small_config):
+    model_py = _load("model")
+    step = _small_model(small_config, model_py)
+    plist = sorted(step.collect_params().items())
+    prefix = os.path.commonprefix([n for n, _ in plist])
+    prefix = prefix[:prefix.rfind("_") + 1]
+    values = {n: p.data().data for n, p in plist}
+    named = {n[len(prefix):]: v for n, v in values.items()}
+    tokens = jnp.asarray(np.random.RandomState(0).randint(
+        0, small_config["vocab_size"], (2, 32)), jnp.int32)
+
+    def system(values):
+        trace = ActiveTrace({id(p): values[n] for n, p in plist},
+                            train=True)
+        with trace:
+            return step.forward(tokens)
+
+    def plain(named):
+        scores = reference.logits(named, tokens, small_config)
+        return reference.loss_of(scores, tokens), scores
+
+    (loss, (logits, stats)), got = jax.jit(jax.value_and_grad(
+        lambda v: (lambda out: (out[0], out[1:]))(system(v)),
+        has_aux=True))(values)
+    (want_loss, want_logits), want = jax.jit(jax.value_and_grad(
+        plain, has_aux=True))(named)
+    assert (np.asarray(stats)[:, -1] == 0).all()
+    np.testing.assert_allclose(logits, want_logits, rtol=2e-3, atol=2e-5)
+    np.testing.assert_allclose(loss, want_loss, rtol=1e-5)
+    trained = [n for n, p in plist if p.grad_req != "null"]
+    assert len(trained) == len(plist) - small_config["pattern_held"].count("E")
+    for n in trained:
+        w = np.asarray(want[n[len(prefix):]])
+        np.testing.assert_allclose(
+            got[n], w, rtol=5e-3, atol=5e-3 * np.abs(w).max() + 1e-9,
+            err_msg=n)
+
+
+def test_step_program_holds_the_new_op_scopes_forward_and_backward(
+        small_config):
+    """`ssd_scan`, `causal_conv1d`, `moe_route`, `moe_experts` and the
+    causal `dot_product_attention` under both `jvp(` and
+    `transpose(jvp(`, inside their layer's block scope, with remat on as
+    the cell runs it: what the cell's per-layer metrics are read by."""
+    model_py = _load("model")
+    np.random.seed(0)
+    trainer = model_py.build(0, dict(small_config, dtype="float32"),
+                             {"seq_len": 64, "batch": 1}, 1)
+    assert trainer.remat
+    tokens, = model_py.batch(0, small_config, {"seq_len": 64, "batch": 1},
+                             np.asarray)
+    first = float(trainer.step(tokens).asnumpy())
+    assert np.isfinite(first)
+    assert float(trainer.step(tokens).asnumpy()) < first
+    names = set(spmd.step_programs()[-1]["ops"].values())
+
+    def holds(*parts):
+        return any(all(p in n for p in parts) for n in names)
+
+    for layer, op in (("layer0_mamba", "ssd_scan"),
+                      ("layer0_mamba", "causal_conv1d"),
+                      ("layer1_moe", "moe_route"),
+                      ("layer1_moe", "moe_experts"),
+                      ("layer3_attn", "dot_product_attention"),
+                      ("layer4_moe", "FullyConnected")):
+        assert holds("/jvp(", f"/{layer}/{op}/"), (layer, op)
+        # under remat the backward's names repeat the layer's path, with
+        # `checkpoint` (and `rematted_computation` for the forward done
+        # again) between the layer and the op
+        assert holds("/transpose(jvp(", f"/{layer}/", f"/{op}/"), (layer, op)
+    assert holds("/transpose(jvp(", "rematted_computation/ssd_scan/")

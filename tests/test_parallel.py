@@ -539,45 +539,46 @@ def test_ulysses_rejects_indivisible_heads():
         parallel.ulysses.ulysses_attention_sharded(q, q, q)
 
 
-def _moe_oracle(ws, x, gl, capacity):
-    """Pure-numpy top-1 capacity MoE (GShard drop semantics)."""
-    t, e = gl.shape
-    probs = np.exp(gl - gl.max(-1, keepdims=True))
-    probs /= probs.sum(-1, keepdims=True)
-    pick = probs.argmax(-1)
-    gate = probs.max(-1)
-    counts = np.zeros(e, np.int64)
-    y = np.zeros((t, ws[0].shape[1]), np.float32)
-    for i in range(t):
-        ei = pick[i]
-        if counts[ei] < capacity:
-            y[i] = np.tanh(x[i] @ ws[ei]) * gate[i]
-            counts[ei] += 1
+def _moe_oracle(x, u, wr, w1, w2, top_k, scale):
+    """The routed part of a sigmoid top-k expert layer in numpy, token by
+    token: weight = scale * score / sum of the chosen scores; an expert
+    is relu(u W1)^2 W2.  Nothing is ever dropped."""
+    score = 1.0 / (1.0 + np.exp(-(x @ wr.T)))
+    y = np.zeros_like(u)
+    for t in range(len(x)):
+        chosen = np.argsort(-score[t], kind="stable")[:top_k]
+        for e in chosen:
+            hidden = np.maximum(u[t] @ w1[e], 0) ** 2
+            y[t] += (scale * score[t, e] / score[t, chosen].sum()
+                     * (hidden @ w2[e]))
     return y
 
 
-def test_moe_expert_parallel_matches_oracle():
-    import math
+@pytest.mark.parametrize("ep", [4, 2])
+def test_moe_expert_parallel_matches_oracle(ep):
+    """The expert layer over an `ep` mesh (each device holds E / ep
+    experts, routes over all E, computes its own part; the parts are
+    summed) equals the one-program result and the numpy oracle."""
     from mxnet_tpu.parallel import moe
 
     rng = np.random.RandomState(5)
-    T, D, E, cf = 32, 8, 4, 1.25
+    T, D, K, N, E = 32, 8, 8, 12, 8
     x = rng.randn(T, D).astype(np.float32)
-    gl = rng.randn(T, E).astype(np.float32)
-    ws = [rng.randn(D, D).astype(np.float32) * 0.3 for _ in range(E)]
-    stacked = {"w": jnp.stack([jnp.asarray(w) for w in ws])}
-
-    def expert(p, tok):
-        return jnp.tanh(tok @ p["w"])
-
-    cap = max(1, math.ceil(T / E * cf))
-    ref = _moe_oracle(ws, x, gl, cap)
-    with parallel.make_mesh(ep=4):
-        y, aux = moe.moe_apply(expert, stacked, jnp.asarray(x),
-                               jnp.asarray(gl), capacity_factor=cf)
+    u = rng.randn(T, K).astype(np.float32)
+    wr = rng.randn(E, D).astype(np.float32) * 0.5
+    w1 = rng.randn(E, K, N).astype(np.float32) * 0.3
+    w2 = rng.randn(E, N, K).astype(np.float32) * 0.3
+    args = [jnp.asarray(a) for a in (x, u, wr, np.zeros(E, np.float32),
+                                     w1, w2)]
+    ref = _moe_oracle(x, u, wr, w1, w2, top_k=3, scale=2.5)
+    with parallel.make_mesh(ep=ep, devices=jax.devices()[:ep]):
+        y, dropped = jax.jit(lambda *a: moe.moe_apply(
+            *a, top_k=3, scale=2.5))(*args)
     np.testing.assert_allclose(np.asarray(y), ref, rtol=2e-5, atol=2e-5)
-    assert 0.0 <= float(aux["dropped_frac"]) < 1.0
-    # no-mesh fallback matches too
-    y2, _ = moe.moe_apply(expert, stacked, jnp.asarray(x),
-                          jnp.asarray(gl), capacity_factor=cf)
+    assert int(dropped) == 0
+    # without a mesh the same layer runs in one piece
+    y2, dropped2 = moe.moe_apply(*args, top_k=3, scale=2.5)
     np.testing.assert_allclose(np.asarray(y2), ref, rtol=2e-5, atol=2e-5)
+    np.testing.assert_allclose(np.asarray(y), np.asarray(y2), rtol=2e-5,
+                               atol=2e-5)
+    assert int(dropped2) == 0
