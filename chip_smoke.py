@@ -396,18 +396,22 @@ def attention_train_stage(shapes, *, dtype="bfloat16", expect_mosaic=True,
 
 def decoder_phase(*, seq=1024, heads=8, kv_heads=2, dim=128, tokens=2048,
                   experts=32, held=8, top_k=4, latent=256, width=384,
-                  dtype="bfloat16", expect_mosaic=True) -> dict:
-    """Value and gradients of the hybrid decoder's two kernel routes
+                  scan=(8192, 128, 64, 8, 128), dtype="bfloat16",
+                  expect_mosaic=True) -> dict:
+    """Value and gradients of the hybrid decoder's three kernel routes
     against plain XLA on the same operands: causal grouped-query attention
     through the op (upstream's flash kernels) against the S x S reference,
-    and the held experts' grouped products (the grouped-matmul kernel,
+    the held experts' grouped products (the grouped-matmul kernel,
     most of whose rows are the empty tail) against every held expert
-    applied densely to every token.  The benchmark's reference check sees
-    only their forward."""
+    applied densely to every token, and one Mamba-2 scan (`scan`: S, H,
+    P, G, N; the published widths at S = 8192) through `ops.pallas_ssd`'s
+    kernels against the `chunked_xla` route.  The benchmark's reference
+    check sees only their forward."""
     import jax
     import jax.numpy as jnp
 
     from mxnet_tpu.ops import pallas_attention as pa
+    from mxnet_tpu.ops import ssm
     from mxnet_tpu.parallel import moe
 
     rng = np.random.RandomState(0)
@@ -488,6 +492,27 @@ def decoder_phase(*, seq=1024, heads=8, kv_heads=2, dim=128, tokens=2048,
     compare(f"experts_t{tokens}_held{held}_k{latent}_n{width}", grouped,
             dense, (u, w1, w2, ct, plan.token, plan.weight,
                     plan.group_sizes), out)
+
+    s, h, p, g, n = scan
+    x, ct = (jnp.asarray(rng.randn(1, s, h, p), dtype) for _ in range(2))
+    bm, cm = (jnp.asarray(rng.randn(1, s, g, n) * 0.5, dtype)
+              for _ in range(2))
+    rest = (jnp.asarray(rng.randn(1, s, h), dtype),
+            jnp.asarray(np.log(rng.uniform(1, 16, h)), jnp.float32),
+            jnp.asarray(rng.randn(h), jnp.float32),
+            jnp.asarray(rng.randn(h) - 3.0, jnp.float32))
+    before = ssm.route_counts()["fused_kernel"]
+
+    def scanned(route):
+        def f(x, bm, cm, ct, dt, a_log, d, dt_bias):
+            return weighed(route(x, dt, a_log, bm, cm, d, dt_bias,
+                                 chunk=128), ct)
+        return f
+
+    compare(f"ssd_scan_s{s}_h{h}_p{p}_g{g}_n{n}", scanned(ssm._ssd_scan),
+            scanned(ssm._scan_xla), (x, bm, cm, ct, *rest), out)
+    _require(ssm.route_counts()["fused_kernel"] > before,
+             f"the scan did not take the kernel route: {ssm.route_counts()}")
     return out
 
 

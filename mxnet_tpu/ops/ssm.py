@@ -10,30 +10,43 @@ dt_t > 0:
 `ssd_scan` computes it chunk by chunk as four matrix products (scores
 C B^T within a chunk, decayed scores times values, the state each chunk
 leaves behind, the entering state times C) plus one small recurrence over
-the chunks; the backward is autodiff of the same products.  dt, a, the
-cumulative decays and their exponentials stay in float32 whatever the
-inputs' dtype, and so does the state carried from chunk to chunk; the
-products take their operands in the inputs' dtype and accumulate in
-float32, as the published kernels do.  `ssd_scan_sequential` is the
-recurrence above, step by step: the oracle the chunked form is tested
-against.
+the chunks.  dt, a, the cumulative decays and their exponentials stay in
+float32 whatever the inputs' dtype, and so does the state carried from
+chunk to chunk; the products take their operands in the inputs' dtype and
+accumulate in float32, as the published kernels do.
+`ssd_scan_sequential` is the recurrence above, step by step: the oracle
+both routes are tested against.
 
-One route so far, plain XLA; `route_counts()` says so at trace time, as
-`ops.pallas_attention.route_counts()` does for attention, so that a
-kernel PR has a counter to move.
+Two routes, one algorithm, chosen at trace time from what the op can
+observe (`route_counts()` counts them, as
+`ops.pallas_attention.route_counts()` does for attention):
+
+- `fused_kernel` (PR 28): `ops.pallas_ssd`'s forward and backward kernels
+  under a custom_vjp, every per-chunk intermediate in VMEM, in a program
+  lowered for the TPU (its XLA twin elsewhere, the kernels themselves
+  under `MXNET_PALLAS_INTERPRET`); taken when the widths fill whole lanes
+  (`pallas_ssd.supports`) and no mesh of several devices is active.
+- `chunked_xla`: plain XLA einsums, backward by autodiff; every other
+  shape, any mesh of several devices, and the reference the kernels are
+  tested against.
 """
 from __future__ import annotations
+
+import functools
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 
 from ..base import MXNetError
+from ..util import env
+from . import pallas_ssd
+from .pallas_attention import _mesh_batch_axes
 from .registry import register_op
 
 __all__ = ["ssd_scan_sequential", "route_counts"]
 
-ROUTES = ("chunked_xla",)
+ROUTES = ("chunked_xla", "fused_kernel")
 _route_counts = dict.fromkeys(ROUTES, 0)
 
 
@@ -151,8 +164,46 @@ def _ssd_scan(x, dt, a_log, b, c, d, dt_bias, chunk=128):
         raise MXNetError(
             f"ssd_scan: sequence {s} must be a multiple of the chunk "
             f"{chunk}, heads {h} of the groups {g}")
-    _route_counts["chunked_xla"] += 1
+    # GSPMD cannot partition a Mosaic call: no mesh of several devices
+    fused = (env.get_bool("MXNET_USE_PALLAS")
+             and _mesh_batch_axes(x.shape[0]) is None
+             and pallas_ssd.supports(h, x.shape[3], g, b.shape[3], s, chunk))
+    _route_counts["fused_kernel" if fused else "chunked_xla"] += 1
+    operands = (x, dt, a_log, b, c, d, dt_bias)
+    xla = functools.partial(_scan_xla, chunk=chunk)
+    kernels = functools.partial(_scan_kernels, chunk=chunk)
+    if not fused:
+        return xla(*operands)
+    if env.get_bool("MXNET_PALLAS_INTERPRET"):
+        return kernels(*operands)
+    # autodiff goes through the chosen branch, as in `_attend_causal`
+    return jax.lax.platform_dependent(*operands, tpu=kernels, default=xla)
+
+
+def _scan_xla(x, dt, a_log, b, c, d, dt_bias, chunk):
     dt, a = _discretize(dt, dt_bias, a_log)
     y = _ssd_chunked(x, dt, a, b, c, chunk)
     y = y + d.astype(jnp.float32)[:, None] * x.astype(jnp.float32)
     return y.astype(x.dtype)
+
+
+def _kernel_operands(x, dt, a_log, b, c, d, dt_bias, chunk):
+    """What `ops.pallas_ssd`'s kernels read: x, B and C in the layout
+    they arrive in (free reshapes), dt after its softplus and the
+    cumulative log-decay inside each chunk, float32 (B, S, H)."""
+    bsz, s, h, _p = x.shape
+    dt, a = _discretize(dt, dt_bias, a_log)
+    cs = jnp.cumsum((dt * a).reshape(bsz, s // chunk, chunk, h),
+                    axis=2).reshape(bsz, s, h)
+    return (x.reshape(bsz, s, -1), dt, cs, b.reshape(bsz, s, -1),
+            c.reshape(bsz, s, -1), d)
+
+
+def _scan_kernels(x, dt, a_log, b, c, d, dt_bias, chunk):
+    """The same function through the kernels: the (B, S, H) arithmetic
+    (softplus, the cumulative sums, and their derivatives by autodiff)
+    stays in XLA, 4 MB arrays."""
+    y = pallas_ssd.ssd_scan_kernels(
+        *_kernel_operands(x, dt, a_log, b, c, d, dt_bias, chunk),
+        b.shape[2], chunk)
+    return y.reshape(x.shape)

@@ -103,12 +103,13 @@ def test_attention_train_stage_toy_interpret(monkeypatch, dtype, n_dev):
 
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
 def test_decoder_phase_toy(dtype):
-    """The decoder stage's two comparisons at toy sizes, lowered for the
-    CPU (the routes' XLA twins against the stage's plain oracles)."""
+    """The decoder stage's three comparisons at toy sizes, lowered for
+    the CPU (the routes' XLA twins against the stage's plain oracles)."""
     out = chip_smoke.decoder_phase(seq=128, heads=4, tokens=64, experts=8,
                                    held=2, top_k=3, latent=16, width=24,
+                                   scan=(256, 4, 64, 2, 128),
                                    dtype=dtype, expect_mosaic=False)
-    assert len(out) == 2
+    assert len(out) == 3 and "ssd_scan_s256_h4_p64_g2_n128" in out
     assert max(v["max_rel_err"] for v in out.values()) < (
         1e-4 if dtype == "float32" else 2e-2)
 
@@ -277,7 +278,7 @@ print("AOT_OK")
 """
 
 
-# the decoder's two kernel routes at the nemotron3_super_s8192 cell's
+# the decoder's kernel routes at the nemotron3_super_s8192 cell's
 # shapes: jax.grad through the causal grouped-query attention route (32
 # query heads over 2 key/value heads of 128, S = 8192) and through the
 # held experts' grouped products (8192 tokens, 8 experts held of a top-22
@@ -340,6 +341,33 @@ assert all("moe_experts" in n for n in names), names
 assert sum("transpose(" not in n for n in names) == 1, names
 assert sum("transpose(" in n and "gmm" in n for n in names) == 4, names
 assert moe.route_counts()["grouped_kernel"] == 2, moe.route_counts()
+
+from mxnet_tpu.ops import ssm
+s, h, p, g, n = 8192, 128, 64, 8, 128
+def scan_loss(*a):
+    with jax.named_scope("ssd_scan"):
+        return ssm._ssd_scan(*a, chunk=128).astype(jnp.float32).sum()
+vec = arg((h,), jnp.float32)
+names = mosaic_names(
+    jax.grad(scan_loss, argnums=tuple(range(7))), arg((1, s, h, p)),
+    arg((1, s, h)), vec, arg((1, s, g, n)), arg((1, s, g, n)), vec, vec)
+print("MOSAIC scan", names)
+assert len(names) == 2 and all("ssd_scan" in n for n in names), names
+assert sum("mx_ssd_scan_fwd" in n and "transpose(" not in n
+           for n in names) == 1, names
+assert sum("mx_ssd_scan_bwd" in n and "transpose(" in n
+           for n in names) == 1, names
+assert ssm.route_counts() == {"chunked_xla": 0, "fused_kernel": 1}
+# the other widths the route admits: a head of 128 alone in its lane
+# block, a head of 256, a state of two lane blocks, float32 operands
+for s, h, p, g, n, dt in ((1024, 8, 128, 2, 128, jnp.bfloat16),
+                          (1024, 4, 256, 1, 128, jnp.bfloat16),
+                          (1024, 16, 64, 2, 256, jnp.float32)):
+    names = mosaic_names(
+        jax.grad(scan_loss, argnums=tuple(range(7))), arg((2, s, h, p), dt),
+        arg((2, s, h), dt), arg((h,), jnp.float32), arg((2, s, g, n), dt),
+        arg((2, s, g, n), dt), arg((h,), jnp.float32), arg((h,), jnp.float32))
+    assert len(names) == 2, (s, h, p, g, n, names)
 print("AOT_OK")
 """
 
@@ -388,11 +416,12 @@ def test_attention_training_route_compiles_beyond_the_benchmark_shapes(mode):
 
 def test_decoder_kernel_routes_compile_for_v5e_ahead_of_time():
     """Causal grouped-query training attention through the O(S) flash
-    route, and the expert layer's grouped products through the grouped-
-    matmul kernel, at the hybrid decoder cell's shapes: Mosaic takes
-    them, and every call keeps its op scope and, in the backward,
-    `transpose(`: what `causal_attention_device_ms` and `moe_device_ms`
-    are read by."""
+    route, the expert layer's grouped products through the grouped-
+    matmul kernel, and the Mamba-2 scan through its forward and backward
+    kernels, at the hybrid decoder cell's shapes: Mosaic takes them, and
+    every call keeps its op scope and, in the backward, `transpose(`:
+    what `causal_attention_device_ms`, `moe_device_ms` and
+    `ssd_device_ms` are read by."""
     p = _run(["-c", _AOT_DECODER], timeout=300)
     if "NO_TPU_COMPILER" in p.stdout:
         pytest.skip(p.stdout.strip()[:200])
