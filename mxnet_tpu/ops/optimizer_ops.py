@@ -63,7 +63,11 @@ def _adam_update(weight, grad, mean, var, lr=0.001, beta1=0.9, beta2=0.999,
     g = _rescale_clip(grad, rescale_grad, clip_gradient, wd, weight)
     new_mean = beta1 * mean + (1 - beta1) * g
     new_var = beta2 * var + (1 - beta2) * jnp.square(g)
-    return (weight - lr * new_mean / (jnp.sqrt(new_var) + epsilon),
+    # the ratio first: with bfloat16 moments and a traced float32 lr
+    # (parallel.SPMDTrainer) the divide then stays a bfloat16 divide; as
+    # (lr * mean) / (...) it is a float32 one, which cost the v5e 0.5% of
+    # BERT's wgrad + Adam fusions (PERF.md, PR 29)
+    return (weight - lr * (new_mean / (jnp.sqrt(new_var) + epsilon)),
             new_mean, new_var)
 
 

@@ -56,7 +56,7 @@ from ..compile_cache import jax_cache as _jax_cache
 from .optimizer import Optimizer, Updater
 
 __all__ = ["FusedUpdater", "FusedUnsupported", "ExecutableCache",
-           "apply_param", "compile_stats"]
+           "apply_master", "apply_param", "compile_stats"]
 
 
 class FusedUnsupported(Exception):
@@ -297,21 +297,27 @@ def _leaf_aval(x):
     return type(x).__name__
 
 
+def apply_master(opt: Optimizer, w, g, s, h):
+    """One multi-precision update on raw jax values (mp_* semantics):
+    ``s`` is ``(inner state, fp32 master weight)``, the math runs on the
+    master and the weight is its cast.  THE master-weight rule of every
+    traced step: ``apply_param`` below and ``parallel.SPMDTrainer``."""
+    inner, w32 = s
+    nw32, ninner = opt.fused_apply(w32, g.astype(jnp.float32), inner, h)
+    return nw32.astype(w.dtype), (ninner, nw32)
+
+
 def apply_param(opt: Optimizer, w, g, s, mp: bool, h: Dict[str, Any]):
     """One parameter's optimizer update on raw jax values, multi-
-    precision aware — THE traced inner math, shared by the per-replica
+    precision aware — the traced inner math shared by the per-replica
     fused step below and the mesh-wide SPMD step (optimizer/spmd.py).
 
-    ``h`` maps hyper keys to 0-d float32 scalars.  Under mp the fp32
-    master weight is the last state element and is what the math runs
-    on (mp_* semantics); otherwise scalars cast to the weight dtype,
-    matching the eager path's weak-scalar promotion (a python-float
-    attr never upcasts an f16 kernel)."""
+    ``h`` maps hyper keys to 0-d float32 scalars.  Without a master
+    they are cast to the weight dtype, matching the eager path's
+    weak-scalar promotion (a python-float attr never upcasts an f16
+    kernel)."""
     if mp:
-        inner, w32 = s
-        nw32, ninner = opt.fused_apply(w32, g.astype(jnp.float32),
-                                       inner, h)
-        return nw32.astype(w.dtype), (ninner, nw32)
+        return apply_master(opt, w, g, s, h)
     h = {k: v.astype(w.dtype) for k, v in h.items()}
     return opt.fused_apply(w, g, s, h)
 

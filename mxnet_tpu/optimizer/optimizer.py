@@ -121,6 +121,10 @@ class Optimizer:
     #       t where the kernel needs it).  These enter the program as
     #       TRACED arguments, so set_learning_rate / a new
     #       rescale_grad = scale/batch_size never retrigger a compile.
+    #   fused_fold_t  : the per-class half of fused_hyper, how the
+    #       update count t enters lr / wd / rescale_grad.  Plain
+    #       arithmetic, so parallel.SPMDTrainer calls it INSIDE its step
+    #       program with a traced t and a traced lr.
     #   fused_apply   : the pure math, (weight, grad, state, hyper) ->
     #       (new_weight, new_state) on raw jax values.
 
@@ -151,9 +155,20 @@ class Optimizer:
     def fused_hyper(self, index, t) -> Dict[str, float]:
         """Per-step scalars for parameter `index` at update count `t`,
         computed on the host and passed as traced jit arguments."""
-        return {"lr": float(self._get_lr(index)),
-                "wd": float(self._get_wd(index)),
-                "rescale_grad": float(self.rescale_grad)}
+        return self.fused_fold_t({"lr": float(self._get_lr(index)),
+                                  "wd": float(self._get_wd(index)),
+                                  "rescale_grad": float(self.rescale_grad)},
+                                 t)
+
+    def fused_fold_t(self, hyper, t):
+        """Fold the update count into `hyper` (lr, wd, rescale_grad of
+        one parameter) and return it: bias correction into lr (Adam),
+        or `t` itself where the kernel takes it (_FUSED_T_HYPER).  `t`
+        and the values of `hyper` are Python numbers or traced float32
+        scalars alike."""
+        if self._FUSED_T_HYPER:
+            hyper["t"] = t
+        return hyper
 
     def _fused_clip(self) -> float:
         return self.clip_gradient if self.clip_gradient is not None else -1.0
@@ -323,12 +338,12 @@ class Adam(Optimizer):
 
     _FUSED_STATIC = ("beta1", "beta2", "epsilon", "clip_gradient")
 
-    def fused_hyper(self, index, t):
-        h = super().fused_hyper(index, t)
-        # same host-side bias-correction fold as the eager path — a new
-        # t only changes a traced scalar, never the program
-        h["lr"] *= (1.0 - self.beta2 ** t) ** 0.5 / (1.0 - self.beta1 ** t)
-        return h
+    def fused_fold_t(self, hyper, t):
+        # the same bias-correction fold as the eager path — a new t
+        # only changes a traced scalar, never the program
+        hyper["lr"] *= (1.0 - self.beta2 ** t) ** 0.5 \
+            / (1.0 - self.beta1 ** t)
+        return hyper
 
     def fused_apply(self, weight, grad, state, hyper):
         mean, var = state
@@ -418,11 +433,6 @@ class Adamax(Optimizer):
     _FUSED_STATIC = ("beta1", "beta2", "clip_gradient")
     _FUSED_T_HYPER = True
 
-    def fused_hyper(self, index, t):
-        h = super().fused_hyper(index, t)
-        h["t"] = float(t)
-        return h
-
     def fused_apply(self, weight, grad, state, hyper):
         mean, var = state
         nw, nm, nv = apply_pure("adamax_update", weight, grad, mean, var,
@@ -459,11 +469,6 @@ class Nadam(Optimizer):
     _FUSED_STATIC = ("beta1", "beta2", "epsilon", "schedule_decay",
                      "clip_gradient")
     _FUSED_T_HYPER = True
-
-    def fused_hyper(self, index, t):
-        h = super().fused_hyper(index, t)
-        h["t"] = float(t)
-        return h
 
     def fused_apply(self, weight, grad, state, hyper):
         mean, var = state
@@ -638,11 +643,6 @@ class LAMB(Optimizer):
     # the phase-2 trust ratio is per-TENSOR (norm(w)/norm(update)):
     # concatenating params would corrupt the norms
     _FUSED_ELEMENTWISE = False
-
-    def fused_hyper(self, index, t):
-        h = super().fused_hyper(index, t)
-        h["t"] = float(t)
-        return h
 
     def fused_apply(self, weight, grad, state, hyper):
         mean, var = state

@@ -32,7 +32,7 @@ from __future__ import annotations
 from .mesh import DeviceMesh, make_mesh, current_mesh, get_mesh
 from .sharding import (ShardingRules, named_sharding, replicated,
                        shard_batch, constraint, DEFAULT_RULES)
-from .spmd import SPMDTrainer, functional_optimizer
+from .spmd import SPMDTrainer
 from .checkpoint import save_sharded, load_sharded
 from . import dist
 from . import ring
@@ -44,6 +44,6 @@ __all__ = [
     "DeviceMesh", "make_mesh", "current_mesh", "get_mesh",
     "ShardingRules", "named_sharding", "replicated", "shard_batch",
     "constraint", "DEFAULT_RULES",
-    "SPMDTrainer", "functional_optimizer",
+    "SPMDTrainer",
     "dist", "ring", "ulysses", "moe", "pipeline",
 ]

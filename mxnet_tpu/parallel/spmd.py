@@ -17,7 +17,6 @@ math to KVStore('nccl') push/pull in the reference, one fused program here.
 """
 from __future__ import annotations
 
-import functools
 import re
 from collections import OrderedDict
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
@@ -40,8 +39,7 @@ from .mesh import DeviceMesh, current_mesh, layout_key, make_mesh
 from .sharding import (ShardingRules, DEFAULT_RULES, shard_batch,
                        zero_state_spec)
 
-__all__ = ["SPMDTrainer", "functional_optimizer", "FunctionalOptimizer",
-           "step_compile_stats", "step_programs"]
+__all__ = ["SPMDTrainer", "step_compile_stats", "step_programs"]
 
 # mesh-wide fwd+bwd+update executables: routed through the persistent
 # compile cache (PR 7) so a same-topology restart warm-starts the step
@@ -154,47 +152,6 @@ def _block_token(block) -> int:
         return tok
 
 
-# ---------------------------------------------------------------------------
-# functional optimizers — pure (w, g, state, lr, t) -> (w', state') built on
-# the same registered update ops the imperative Optimizer classes use
-# (ops/optimizer_ops.py; ref src/operator/optimizer_op.cc)
-# ---------------------------------------------------------------------------
-
-class FunctionalOptimizer:
-    def __init__(self, n_state: int, update: Callable, wd: float = 0.0,
-                 clip_gradient: float = -1.0):
-        self.n_state = n_state
-        self._update = update
-        self.wd = wd
-        self.clip_gradient = clip_gradient
-        # set from Optimizer.multi_precision by functional_optimizer()
-        self.multi_precision = False
-
-    def needs_master(self, value) -> bool:
-        """Under Optimizer(multi_precision=True), low-precision params get
-        fp32 optimizer state AND an fp32 master weight carried as the LAST
-        element of the state tuple — the reference's mp_sgd_* / mp_adam
-        weight32 state (ref: optimizer_op.cc MP_SGD kernels).  Without the
-        master, updates below one bf16 ulp round away (reference non-mp
-        behavior, the default there too); mp costs ~4% step time on the
-        ResNet-50 bench."""
-        return (self.multi_precision
-                and value.dtype in (jnp.bfloat16, jnp.float16))
-
-    def init(self, value: jax.Array) -> Tuple[jax.Array, ...]:
-        # state dtype is FIXED from step 0 (update math runs in fp32; a
-        # bf16 state that flipped to fp32 after step 1 would retrace)
-        if self.needs_master(value):
-            return tuple(jnp.zeros(value.shape, jnp.float32)
-                         for _ in range(self.n_state)) + (
-                value.astype(jnp.float32),)
-        return tuple(jnp.zeros_like(value) for _ in range(self.n_state))
-
-    def apply(self, value, grad, state, lr, t, lr_mult=1.0, wd_mult=1.0):
-        return self._update(value, grad, state, lr * lr_mult,
-                            self.wd * wd_mult, self.clip_gradient, t)
-
-
 def _global_put(v, sh):
     """device_put that also works on multi-process meshes whose backend
     has no cross-host transfers (CPU+gloo).
@@ -213,145 +170,6 @@ def _global_put(v, sh):
         return jax.jit(lambda x: x, out_shardings=sh)(v)
     v = np.asarray(v)
     return jax.make_array_from_callback(v.shape, sh, lambda idx: v[idx])
-
-
-def _pure(name):
-    from ..ops.registry import apply_pure
-
-    return functools.partial(apply_pure, name)
-
-
-def functional_optimizer(opt) -> FunctionalOptimizer:
-    """Build the pure update for an Optimizer instance (or name)."""
-    if isinstance(opt, str):
-        opt = opt_mod.create(opt)
-    fo = _functional_optimizer_impl(opt)
-    fo.multi_precision = bool(getattr(opt, "multi_precision", False))
-    return fo
-
-
-def _functional_optimizer_impl(opt) -> FunctionalOptimizer:
-    wd = float(opt.wd)
-    clip = float(opt.clip_gradient) if opt.clip_gradient is not None else -1.0
-    kind = type(opt).__name__
-
-    if kind in ("SGD", "NAG"):
-        momentum = float(getattr(opt, "momentum", 0.0))
-        if momentum == 0.0:
-            upd = _pure("sgd_update")
-
-            def update(w, g, s, lr, wd_, c, t):
-                return upd(w, g, lr=lr, wd=wd_, clip_gradient=c), ()
-            return FunctionalOptimizer(0, update, wd, clip)
-        op_name = "nag_mom_update" if kind == "NAG" else "sgd_mom_update"
-        upd = _pure(op_name)
-
-        def update(w, g, s, lr, wd_, c, t):
-            nw, nm = upd(w, g, s[0], lr=lr, momentum=momentum, wd=wd_,
-                         clip_gradient=c)
-            return nw, (nm,)
-        return FunctionalOptimizer(1, update, wd, clip)
-
-    if kind == "Adam":
-        b1, b2, eps = float(opt.beta1), float(opt.beta2), float(opt.epsilon)
-        upd = _pure("adam_update")
-
-        def update(w, g, s, lr, wd_, c, t):
-            # bias correction (ref: Adam.update computes coef host-side)
-            tt = t.astype(jnp.float32)
-            coef = jnp.sqrt(1.0 - b2 ** tt) / (1.0 - b1 ** tt)
-            nw, nm, nv = upd(w, g, s[0], s[1], lr=1.0, beta1=b1, beta2=b2,
-                             epsilon=eps, wd=wd_, clip_gradient=c)
-            # adam_update applies lr directly; redo with scaled lr instead
-            return w + (nw - w) * (lr * coef), (nm, nv)
-        return FunctionalOptimizer(2, update, wd, clip)
-
-    if kind == "RMSProp":
-        g1 = float(getattr(opt, "gamma1", 0.9))
-        g2 = float(getattr(opt, "gamma2", 0.9))
-        eps = float(getattr(opt, "epsilon", 1e-8))
-        if getattr(opt, "centered", False):
-            upd = _pure("rmspropalex_update")
-
-            def update(w, g, s, lr, wd_, c, t):
-                nw, nn, ng, ndel = upd(w, g, s[0], s[1], s[2], lr=lr,
-                                       gamma1=g1, gamma2=g2, epsilon=eps,
-                                       wd=wd_, clip_gradient=c)
-                return nw, (nn, ng, ndel)
-            return FunctionalOptimizer(3, update, wd, clip)
-        upd = _pure("rmsprop_update")
-
-        def update(w, g, s, lr, wd_, c, t):
-            nw, nn = upd(w, g, s[0], lr=lr, gamma1=g1, epsilon=eps, wd=wd_,
-                         clip_gradient=c)
-            return nw, (nn,)
-        return FunctionalOptimizer(1, update, wd, clip)
-
-    if kind == "AdaGrad":
-        eps = float(getattr(opt, "float_stable_eps",
-                            getattr(opt, "eps",
-                                    getattr(opt, "epsilon", 1e-7))))
-        upd = _pure("adagrad_update")
-
-        def update(w, g, s, lr, wd_, c, t):
-            nw, nh = upd(w, g, s[0], lr=lr, epsilon=eps, wd=wd_,
-                         clip_gradient=c)
-            return nw, (nh,)
-        return FunctionalOptimizer(1, update, wd, clip)
-
-    if kind in ("Signum", "SignSGD"):
-        momentum = float(getattr(opt, "momentum", 0.0))
-        if momentum == 0.0:
-            upd = _pure("signsgd_update")
-
-            def update(w, g, s, lr, wd_, c, t):
-                return upd(w, g, lr=lr, wd=wd_, clip_gradient=c), ()
-            return FunctionalOptimizer(0, update, wd, clip)
-        upd = _pure("signum_update")
-
-        def update(w, g, s, lr, wd_, c, t):
-            nw, nm = upd(w, g, s[0], lr=lr, momentum=momentum, wd=wd_,
-                         clip_gradient=c)
-            return nw, (nm,)
-        return FunctionalOptimizer(1, update, wd, clip)
-
-    if kind == "AdaDelta":
-        rho = float(opt.rho)
-        eps = float(opt.epsilon)
-        upd = _pure("adadelta_update")
-
-        def update(w, g, s, lr, wd_, c, t):
-            nw, na, nd = upd(w, g, s[0], s[1], lr=lr, rho=rho, epsilon=eps,
-                             wd=wd_, clip_gradient=c)
-            return nw, (na, nd)
-        return FunctionalOptimizer(2, update, wd, clip)
-
-    if kind == "Adamax":
-        b1, b2 = float(opt.beta1), float(opt.beta2)
-        upd = _pure("adamax_update")
-
-        def update(w, g, s, lr, wd_, c, t):
-            tt = t.astype(jnp.float32)
-            lr_t = lr / (1.0 - b1 ** tt)
-            nw, nm, nv = upd(w, g, s[0], s[1], lr=lr_t, beta1=b1, beta2=b2,
-                             wd=wd_, clip_gradient=c)
-            return nw, (nm, nv)
-        return FunctionalOptimizer(2, update, wd, clip)
-
-    if kind == "Ftrl":
-        lamda1 = float(opt.lamda1)
-        beta = float(opt.beta)
-        upd = _pure("ftrl_update")
-
-        def update(w, g, s, lr, wd_, c, t):
-            nw, nz, nn = upd(w, g, s[0], s[1], lr=lr, lamda1=lamda1,
-                             beta=beta, wd=wd_, clip_gradient=c)
-            return nw, (nz, nn)
-        return FunctionalOptimizer(2, update, wd, clip)
-
-    raise MXNetError(
-        f"no functional form for optimizer {kind}; supported: SGD, NAG, "
-        "Adam, RMSProp, AdaGrad, Signum, SignSGD, AdaDelta, Adamax, Ftrl")
 
 
 # ---------------------------------------------------------------------------
@@ -412,8 +230,13 @@ class SPMDTrainer:
         elif optimizer_params:
             raise MXNetError("optimizer_params must be None when optimizer "
                              "is an instance")
+        if optimizer.fused_static_key() is None:
+            raise MXNetError(
+                f"no functional form for optimizer "
+                f"{type(optimizer).__name__}: the step program calls "
+                "Optimizer.fused_apply, which needs _FUSED_STATIC declared "
+                "(optimizer/optimizer.py)")
         self._optimizer = optimizer
-        self._fopt = functional_optimizer(optimizer)
 
         self._plist = sorted(block.collect_params().items())
         self._mults = {
@@ -443,38 +266,11 @@ class SPMDTrainer:
                 if self._zero else spec
             self._state_shardings[n] = NamedSharding(self.mesh.mesh, sspec)
             self.params[n] = _global_put(v, sh)
+        self._state_layouts: Dict[Any, Tuple] = {}
         self.opt_state = {
             n: tuple(_global_put(s, self._state_shardings[n])
-                     for s in self._fopt.init(v))
+                     for s in self._init_state(v))
             for n, v in self.params.items() if self._trainable[n]}
-
-        # replicated trainable params fuse into one flat update kernel per
-        # (lr_mult, wd_mult) group; mesh-sharded params stay per-parameter
-        from ..util import env
-
-        self._has_master = {
-            n: self._fopt.needs_master(v) for n, v in self.params.items()
-            if self._trainable[n]}
-        groups: Dict[Tuple, List[str]] = {}
-        self._per_param: List[str] = []
-        # default OFF: profiling showed the 1-D concat destroys conv-weight
-        # tiled layouts and donation aliasing, costing far more than the
-        # per-param fusions it merges (162ms vs 113ms ResNet-50 step); the
-        # per-param updates fuse into the wgrad epilogue anyway
-        flat_on = env.get_bool("MXNET_FUSED_OPTIMIZER")
-        for n, p in self._plist:
-            if not self._trainable[n]:
-                continue
-            if flat_on and self._shardings[n].is_fully_replicated:
-                # dtype in the key: groups must be homogeneous (concat
-                # would silently promote, and master-weight handling
-                # differs between bf16 and fp32 params)
-                key = self._mults[n] + (str(self.params[n].dtype),)
-                groups.setdefault(key, []).append(n)
-            else:
-                self._per_param.append(n)
-        self._flat_groups = [(tuple(names), lm, wm)
-                             for (lm, wm, _dt), names in sorted(groups.items())]
 
         # per-shape fast path over _STEP_CACHE; LRU-bounded because
         # each value strong-refs a whole-step executable — an unbounded
@@ -485,12 +281,43 @@ class SPMDTrainer:
         self._param_by_name = {n: p for n, p in self._plist}
         self._t = 0
 
+    # ---- optimizer state -------------------------------------------------
+    def _state_layout(self, dtype):
+        """``(treedef, leaf dtypes, has_master)`` of the optimizer's state
+        for a weight of ``dtype``.  ``opt_state`` (and every checkpoint)
+        keeps a FLAT tuple per parameter, the float32 master weight last;
+        ``fused_apply`` takes what ``create_state`` builds: None, an
+        array or a tuple, under multi-precision ``(that, master)``.  The
+        structure is learnt from the optimizer itself on a one-element
+        weight, so no full-size state is ever built on the host."""
+        if dtype not in self._state_layouts:
+            from ..ndarray.ndarray import zeros
+
+            opt = self._optimizer
+            w = zeros((1,), dtype=str(dtype))
+            state = opt.create_state_multi_precision(0, w)
+            leaves, treedef = jax.tree_util.tree_flatten(state)
+            self._state_layouts[dtype] = (
+                treedef, tuple(s.data.dtype for s in leaves),
+                opt._mp_active(w, state))
+        return self._state_layouts[dtype]
+
+    def _init_state(self, value: jax.Array) -> Tuple[jax.Array, ...]:
+        """Zeros beside ``value`` (its sharding, the state's own dtypes,
+        FIXED from step 0: a state that changed dtype after one step
+        would retrace) and, where the layout has one, the master copy."""
+        _treedef, dtypes, has_master = self._state_layout(value.dtype)
+        zeros = tuple(jnp.zeros_like(value, dtype=dt)
+                      for dt in dtypes[:len(dtypes) - has_master])
+        return zeros + ((value.astype(jnp.float32),) if has_master else ())
+
     # ---- the pure step ---------------------------------------------------
     def _build_pure(self):
         plist = self._plist
-        block, loss, fopt = self.block, self.loss, self._fopt
+        block, loss, opt = self.block, self.loss, self._optimizer
         mults, trainable = self._mults, self._trainable
         trainer = self
+        wd = float(opt.wd)
 
         from ..gluon.block import ActiveTrace
 
@@ -529,55 +356,34 @@ class SPMDTrainer:
                 if not trainable[n]:
                     new_params[n] = params[n]
 
-            # Fused flat update: replicated trainable params concatenate
-            # into ONE elementwise update kernel per (lr_mult, wd_mult)
-            # group instead of one tiny fusion per parameter — profiling
-            # showed the per-parameter tail costing ~17% of the ResNet-50
-            # step.  Mesh-sharded params keep the per-parameter path (a
-            # concat across different shardings would force gathers).
-            def apply_one(n, w, g, state, lm, wm):
-                """Update one (possibly flat-concatenated) weight; the fp32
-                master weight, when present, is the last state element and
-                is what the update math runs on (mp_* semantics)."""
-                if trainer._has_master[n]:
-                    w32, st = state[-1], state[:-1]
-                    nw32, ns = fopt.apply(w32, g, st, lr, t,
-                                          lr_mult=lm, wd_mult=wm)
-                    return nw32.astype(w.dtype), ns + (nw32,)
-                nw, ns = fopt.apply(w, g, state, lr, t,
-                                    lr_mult=lm, wd_mult=wm)
+            def update(w, g, flat, hyper):
+                """One parameter through ``Optimizer.fused_apply``; the
+                hyper scalars stay float32 whatever the weight's dtype,
+                results return to the weight's and each state's."""
+                treedef, _dtypes, has_master = trainer._state_layout(w.dtype)
+                state = jax.tree_util.tree_unflatten(treedef, flat)
+                if has_master:
+                    nw, ns = opt_mod.fused.apply_master(opt, w, g, state,
+                                                        hyper)
+                else:
+                    nw, ns = opt.fused_apply(w, g, state, hyper)
                 return nw.astype(w.dtype), tuple(
-                    sv.astype(state[i].dtype) for i, sv in enumerate(ns))
+                    sv.astype(old.dtype) for sv, old in
+                    zip(jax.tree_util.tree_leaves(ns), flat))
 
             with jax.named_scope("mx.update"):
-                for names, lm, wm in trainer._flat_groups:
-                    # concat in NATIVE dtypes — upcasts happen in-register
-                    # inside the one fused update kernel, never materialized
-                    n_st = len(opt_state[names[0]])
-                    fw = jnp.concatenate(
-                        [params[n].reshape(-1) for n in names])
-                    fg = jnp.concatenate(
-                        [grads[n].reshape(-1) for n in names])
-                    fs = tuple(
-                        jnp.concatenate(
-                            [opt_state[n][i].reshape(-1) for n in names])
-                        for i in range(n_st))
-                    nw, ns = apply_one(names[0], fw, fg, fs, lm, wm)
-                    off = 0
-                    for n in names:
-                        p = params[n]
-                        sz = int(np.prod(p.shape)) if p.shape else 1
-                        sl = lax.slice(nw, (off,), (off + sz,))
-                        new_params[n] = sl.reshape(p.shape).astype(p.dtype)
-                        new_state[n] = tuple(
-                            lax.slice(s, (off,), (off + sz,))
-                            .reshape(p.shape).astype(opt_state[n][i].dtype)
-                            for i, s in enumerate(ns))
-                        off += sz
-                for n in trainer._per_param:
+                # lr, t: traced; the mults, wd and rescale_grad (the loss
+                # is a global mean): constants of the program
+                tf = t.astype(jnp.float32)
+                for n, _ in plist:
+                    if not trainable[n]:
+                        continue
                     lm, wm = mults[n]
-                    new_params[n], new_state[n] = apply_one(
-                        n, params[n], grads[n], opt_state[n], lm, wm)
+                    hyper = opt.fused_fold_t(
+                        {"lr": lr * lm, "wd": wd * wm, "rescale_grad": 1.0},
+                        tf)
+                    new_params[n], new_state[n] = update(
+                        params[n], grads[n], opt_state[n], hyper)
             # aux state (BatchNorm moving stats) accumulates across steps:
             # fold the traced updates back into the param dict so the next
             # step's trace reads them (stop_gradient — not a learnable path)
@@ -586,16 +392,6 @@ class SPMDTrainer:
             return new_params, new_state, lval, aux
 
         return mx_train_step
-
-    def _opt_static_fingerprint(self) -> Tuple:
-        """Hashable fingerprint of the optimizer attrs BAKED into the
-        traced program (wd, momentum, betas, ... — read at functional-
-        optimizer construction).  lr and rescale_grad stay out: they
-        are traced arguments and must never force a recompile."""
-        skip = {"lr", "rescale_grad", "num_update", "begin_num_update"}
-        return tuple(sorted(
-            (k, v) for k, v in self._optimizer.__dict__.items()
-            if k not in skip and isinstance(v, (int, float, bool, str))))
 
     def _get_step(self, args, ikey):
         if ikey not in self._step_fns:
@@ -624,33 +420,29 @@ class SPMDTrainer:
             # concrete devices (an executable is bound to its device
             # assignment — two trainers on disjoint subsets of the same
             # topology must not share one); the PERSISTENT key adds the
-            # lowered program text, which pins the actual model code
-            sig = ("spmd-train-step", _block_token(block),
-                   f"{type(block).__module__}.{type(block).__qualname__}",
-                   tuple(n for n, _ in self._plist),
-                   tuple(sorted(self._mults.items())),
-                   type(self._optimizer), self._opt_static_fingerprint(),
-                   tuple(self._flat_groups), self.remat,
-                   layout_key(self.mesh),
-                   tuple(str(d) for d in mesh.devices),
-                   self._zero, self._donate,
-                   treedef,
-                   tuple(opt_mod.fused._leaf_aval(x) for x in leaves))
+            # lowered program text, which pins the actual model code.
+            # One mapping: the hashable sig and the named components that
+            # compile provenance prints cannot drift apart
+            opt = self._optimizer
+            named = {
+                "block": (_block_token(block),
+                          f"{type(block).__module__}."
+                          f"{type(block).__qualname__}",
+                          tuple(n for n, _ in self._plist)),
+                "mults": tuple(sorted(self._mults.items())),
+                "optimizer": type(opt),
+                "statics": opt.fused_static_key() + (("wd", float(opt.wd)),),
+                "remat": self.remat,
+                "layout": layout_key(self.mesh),
+                "devices": tuple(str(d) for d in mesh.devices),
+                "zero": self._zero, "donation": self._donate,
+                "treedef": treedef,
+                "avals": tuple(opt_mod.fused._leaf_aval(x) for x in leaves)}
+            sig = ("spmd-train-step",) + tuple(named.items())
             fn = _STEP_CACHE.lookup(sig)
             if fn is None:
-                # named sig view for compile provenance (same order as
-                # the sig tuple above)
-                components = {
-                    "block": sig[1:4], "mults": sig[4],
-                    "optimizer": sig[5], "statics": sig[6],
-                    "flat_groups": sig[7], "remat": sig[8],
-                    "layout": sig[9], "devices": sig[10],
-                    "zero": sig[11], "donation": sig[12],
-                    "treedef": sig[13], "avals": sig[14]}
-                fn = _STEP_CACHE.compile(sig, build_lowered,
-                                         self._optimizer,
-                                         alias_ok=False,
-                                         components=components,
+                fn = _STEP_CACHE.compile(sig, build_lowered, opt,
+                                         alias_ok=False, components=named,
                                          donate=self._donate)
             # per-trainer fast path keyed by input avals: a batch-shape
             # change rebuilds (AOT does not silently retrace), a repeat

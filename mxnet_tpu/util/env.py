@@ -313,10 +313,6 @@ declare("MXNET_FUSED_BUCKET_BYTES", int, 4 << 20,
         "(KVStore.pushpull_fused): one collective per ~this many bytes "
         "of dtype-homogeneous dense gradients.",
         tunable=Tunable(lo=256 << 10, hi=64 << 20, scale="log"))
-declare("MXNET_FUSED_OPTIMIZER", bool, False,
-        "SPMD trainer: concatenate fully-replicated parameters into one "
-        "flat optimizer update. Default off — profiling showed the 1-D "
-        "concat destroys conv-weight tiled layouts and donation aliasing.")
 declare("MXNET_KVSTORE_TIMEOUT", float, None,
         "Seconds a distributed collective may block before the worker "
         "aborts loudly instead of hanging on a dead peer. Unset/0 = wait "

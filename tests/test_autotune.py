@@ -106,9 +106,9 @@ class TestOverlayPrecedence:
         assert info["applied"] == ["MXNET_ZERO_MIN_SIZE"]
 
     def test_bool_and_float_values_convert_like_env(self):
-        env.apply_overlay({"MXNET_FUSED_OPTIMIZER": True,
+        env.apply_overlay({"MXNET_SPMD": True,
                            "MXNET_RETRY_BASE_MS": 75.5})
-        assert env.get_bool("MXNET_FUSED_OPTIMIZER") is True
+        assert env.get_bool("MXNET_SPMD") is True
         assert env.get_float("MXNET_RETRY_BASE_MS") == 75.5
 
     def test_clear_overlay_restores_defaults(self):

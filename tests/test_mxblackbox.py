@@ -54,8 +54,14 @@ def bb(tmp_path, monkeypatch):
     """A fresh, enabled mxblackbox scoped to a tmp dir; module state
     restored afterwards so the rest of the suite sees the default
     (disabled) fast path."""
+    from mxnet_tpu.telemetry import tracing
+
     d = str(tmp_path / "bb")
     monkeypatch.setenv("MXNET_BLACKBOX_DIR", d)
+    # a test file that ran earlier in this worker may have left a job
+    # rank behind (parallel.dist.init() sets it and nothing unsets it):
+    # "fresh" means the journal starts without one
+    monkeypatch.setattr(tracing, "_RANK", None)
     saved = (mxblackbox._JOURNAL, mxblackbox._ACTIVE,
              mxblackbox._LAST_BUNDLE)
     mxblackbox._JOURNAL = None

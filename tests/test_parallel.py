@@ -78,19 +78,27 @@ def test_spmd_trainer_dp_loss_decreases():
     assert losses[-1] < losses[0] * 0.7, losses[::10]
 
 
-def test_spmd_trainer_matches_local_training():
+from test_fused_step import CASES as OPTIMIZER_CASES
+
+
+@pytest.mark.parametrize("name,kwargs", OPTIMIZER_CASES,
+                         ids=[f"{n}-{i}" for i, (n, _)
+                              in enumerate(OPTIMIZER_CASES)])
+def test_spmd_trainer_matches_local_training(name, kwargs):
     """DP-SPMD must compute the same math as single-device Trainer+KVStore
-    (the check_consistency pattern, SURVEY.md §4)."""
+    (the check_consistency pattern, SURVEY.md §4), for every registered
+    optimizer: the step program's update is Optimizer.fused_apply, the
+    reference is the eager update()."""
     rng = np.random.RandomState(1)
     x = rng.randn(32, 16).astype(np.float32)
     y = (rng.rand(32) * 10).astype(np.int32)
+    opt_kw = dict(kwargs, learning_rate=0.1)
 
     def run_local():
         np.random.seed(7)
         mx.random.seed(7)
         net = _make_mlp()
-        tr = mx.gluon.Trainer(net.collect_params(), "sgd",
-                              {"learning_rate": 0.1})
+        tr = mx.gluon.Trainer(net.collect_params(), name, dict(opt_kw))
         lfn = gloss.SoftmaxCrossEntropyLoss()
         for _ in range(5):
             with mx.autograd.record():
@@ -107,7 +115,7 @@ def test_spmd_trainer_matches_local_training():
         with mesh:
             net = _make_mlp()
             tr = parallel.SPMDTrainer(net, gloss.SoftmaxCrossEntropyLoss(),
-                                      "sgd", {"learning_rate": 0.1})
+                                      name, dict(opt_kw))
             for _ in range(5):
                 tr.step(x, y)
             tr.sync_to_block()
@@ -174,22 +182,6 @@ def test_spmd_trainer_adam_and_bn():
     one_step_norm = 0.1 * np.abs(mean_after).max() / max(
         1.0 - 0.9 ** 20, 1e-9)
     assert np.abs(mean_after).max() > 3 * one_step_norm
-
-
-def test_functional_rmsprop_centered_and_adagrad_eps():
-    from mxnet_tpu.parallel.spmd import functional_optimizer
-    import mxnet_tpu.optimizer as opt_mod
-
-    f = functional_optimizer(opt_mod.create("rmsprop", centered=True))
-    assert f.n_state == 3
-    f2 = functional_optimizer(opt_mod.create("rmsprop"))
-    assert f2.n_state == 1
-    # adagrad with custom eps must not crash and must produce finite updates
-    f3 = functional_optimizer(opt_mod.create("adagrad"))
-    w = jnp.ones((4,))
-    g = jnp.ones((4,))
-    nw, ns = f3.apply(w, g, f3.init(w), jnp.float32(0.1), jnp.int32(1))
-    assert np.all(np.isfinite(np.asarray(nw)))
 
 
 def test_ring_attention_matches_dense():
@@ -271,7 +263,7 @@ def test_spmd_trainer_bf16_master_weights():
             net, lambda out, y: ((out - y) ** 2).mean(), opt,
             n_labels=1)
         name = [n for n, _ in trainer._plist][0]
-        assert trainer._has_master[name]
+        assert len(trainer.opt_state[name]) == 1    # the master, alone
         x = np.ones((8, 4), "bfloat16")
         y = np.zeros((8, 1), "bfloat16")
         for _ in range(40):
@@ -282,6 +274,108 @@ def test_spmd_trainer_bf16_master_weights():
         # master must have accumulated a visible decrease
         assert master.max() < 1.0 - 1e-3, master
         assert master.dtype == np.float32
+
+
+def _bf16_net():
+    # fixed prefix: checkpoint keys must not depend on how many nets
+    # were created earlier in the process
+    np.random.seed(3)
+    mx.random.seed(3)
+    net = nn.HybridSequential(prefix="bf16net_")
+    with net.name_scope():
+        net.add(nn.Dense(8, activation="relu", in_units=4),
+                nn.Dense(1, in_units=8))
+    net.initialize(ctx=mx.cpu())
+    net.cast("bfloat16")
+    return net
+
+
+def _mse(out, y):
+    return ((out - y) ** 2).mean()
+
+
+def _state_dtypes(trainer):
+    return {n: (str(trainer.params[n].dtype),
+                tuple(str(s.dtype) for s in trainer.opt_state[n]))
+            for n in trainer.opt_state}
+
+
+@pytest.mark.parametrize("base,kwargs,n_state", [
+    ("Adam", {}, 2), ("SGD", {"momentum": 0.9}, 1)],
+    ids=["adam", "sgd_momentum"])
+def test_spmd_trainer_bf16_hyper_scalars_stay_float32(base, kwargs, n_state):
+    """bf16 weights without a master: lr (and Adam's bias correction in
+    it) reaches fused_apply as a float32 scalar - cast to bfloat16 it
+    would keep three digits - while wd and rescale_grad are constants
+    of the program; weights and states keep their dtypes from step to
+    step, so the one step program is built once."""
+    from mxnet_tpu.parallel.spmd import step_compile_stats
+
+    seen = []
+
+    class Recording(getattr(mx.optimizer, base)):
+        def fused_apply(self, weight, grad, state, hyper):
+            seen.append((str(weight.dtype),
+                         {k: str(getattr(v, "dtype", type(v).__name__))
+                          for k, v in hyper.items()}))
+            return super().fused_apply(weight, grad, state, hyper)
+
+    x = np.ones((8, 4), "bfloat16")
+    y = np.zeros((8, 1), "bfloat16")
+    with parallel.make_mesh(dp=1):
+        trainer = parallel.SPMDTrainer(
+            _bf16_net(), _mse, Recording(learning_rate=1e-2, **kwargs))
+        first = _state_dtypes(trainer)
+        builds = step_compile_stats()["count"]
+        losses = [float(trainer.step(x, y).asnumpy()) for _ in range(4)]
+    assert step_compile_stats()["count"] == builds + 1
+    assert losses[-1] < losses[0]
+    assert _state_dtypes(trainer) == first
+    assert all(v == ("bfloat16", ("bfloat16",) * n_state)
+               for v in first.values()), first
+    assert len(seen) == len(first)          # traced once, a call a weight
+    for weight_dtype, hyper in seen:
+        assert weight_dtype == "bfloat16"
+        assert hyper == {"lr": "float32", "wd": "float",
+                         "rescale_grad": "float"}
+
+
+def test_spmd_trainer_bf16_master_checkpoint_round_trip(tmp_path):
+    """multi_precision=True: float32 moments, the float32 master LAST in
+    each parameter's flat state tuple, and save_checkpoint /
+    load_checkpoint carry it (the resumed trainer's next loss is the
+    uninterrupted one's)."""
+    pytest.importorskip("orbax.checkpoint")
+    x = np.ones((8, 4), "bfloat16")
+    y = np.zeros((8, 1), "bfloat16")
+
+    def make():
+        return parallel.SPMDTrainer(
+            _bf16_net(), _mse,
+            mx.optimizer.Adam(learning_rate=1e-3, multi_precision=True))
+
+    with parallel.make_mesh(dp=1):
+        trainer = make()
+        for _ in range(3):
+            trainer.step(x, y)
+        for n, state in trainer.opt_state.items():
+            assert [str(s.dtype) for s in state] == ["float32"] * 3
+            master = np.asarray(state[-1])
+            np.testing.assert_array_equal(
+                master.astype("bfloat16"), np.asarray(trainer.params[n]))
+            # the master holds what bfloat16 cannot
+            assert np.any(master != master.astype("bfloat16").astype("f4"))
+        trainer.save_checkpoint(str(tmp_path / "ckpt"))
+        saved = {n: [np.asarray(s) for s in state]
+                 for n, state in trainer.opt_state.items()}
+        resumed = make()
+        resumed.load_checkpoint(str(tmp_path / "ckpt"))
+        for n, state in saved.items():
+            for a, b in zip(state, resumed.opt_state[n]):
+                assert b.dtype == a.dtype
+                np.testing.assert_array_equal(a, np.asarray(b))
+        assert float(resumed.step(x, y).asnumpy()) == \
+            float(trainer.step(x, y).asnumpy())
 
 
 def test_spmd_trainer_retrace_on_shape_change():
