@@ -57,6 +57,25 @@ def _require(cond, message) -> None:
         raise AssertionError(message)
 
 
+def _inner_jaxprs(eqn):
+    for value in eqn.params.values():
+        for j in value if isinstance(value, (tuple, list)) else (value,):
+            j = getattr(j, "jaxpr", j)
+            if hasattr(j, "eqns"):
+                yield j
+
+
+def _holds(jaxpr, primitive: str, inside: str = "") -> bool:
+    """Whether `jaxpr` holds an equation of `primitive`, at any depth;
+    with `inside`, one somewhere under an equation of that primitive."""
+    return any(
+        (not inside and e.primitive.name == primitive)
+        or any(_holds(j, primitive,
+                      "" if e.primitive.name == inside else inside)
+               for j in _inner_jaxprs(e))
+        for e in jaxpr.eqns)
+
+
 def device_phase(dev=None) -> dict:
     """What JAX sees (`dev` defaults to its first device), and that the
     peak table knows this device_kind."""
@@ -395,15 +414,18 @@ def attention_train_stage(shapes, *, dtype="bfloat16", expect_mosaic=True,
 
 
 def decoder_phase(*, seq=1024, heads=8, kv_heads=2, dim=128, tokens=2048,
-                  experts=32, held=8, top_k=4, latent=256, width=384,
+                  experts=32, held=8, top_k=4, held_bias=0.2, latent=256,
+                  width=384,
                   scan=(8192, 128, 64, 8, 128), dtype="bfloat16",
                   expect_mosaic=True) -> dict:
     """Value and gradients of the hybrid decoder's three kernel routes
     against plain XLA on the same operands: causal grouped-query attention
     through the op (upstream's flash kernels) against the S x S reference,
-    the held experts' grouped products (the grouped-matmul kernel,
-    most of whose rows are the empty tail) against every held expert
-    applied densely to every token, and one Mamba-2 scan (`scan`: S, H,
+    the held experts' stage (a loop over chunks of the plan's rows around
+    the grouped-matmul kernel; `held_bias` on the router draws enough
+    tokens to the held experts that it runs twice, a group across the
+    boundary and an empty tail in the second chunk) against every held
+    expert applied densely to every token, and one Mamba-2 scan (`scan`: S, H,
     P, G, N; the published widths at S = 8192) through `ops.pallas_ssd`'s
     kernels against the `chunked_xla` route.  The benchmark's reference
     check sees only their forward."""
@@ -419,11 +441,16 @@ def decoder_phase(*, seq=1024, heads=8, kv_heads=2, dim=128, tokens=2048,
     def weighed(o, ct):
         return (o.astype(jnp.float32) * ct.astype(jnp.float32)).sum(), o
 
-    def compare(name, route, plain, operands, out):
+    def compare(name, route, plain, operands, out, looped=False):
         grad = jax.jit(jax.grad(route, argnums=(0, 1, 2), has_aux=True))
         if expect_mosaic:
-            _require("tpu_custom_call" in grad.lower(*operands).as_text(),
+            traced = grad.trace(*operands)
+            _require("tpu_custom_call" in traced.lower().as_text(),
                      f"no Mosaic call in the lowered gradient of {name}")
+            _require(not looped
+                     or _holds(traced.jaxpr, "pallas_call", "while"),
+                     f"no loop around the Mosaic calls in the gradient of "
+                     f"{name}")
         got = jax.block_until_ready(grad(*operands))
         ref = jax.jit(jax.grad(plain, argnums=(0, 1, 2),
                                has_aux=True))(*operands)
@@ -465,10 +492,15 @@ def decoder_phase(*, seq=1024, heads=8, kv_heads=2, dim=128, tokens=2048,
 
     x = jnp.asarray(rng.randn(tokens, 64), jnp.float32)
     plan = moe.route(x, jnp.asarray(rng.randn(experts, 64), jnp.float32),
-                     jnp.zeros((experts,), jnp.float32), top_k=top_k,
+                     jnp.zeros((experts,), jnp.float32).at[:held].set(
+                         held_bias), top_k=top_k,
                      scale=2.5, first_expert=0, n_local=held)
     _require(int(plan.dropped) == 0 and int(plan.group_sizes.sum()) > 0,
              "the router dropped assignments or placed none")
+    rows, chunks = plan.token.shape[0], int(moe.plan_chunks(plan.group_sizes))
+    _require(rows <= moe.ROW_CHUNK or (
+        chunks > 1 and int(plan.group_sizes.sum()) < rows),
+        f"{chunks} chunk(s) of {rows} rows: the experts' loop is not tried")
     u, ct = (jnp.asarray(rng.randn(tokens, latent), dtype) for _ in range(2))
     w1 = jnp.asarray(rng.randn(held, latent, width) * 0.1, dtype)
     w2 = jnp.asarray(rng.randn(held, width, latent) * 0.1, dtype)
@@ -491,7 +523,7 @@ def decoder_phase(*, seq=1024, heads=8, kv_heads=2, dim=128, tokens=2048,
 
     compare(f"experts_t{tokens}_held{held}_k{latent}_n{width}", grouped,
             dense, (u, w1, w2, ct, plan.token, plan.weight,
-                    plan.group_sizes), out)
+                    plan.group_sizes), out, looped=True)
 
     s, h, p, g, n = scan
     x, ct = (jnp.asarray(rng.randn(1, s, h, p), dtype) for _ in range(2))

@@ -16,6 +16,13 @@ assignments made carry weight 0 and an out-of-range token.  The rows are
 laid out expert by expert (`group_sizes`), which is what a grouped matrix
 product wants.
 
+`rows` is a bound and not the work.  `experts` goes over the plan in
+chunks of `ROW_CHUNK` rows under a trip count it reads from `group_sizes`
+on the device (`plan_chunks`): gather, products, relu^2, combine and
+their gradients touch the chunks that hold assignments and no others, so
+a layer that holds 8 of 512 experts pays for the ~4% of its rows that
+are used and a layer under the worst imbalance pays for all of them.
+
     route(x, w_router, bias, ...)   -> RoutePlan for the held experts
     experts(u, plan, w1, w2)        -> sum over held experts e of
                                         weight_e * (relu(u W1_e)^2 W2_e)
@@ -45,16 +52,18 @@ from ._compat import shard_map_unchecked
 from .mesh import DeviceMesh, current_mesh
 
 __all__ = ["RoutePlan", "route", "experts", "moe_apply", "plan_rows",
-           "route_counts"]
+           "plan_chunks", "route_counts"]
 
 ROW_TILE = 512      # rows a grouped-product tile takes: `rows` is a multiple
+ROW_CHUNK = 4096    # rows `experts` handles a trip of its loop: a multiple
 
 ROUTES = ("grouped_kernel", "ragged_dot")
 _route_counts = dict.fromkeys(ROUTES, 0)
 
 
 def route_counts():
-    """{route: `experts` calls traced through it} since import.  As for
+    """{route: grouped products of the `experts` calls traced through it
+    (two a call, whatever its gradient traces)} since import.  As for
     attention, `grouped_kernel` in a program lowered for the CPU runs its
     `ragged_dot` twin."""
     return dict(_route_counts)
@@ -72,6 +81,13 @@ def plan_rows(tokens: int, top_k: int, n_local: int) -> int:
     """Rows that hold every assignment onto `n_local` held experts."""
     rows = tokens * min(top_k, n_local)
     return -(-rows // ROW_TILE) * ROW_TILE
+
+
+def plan_chunks(group_sizes):
+    """Trips of `experts`' loop under a plan with these `group_sizes`
+    (an array, traced or not): the chunks of `ROW_CHUNK` rows that hold
+    an assignment."""
+    return -(-group_sizes.sum() // ROW_CHUNK)
 
 
 def route(x, w_router, bias, *, top_k: int, scale: float = 1.0,
@@ -135,31 +151,118 @@ def _gmm(lhs, rhs, group_sizes):
                        _tile(rhs.shape[2], 512)))
 
 
+def _use_kernel() -> bool:
+    return env.get_bool("MXNET_USE_PALLAS")
+
+
 def _grouped(lhs, rhs, group_sizes):
     """lhs (rows, K) @ rhs[g] (K, N) for the rows of group g.  Rows past
     the last group come back undefined from the kernel: the caller masks
     them."""
-    if not env.get_bool("MXNET_USE_PALLAS"):
-        _route_counts["ragged_dot"] += 1
+    if not _use_kernel():
         return _ragged(lhs, rhs, group_sizes)
-    _route_counts["grouped_kernel"] += 1
     return lax.platform_dependent(lhs, rhs, group_sizes, tpu=_gmm,
                                   default=_ragged)
+
+
+def _chunk_rows(x, used, weight, sizes, w1, w2):
+    """One chunk's rows through their experts: x (C, K) gathered tokens,
+    `used` (C,) which rows hold an assignment, sizes (n_local,) the rows
+    of each group inside the chunk.  Returns (C, K) float32, weighed."""
+    hidden = jnp.square(jnp.maximum(_grouped(x, w1, sizes), 0))
+    out = _grouped(hidden, w2, sizes)
+    return (jnp.where(used[:, None], out, 0).astype(jnp.float32)
+            * weight[:, None])
+
+
+def _chunks(u, token, weight, group_sizes):
+    """How `experts`' two loops cut a plan: (its rows rounded up to whole
+    chunks, the trip count, window(c) -> (chunk c's first row, its
+    tokens, its weights, the rows of each group that lie inside it, its
+    tokens' rows of u))."""
+    t, rows = u.shape[0], token.shape[0]
+    chunk = min(ROW_CHUNK, rows)
+    most = -(-rows // chunk)
+    pad = most * chunk - rows
+    token = jnp.pad(token, (0, pad), constant_values=t)
+    weight = jnp.pad(weight, (0, pad))
+    ends = jnp.cumsum(group_sizes)
+    starts = ends - group_sizes
+
+    def window(c):
+        lo = c * chunk
+        tok = lax.dynamic_slice(token, (lo,), (chunk,))
+        return (lo, tok, lax.dynamic_slice(weight, (lo,), (chunk,)),
+                jnp.clip(ends, lo, lo + chunk)
+                - jnp.clip(starts, lo, lo + chunk),
+                jnp.take(u, tok, axis=0, mode="fill", fill_value=0))
+
+    return most * chunk, jnp.minimum(plan_chunks(group_sizes), most), window
+
+
+def _forward(u, token, weight, group_sizes, w1, w2):
+    t = u.shape[0]
+    _, trips, window = _chunks(u, token, weight, group_sizes)
+
+    def body(c, acc):
+        _, tok, wt, sizes, x = window(c)
+        return acc.at[tok].add(_chunk_rows(x, tok < t, wt, sizes, w1, w2),
+                               mode="drop")
+
+    return lax.fori_loop(0, trips, body,
+                         jnp.zeros(u.shape, jnp.float32)).astype(u.dtype)
+
+
+def _forward_and_inputs(*inputs):
+    # the residuals are the inputs and nothing of size rows x N: the
+    # backward's loop makes each chunk's hidden again from its rows
+    return _forward(*inputs), inputs
+
+
+def _backward(res, g):
+    u, token, weight, group_sizes, w1, w2 = res
+    t = u.shape[0]
+    padded, trips, window = _chunks(u, token, weight, group_sizes)
+    g = g.astype(jnp.float32)
+
+    def body(c, carry):
+        du, dweight, dw1, dw2 = carry
+        lo, tok, wt, sizes, x = window(c)
+        _, pull = jax.vjp(
+            lambda x, wt, w1, w2: _chunk_rows(x, tok < t, wt, sizes, w1, w2),
+            x, wt, w1, w2)
+        dx, dwt, d1, d2 = pull(
+            jnp.take(g, tok, axis=0, mode="fill", fill_value=0))
+        return (du.at[tok].add(dx.astype(jnp.float32), mode="drop"),
+                lax.dynamic_update_slice(dweight, dwt, (lo,)),
+                dw1 + d1.astype(jnp.float32), dw2 + d2.astype(jnp.float32))
+
+    du, dweight, dw1, dw2 = lax.fori_loop(0, trips, body, (
+        jnp.zeros(u.shape, jnp.float32), jnp.zeros((padded,), jnp.float32),
+        jnp.zeros(w1.shape, jnp.float32), jnp.zeros(w2.shape, jnp.float32)))
+    return (du.astype(u.dtype), None,
+            dweight[:weight.shape[0]].astype(weight.dtype), None,
+            dw1.astype(w1.dtype), dw2.astype(w2.dtype))
+
+
+# a loop with a traced trip count has no reverse mode of its own
+_experts = jax.custom_vjp(_forward)
+_experts.defvjp(_forward_and_inputs, _backward)
 
 
 def experts(u, plan: RoutePlan, w1, w2):
     """The held experts' part of the layer: u (T, K) tokens, w1
     (n_local, K, N), w2 (n_local, N, K); returns (T, K) in u's dtype:
-    sum over a token's held experts of weight * relu(u W1_e)^2 W2_e."""
-    t = u.shape[0]
-    rows = jnp.take(u, plan.token, axis=0, mode="fill", fill_value=0)
-    hidden = _grouped(rows, w1, plan.group_sizes)
-    hidden = jnp.square(jnp.maximum(hidden, 0))
-    out = _grouped(hidden, w2, plan.group_sizes)
-    used = (plan.token < t)[:, None]
-    out = jnp.where(used, out, 0).astype(jnp.float32) * plan.weight[:, None]
-    return jnp.zeros((t, u.shape[1]), jnp.float32).at[plan.token].add(
-        out, mode="drop").astype(u.dtype)
+    sum over a token's held experts of weight * relu(u W1_e)^2 W2_e.
+
+    The plan's rows are handled `ROW_CHUNK` at a time, `plan_chunks(
+    plan.group_sizes)` times: per chunk gather, grouped product, relu^2,
+    grouped product, mask, scale in float32, add into a (T, K) float32
+    sum.  The gradient is a second loop of the same trip count over the
+    same chunks; nothing of a forward pass is kept for it but the
+    inputs."""
+    _route_counts["grouped_kernel" if _use_kernel() else "ragged_dot"] += 2
+    return _experts(u, plan.token, plan.weight, plan.group_sizes, w1, w2)
 
 
 def moe_apply(x, u, w_router, bias, w1, w2, *, top_k: int,

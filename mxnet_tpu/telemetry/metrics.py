@@ -68,6 +68,8 @@ def _fmt_value(v: float) -> str:
         return "+Inf"
     if v == -math.inf:
         return "-Inf"
+    if v != v:
+        return "NaN"
     if isinstance(v, float) and v.is_integer() and abs(v) < 1e15:
         return str(int(v))
     return repr(v)

@@ -281,8 +281,8 @@ print("AOT_OK")
 # the decoder's kernel routes at the nemotron3_super_s8192 cell's
 # shapes: jax.grad through the causal grouped-query attention route (32
 # query heads over 2 key/value heads of 128, S = 8192) and through the
-# held experts' grouped products (8192 tokens, 8 experts held of a top-22
-# router: 65536 rows, latent 1024, expert 2688)
+# held experts' stage (8192 tokens, 8 experts held of a top-22 router: a
+# plan of 65536 rows in chunks of ROW_CHUNK, latent 1024, expert 2688)
 _AOT_DECODER = r"""
 import re, sys
 import jax, jax.numpy as jnp
@@ -330,16 +330,19 @@ def experts_loss(u, w1, w2, token, weight, sizes):
     with jax.named_scope("moe_experts"):
         return moe.experts(u, plan, w1, w2).astype(jnp.float32).sum()
 names = mosaic_names(
-    jax.grad(experts_loss, argnums=(0, 1, 2)), arg((t, latent)),
+    jax.value_and_grad(experts_loss, argnums=(0, 1, 2, 4)), arg((t, latent)),
     arg((held, latent, width)), arg((held, width, latent)),
     arg((rows,), jnp.int32), arg((rows,), jnp.float32),
     arg((held,), jnp.int32))
 print("MOSAIC experts", names)
-# the gradient of a sum needs the first product's forward (the second's
-# output is dead) and, for each product, dlhs and the transposed drhs
-assert all("moe_experts" in n for n in names), names
-assert sum("transpose(" not in n for n in names) == 1, names
-assert sum("transpose(" in n and "gmm" in n for n in names) == 4, names
+# every call sits in one of the two loops over the plan's chunks: the
+# forward's two products; in the backward's, both again (the chunk's
+# hidden is not kept, and its output is what the combine weights'
+# gradient is made of) and, for each, dlhs and the transposed drhs
+assert all("moe_experts" in n and "/while/body/" in n for n in names), names
+assert sum("transpose(" not in n for n in names) == 2, names
+back = [n for n in names if "transpose(jvp(moe_experts))/while/body/" in n]
+assert len(back) == 6 and sum("jit(tgmm)" in n for n in back) == 2, names
 assert moe.route_counts()["grouped_kernel"] == 2, moe.route_counts()
 
 from mxnet_tpu.ops import ssm

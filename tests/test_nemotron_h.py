@@ -220,6 +220,117 @@ def test_expert_products_count_their_route(monkeypatch):
     assert after["ragged_dot"] == before["ragged_dot"] + 2
 
 
+def _dense_experts(u, token, weight, group_sizes, w1, w2):
+    """`moe.experts` row by row in plain jnp: every row through its own
+    expert's two matrices, no grouped product, no loop."""
+    t = u.shape[0]
+    expert = jnp.minimum(jnp.searchsorted(
+        jnp.cumsum(group_sizes), jnp.arange(token.shape[0]), side="right"),
+        w1.shape[0] - 1)
+    x = jnp.take(u, token, axis=0, mode="fill", fill_value=0)
+    hidden = jnp.square(jnp.maximum(
+        jnp.einsum("rk,rkn->rn", x, w1[expert]), 0))
+    out = jnp.einsum("rn,rnk->rk", hidden, w2[expert])
+    out = jnp.where((token < t)[:, None], out, 0) * weight[:, None]
+    return jnp.zeros_like(u).at[token].add(out, mode="drop")
+
+
+# bias on the held experts 2 and 3, rows a chunk: the assignments made
+# and the chunks that hold them
+_LOADS = {"typical_one_chunk": (0.0, 64),
+          "every_token_several_chunks": (10.0, 16),
+          "no_assignment_no_chunk": (-10.0, 16)}
+
+
+def _loaded_plan(monkeypatch, load):
+    bias, chunk = _LOADS[load]
+    monkeypatch.setattr(moe, "ROW_CHUNK", chunk)
+    monkeypatch.setenv("MXNET_USE_PALLAS", "0")     # the ragged_dot twin
+    w = _moe_weights(np.random.RandomState(6))
+    w["b"][2:4] = bias
+    plan = moe.route(jnp.asarray(w["x"]), jnp.asarray(w["wr"]),
+                     jnp.asarray(w["b"]), top_k=3, scale=2.0,
+                     first_expert=2, n_local=2)
+    return w, plan
+
+
+@pytest.mark.parametrize("wrap", [jax.jit,
+                                  lambda f: jax.jit(jax.checkpoint(f))],
+                         ids=["jit", "checkpoint"])
+@pytest.mark.parametrize("load", list(_LOADS))
+def test_experts_in_chunks_match_the_dense_form(monkeypatch, load, wrap):
+    """The expert stage goes over its plan `ROW_CHUNK` rows at a time, as
+    often as the assignments made ask for: one chunk, several with a
+    group that straddles their boundaries, none.  Result and gradients
+    with respect to u, the combine weights, w1 and w2 are the dense
+    form's."""
+    w, plan = _loaded_plan(monkeypatch, load)
+    sizes = np.asarray(plan.group_sizes)
+    chunks = int(moe.plan_chunks(plan.group_sizes))
+    if load == "typical_one_chunk":
+        assert chunks == 1 and 0 < sizes.sum() <= 64
+    elif load == "every_token_several_chunks":
+        # expert 2's 40 rows end inside the third chunk of 16
+        assert sizes.tolist() == [40, 40] and chunks == 5
+        assert int(plan.dropped) == 0
+    else:
+        assert sizes.sum() == 0 and chunks == 0
+    ct = jnp.asarray(np.random.RandomState(7).randn(40, 8), jnp.float32)
+
+    def loss(form):
+        def f(u, weight, w1, w2):
+            out = form(u, weight, w1, w2)
+            return (out * ct).sum(), out
+        return f
+
+    def chunked(u, weight, w1, w2):
+        return moe.experts(u, plan._replace(weight=weight), w1, w2)
+
+    def dense(u, weight, w1, w2):
+        return _dense_experts(u, plan.token, weight, plan.group_sizes,
+                              w1, w2)
+
+    args = (jnp.asarray(w["u"]), plan.weight, jnp.asarray(w["w1"][2:4]),
+            jnp.asarray(w["w2"][2:4]))
+    grads, out = wrap(jax.grad(loss(chunked), argnums=(0, 1, 2, 3),
+                               has_aux=True))(*args)
+    want, want_out = jax.grad(loss(dense), argnums=(0, 1, 2, 3),
+                              has_aux=True)(*args)
+    np.testing.assert_allclose(out, _routed_oracle(w, 3, 2.0, {2, 3}),
+                               rtol=2e-5, atol=2e-5)
+    for got, ref in zip((out, *grads), (want_out, *want)):
+        assert got.shape == ref.shape and got.dtype == ref.dtype
+        assert np.isfinite(np.asarray(got)).all()
+        np.testing.assert_allclose(got, ref, rtol=2e-5, atol=2e-5)
+    if chunks == 0:
+        assert not any(np.asarray(g).any() for g in (out, *grads))
+
+
+def test_plan_chunks_is_the_trip_count_of_both_loops(monkeypatch):
+    """`plan_chunks` says how often the loops ran: the forward's body and
+    the backward's each run that many times, counted on the host."""
+    w, plan = _loaded_plan(monkeypatch, "every_token_several_chunks")
+    assert int(plan.dropped) == 0
+    assert plan.token.shape[0] == moe.plan_rows(40, 3, 2)   # the bound
+    ran, real = [], moe._chunk_rows
+
+    def counted(*args):
+        jax.debug.callback(lambda: ran.append(1))
+        return real(*args)
+
+    monkeypatch.setattr(moe, "_chunk_rows", counted)
+    args = (jnp.asarray(w["u"]), plan, jnp.asarray(w["w1"][2:4]),
+            jnp.asarray(w["w2"][2:4]))
+    jax.block_until_ready(jax.jit(moe.experts)(*args))
+    jax.effects_barrier()
+    chunks = int(moe.plan_chunks(plan.group_sizes))
+    assert len(ran) == chunks == 5
+    jax.block_until_ready(jax.jit(jax.grad(
+        lambda u: moe.experts(u, *args[1:]).sum()))(args[0]))
+    jax.effects_barrier()
+    assert len(ran) == 3 * chunks
+
+
 def _layer(held=None, first=0, **kw):
     layer = zoo.LatentMoELayer(16, 8, 3, 8, 12, 24, 2.5, 1e-5,
                                experts_held=held, first_expert=first, **kw)

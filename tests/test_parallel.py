@@ -633,14 +633,15 @@ def test_ulysses_rejects_indivisible_heads():
         parallel.ulysses.ulysses_attention_sharded(q, q, q)
 
 
-def _moe_oracle(x, u, wr, w1, w2, top_k, scale):
+def _moe_oracle(x, u, wr, w1, w2, top_k, scale, bias=0.0):
     """The routed part of a sigmoid top-k expert layer in numpy, token by
-    token: weight = scale * score / sum of the chosen scores; an expert
-    is relu(u W1)^2 W2.  Nothing is ever dropped."""
+    token: the top_k of score + bias chosen, weight = scale * score / sum
+    of the chosen scores; an expert is relu(u W1)^2 W2.  Nothing is ever
+    dropped."""
     score = 1.0 / (1.0 + np.exp(-(x @ wr.T)))
     y = np.zeros_like(u)
     for t in range(len(x)):
-        chosen = np.argsort(-score[t], kind="stable")[:top_k]
+        chosen = np.argsort(-(score[t] + bias), kind="stable")[:top_k]
         for e in chosen:
             hidden = np.maximum(u[t] @ w1[e], 0) ** 2
             y[t] += (scale * score[t, e] / score[t, chosen].sum()
@@ -648,11 +649,15 @@ def _moe_oracle(x, u, wr, w1, w2, top_k, scale):
     return y
 
 
-@pytest.mark.parametrize("ep", [4, 2])
-def test_moe_expert_parallel_matches_oracle(ep):
+@pytest.mark.parametrize("ep,skewed", [(4, False), (2, False), (4, True)],
+                         ids=["4", "2", "4-trip_counts_differ"])
+def test_moe_expert_parallel_matches_oracle(monkeypatch, ep, skewed):
     """The expert layer over an `ep` mesh (each device holds E / ep
     experts, routes over all E, computes its own part; the parts are
-    summed) equals the one-program result and the numpy oracle."""
+    summed) equals the one-program result and the numpy oracle.  Skewed:
+    a bias sends every token to the first device's two experts, so its
+    loop over the rows runs 4 chunks of 16 where the others run 1: the
+    trip count is each device's own and the sum waits outside the loop."""
     from mxnet_tpu.parallel import moe
 
     rng = np.random.RandomState(5)
@@ -662,9 +667,18 @@ def test_moe_expert_parallel_matches_oracle(ep):
     wr = rng.randn(E, D).astype(np.float32) * 0.5
     w1 = rng.randn(E, K, N).astype(np.float32) * 0.3
     w2 = rng.randn(E, N, K).astype(np.float32) * 0.3
-    args = [jnp.asarray(a) for a in (x, u, wr, np.zeros(E, np.float32),
-                                     w1, w2)]
-    ref = _moe_oracle(x, u, wr, w1, w2, top_k=3, scale=2.5)
+    bias = np.zeros(E, np.float32)
+    if skewed:
+        bias[:2] = 10.0
+        monkeypatch.setattr(moe, "ROW_CHUNK", 16)
+        monkeypatch.setenv("MXNET_USE_PALLAS", "0")     # 16 is no row tile
+        trips = [int(moe.plan_chunks(moe.route(
+            jnp.asarray(x), jnp.asarray(wr), jnp.asarray(bias), top_k=3,
+            first_expert=first, n_local=E // ep).group_sizes))
+            for first in range(0, E, E // ep)]
+        assert trips[0] == 4 and set(trips[1:]) == {1}, trips
+    args = [jnp.asarray(a) for a in (x, u, wr, bias, w1, w2)]
+    ref = _moe_oracle(x, u, wr, w1, w2, top_k=3, scale=2.5, bias=bias)
     with parallel.make_mesh(ep=ep, devices=jax.devices()[:ep]):
         y, dropped = jax.jit(lambda *a: moe.moe_apply(
             *a, top_k=3, scale=2.5))(*args)

@@ -95,7 +95,8 @@ def test_http_metrics_and_healthz_smoke(artifact):
         assert status == 200
         sample_re = re.compile(
             r'^[a-zA-Z_:][a-zA-Z0-9_:]*(\{[^{}]*\})? '
-            r'[0-9eE\.\+\-]+$')
+            r'([0-9eE\.\+\-]+|NaN|[+-]Inf)$')    # a gauge another test of
+        # this process left non-finite (mx_grad_norm) is still well-formed
         families = set()
         n_samples = 0
         for ln in text.strip().split("\n"):
