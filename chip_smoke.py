@@ -413,15 +413,17 @@ def attention_train_stage(shapes, *, dtype="bfloat16", expect_mosaic=True,
     return out
 
 
-def decoder_phase(*, seq=1024, heads=8, kv_heads=2, dim=128, tokens=2048,
-                  experts=32, held=8, top_k=4, held_bias=0.2, latent=256,
-                  width=384,
+def decoder_phase(*, seq=1024, heads=8, kv_heads=2, dim=128, window=256,
+                  tokens=2048, experts=32, held=8, top_k=4, held_bias=0.2,
+                  latent=256, width=384,
                   scan=(8192, 128, 64, 8, 128), dtype="bfloat16",
                   expect_mosaic=True) -> dict:
-    """Value and gradients of the hybrid decoder's three kernel routes
-    against plain XLA on the same operands: causal grouped-query attention
-    through the op (upstream's flash kernels) against the S x S reference,
-    the held experts' stage (a loop over chunks of the plan's rows around
+    """Value and gradients of the decoders' kernel routes against plain
+    XLA on the same operands: causal grouped-query attention through the
+    op (upstream's flash kernels) against the S x S reference, the same
+    under a sliding `window` (upstream's splash kernels over the band)
+    against the banded XLA form, the held experts' stage in both forms
+    (relu^2 and silu-gated) (a loop over chunks of the plan's rows around
     the grouped-matmul kernel; `held_bias` on the router draws enough
     tokens to the held experts that it runs twice, a group across the
     boundary and an empty tail in the second chunk) against every held
@@ -477,17 +479,35 @@ def decoder_phase(*, seq=1024, heads=8, kv_heads=2, dim=128, tokens=2048,
             q, k, v, None, None, num_heads=heads, num_kv_heads=kv_heads,
             causal=True, _train=True), ct)
 
+    def split(q, k, v):
+        return (pa._split_to_heads(q, heads), pa._split_to_heads(k, kv_heads),
+                pa._split_to_heads(v, kv_heads))
+
     def attention_plain(q, k, v, ct):
-        def split(x, n):
-            return x.reshape(1, seq, n, dim).transpose(0, 2, 1, 3)
-        o = pa._causal_xla(split(q, heads), split(k, kv_heads),
-                           split(v, kv_heads), dim ** -0.5)
+        o = pa._causal_xla(*split(q, k, v), dim ** -0.5)
         return weighed(o.transpose(0, 2, 1, 3).reshape(q.shape), ct)
 
     compare(f"causal_gqa_h{heads}_kv{kv_heads}_s{seq}_d{dim}", attention,
             attention_plain, (q, k, v, ct), out)
     _require(pa.route_counts()["flash_causal"] > before,
              f"the causal call did not take the flash route: "
+             f"{pa.route_counts()}")
+
+    before = pa.route_counts()["splash_window"]
+
+    def windowed(q, k, v, ct):
+        return weighed(pa._sliding_window_attention(
+            q, k, v, num_heads=heads, num_kv_heads=kv_heads,
+            window=window), ct)
+
+    def windowed_plain(q, k, v, ct):
+        o = pa._window_xla(*split(q, k, v), dim ** -0.5, window)
+        return weighed(o.transpose(0, 2, 1, 3).reshape(q.shape), ct)
+
+    compare(f"window{window}_gqa_h{heads}_kv{kv_heads}_s{seq}_d{dim}",
+            windowed, windowed_plain, (q, k, v, ct), out)
+    _require(pa.route_counts()["splash_window"] > before,
+             f"the windowed call did not take the splash route: "
              f"{pa.route_counts()}")
 
     x = jnp.asarray(rng.randn(tokens, 64), jnp.float32)
@@ -505,25 +525,40 @@ def decoder_phase(*, seq=1024, heads=8, kv_heads=2, dim=128, tokens=2048,
     w1 = jnp.asarray(rng.randn(held, latent, width) * 0.1, dtype)
     w2 = jnp.asarray(rng.randn(held, width, latent) * 0.1, dtype)
 
-    def grouped(u, w1, w2, ct, token, weight, sizes):
-        return weighed(moe.experts(
-            u, moe.RoutePlan(token, weight, sizes, plan.dropped), w1, w2), ct)
+    def grouped(form):
+        def f(u, w1, w2, ct, token, weight, sizes):
+            return weighed(moe.experts(
+                u, moe.RoutePlan(token, weight, sizes, plan.dropped), w1, w2,
+                form), ct)
+        return f
 
-    def dense(u, w1, w2, ct, token, weight, sizes):
-        # (tokens, held) combine weights back from the rows' layout
-        expert = jnp.searchsorted(jnp.cumsum(sizes), jnp.arange(
-            token.shape[0]), side="right")
-        table = jnp.zeros((tokens, held + 1), jnp.float32).at[
-            token, jnp.minimum(expert, held)].add(weight, mode="drop")
-        total = jnp.zeros((tokens, latent), jnp.float32)
-        for e in range(held):
-            hidden = jnp.square(jnp.maximum(u @ w1[e], 0))
-            total += table[:, e, None] * (hidden @ w2[e]).astype(jnp.float32)
-        return weighed(total.astype(u.dtype), ct)
+    def dense(form):
+        def f(u, w1, w2, ct, token, weight, sizes):
+            # (tokens, held) combine weights back from the rows' layout
+            expert = jnp.searchsorted(jnp.cumsum(sizes), jnp.arange(
+                token.shape[0]), side="right")
+            table = jnp.zeros((tokens, held + 1), jnp.float32).at[
+                token, jnp.minimum(expert, held)].add(weight, mode="drop")
+            total = jnp.zeros((tokens, latent), jnp.float32)
+            for e in range(held):
+                hidden = u @ w1[e]
+                if form == "relu2":
+                    hidden = jnp.square(jnp.maximum(hidden, 0))
+                else:
+                    hidden = (jax.nn.silu(hidden[:, :width])
+                              * hidden[:, width:])
+                total += table[:, e, None] * (hidden @ w2[e]).astype(
+                    jnp.float32)
+            return weighed(total.astype(u.dtype), ct)
+        return f
 
-    compare(f"experts_t{tokens}_held{held}_k{latent}_n{width}", grouped,
-            dense, (u, w1, w2, ct, plan.token, plan.weight,
-                    plan.group_sizes), out, looped=True)
+    gated_w1 = jnp.asarray(rng.randn(held, latent, 2 * width) * 0.1, dtype)
+    for form, first in (("relu2", w1), ("silu_gated", gated_w1)):
+        name = "experts" if form == "relu2" else "gated_experts"
+        compare(f"{name}_t{tokens}_held{held}_k{latent}_n{width}",
+                grouped(form), dense(form),
+                (u, first, w2, ct, plan.token, plan.weight,
+                 plan.group_sizes), out, looped=True)
 
     s, h, p, g, n = scan
     x, ct = (jnp.asarray(rng.randn(1, s, h, p), dtype) for _ in range(2))

@@ -1,6 +1,7 @@
 """Gluon model zoo (ref: python/mxnet/gluon/model_zoo/__init__.py)."""
 from . import vision
 from . import nemotron_h
+from . import laguna
 from .vision import get_model
 
-__all__ = ["vision", "nemotron_h", "get_model"]
+__all__ = ["vision", "nemotron_h", "laguna", "get_model"]

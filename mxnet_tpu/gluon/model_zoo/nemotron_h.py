@@ -30,11 +30,13 @@ from ... import initializer as init_mod
 from ...base import MXNetError
 from .. import nn
 from ..block import HybridBlock
+from ._decoder import FP32 as _FP32
+from ._decoder import Head as _Head
+from ._decoder import Layer as _Layer
+from ._decoder import project as _project
 
 __all__ = ["NemotronHModel", "MambaLayer", "AttentionLayer",
            "LatentMoELayer", "nemotron_h"]
-
-_FP32 = "float32"
 
 
 class _ALog(init_mod.Initializer):
@@ -57,38 +59,6 @@ class _DtBias(init_mod.Initializer):
         dt = np.maximum(np.exp(np.random.uniform(lo, hi, arr.shape)),
                         self._floor)
         arr[:] = dt + np.log(-np.expm1(-dt))
-
-
-class _Layer(HybridBlock):
-    """h + mixer(RMSNorm(h)); subclasses give `mix`.  Parameters named in
-    `_FLOAT32` keep float32 under `cast`, as the published model keeps
-    them."""
-
-    _FLOAT32 = ()
-
-    def __init__(self, hidden_size, eps, **kwargs):
-        super().__init__(**kwargs)
-        self._hidden, self._eps = hidden_size, eps
-        with self.name_scope():
-            self.norm_weight = self.params.get(
-                "norm_weight", shape=(hidden_size,), init="ones")
-
-    def cast(self, dtype):
-        self._clear_cached_op()
-        for name, p in self._reg_params.items():
-            p.cast(_FP32 if name in self._FLOAT32 else dtype)
-
-    def hybrid_forward(self, F, x, norm_weight, **params):
-        mixed = self.mix(F, F.RMSNorm(x, norm_weight, eps=self._eps),
-                         **params)
-        if isinstance(mixed, (list, tuple)):        # (output, statistics)
-            return (x + mixed[0], *mixed[1:])
-        return x + mixed
-
-
-def _project(F, x, weight):
-    return F.FullyConnected(x, weight, None, num_hidden=weight.shape[0],
-                            no_bias=True, flatten=False)
 
 
 class MambaLayer(_Layer):
@@ -248,22 +218,6 @@ class LatentMoELayer(_Layer):
         out = _project(F, routed, latent_up_weight) + shared
         stats = F.concat(group_sizes, F.reshape(dropped, shape=(1,)), dim=0)
         return F.reshape(out, shape=(b, s, self._hidden)), stats
-
-
-class _Head(HybridBlock):
-    """Final RMSNorm and the untied vocabulary projection."""
-
-    def __init__(self, hidden_size, vocab_size, eps, **kwargs):
-        super().__init__(**kwargs)
-        self._eps = eps
-        with self.name_scope():
-            self.norm_weight = self.params.get(
-                "norm_weight", shape=(hidden_size,), init="ones")
-            self.weight = self.params.get(
-                "weight", shape=(vocab_size, hidden_size))
-
-    def hybrid_forward(self, F, x, norm_weight, weight):
-        return _project(F, F.RMSNorm(x, norm_weight, eps=self._eps), weight)
 
 
 class NemotronHModel(HybridBlock):

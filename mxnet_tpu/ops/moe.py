@@ -23,13 +23,17 @@ def _moe_route(data, router_weight, router_bias, top_k=1, scale=1.0,
 
 
 @register_op("moe_experts")
-def _moe_experts(data, token, weight, group_sizes, w1, w2):
+def _moe_experts(data, token, weight, group_sizes, w1, w2, form="relu2",
+                 expected_rows=0):
     """The held experts' part for tokens `data` (T, K) under a plan from
-    `moe_route`: sum of weight * relu(data W1_e)^2 W2_e over a token's
-    held experts; w1 (n, K, N), w2 (n, N, K)."""
+    `moe_route`, over a token's held experts; w2 (n, N, K).  `form`
+    "relu2": sum of weight * relu(data W1_e)^2 W2_e, w1 (n, K, N);
+    "silu_gated": sum of weight * (silu(data G_e) * (data U_e)) W2_e, w1
+    (n, K, 2N) = [G | U].  `expected_rows`: the assignments the model
+    expects on the held experts (`parallel.moe.row_chunk`)."""
     import jax.numpy as jnp
 
     from ..parallel import moe
 
     plan = moe.RoutePlan(token, weight, group_sizes, jnp.zeros((), jnp.int32))
-    return moe.experts(data, plan, w1, w2)
+    return moe.experts(data, plan, w1, w2, form, expected_rows)

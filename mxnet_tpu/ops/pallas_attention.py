@@ -28,6 +28,19 @@ lowered for; no switch of its own):
     few MB at 2 of 32 heads; autodiff sums their gradient back).  Not
     under a mesh of several devices (a bare Mosaic call), where the call
     takes the dropout-free route below.  Counted `flash_causal`.
+  * Causal sliding-window self-attention (`sliding_window_attention`, an
+    op of its own so that a profile reads the window and the full cores
+    apart): query i sees keys j with 0 <= i - j < window.  Shapes as for
+    `flash_causal`, window < S: `_attend_window`, upstream's splash
+    kernels (jax.experimental.pallas.ops.tpu.splash_attention: forward,
+    dQ and dK/dV under their own custom_vjp) over a `LocalMask`, which
+    visit only the blocks the band touches: O(S x window) work where
+    `flash_causal` does O(S^2 / 2).  The query heads of one key/value
+    head go through the multi-query kernel together, so the key/value
+    heads are not repeated in HBM.  Not under a mesh of several devices.
+    Counted `splash_window`; every other windowed call (odd shapes, a
+    mesh, MXNET_USE_PALLAS=0) is the banded XLA form, counted
+    `reference`; window >= S is causal attention and takes that route.
   * Training with dropout on the probabilities, self-attention shaped as
     BERT's (not causal, sq == sk, a multiple of 128 up to 1024, heads of
     64, 128 or 256 filling whole 128-lane blocks): `_attend_train`, two
@@ -768,6 +781,12 @@ def _fused_train_shape(heads, sq, sk, d, causal):
 # Causal self-attention without dropout: a decoder's layers
 # ---------------------------------------------------------------------------
 
+def _split_to_heads(x, heads):
+    """(B, S, heads * D) -> (B, heads, S, D)."""
+    b, s, u = x.shape
+    return x.reshape(b, s, heads, u // heads).transpose(0, 2, 1, 3)
+
+
 def _repeat_kv(x, heads):
     """(B, Hkv, S, D) -> (B, heads, S, D): each key/value head serves
     heads // Hkv consecutive query heads."""
@@ -812,6 +831,87 @@ def _causal_flash_shape(heads, kv_heads, sq, sk, d):
             and heads % kv_heads == 0)
 
 
+# ---------------------------------------------------------------------------
+# Causal sliding-window self-attention: a decoder's local layers
+# ---------------------------------------------------------------------------
+
+def _window_xla(q, k, v, scale, window):
+    """The band 0 <= i - j < window in plain XLA: q (B, H, S, D), k and
+    v (B, Hkv, S, D); scores and softmax in float32.  Queries in blocks
+    of `window`, each against its own block of keys and the one before
+    it, so the scores are (B, H, S, 2 window) where a dense mask would
+    hold (B, H, S, S): the form a mesh of several devices and the CPU
+    take stays O(S x window) in memory and work, as the kernels are."""
+    b, h, s, d = q.shape
+    n = -(-s // window)
+
+    def blocks(x):
+        """(B, H, S, D) -> (B, H, n, window, D), zeros after S: keys no
+        query of the S looks ahead to, queries cut off again below."""
+        x = jnp.pad(x, ((0, 0), (0, 0), (0, n * window - s), (0, 0)))
+        return x.reshape(b, h, n, window, d)
+
+    def with_previous(x):
+        before = jnp.pad(x[:, :, :-1],
+                         ((0, 0), (0, 0), (1, 0), (0, 0), (0, 0)))
+        return jnp.concatenate([before, x], axis=3)
+
+    score = jnp.einsum("bhnqd,bhnkd->bhnqk", blocks(q),
+                       with_previous(blocks(_repeat_kv(k, h))),
+                       preferred_element_type=jnp.float32) * scale
+    # key c of a block's 2 window is position c - window of the block
+    ahead = jnp.arange(window)[:, None] + window - jnp.arange(2 * window)
+    exists = (jnp.arange(n)[:, None, None] > 0) | (
+        jnp.arange(2 * window) >= window)           # block 0 has no before
+    score = jnp.where((ahead >= 0) & (ahead < window) & exists, score,
+                      -1e30)
+    prob = jax.nn.softmax(score, axis=-1).astype(v.dtype)
+    out = jnp.einsum("bhnqk,bhnkd->bhnqd", prob,
+                     with_previous(blocks(_repeat_kv(v, h))))
+    return out.reshape(b, h, n * window, d)[:, :, :s]
+
+
+def _window_splash(q, k, v, scale, window, interpret=False):
+    """The splash kernels over the band: the `groups` query heads of one
+    key/value head are one multi-query call, vmapped over batch and
+    key/value heads.  Blocks of 512 where they divide S: a query block
+    visits the 2 key blocks its band touches.  On the v5e at the window
+    of 512, forward + backward (PERF.md, PR 31): 512 23.4 ms, 256 (3
+    blocks, 768 keys for the 512 a query sees, but three times the grid
+    steps) 33.5, 128 69.3, (1024, 512) 30.1, the fused backward slower."""
+    from jax.experimental.pallas.ops.tpu import splash_attention as sa
+
+    b, h, s, d = q.shape
+    kv = k.shape[1]
+    groups = h // kv
+    blk = next(n for n in (512, 256, 128) if s % n == 0)
+    mask = sa.MultiHeadMask([sa.LocalMask((s, s), (window - 1, 0), 0)
+                             for _ in range(groups)])
+    kernel = sa.make_splash_mqa_single_device(
+        mask, block_sizes=sa.BlockSizes(
+            block_q=blk, block_kv=blk, block_kv_compute=blk,
+            block_q_dkv=blk, block_kv_dkv=blk, block_kv_dkv_compute=blk,
+            block_q_dq=blk, block_kv_dq=blk),
+        interpret=interpret)
+    # the kernels apply no scale of their own
+    q = (q * jnp.asarray(scale, q.dtype)).reshape(b, kv, groups, s, d)
+    return jax.vmap(jax.vmap(kernel))(q, k, v).reshape(b, h, s, d)
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "window", "interpret"))
+def _attend_window(q, k, v, scale, window, interpret):
+    """q (B, H, S, D), k and v (B, Hkv, S, D) -> (B, H, S, D): the splash
+    kernels in a program lowered for the TPU (or anywhere under the
+    interpreter), the banded XLA form elsewhere.  Jitted, so that a
+    stack of window layers traces and lowers the kernels once."""
+    if interpret:
+        return _window_splash(q, k, v, scale, window, interpret=True)
+    return jax.lax.platform_dependent(
+        q, k, v,
+        tpu=functools.partial(_window_splash, scale=scale, window=window),
+        default=functools.partial(_window_xla, scale=scale, window=window))
+
+
 # Routes CHOSEN, counted where the branch is chosen: at TRACE time (once a
 # compiled program, never per step), not kernels run: a `fused_train` or
 # `kernel_infer` call in a program lowered for the CPU runs the XLA twin of
@@ -820,7 +920,7 @@ def _causal_flash_shape(heads, kv_heads, sq, sk, d):
 # store; the telemetry counter `mx_attention_route_total{route}` is its
 # export and counts only while telemetry is enabled.
 ROUTES = ("fused_train", "xla_dropout", "kernel_infer", "reference",
-          "flash_causal")
+          "flash_causal", "splash_window")
 _route_counts = dict.fromkeys(ROUTES, 0)
 
 
@@ -873,9 +973,8 @@ def _dot_product_attention(query, key, value, valid_mask=None, rng_key=None,
             and _mesh_batch_axes(b) is None):
         _count_route("flash_causal")
         if packed:
-            qh, kh, vh = (x.reshape(b, sq, n, d).transpose(0, 2, 1, 3)
-                          for x, n in ((query, h), (key, h_kv),
-                                       (value, h_kv)))
+            qh, kh, vh = (_split_to_heads(x, n) for x, n in (
+                (query, h), (key, h_kv), (value, h_kv)))
         else:
             qh, kh, vh = query, key, value
         oh = _attend_causal(qh, kh, vh, float(scale))
@@ -896,10 +995,8 @@ def _dot_product_attention(query, key, value, valid_mask=None, rng_key=None,
         return out if packed else out.reshape(b, sq, h, d).transpose(
             0, 2, 1, 3)
     if packed:
-        def split(x, n):
-            bs, s, _ = x.shape
-            return x.reshape(bs, s, n, d).transpose(0, 2, 1, 3)
-        qh, kh, vh = split(query, h), split(key, h_kv), split(value, h_kv)
+        qh, kh, vh = (_split_to_heads(x, n) for x, n in (
+            (query, h), (key, h_kv), (value, h_kv)))
     else:
         qh, kh, vh = query, key, value
     kh, vh = _repeat_kv(kh, h), _repeat_kv(vh, h)
@@ -923,3 +1020,40 @@ def _dot_product_attention(query, key, value, valid_mask=None, rng_key=None,
     if packed:
         return oh.transpose(0, 2, 1, 3).reshape(b, sq, h * d)
     return oh
+
+
+@register_op("sliding_window_attention")
+def _sliding_window_attention(query, key, value, num_heads=1, window=0,
+                              scale=None, num_kv_heads=0):
+    """Causal sliding-window self-attention without dropout or key mask:
+    query i attends to the keys j with 0 <= i - j < `window`.
+
+    query (B, S, num_heads * D), key and value (B, S, num_kv_heads * D)
+    (0: as num_heads), each key/value head serving num_heads //
+    num_kv_heads query heads.  Returns (B, S, num_heads * D).  A window
+    that covers the sequence is causal attention, and is handed to
+    `dot_product_attention`'s routes."""
+    b, s, u = query.shape
+    h, h_kv = num_heads, num_kv_heads or num_heads
+    d = u // h
+    if window <= 0 or h % h_kv:
+        raise ValueError(f"sliding_window_attention: window {window}, "
+                         f"{h} query heads over {h_kv} key/value heads")
+    if scale is None:
+        scale = 1.0 / np.sqrt(d)
+    if window >= s:
+        return _dot_product_attention(
+            query, key, value, num_heads=h, num_kv_heads=h_kv, scale=scale,
+            causal=True)
+    qh, kh, vh = (_split_to_heads(x, n) for x, n in (
+        (query, h), (key, h_kv), (value, h_kv)))
+    if (env.get_bool("MXNET_USE_PALLAS")
+            and _causal_flash_shape(h, h_kv, s, s, d)
+            and _mesh_batch_axes(b) is None):
+        _count_route("splash_window")
+        oh = _attend_window(qh, kh, vh, float(scale), int(window),
+                            env.get_bool("MXNET_PALLAS_INTERPRET"))
+    else:
+        _count_route("reference")
+        oh = _window_xla(qh, kh, vh, float(scale), int(window))
+    return oh.transpose(0, 2, 1, 3).reshape(b, s, u)

@@ -22,11 +22,20 @@ on the device (`plan_chunks`): gather, products, relu^2, combine and
 their gradients touch the chunks that hold assignments and no others, so
 a layer that holds 8 of 512 experts pays for the ~4% of its rows that
 are used and a layer under the worst imbalance pays for all of them.
+A model that expects more than a chunk of assignments says so
+(`expected_rows`), and the chunk grows to hold twice that in one trip
+(`row_chunk`).
 
     route(x, w_router, bias, ...)   -> RoutePlan for the held experts
-    experts(u, plan, w1, w2)        -> sum over held experts e of
-                                        weight_e * (relu(u W1_e)^2 W2_e)
+    experts(u, plan, w1, w2, form, expected_rows)
+                                    -> sum over held experts e of
+                                        weight_e * (act_e(u) W2_e)
     moe_apply(...)                  -> the same over the 'ep' axis
+
+An expert has one of two `FORMS`, which the model states: `relu2`,
+act(u) = relu(u W1)^2 with w1 (n, K, N), or `silu_gated`, act(u) =
+silu(u G) * (u U) with w1 (n, K, 2N) holding G and U side by side, so
+that either form is two grouped products a chunk.
 
 Routing is the sigmoid-score form of DeepSeek-V3 / Nemotron-H: scores
 s = sigmoid(x W_r^T) in float32, the top_k of s + bias chosen (the bias
@@ -52,11 +61,12 @@ from ._compat import shard_map_unchecked
 from .mesh import DeviceMesh, current_mesh
 
 __all__ = ["RoutePlan", "route", "experts", "moe_apply", "plan_rows",
-           "plan_chunks", "route_counts"]
+           "plan_chunks", "row_chunk", "route_counts"]
 
 ROW_TILE = 512      # rows a grouped-product tile takes: `rows` is a multiple
 ROW_CHUNK = 4096    # rows `experts` handles a trip of its loop: a multiple
 
+FORMS = ("relu2", "silu_gated")   # an expert's activation: see `experts`
 ROUTES = ("grouped_kernel", "ragged_dot")
 _route_counts = dict.fromkeys(ROUTES, 0)
 
@@ -83,11 +93,28 @@ def plan_rows(tokens: int, top_k: int, n_local: int) -> int:
     return -(-rows // ROW_TILE) * ROW_TILE
 
 
-def plan_chunks(group_sizes):
+def row_chunk(expected_rows: int = 0) -> int:
+    """Rows a trip of `experts`' loop handles: `ROW_CHUNK`, or, where the
+    model says how many assignments it expects on the held experts
+    (tokens x top_k x held / experts under even routing: a static
+    number) and twice that is more, twice that in whole row tiles.  An
+    uneven layer is then still ONE trip: at random weights a layer's load
+    read up to 1.74 times the expected, and it grows as the held experts
+    train (PERF.md, PR 31).  In chunks of `ROW_CHUNK` a load of several
+    chunks pays each trip's fixed costs (the float32 weight-gradient
+    carries are rewritten a trip) that many times, and the step's time
+    follows the seed's load trip by trip (laguna_xs2_s8192 expects
+    16,384 = 4 x 4096 a layer); a quarter of headroom was too little
+    (some layers took a second trip of 20,480 rows, by the seed and the
+    step)."""
+    return max(ROW_CHUNK, -(-2 * expected_rows // ROW_TILE) * ROW_TILE)
+
+
+def plan_chunks(group_sizes, expected_rows: int = 0):
     """Trips of `experts`' loop under a plan with these `group_sizes`
-    (an array, traced or not): the chunks of `ROW_CHUNK` rows that hold
-    an assignment."""
-    return -(-group_sizes.sum() // ROW_CHUNK)
+    (an array, traced or not): the chunks of `row_chunk(expected_rows)`
+    rows that hold an assignment."""
+    return -(-group_sizes.sum() // row_chunk(expected_rows))
 
 
 def route(x, w_router, bias, *, top_k: int, scale: float = 1.0,
@@ -165,23 +192,28 @@ def _grouped(lhs, rhs, group_sizes):
                                   default=_ragged)
 
 
-def _chunk_rows(x, used, weight, sizes, w1, w2):
+def _chunk_rows(x, used, weight, sizes, w1, w2, form):
     """One chunk's rows through their experts: x (C, K) gathered tokens,
     `used` (C,) which rows hold an assignment, sizes (n_local,) the rows
     of each group inside the chunk.  Returns (C, K) float32, weighed."""
-    hidden = jnp.square(jnp.maximum(_grouped(x, w1, sizes), 0))
+    hidden = _grouped(x, w1, sizes)
+    if form == "relu2":
+        hidden = jnp.square(jnp.maximum(hidden, 0))
+    else:
+        gate, up = jnp.split(hidden, 2, axis=1)
+        hidden = jax.nn.silu(gate) * up
     out = _grouped(hidden, w2, sizes)
     return (jnp.where(used[:, None], out, 0).astype(jnp.float32)
             * weight[:, None])
 
 
-def _chunks(u, token, weight, group_sizes):
+def _chunks(u, token, weight, group_sizes, expected_rows):
     """How `experts`' two loops cut a plan: (its rows rounded up to whole
     chunks, the trip count, window(c) -> (chunk c's first row, its
     tokens, its weights, the rows of each group that lie inside it, its
     tokens' rows of u))."""
     t, rows = u.shape[0], token.shape[0]
-    chunk = min(ROW_CHUNK, rows)
+    chunk = min(row_chunk(expected_rows), rows)
     most = -(-rows // chunk)
     pad = most * chunk - rows
     token = jnp.pad(token, (0, pad), constant_values=t)
@@ -197,17 +229,18 @@ def _chunks(u, token, weight, group_sizes):
                 - jnp.clip(starts, lo, lo + chunk),
                 jnp.take(u, tok, axis=0, mode="fill", fill_value=0))
 
-    return most * chunk, jnp.minimum(plan_chunks(group_sizes), most), window
+    return most * chunk, jnp.minimum(
+        plan_chunks(group_sizes, expected_rows), most), window
 
 
-def _forward(u, token, weight, group_sizes, w1, w2):
+def _forward(u, token, weight, group_sizes, w1, w2, form, expected_rows):
     t = u.shape[0]
-    _, trips, window = _chunks(u, token, weight, group_sizes)
+    _, trips, window = _chunks(u, token, weight, group_sizes, expected_rows)
 
     def body(c, acc):
         _, tok, wt, sizes, x = window(c)
-        return acc.at[tok].add(_chunk_rows(x, tok < t, wt, sizes, w1, w2),
-                               mode="drop")
+        return acc.at[tok].add(
+            _chunk_rows(x, tok < t, wt, sizes, w1, w2, form), mode="drop")
 
     return lax.fori_loop(0, trips, body,
                          jnp.zeros(u.shape, jnp.float32)).astype(u.dtype)
@@ -216,21 +249,22 @@ def _forward(u, token, weight, group_sizes, w1, w2):
 def _forward_and_inputs(*inputs):
     # the residuals are the inputs and nothing of size rows x N: the
     # backward's loop makes each chunk's hidden again from its rows
-    return _forward(*inputs), inputs
+    return _forward(*inputs), inputs[:-2]
 
 
-def _backward(res, g):
+def _backward(form, expected_rows, res, g):
     u, token, weight, group_sizes, w1, w2 = res
     t = u.shape[0]
-    padded, trips, window = _chunks(u, token, weight, group_sizes)
+    padded, trips, window = _chunks(u, token, weight, group_sizes,
+                                    expected_rows)
     g = g.astype(jnp.float32)
 
     def body(c, carry):
         du, dweight, dw1, dw2 = carry
         lo, tok, wt, sizes, x = window(c)
         _, pull = jax.vjp(
-            lambda x, wt, w1, w2: _chunk_rows(x, tok < t, wt, sizes, w1, w2),
-            x, wt, w1, w2)
+            lambda x, wt, w1, w2: _chunk_rows(x, tok < t, wt, sizes, w1, w2,
+                                              form), x, wt, w1, w2)
         dx, dwt, d1, d2 = pull(
             jnp.take(g, tok, axis=0, mode="fill", fill_value=0))
         return (du.at[tok].add(dx.astype(jnp.float32), mode="drop"),
@@ -246,32 +280,43 @@ def _backward(res, g):
 
 
 # a loop with a traced trip count has no reverse mode of its own
-_experts = jax.custom_vjp(_forward)
+_experts = jax.custom_vjp(_forward, nondiff_argnums=(6, 7))
 _experts.defvjp(_forward_and_inputs, _backward)
 
 
-def experts(u, plan: RoutePlan, w1, w2):
-    """The held experts' part of the layer: u (T, K) tokens, w1
-    (n_local, K, N), w2 (n_local, N, K); returns (T, K) in u's dtype:
-    sum over a token's held experts of weight * relu(u W1_e)^2 W2_e.
+def experts(u, plan: RoutePlan, w1, w2, form: str = "relu2",
+            expected_rows: int = 0):
+    """The held experts' part of the layer: u (T, K) tokens, w2
+    (n_local, N, K), and w1 (n_local, K, N) for `form` "relu2" or
+    (n_local, K, 2N), gate and up side by side, for "silu_gated";
+    returns (T, K) in u's dtype: sum over a token's held experts of
+    weight * relu(u W1_e)^2 W2_e, or of weight * (silu(u G_e) * (u U_e))
+    W2_e.
 
-    The plan's rows are handled `ROW_CHUNK` at a time, `plan_chunks(
-    plan.group_sizes)` times: per chunk gather, grouped product, relu^2,
-    grouped product, mask, scale in float32, add into a (T, K) float32
-    sum.  The gradient is a second loop of the same trip count over the
+    The plan's rows are handled `row_chunk(expected_rows)` at a time
+    (`expected_rows`, static: the assignments the model expects on the
+    held experts; 0 leaves the chunk at `ROW_CHUNK`), `plan_chunks(
+    plan.group_sizes, expected_rows)` times: per chunk gather, grouped product, the
+    activation, grouped product, mask, scale in float32, add into a
+    (T, K) float32 sum.  The gradient is a second loop of the same trip count over the
     same chunks; nothing of a forward pass is kept for it but the
     inputs."""
+    if form not in FORMS or w1.shape[2] != w2.shape[1] * (
+            2 if form == "silu_gated" else 1):
+        raise MXNetError(f"experts: form {form!r} (of {FORMS}) with w1 "
+                         f"{w1.shape} and w2 {w2.shape}")
     _route_counts["grouped_kernel" if _use_kernel() else "ragged_dot"] += 2
-    return _experts(u, plan.token, plan.weight, plan.group_sizes, w1, w2)
+    return _experts(u, plan.token, plan.weight, plan.group_sizes, w1, w2,
+                    form, int(expected_rows))
 
 
 def moe_apply(x, u, w_router, bias, w1, w2, *, top_k: int,
-              scale: float = 1.0, mesh: Optional[DeviceMesh] = None,
-              axis_name: str = "ep"):
+              scale: float = 1.0, form: str = "relu2",
+              mesh: Optional[DeviceMesh] = None, axis_name: str = "ep"):
     """The routed part of one expert layer over every expert in `w1` /
     `w2` (E, ...): tokens x (T, D) are scored, their latents u (T, K) go
-    through the chosen experts.  Under a mesh with an `axis_name` axis of
-    several devices the stacked experts are split over it, each device
+    through the chosen experts of `form` (see `experts`).  Under a mesh
+    with an `axis_name` axis of several devices the stacked experts are split over it, each device
     routes over all E and computes its own share (tokens replicated in
     the group, as the weights outside the experts are), and the shares
     are summed; without one the layer runs in one piece.  Returns ((T, K)
@@ -289,7 +334,7 @@ def moe_apply(x, u, w_router, bias, w1, w2, *, top_k: int,
     def share(first, x, u, w_router, bias, w1, w2):
         plan = route(x, w_router, bias, top_k=top_k, scale=scale,
                      first_expert=first, n_local=w1.shape[0])
-        return experts(u, plan, w1, w2), plan.dropped
+        return experts(u, plan, w1, w2, form), plan.dropped
 
     if n == 1:
         return share(0, x, u, w_router, bias, w1, w2)

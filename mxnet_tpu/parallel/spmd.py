@@ -62,6 +62,24 @@ _HLO_MATMUL = re.compile(r"(?<![%\w.\-])(?:convolution|dot)\(")
 _HLO_FUSION = re.compile(r"(?<![%\w.\-])fusion\(")
 
 
+def _whole_instructions(hlo_text: str):
+    """The text's lines, with an instruction that XLA printed over
+    several lines joined into one: a frontend attribute may hold a
+    newline (splash attention's `kernel_metadata={` is followed by one),
+    and the instruction's `metadata={op_name=...}` then sits on the last
+    of its lines."""
+    depth, whole = 0, []
+    for line in hlo_text.splitlines():
+        if depth > 0:
+            whole[-1] += line
+            depth += line.count("{") - line.count("}")
+            continue
+        whole.append(line)
+        if line.startswith("  ") and " = " in line and line.endswith("{"):
+            depth = line.count("{") - line.count("}")
+    return whole
+
+
 def program_table(hlo_text: str) -> Dict[str, Any]:
     """``{"module", "scoped", "ops"}`` of one compiled program's text
     (``Compiled.as_text()``): ``ops`` maps every instruction outside a
@@ -83,7 +101,7 @@ def program_table(hlo_text: str) -> Dict[str, Any]:
     module, comp, scoped = "", None, False
     matmul_of: Dict[str, str] = {}      # fused computation -> op_name
     rows = []       # (computation, instruction, op_name, fusion's callee)
-    for line in hlo_text.splitlines():
+    for line in _whole_instructions(hlo_text):
         if line.startswith("HloModule "):
             module = line[len("HloModule "):].split(",")[0].strip()
         elif line[:1] not in (" ", "}", "") and line.endswith("{"):

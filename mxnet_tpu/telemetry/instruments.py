@@ -139,10 +139,12 @@ def op_dispatch_total(op_name: str):
 
 
 _spec("mx_attention_route_total", "counter",
-      "dot_product_attention calls TRACED through each route "
+      "Attention op calls TRACED through each route "
       "(fused_train = the fused training kernels, xla_dropout = the XLA "
       "path with saved probabilities, kernel_infer / reference = the "
-      "dropout-free call): counted once a compiled program, never per "
+      "dropout-free call, flash_causal = the causal flash kernels, "
+      "splash_window = sliding_window_attention's splash kernels): "
+      "counted once a compiled program, never per "
       "step. fused_train over fused_train + xla_dropout is the share of "
       "training attention that engaged the kernels.", ("route",))
 

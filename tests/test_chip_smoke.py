@@ -103,13 +103,15 @@ def test_attention_train_stage_toy_interpret(monkeypatch, dtype, n_dev):
 
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
 def test_decoder_phase_toy(dtype):
-    """The decoder stage's three comparisons at toy sizes, lowered for
+    """The decoder stage's five comparisons at toy sizes, lowered for
     the CPU (the routes' XLA twins against the stage's plain oracles)."""
-    out = chip_smoke.decoder_phase(seq=128, heads=4, tokens=64, experts=8,
-                                   held=2, top_k=3, latent=16, width=24,
-                                   scan=(256, 4, 64, 2, 128),
+    out = chip_smoke.decoder_phase(seq=128, heads=4, window=64, tokens=64,
+                                   experts=8, held=2, top_k=3, latent=16,
+                                   width=24, scan=(256, 4, 64, 2, 128),
                                    dtype=dtype, expect_mosaic=False)
-    assert len(out) == 3 and "ssd_scan_s256_h4_p64_g2_n128" in out
+    assert len(out) == 5 and "ssd_scan_s256_h4_p64_g2_n128" in out
+    assert "window64_gqa_h4_kv2_s128_d128" in out
+    assert "gated_experts_t64_held2_k16_n24" in out
     assert max(v["max_rel_err"] for v in out.values()) < (
         1e-4 if dtype == "float32" else 2e-2)
 
@@ -387,6 +389,73 @@ def test_attention_kernel_compiles_for_v5e_ahead_of_time():
     assert p.returncode == 0 and "AOT_OK" in p.stdout, p.stderr[-3000:]
 
 
+_AOT_LAGUNA = r"""
+import re, sys
+import jax, jax.numpy as jnp
+from jax.experimental import topologies
+from jax.sharding import SingleDeviceSharding
+try:
+    topo = topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
+except Exception as e:
+    print("NO_TPU_COMPILER", type(e).__name__, e)
+    sys.exit(0)
+from mxnet_tpu.ops import pallas_attention as pa
+from mxnet_tpu.parallel import moe
+sh = SingleDeviceSharding(topo.devices[0])
+arg = lambda shape, dt=jnp.bfloat16: jax.ShapeDtypeStruct(
+    shape, dt, sharding=sh)
+
+def mosaic_names(fn, *args):
+    # splash's custom calls are printed over three lines: the table's
+    # own reader joins them
+    from mxnet_tpu.parallel.spmd import _whole_instructions
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    calls = [ln for ln in _whole_instructions(text)
+             if 'custom_call_target="tpu_custom_call"' in ln]
+    return [re.search(r'op_name="([^"]*)"', ln).group(1) for ln in calls]
+
+b, s, h, kv, d, window = 2, 8192, 64, 8, 128, 512
+def window_loss(q, k, v):
+    with jax.named_scope("sliding_window_attention"):
+        o = pa._sliding_window_attention(q, k, v, num_heads=h,
+                                         num_kv_heads=kv, window=window)
+    return o.astype(jnp.float32).sum()
+names = mosaic_names(jax.grad(window_loss, argnums=(0, 1, 2)),
+                     arg((b, s, h * d)), arg((b, s, kv * d)),
+                     arg((b, s, kv * d)))
+print("MOSAIC window", names)
+assert len(names) == 3, names       # forward, dK/dV, dQ
+assert all("sliding_window_attention" in n for n in names), names
+assert sum("transpose(" in n for n in names) == 2, names
+assert pa.route_counts()["splash_window"] == 1, pa.route_counts()
+assert pa.route_counts()["flash_causal"] == 0, pa.route_counts()
+
+t, held, top_k, hidden, width = 16384, 32, 8, 2048, 512
+rows = moe.plan_rows(t, top_k, held)
+assert rows == t * top_k
+expected = t * top_k * held // 256      # the model's: one chunk of 32,768
+assert moe.row_chunk(expected) == 32768
+def experts_loss(u, w1, w2, token, weight, sizes):
+    plan = moe.RoutePlan(token, weight, sizes, jnp.zeros((), jnp.int32))
+    with jax.named_scope("moe_experts"):
+        return moe.experts(u, plan, w1, w2, "silu_gated",
+                           expected).astype(jnp.float32).sum()
+names = mosaic_names(
+    jax.value_and_grad(experts_loss, argnums=(0, 1, 2, 4)), arg((t, hidden)),
+    arg((held, hidden, 2 * width)), arg((held, width, hidden)),
+    arg((rows,), jnp.int32), arg((rows,), jnp.float32),
+    arg((held,), jnp.int32))
+print("MOSAIC gated experts", names)
+# as for relu^2: both loops' calls, two forward, six backward
+assert all("moe_experts" in n and "/while/body/" in n for n in names), names
+assert sum("transpose(" not in n for n in names) == 2, names
+back = [n for n in names if "transpose(jvp(moe_experts))/while/body/" in n]
+assert len(back) == 6 and sum("jit(tgmm)" in n for n in back) == 2, names
+print("AOT_OK")
+"""
+
+
 def test_attention_training_kernels_compile_for_v5e_ahead_of_time():
     """The gradient of a BERT-base training call holds two Mosaic calls at
     both benchmark shapes: `mx_attention_train_fwd` and ONE backward,
@@ -411,6 +480,20 @@ def test_attention_training_route_compiles_beyond_the_benchmark_shapes(mode):
     divide, S=1024, 128- and 256-wide heads and f32 operands fit the VMEM
     the code asks for."""
     p = _run(["-c", _AOT_TRAIN_MORE, mode], timeout=300)
+    if "NO_TPU_COMPILER" in p.stdout:
+        pytest.skip(p.stdout.strip()[:200])
+    assert p.returncode == 0 and "AOT_OK" in p.stdout, \
+        p.stdout[-2000:] + p.stderr[-3000:]
+
+
+def test_window_and_gated_expert_routes_compile_for_v5e_ahead_of_time():
+    """Sliding-window grouped-query attention through the splash route
+    (forward, dK/dV and dQ) and the silu-gated expert stage through the
+    grouped-matmul kernel, at `laguna_xs2_s8192`'s shapes: Mosaic takes
+    them, and every call keeps its op scope and, in the backward,
+    `transpose(`: what `window_attention_device_ms` and
+    `gated_moe_device_ms` are read by."""
+    p = _run(["-c", _AOT_LAGUNA], timeout=300)
     if "NO_TPU_COMPILER" in p.stdout:
         pytest.skip(p.stdout.strip()[:200])
     assert p.returncode == 0 and "AOT_OK" in p.stdout, \

@@ -242,6 +242,31 @@ def test_a_fusion_holding_a_dot_is_booked_to_the_dot_not_to_its_root():
     assert stale["module"] == "jit_old_step" and stale["scoped"] is False
 
 
+def test_an_instruction_printed_over_several_lines_keeps_its_op_name():
+    """XLA prints splash attention's `kernel_metadata` frontend attribute
+    with a newline in it: the custom call, and every instruction that
+    inherits the attribute, span three lines, the `op_name` on the last."""
+    scope = "jit(mx_train_step)/jvp(net)/layer1/sliding_window_attention"
+    text = _HLO.replace('  %copy.4 = f32[4,8]{1,0} copy(%fusion.2)\n', (
+        '  %splash.1 = f32[4,8]{1,0} custom-call(%fusion.2), '
+        'custom_call_target="tpu_custom_call", '
+        'frontend_attributes={kernel_metadata={\n'
+        '"xprof_metadata":"{\\"block_q\\": 256}"\n'
+        '}}, metadata={op_name="' + scope + '/pallas_call"}, '
+        'backend_config={"a":{"b":[]}}\n'
+        '  %copy.4 = f32[4,8]{1,0} copy(%splash.1), '
+        'frontend_attributes={kernel_metadata={\n'
+        '"xprof_metadata":"{\\"block_q\\": 256}"\n'
+        '}}, metadata={op_name="' + scope + '/copy"}\n'))
+    assert text != _HLO
+    ops = spmd.program_table(text)["ops"]
+    assert ops["splash.1"] == scope + "/pallas_call"
+    assert ops["copy.4"] == scope + "/copy"
+    # what follows is read as before
+    assert ops["fusion.14"] == spmd.program_table(_HLO)["ops"]["fusion.14"]
+    assert set(ops) == set(spmd.program_table(_HLO)["ops"]) | {"splash.1"}
+
+
 # first-step losses of the two seeded nets before any scope existed
 # (float32 on this CPU backend): a scope is metadata and changes no
 # arithmetic.  The convolution net's is a225b38's; the attention net's is
