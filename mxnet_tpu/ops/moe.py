@@ -13,7 +13,8 @@ def _moe_route(data, router_weight, router_bias, top_k=1, scale=1.0,
     """Route tokens `data` (T, D) over all rows of `router_weight` (E, D)
     and lay out the assignments onto the `num_local` experts held from
     `first_expert` on (all E where 0).  Returns (token of each row, its
-    weight, rows per held expert, dropped assignments = 0): see
+    weight, rows per held expert, dropped assignments = 0), the rows
+    expert by expert and token-ascending inside an expert: see
     `parallel.moe.route`."""
     from ..parallel import moe
 

@@ -14,7 +14,11 @@ held experts, so `rows = T * min(top_k, n_local)` (rounded up to the row
 tile) holds every assignment under any imbalance; rows beyond the
 assignments made carry weight 0 and an out-of-range token.  The rows are
 laid out expert by expert (`group_sizes`), which is what a grouped matrix
-product wants.
+product wants, and inside an expert by ascending token: one sort of the
+T * top_k assignments by (held expert, token) makes the plan and a second
+one its gradient, and nothing in `route` is a scatter (on the TPU a
+scatter runs update by update: the scatter-built plan cost 300 ms of a
+1,445 ms step at 16,384 tokens a layer, PERF.md PR 32).
 
 `rows` is a bound and not the work.  `experts` goes over the plan in
 chunks of `ROW_CHUNK` rows under a trip count it reads from `group_sizes`
@@ -68,13 +72,15 @@ ROW_CHUNK = 4096    # rows `experts` handles a trip of its loop: a multiple
 
 FORMS = ("relu2", "silu_gated")   # an expert's activation: see `experts`
 ROUTES = ("grouped_kernel", "ragged_dot")
-_route_counts = dict.fromkeys(ROUTES, 0)
+_route_counts = dict.fromkeys(ROUTES + ("sorted_layout",), 0)
 
 
 def route_counts():
-    """{route: grouped products of the `experts` calls traced through it
-    (two a call, whatever its gradient traces)} since import.  As for
-    attention, `grouped_kernel` in a program lowered for the CPU runs its
+    """Since import: {route: grouped products of the `experts` calls
+    traced through it (two a call, whatever its gradient traces)}, and
+    under `sorted_layout` the `route` calls traced (every one lays its
+    rows out by a sort: there is no other form).  As for attention,
+    `grouped_kernel` in a program lowered for the CPU runs its
     `ragged_dot` twin."""
     return dict(_route_counts)
 
@@ -117,13 +123,59 @@ def plan_chunks(group_sizes, expected_rows: int = 0):
     return -(-group_sizes.sum() // row_chunk(expected_rows))
 
 
+def _fit(a, n: int, fill):
+    """`a` (m,) cut to its first `n` entries, or padded to `n` with
+    `fill`."""
+    m = a.shape[0]
+    return a[:n] if m >= n else jnp.pad(a, (0, n - m), constant_values=fill)
+
+
+def _lay_out(weights, col, top_k, n_local, rows):
+    """The plan's rows from the flat (T * top_k,) assignments: `col` the
+    local expert of each (`n_local`: none that is held), `weights` its
+    combine weight.  One sort by (expert, flat index) puts the held
+    assignments first, expert by expert and token-ascending inside an
+    expert.  -> ((token, weight, group_sizes), what the gradient reads)."""
+    n = col.shape[0]
+    t = n // top_k
+    expert, order, weight = lax.sort(
+        (col, jnp.arange(n, dtype=jnp.int32), weights), num_keys=2)
+    held = expert < n_local
+    token = _fit(jnp.where(held, order // top_k, t), rows, t)
+    weight = _fit(jnp.where(held, weight, 0), rows, 0)
+    group_sizes = (col[:, None] == jnp.arange(n_local)).sum(
+        0, dtype=jnp.int32)
+    return (token, weight, group_sizes), (order, held)
+
+
+def _lay_out_backward(top_k, n_local, rows, res, g):
+    """The weights' gradient is the rows' in the assignments' own order:
+    a second sort, by the flat index the first one carried.  (JAX's rule
+    for a sorted operand is a scatter-add over the rows.)"""
+    order, held = res
+    dweight = jnp.where(held, _fit(g[1], order.shape[0], 0), 0)
+    return lax.sort((order, dweight), num_keys=1)[1], None
+
+
+_layout = jax.custom_vjp(lambda *a: _lay_out(*a)[0],
+                         nondiff_argnums=(2, 3, 4))
+_layout.defvjp(_lay_out, _lay_out_backward)
+
+
 def route(x, w_router, bias, *, top_k: int, scale: float = 1.0,
           first_expert=0, n_local: Optional[int] = None) -> RoutePlan:
     """Score `x` (T, D) against all E rows of `w_router` (E, D) in
     float32, choose `top_k` experts a token by score + `bias` (E,), and
     lay out the assignments that land on experts `first_expert ..
     first_expert + n_local - 1` (`first_expert` may be traced, as under a
-    shard_map)."""
+    shard_map).
+
+    The row order is a property callers rely on: the held experts' rows
+    one expert after another (`group_sizes`), inside an expert by
+    ascending token, then the unused rows.  Neither the plan nor its
+    gradient is built with a scatter: a token's scores are picked by
+    comparison, the rows come from one sort (`_lay_out`) and the
+    weights' gradient from a second."""
     t, e = x.shape[0], w_router.shape[0]
     n_local = e if n_local is None else n_local
     if not 0 < top_k <= e or not 0 < n_local <= e:
@@ -134,26 +186,18 @@ def route(x, w_router, bias, *, top_k: int, scale: float = 1.0,
         precision=lax.Precision.HIGHEST))
     _, chosen = lax.top_k(scores + lax.stop_gradient(
         bias.astype(jnp.float32)), top_k)                       # (T, k)
-    picked = jnp.take_along_axis(scores, chosen, axis=1)
+    # scores[t, chosen[t, k]], exact: one term of the sum is not 0.  (A
+    # gather's gradient would be a scatter-add into (T, E).)
+    picked = jnp.where(chosen[:, :, None] == jnp.arange(e),
+                       scores[:, None, :], 0).sum(-1)
     weights = scale * picked / (picked.sum(-1, keepdims=True) + 1e-20)
 
-    # (T, n_local) tables of the assignments that land here; column
-    # n_local collects the others and is cut off
     local = chosen - first_expert
     col = jnp.where((local >= 0) & (local < n_local), local, n_local)
-    row_of = jnp.arange(t)[:, None]
-    hit = jnp.zeros((t, n_local + 1), jnp.int32).at[row_of, col].add(
-        1)[:, :n_local]
-    wt = jnp.zeros((t, n_local + 1), jnp.float32).at[row_of, col].add(
-        weights)[:, :n_local]
-    group_sizes = hit.sum(0)
-    offsets = jnp.cumsum(group_sizes) - group_sizes
-    rows = plan_rows(t, top_k, n_local)
-    # a token's row: its expert's offset + how many earlier tokens chose it
-    pos = jnp.where(hit > 0, offsets + jnp.cumsum(hit, axis=0) - hit, rows)
-    token = jnp.full((rows,), t, jnp.int32).at[pos].set(
-        jnp.broadcast_to(row_of, pos.shape).astype(jnp.int32), mode="drop")
-    weight = jnp.zeros((rows,), jnp.float32).at[pos].set(wt, mode="drop")
+    _route_counts["sorted_layout"] += 1
+    token, weight, group_sizes = _layout(
+        weights.reshape(-1), col.reshape(-1), top_k, n_local,
+        plan_rows(t, top_k, n_local))
     dropped = group_sizes.sum() - (token < t).sum().astype(jnp.int32)
     return RoutePlan(token, weight, group_sizes, dropped)
 

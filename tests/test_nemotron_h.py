@@ -193,6 +193,180 @@ def test_router_top_k_normalisation_scale_and_bias():
             rtol=1e-5)
 
 
+def _scatter_route(x, w_router, bias, *, top_k, scale=1.0, first_expert=0,
+                   n_local=None):
+    """`moe.route` as it was until PR 32, the plain reference of its
+    layout: two scatter-added (T, n_local) tables, a row's place from a
+    cumulative sum over the tokens, the rows scatter-set."""
+    t, e = x.shape[0], w_router.shape[0]
+    n_local = e if n_local is None else n_local
+    scores = jax.nn.sigmoid(jnp.einsum(
+        "td,ed->te", x.astype(jnp.float32), w_router.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST))
+    _, chosen = jax.lax.top_k(scores + jax.lax.stop_gradient(
+        bias.astype(jnp.float32)), top_k)
+    picked = jnp.take_along_axis(scores, chosen, axis=1)
+    weights = scale * picked / (picked.sum(-1, keepdims=True) + 1e-20)
+    local = chosen - first_expert
+    col = jnp.where((local >= 0) & (local < n_local), local, n_local)
+    row_of = jnp.arange(t)[:, None]
+    hit = jnp.zeros((t, n_local + 1), jnp.int32).at[row_of, col].add(
+        1)[:, :n_local]
+    wt = jnp.zeros((t, n_local + 1), jnp.float32).at[row_of, col].add(
+        weights)[:, :n_local]
+    group_sizes = hit.sum(0)
+    offsets = jnp.cumsum(group_sizes) - group_sizes
+    rows = moe.plan_rows(t, top_k, n_local)
+    pos = jnp.where(hit > 0, offsets + jnp.cumsum(hit, axis=0) - hit, rows)
+    token = jnp.full((rows,), t, jnp.int32).at[pos].set(
+        jnp.broadcast_to(row_of, pos.shape).astype(jnp.int32), mode="drop")
+    weight = jnp.zeros((rows,), jnp.float32).at[pos].set(wt, mode="drop")
+    dropped = group_sizes.sum() - (token < t).sum().astype(jnp.int32)
+    return moe.RoutePlan(token, weight, group_sizes, dropped)
+
+
+# tokens, experts, top_k, first held expert, experts held (None: all),
+# bias on the held experts
+_LAYOUTS = {
+    "top8_of_256_held_32_from_0": (200, 256, 8, 0, 32, 0.0),
+    "top8_of_256_held_32_from_64": (200, 256, 8, 64, 32, 0.0),
+    "top22_of_512_held_8_fewer_than_top_k": (120, 512, 22, 0, 8, 0.0),
+    "the_whole_layer": (40, 8, 3, 0, None, 0.0),
+    "every_token_on_one_held_expert": (300, 16, 3, 2, 2, 10.0),
+    "no_token_on_a_held_expert": (300, 16, 3, 2, 2, -10.0),
+}
+
+
+def _layout_case(name):
+    t, e, top_k, first, n_local, held_bias = _LAYOUTS[name]
+    rng = np.random.RandomState(len(name))
+    bias = np.zeros(e, np.float32)
+    bias[first:first + (n_local or e)] = held_bias
+    return (jnp.asarray(rng.randn(t, 16).astype(np.float32)),
+            jnp.asarray(rng.randn(e, 16).astype(np.float32) * 0.3),
+            jnp.asarray(bias), first,
+            dict(top_k=top_k, scale=2.5, n_local=n_local))
+
+
+@pytest.mark.parametrize("traced", [False, True],
+                         ids=["eager", "first_expert_traced_under_jit"])
+@pytest.mark.parametrize("case", list(_LAYOUTS))
+def test_sorted_layout_is_the_scatter_form_row_for_row(case, traced):
+    x, wr, bias, first, kw = _layout_case(case)
+
+    def plan(route):
+        def held_from(first):
+            return route(x, wr, bias, first_expert=first, **kw)
+        return jax.jit(held_from)(jnp.int32(first)) if traced \
+            else held_from(first)
+
+    got, want = plan(moe.route), plan(_scatter_route)
+    for field in ("token", "group_sizes", "dropped"):
+        np.testing.assert_array_equal(getattr(got, field),
+                                      getattr(want, field), err_msg=field)
+    if traced:      # a fused sigmoid may round its last bit another way
+        np.testing.assert_allclose(got.weight, want.weight, rtol=1e-6)
+    else:
+        np.testing.assert_array_equal(got.weight, want.weight)
+    assert int(got.dropped) == 0
+    held = int(got.group_sizes.sum())
+    if case == "every_token_on_one_held_expert":
+        assert int(got.group_sizes[0]) == x.shape[0]
+    if case == "no_token_on_a_held_expert":
+        assert held == 0
+    # the stated order: expert by expert, token-ascending inside one
+    token = np.asarray(got.token)
+    assert (token[held:] == x.shape[0]).all()
+    for rows in np.split(token[:held],
+                         np.cumsum(np.asarray(got.group_sizes))[:-1]):
+        assert (np.diff(rows) > 0).all()
+
+
+@pytest.mark.parametrize("wrap", [jax.jit,
+                                  lambda f: jax.jit(jax.checkpoint(f))],
+                         ids=["jit", "checkpoint"])
+@pytest.mark.parametrize("case", ["top8_of_256_held_32_from_64",
+                                  "top22_of_512_held_8_fewer_than_top_k",
+                                  "the_whole_layer"])
+def test_sorted_layout_gradients_are_the_scatter_forms(case, wrap):
+    x, wr, bias, first, kw = _layout_case(case)
+    rows = moe.plan_rows(x.shape[0], kw["top_k"],
+                         kw["n_local"] or wr.shape[0])
+    coef = jnp.asarray(np.random.RandomState(1).randn(rows), jnp.float32)
+
+    def weighted(route):
+        return lambda x, wr: (route(x, wr, bias, first_expert=first,
+                                    **kw).weight * coef).sum()
+
+    want = jax.grad(weighted(_scatter_route), (0, 1))(x, wr)
+    got = wrap(jax.grad(weighted(moe.route), (0, 1)))(x, wr)
+    for g, w in zip(got, want):
+        assert float(jnp.abs(w).max()) > 1e-4
+        np.testing.assert_allclose(g, w, rtol=2e-5, atol=1e-7)
+
+
+@pytest.mark.parametrize("cell", ["laguna_xs2_s8192",
+                                  "nemotron3_super_s8192"])
+def test_route_and_its_gradient_lower_without_a_scatter(cell):
+    """At the two cells' shapes, from abstract arrays: one sort lays the
+    rows out (under remat once more in the backward pass), another is
+    the weights' gradient, and nothing in the routing stage is a scatter (PERF.md, PR 32: ~22 M serial updates a
+    step were 290 of laguna_xs2_s8192's 1,445 ms)."""
+    t, d, e, top_k, n_local = {
+        "laguna_xs2_s8192": (16384, 2048, 256, 8, 32),
+        "nemotron3_super_s8192": (8192, 4096, 512, 22, 8)}[cell]
+    x = jax.ShapeDtypeStruct((t, d), jnp.bfloat16)
+    wr = jax.ShapeDtypeStruct((e, d), jnp.float32)
+    bias = jax.ShapeDtypeStruct((e,), jnp.float32)
+
+    def route(x, wr, bias):
+        return moe.route(x, wr, bias, top_k=top_k, scale=2.5,
+                         n_local=n_local)
+
+    def weighted(x, wr, bias):
+        plan = route(x, wr, bias)
+        return (plan.weight * jnp.cos(jnp.arange(plan.weight.shape[0],
+                                                 dtype=jnp.float32))).sum()
+
+    before = moe.route_counts()["sorted_layout"]
+    forward = jax.jit(route).lower(x, wr, bias).as_text()
+    assert moe.route_counts()["sorted_layout"] == before + 1
+    both = jax.jit(jax.value_and_grad(jax.checkpoint(weighted), (0, 1))
+                   ).lower(x, wr, bias).as_text()
+    for text, sorts in ((forward, 1), (both, 3)):
+        assert "scatter" not in text
+        assert text.count("stablehlo.sort") == sorts
+    assert f"tensor<{moe.plan_rows(t, top_k, n_local)}xi32>" in forward
+
+
+def test_four_expert_parallel_shares_equal_one_device_with_gradients():
+    """`moe_apply` over four virtual `ep` devices (each sorts its own
+    share's rows under a traced `first_expert`) against the layer in one
+    piece: the result and its gradients, the router's included."""
+    from mxnet_tpu import parallel
+
+    w = _moe_weights(np.random.RandomState(8), t=64)
+    args = [jnp.asarray(w[k]) for k in ("x", "u", "wr", "b", "w1", "w2")]
+    coef = jnp.asarray(np.random.RandomState(2).randn(*w["u"].shape),
+                       jnp.float32)
+
+    def loss(x, u, wr, w1, w2):
+        out, dropped = moe.moe_apply(x, u, wr, args[3], w1, w2, top_k=3,
+                                     scale=2.0)
+        return (out * coef).sum(), dropped
+
+    inputs = [args[i] for i in (0, 1, 2, 4, 5)]
+    grad = jax.value_and_grad(loss, (0, 1, 2, 3, 4), has_aux=True)
+    (want, _), want_grads = jax.jit(grad)(*inputs)
+    with parallel.make_mesh(ep=4, devices=jax.devices()[:4]):
+        (got, dropped), got_grads = jax.jit(grad)(*inputs)
+    assert int(dropped) == 0
+    np.testing.assert_allclose(got, want, rtol=2e-5)
+    for g, w_ in zip(got_grads, want_grads):
+        assert float(jnp.abs(w_).max()) > 1e-4
+        np.testing.assert_allclose(g, w_, rtol=2e-4, atol=2e-5)
+
+
 def test_every_token_on_one_held_expert_nothing_dropped():
     """The worst imbalance: a bias sends every token to expert 2 (and
     its two next choices elsewhere); the layer holds experts 2 and 3."""
