@@ -2,6 +2,7 @@
 from . import vision
 from . import nemotron_h
 from . import laguna
+from . import evabyte
 from .vision import get_model
 
-__all__ = ["vision", "nemotron_h", "laguna", "get_model"]
+__all__ = ["vision", "nemotron_h", "laguna", "evabyte", "get_model"]

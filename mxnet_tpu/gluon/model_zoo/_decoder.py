@@ -1,7 +1,8 @@
-"""What the zoo's decoder stacks share (`nemotron_h.py`, `laguna.py`):
-the pre-norm residual sub-layer, the bias-free projection, the final norm
-and untied head, and the rule that named parameters keep float32 under
-`cast`."""
+"""What the zoo's decoder stacks share (`nemotron_h.py`, `laguna.py`,
+`evabyte.py`): the pre-norm residual sub-layer, the bias-free projection,
+the gated MLP, the final norm and untied head, and the rule that named
+parameters keep float32 under `cast`.  A norm's `offset` is added to its
+stored gain (1: the unit offset, the gain stored from zero)."""
 from __future__ import annotations
 
 from ..block import HybridBlock
@@ -14,10 +15,19 @@ def project(F, x, weight):
                             no_bias=True, flatten=False)
 
 
-def norm_residual(F, x, norm_weight, eps, mix, *args, **params):
+def gated_mlp(F, x, gate_weight, up_weight, down_weight):
+    """(silu(x G) * (x U)) Dn."""
+    return project(F, F.Activation(project(F, x, gate_weight),
+                                   act_type="silu")
+                   * project(F, x, up_weight), down_weight)
+
+
+def norm_residual(F, x, norm_weight, eps, mix, *args, offset=0.0,
+                  **params):
     """x + mix(F, RMSNorm(x), ...).  Where `mix` gives (output,
     statistics...) the statistics pass through beside the sum."""
-    mixed = mix(F, F.RMSNorm(x, norm_weight, eps=eps), *args, **params)
+    mixed = mix(F, F.RMSNorm(x, norm_weight, eps=eps, offset=offset),
+                *args, **params)
     if isinstance(mixed, (list, tuple)):
         return (x + mixed[0], *mixed[1:])
     return x + mixed
@@ -30,12 +40,16 @@ class Layer(HybridBlock):
 
     _FLOAT32 = ()
 
-    def __init__(self, hidden_size, eps, **kwargs):
+    def __init__(self, hidden_size, eps, norm_offset=0.0, **kwargs):
         super().__init__(**kwargs)
-        self._hidden, self._eps = hidden_size, eps
+        self._hidden, self._eps, self._offset = hidden_size, eps, norm_offset
         with self.name_scope():
-            self.norm_weight = self.params.get(
-                "norm_weight", shape=(hidden_size,), init="ones")
+            self.norm_weight = self._norm_gain("norm_weight")
+
+    def _norm_gain(self, name):
+        """A gain that starts the norm at identity."""
+        return self.params.get(name, shape=(self._hidden,),
+                               init="zeros" if self._offset else "ones")
 
     def cast(self, dtype):
         self._clear_cached_op()
@@ -44,20 +58,27 @@ class Layer(HybridBlock):
 
     def hybrid_forward(self, F, x, norm_weight, **params):
         return norm_residual(F, x, norm_weight, self._eps, self.mix,
-                             **params)
+                             offset=self._offset, **params)
 
 
 class Head(HybridBlock):
-    """Final RMSNorm and the untied vocabulary projection."""
+    """Final RMSNorm and the untied vocabulary projection; with
+    `logits_dtype` the product's operands are cast to it first (float32
+    logits from bfloat16 weights)."""
 
-    def __init__(self, hidden_size, vocab_size, eps, **kwargs):
+    def __init__(self, hidden_size, vocab_size, eps, norm_offset=0.0,
+                 logits_dtype=None, **kwargs):
         super().__init__(**kwargs)
-        self._eps = eps
+        self._eps, self._offset, self._dtype = eps, norm_offset, logits_dtype
         with self.name_scope():
             self.norm_weight = self.params.get(
-                "norm_weight", shape=(hidden_size,), init="ones")
+                "norm_weight", shape=(hidden_size,),
+                init="zeros" if norm_offset else "ones")
             self.weight = self.params.get(
                 "weight", shape=(vocab_size, hidden_size))
 
     def hybrid_forward(self, F, x, norm_weight, weight):
-        return project(F, F.RMSNorm(x, norm_weight, eps=self._eps), weight)
+        x = F.RMSNorm(x, norm_weight, eps=self._eps, offset=self._offset)
+        if self._dtype is not None:
+            x, weight = (F.cast(a, dtype=self._dtype) for a in (x, weight))
+        return project(F, x, weight)

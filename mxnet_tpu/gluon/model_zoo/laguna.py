@@ -32,17 +32,11 @@ from ...base import MXNetError
 from ...ops import rotary
 from .. import nn
 from ..block import HybridBlock
-from ._decoder import FP32, Head, Layer, norm_residual, project
+from ._decoder import FP32, Head, Layer, gated_mlp, norm_residual, project
 
 __all__ = ["LagunaModel", "LagunaLayer"]
 
 KINDS = ("full_attention", "sliding_attention")
-
-
-def _gated_mlp(F, x, gate_weight, up_weight, down_weight):
-    return project(F, F.Activation(project(F, x, gate_weight),
-                                   act_type="silu")
-                   * project(F, x, up_weight), down_weight)
 
 
 class LagunaLayer(Layer):
@@ -137,8 +131,8 @@ class LagunaLayer(Layer):
         return project(F, F.reshape(out, shape=(b, s, -1)), o_proj_weight)
 
     def dense(self, F, u, mlp_gate_weight, mlp_up_weight, mlp_down_weight):
-        return _gated_mlp(F, u, mlp_gate_weight, mlp_up_weight,
-                          mlp_down_weight)
+        return gated_mlp(F, u, mlp_gate_weight, mlp_up_weight,
+                         mlp_down_weight)
 
     def experts(self, F, u, router_weight, router_bias, experts_w1,
                 experts_w2, shared_gate_weight, shared_up_weight,
@@ -152,8 +146,8 @@ class LagunaLayer(Layer):
         out = F.moe_experts(tokens, token, weight, group_sizes, experts_w1,
                             experts_w2, form="silu_gated",
                             expected_rows=int(b * s * self._held_share)) \
-            + _gated_mlp(F, tokens, shared_gate_weight, shared_up_weight,
-                         shared_down_weight)
+            + gated_mlp(F, tokens, shared_gate_weight, shared_up_weight,
+                        shared_down_weight)
         stats = F.concat(group_sizes, F.reshape(dropped, shape=(1,)), dim=0)
         return F.reshape(out, shape=(b, s, self._hidden)), stats
 

@@ -337,15 +337,19 @@ def _group_norm(data, gamma, beta, num_groups=1, eps=1e-5):
 
 
 @register_op("RMSNorm", aliases=("rms_norm",))
-def _rms_norm(data, gamma, axis=-1, eps=1e-6):
-    """RMS normalization over ``axis``: scale by 1/RMS and gamma, no
-    mean subtraction.  Computed in float32 whatever the input's dtype (a
-    bfloat16 mean of squares over thousands of features is not a mean),
-    returned in the input's."""
+def _rms_norm(data, gamma, axis=-1, eps=1e-6, offset=0.0):
+    """RMS normalization over ``axis``: scale by 1/RMS and gamma +
+    ``offset``, no mean subtraction.  Computed in float32 whatever the
+    input's dtype (a bfloat16 mean of squares over thousands of features
+    is not a mean), returned in the input's.  ``offset`` 1 is the unit
+    offset: the stored gain starts at zero, and in bfloat16 keeps the
+    resolution near zero that a gain near one has lost."""
     x = data.astype(jnp.float32)
     ms = jnp.mean(jnp.square(x), axis=axis, keepdims=True)
-    return (x * lax.rsqrt(ms + eps)
-            * gamma.astype(jnp.float32)).astype(data.dtype)
+    gain = gamma.astype(jnp.float32)
+    if offset:
+        gain = gain + offset
+    return (x * lax.rsqrt(ms + eps) * gain).astype(data.dtype)
 
 
 # ---------------------------------------------------------------------------

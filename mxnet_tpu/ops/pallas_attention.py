@@ -41,6 +41,11 @@ lowered for; no switch of its own):
     Counted `splash_window`; every other windowed call (odd shapes, a
     mesh, MXNET_USE_PALLAS=0) is the banded XLA form, counted
     `reference`; window >= S is causal attention and takes that route.
+  * `eva_attention` (ops/eva_attention.py: exact attention inside a
+    query's window plus one pooled key and value a chunk of every
+    earlier window, one softmax over both) counts its routes here too:
+    `eva_splash`, the splash kernels over [keys ; summaries] and a mask
+    computed in the kernel, and `eva_xla`, its windowed XLA form.
   * Training with dropout on the probabilities, self-attention shaped as
     BERT's (not causal, sq == sk, a multiple of 128 up to 1024, heads of
     64, 128 or 256 filling whole 128-lane blocks): `_attend_train`, two
@@ -920,7 +925,7 @@ def _attend_window(q, k, v, scale, window, interpret):
 # store; the telemetry counter `mx_attention_route_total{route}` is its
 # export and counts only while telemetry is enabled.
 ROUTES = ("fused_train", "xla_dropout", "kernel_infer", "reference",
-          "flash_causal", "splash_window")
+          "flash_causal", "splash_window", "eva_splash", "eva_xla")
 _route_counts = dict.fromkeys(ROUTES, 0)
 
 

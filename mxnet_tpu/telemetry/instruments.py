@@ -143,7 +143,9 @@ _spec("mx_attention_route_total", "counter",
       "(fused_train = the fused training kernels, xla_dropout = the XLA "
       "path with saved probabilities, kernel_infer / reference = the "
       "dropout-free call, flash_causal = the causal flash kernels, "
-      "splash_window = sliding_window_attention's splash kernels): "
+      "splash_window = sliding_window_attention's splash kernels, "
+      "eva_splash / eva_xla = eva_attention's splash kernels over keys "
+      "and summaries, or its windowed XLA form): "
       "counted once a compiled program, never per "
       "step. fused_train over fused_train + xla_dropout is the share of "
       "training attention that engaged the kernels.", ("route",))

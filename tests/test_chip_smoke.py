@@ -513,3 +513,60 @@ def test_decoder_kernel_routes_compile_for_v5e_ahead_of_time():
         pytest.skip(p.stdout.strip()[:200])
     assert p.returncode == 0 and "AOT_OK" in p.stdout, \
         p.stdout[-2000:] + p.stderr[-3000:]
+
+
+_AOT_EVA = r"""
+import re, sys
+import jax, jax.numpy as jnp
+from jax.experimental import topologies
+from jax.sharding import SingleDeviceSharding
+try:
+    topo = topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
+except Exception as e:
+    print("NO_TPU_COMPILER", type(e).__name__, e)
+    sys.exit(0)
+from mxnet_tpu.ops import pallas_attention as pa
+from mxnet_tpu.ops.registry import apply_pure
+from mxnet_tpu.parallel.spmd import _whole_instructions
+sh = SingleDeviceSharding(topo.devices[0])
+arg = lambda shape, dt=jnp.bfloat16: jax.ShapeDtypeStruct(
+    shape, dt, sharding=sh)
+
+b, s, h, d, window, chunk = 1, 32768, 32, 128, 2048, 16
+def loss(q, k, v, phi, mu):
+    ks, vs = apply_pure("eva_chunk_summary", k, v, phi, mu, num_heads=h,
+                        chunk=chunk)
+    o = apply_pure("eva_attention", q, k, v, ks, vs, num_heads=h,
+                   window=window, chunk=chunk)
+    return o.astype(jnp.float32).sum()
+wide = arg((b, s, h * d))
+compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+    wide, wide, wide, arg((h, d)), arg((h, d))).compile()
+calls = [ln for ln in _whole_instructions(compiled.as_text())
+         if 'custom_call_target="tpu_custom_call"' in ln]
+names = [re.search(r'op_name="([^"]*)"', ln).group(1) for ln in calls]
+print("MOSAIC eva", names)
+assert len(names) == 3, names       # forward, dK/dV, dQ
+assert all("eva_attention" in n for n in names), names
+assert sum("transpose(" in n for n in names) == 2, names
+assert pa.route_counts()["eva_splash"] == 1, pa.route_counts()
+assert pa.route_counts()["eva_xla"] == 0, pa.route_counts()
+# O(S x (W + S / C)): a dense (32, 32768, 34816) score would be 68 GiB
+assert compiled.memory_analysis().temp_size_in_bytes < 4 * 2 ** 30
+print("AOT_OK")
+"""
+
+
+def test_eva_routes_compile_for_v5e_ahead_of_time():
+    """`eva_chunk_summary` and `eva_attention` at `evabyte_s32768`'s
+    shapes (32 heads of 128, 32,768 positions, window 2048, chunk 16),
+    value and every gradient: Mosaic takes the splash kernels over
+    [keys ; summaries] under the mask computed in the kernel (forward,
+    dK/dV, dQ), every call keeps the op scope and, in the backward,
+    `transpose(`: what `eva_attention_device_ms` is read by."""
+    p = _run(["-c", _AOT_EVA], timeout=300)
+    if "NO_TPU_COMPILER" in p.stdout:
+        pytest.skip(p.stdout.strip()[:200])
+    assert p.returncode == 0 and "AOT_OK" in p.stdout, \
+        p.stdout[-2000:] + p.stderr[-3000:]
