@@ -38,6 +38,7 @@ from .. import random as rnd
 from ..base import MXNetError
 from ..context import Context, current_context
 from ..ndarray.ndarray import NDArray
+from ..ops import residuals
 from .parameter import (Constant, DeferredInitializationError, Parameter,
                         ParameterDict)
 
@@ -457,7 +458,8 @@ class CachedOp:
         # mirror: gradient mirroring (ref: MXNET_BACKWARD_DO_MIRROR /
         # GraphExecutor recompute-to-save-memory) — on TPU this is
         # jax.checkpoint: the backward recomputes activations instead of
-        # keeping them in HBM, trading MXU FLOPs for memory
+        # keeping them in HBM, trading MXU FLOPs for memory; all but the
+        # values a kernel named for its backward (ops/residuals.py)
         from ..util import env
 
         self.mirror = (env.get_bool("MXNET_BACKWARD_DO_MIRROR")
@@ -636,7 +638,12 @@ class HybridBlock(Block):
                     # gradient mirroring: each PARAM-BEARING sub-block is a
                     # remat SEGMENT — the backward recomputes its activations
                     # from its inputs instead of keeping them live across the
-                    # whole program.  Param-less containers are NOT wrapped
+                    # whole program.  It keeps, beside those inputs, only the
+                    # values NAMED inside it (ops/residuals.py: what an
+                    # attention kernel wrote for its backward kernels, which
+                    # nothing but a second run of the kernel could make
+                    # again); a segment that names nothing is recomputed
+                    # whole.  Param-less containers are NOT wrapped
                     # (an outer whole-function checkpoint would only add a
                     # redundant full recompute), and blocks with non-array
                     # extra args are left unwrapped.  Aux updates (BatchNorm
@@ -656,7 +663,10 @@ class HybridBlock(Block):
                         aux_params_cell[0] = tuple(inner.aux_params)
                         return out, tuple(inner.aux_values)
 
-                    out, aux_vals = jax.checkpoint(seg)(x, params, *args)
+                    with residuals.segment():
+                        out, aux_vals = jax.checkpoint(
+                            seg, policy=residuals.KEEP_NAMED)(
+                                x, params, *args)
                     for p, v in zip(aux_params_cell[0], aux_vals):
                         ts.add_aux_update(p, v)
                     return out

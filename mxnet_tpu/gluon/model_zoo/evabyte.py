@@ -25,7 +25,10 @@ The model is a plain HybridBlock stack over registered ops
 `FullyConnected`), so `SPMDTrainer` compiles it into one program and a
 profile reads it by those names.  Each layer owns its parameters
 directly: under `SPMDTrainer(remat=True)` a layer is ONE recomputed
-segment.  The rotary tables are made once a forward pass, in float32.
+segment, which keeps its input and what the EVA kernel wrote for the
+backward (`ops/residuals.py`: the output and its logsumexp) and computes
+everything else again.  The rotary tables are made once a forward pass,
+in float32.
 """
 from __future__ import annotations
 

@@ -20,8 +20,11 @@ alone).  The model is a plain HybridBlock stack over registered ops
 profile reads it by those names.
 
 Each layer owns its parameters directly: under `SPMDTrainer(remat=True)`
-a layer is then ONE recomputed segment.  The rotary tables are made once
-a forward pass, in float32, and handed to every layer.
+a layer is then ONE recomputed segment, which keeps its input and what
+its attention kernel wrote for the backward (`ops/residuals.py`: the
+output and softmax statistics; the forward kernel runs once a step) and
+computes everything else again.  The rotary tables are made once a
+forward pass, in float32, and handed to every layer.
 
 A sparse layer HOLDS `experts_held` of the `num_experts` the router
 scores (ids from `first_expert`), as `nemotron_h.LatentMoELayer` does.

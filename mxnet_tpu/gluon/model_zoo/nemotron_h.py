@@ -12,8 +12,9 @@ convolution's.  The model is a plain HybridBlock stack over registered ops
 into one program and a profile reads it by those names.
 
 Each layer owns its parameters directly: under `SPMDTrainer(remat=True)`
-a layer is then ONE recomputed segment (its input is all the forward
-keeps), not a nest of them.
+a layer is then ONE recomputed segment, not a nest of them: its input
+and what its attention kernel wrote for the backward (`ops/residuals.py`:
+the output and softmax statistics) are all the forward keeps.
 
 An expert layer HOLDS `experts_held` of the `n_routed_experts` the router
 scores (ids from `first_expert`), as one chip of an expert-parallel group

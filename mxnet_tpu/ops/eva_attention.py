@@ -176,7 +176,9 @@ def _splash_kernel(heads, s, window, chunk, blk, interpret):
             block_q=blk, block_kv=blk, block_kv_compute=compute,
             block_q_dkv=blk, block_kv_dkv=blk, block_kv_dkv_compute=compute,
             block_q_dq=blk, block_kv_dq=blk),
-        interpret=interpret)
+        # the forward rule names its output and logsumexp: a recomputed
+        # segment keeps them (ops/residuals.py)
+        residual_checkpoint_name="eva_splash", interpret=interpret)
 
 
 def _splash_block(s, summaries):
@@ -251,7 +253,7 @@ def _eva_attention(query, key, value, key_summary, value_summary,
     if (env.get_bool("MXNET_USE_PALLAS") and d % 128 == 0
             and _splash_block(s, s // chunk)
             and pa._mesh_batch_axes(b) is None):
-        pa._count_route("eva_splash")
+        pa._count_kernel_route("eva_splash", b, h, s, d, query.dtype, 1)
         return _attend_eva(
             *packed, heads=h, **sizes,
             interpret=env.get_bool("MXNET_PALLAS_INTERPRET"))

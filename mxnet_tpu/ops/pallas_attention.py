@@ -21,9 +21,10 @@ lowered for; no switch of its own):
     query heads a multiple of the key/value heads (grouped-query):
     `_attend_causal`, upstream's blocked flash kernels
     (jax.experimental.pallas.ops.tpu.flash_attention: forward, dK/dV and
-    dQ, blocks above the diagonal skipped), differentiated by their own
-    custom_vjp.  O(S) memory: the only route that fits a decoder at
-    S = 8192, where `_attend`'s backward would hold 32 x 8192^2 scores.
+    dQ, blocks above the diagonal skipped) under a custom_vjp of this
+    file's, `_flash_core`.  O(S) memory: the only route that fits a
+    decoder at S = 8192, where `_attend`'s backward would hold 32 x
+    8192^2 scores.
     The key/value heads are repeated to the query heads in HBM first (a
     few MB at 2 of 32 heads; autodiff sums their gradient back).  Not
     under a mesh of several devices (a bare Mosaic call), where the call
@@ -71,6 +72,15 @@ is why the dropout STREAM differs from the threefry `bernoulli` this op
 used before PR 26: same distribution, other draws, so a loss pinned under
 attention dropout moved once.
 
+The three routes that run upstream's kernels (`flash_causal`,
+`splash_window`, `eva_splash`) NAME what the forward kernel wrote for the
+backward kernels, the output and the softmax statistics, inside the
+forward rule of the custom VJP (ops/residuals.py): a recomputed segment
+(gluon/block.py: `SPMDTrainer(remat=True)`) keeps those and runs the
+forward kernel once a step, where it would run it again in the backward
+pass only to get them back; `residuals.kept_residuals()` counts them.
+Their XLA twins name nothing and are recomputed whole.
+
 A program lowered for CPU has no Mosaic and takes the pure-XLA path of
 the same function (or the Pallas interpreter under
 MXNET_PALLAS_INTERPRET=1); MXNET_USE_PALLAS=0 selects the XLA paths
@@ -84,9 +94,11 @@ import math
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 
 from ..telemetry import instruments as _instruments
 from ..util import env
+from . import residuals
 from .registry import register_op
 
 __all__ = ["dot_product_attention_ref", "dropout_keep_mask", "route_counts"]
@@ -807,19 +819,71 @@ def _causal_xla(q, k, v, scale):
                                      causal=True).reshape(b, h, s, d)
 
 
-def _causal_flash(q, k, v, scale):
+def _flash_block(s):
+    return next(n for n in (512, 256, 128) if s % n == 0)
+
+
+# Upstream's three flash kernels (forward, dK/dV, dQ) at one block size,
+# under a custom VJP of the repo's own: upstream's `flash_attention` has
+# no hook to name what its forward leaves for its backward, so a
+# recomputed segment ran the forward kernel a second time only to get
+# back o, l and m.  The pieces are jitted, so that a stack of layers
+# traces and lowers each kernel once.
+
+@functools.partial(jax.jit, static_argnames=("scale", "statistics"))
+def flash_attention(q, k, v, scale, statistics):
+    """q, k, v (B, H, S, D) -> o, or (o, l, m) with the softmax's row
+    sums and maxima (B, H, S) in float32.  (Public by its name only: a
+    profile reads the forward kernel by the name of the jit it sits
+    in, which was upstream's.)"""
     from jax.experimental.pallas.ops.tpu import flash_attention as fa
 
-    h, s = q.shape[1], q.shape[2]
-    blk = next(n for n in (512, 256, 128) if s % n == 0)
-    sizes = fa.BlockSizes(
-        block_q=blk, block_k_major=blk, block_k=blk, block_b=1,
-        block_q_major_dkv=blk, block_k_major_dkv=blk, block_k_dkv=blk,
-        block_q_dkv=blk, block_k_major_dq=blk, block_k_dq=blk,
-        block_q_dq=blk)
-    return fa.flash_attention(q, _repeat_kv(k, h), _repeat_kv(v, h),
-                              causal=True, sm_scale=scale,
-                              block_sizes=sizes)
+    blk = _flash_block(q.shape[2])
+    return fa._flash_attention_impl(
+        q, k, v, None, None, statistics, True, scale, 1, blk, blk, blk,
+        False)
+
+
+@functools.partial(jax.jit, static_argnames=("scale",))
+def _flash_backward(q, k, v, o, l, m, do, scale):
+    from jax.experimental.pallas.ops.tpu import flash_attention as fa
+
+    blk = _flash_block(q.shape[2])
+    di = jnp.sum(o.astype(jnp.float32) * do.astype(jnp.float32), axis=-1)
+    rest = dict(block_k_major=blk, block_k=blk, sm_scale=scale,
+                causal=True, mask_value=fa.DEFAULT_MASK_VALUE, debug=False)
+    dk, dv = fa._flash_attention_bwd_dkv(
+        q, k, v, None, None, l, m, do, di, block_q_major=blk, block_q=blk,
+        **rest)
+    dq, _ = fa._flash_attention_bwd_dq(
+        q, k, v, None, None, l, m, do, di, block_q_major=blk, **rest)
+    return dq, dk, dv
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _flash_core(q, k, v, scale):
+    return flash_attention(q, k, v, scale=scale, statistics=False)
+
+
+def _flash_core_fwd(q, k, v, scale):
+    # what the backward kernels read and only the forward kernel can
+    # make again: a recomputed segment keeps them (ops/residuals.py);
+    # q, k, v and the key/value repeat it recomputes from its input
+    o, l, m = (checkpoint_name(x, "flash_causal") for x in flash_attention(
+        q, k, v, scale=scale, statistics=True))
+    return o, (q, k, v, o, l, m)
+
+
+def _flash_core_bwd(scale, res, do):
+    return _flash_backward(*res, do, scale=scale)
+
+
+_flash_core.defvjp(_flash_core_fwd, _flash_core_bwd)
+
+
+def _causal_flash(q, k, v, scale):
+    h = q.shape[1]
+    return _flash_core(q, _repeat_kv(k, h), _repeat_kv(v, h), scale)
 
 
 def _attend_causal(q, k, v, scale):
@@ -897,7 +961,9 @@ def _window_splash(q, k, v, scale, window, interpret=False):
             block_q=blk, block_kv=blk, block_kv_compute=blk,
             block_q_dkv=blk, block_kv_dkv=blk, block_kv_dkv_compute=blk,
             block_q_dq=blk, block_kv_dq=blk),
-        interpret=interpret)
+        # the forward rule names its output and logsumexp: a recomputed
+        # segment keeps them (ops/residuals.py)
+        residual_checkpoint_name="splash_window", interpret=interpret)
     # the kernels apply no scale of their own
     q = (q * jnp.asarray(scale, q.dtype)).reshape(b, kv, groups, s, d)
     return jax.vmap(jax.vmap(kernel))(q, k, v).reshape(b, h, s, d)
@@ -940,6 +1006,14 @@ def _count_route(route):
     _instruments.attention_route_total(route).inc()
 
 
+def _count_kernel_route(route, b, h, s, d, dtype, statistics):
+    """A route whose forward rule names what its kernel wrote for the
+    backward kernels: o (b, h, s, d) and `statistics` float32 rows."""
+    _count_route(route)
+    residuals.note(route, 1 + statistics,
+                   b * h * s * (d * np.dtype(dtype).itemsize + 4 * statistics))
+
+
 @register_op("dot_product_attention",
              aliases=("FusedAttention", "_contrib_dot_product_attention"))
 def _dot_product_attention(query, key, value, valid_mask=None, rng_key=None,
@@ -976,7 +1050,7 @@ def _dot_product_attention(query, key, value, valid_mask=None, rng_key=None,
             and env.get_bool("MXNET_USE_PALLAS")
             and _causal_flash_shape(h, h_kv, sq, sk, d)
             and _mesh_batch_axes(b) is None):
-        _count_route("flash_causal")
+        _count_kernel_route("flash_causal", b, h, sq, d, query.dtype, 2)
         if packed:
             qh, kh, vh = (_split_to_heads(x, n) for x, n in (
                 (query, h), (key, h_kv), (value, h_kv)))
@@ -1055,7 +1129,7 @@ def _sliding_window_attention(query, key, value, num_heads=1, window=0,
     if (env.get_bool("MXNET_USE_PALLAS")
             and _causal_flash_shape(h, h_kv, s, s, d)
             and _mesh_batch_axes(b) is None):
-        _count_route("splash_window")
+        _count_kernel_route("splash_window", b, h, s, d, query.dtype, 1)
         oh = _attend_window(qh, kh, vh, float(scale), int(window),
                             env.get_bool("MXNET_PALLAS_INTERPRET"))
     else:

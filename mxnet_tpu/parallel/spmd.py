@@ -231,7 +231,9 @@ class SPMDTrainer:
         #: remat: gradient mirroring for the fused train step — each
         #: sub-block becomes a jax.checkpoint segment, so the backward
         #: recomputes its activations instead of holding them in HBM
-        #: across the whole fwd+bwd+update program
+        #: across the whole fwd+bwd+update program; a segment keeps only
+        #: its input and the values a kernel named for its backward
+        #: (ops/residuals.py; `kept_residuals()` counts them)
         #: (ref: MXNET_BACKWARD_DO_MIRROR role)
         self.remat = bool(remat)
         self.block = block

@@ -31,6 +31,7 @@ from .metrics import MetricFamily, get_registry
 
 __all__ = [
     "op_dispatch_total", "attention_route_total",
+    "remat_kept_bytes_total",
     "training_phase_seconds", "training_steps_total",
     "fused_step_total", "fused_compile_seconds",
     "spmd_step_total", "spmd_compile_seconds",
@@ -153,6 +154,21 @@ _spec("mx_attention_route_total", "counter",
 
 def attention_route_total(route: str):
     return _child("mx_attention_route_total", (route,))
+
+
+_spec("mx_remat_kept_bytes_total", "counter",
+      "Bytes that the recomputed segments TRACED (gradient mirroring: "
+      "SPMDTrainer(remat=True), hybridize(mirror=True)) keep for their "
+      "backward beside their input, by the name the value was given "
+      "(ops/residuals.py: an attention kernel's output and softmax "
+      "statistics, named after its route): counted once a compiled "
+      "program, never per step. What a segment keeps it does not compute "
+      "again: each name's bytes are added to the step's peak and its "
+      "forward kernel runs once a step, not twice.", ("name",))
+
+
+def remat_kept_bytes_total(name: str):
+    return _child("mx_remat_kept_bytes_total", (name,))
 
 
 # ---- training ---------------------------------------------------------

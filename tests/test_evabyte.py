@@ -15,6 +15,7 @@ import mxnet_tpu as mx
 from mxnet_tpu.gluon.block import ActiveTrace
 from mxnet_tpu.ops import eva_attention as ea
 from mxnet_tpu.ops import pallas_attention as pa
+from mxnet_tpu.ops import residuals
 from mxnet_tpu.ops.registry import apply_pure
 from mxnet_tpu.parallel import spmd
 
@@ -377,7 +378,7 @@ def test_step_program_holds_both_op_scopes_forward_and_backward(
     the logits float32; the route counter counts a call a layer."""
     model_py = _load("model")
     traffic = {"seq_len": 512, "batch": 1}
-    before = pa.route_counts()
+    before, kept = pa.route_counts(), residuals.kept_residuals()
     trainer = model_py.build(0, small_config, traffic, 1)
     assert trainer.remat
     for name, value in trainer.params.items():
@@ -391,6 +392,12 @@ def test_step_program_holds_both_op_scopes_forward_and_backward(
     assert after["eva_splash"] == before["eva_splash"] + layers
     assert after["eva_xla"] == before["eva_xla"]
     assert after["reference"] == before["reference"]
+    # each layer's segment keeps its kernel's o (bfloat16) and logsumexp
+    now = residuals.kept_residuals()["eva_splash"]
+    heads, width = small_config["num_attention_heads"], \
+        small_config["hidden_size"] * 2
+    assert {k: now[k] - kept["eva_splash"][k] for k in now} == {
+        "values": 2 * layers, "bytes": layers * 512 * (width + 4 * heads)}
     names = set(spmd.step_programs()[-1]["ops"].values())
 
     def holds(*parts):

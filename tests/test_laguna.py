@@ -15,7 +15,7 @@ import mxnet_tpu as mx
 from mxnet_tpu.gluon.block import ActiveTrace
 from mxnet_tpu.gluon.model_zoo import laguna as zoo
 from mxnet_tpu.ops import pallas_attention as pa
-from mxnet_tpu.ops import rotary
+from mxnet_tpu.ops import residuals, rotary
 from mxnet_tpu.ops.registry import apply_pure
 from mxnet_tpu.parallel import moe, spmd
 
@@ -507,7 +507,7 @@ def test_step_program_holds_the_new_op_scopes_forward_and_backward(
     model_py = _load("model")
     np.random.seed(0)
     traffic = {"seq_len": 256, "batch": 1}
-    before = pa.route_counts()
+    before, kept = pa.route_counts(), residuals.kept_residuals()
     trainer = model_py.build(0, small_config, traffic, 1)
     assert trainer.remat
     for name, value in trainer.params.items():
@@ -520,6 +520,21 @@ def test_step_program_holds_the_new_op_scopes_forward_and_backward(
     after = pa.route_counts()
     assert after["splash_window"] == before["splash_window"] + 3
     assert after["flash_causal"] == before["flash_causal"] + 2
+    # each layer's segment keeps what its kernel wrote for the backward:
+    # o and the logsumexp of a window layer, o, l and m of a full one
+    now = residuals.kept_residuals()
+    heads = small_config["num_attention_heads_per_layer"]
+    kinds = small_config["layer_types"][:len(heads)]
+    rows = {kind: 256 * sum(h for h, k in zip(heads, kinds) if k == kind)
+            for kind in set(kinds)}
+    width = small_config["head_dim"] * 2      # bfloat16
+    for name, kind, statistics in (
+            ("splash_window", "sliding_attention", 1),
+            ("flash_causal", "full_attention", 2)):
+        grown = {k: now[name][k] - kept[name][k] for k in now[name]}
+        assert grown == {
+            "values": (1 + statistics) * kinds.count(kind),
+            "bytes": rows[kind] * (width + 4 * statistics)}, name
     names = set(spmd.step_programs()[-1]["ops"].values())
 
     def holds(*parts):

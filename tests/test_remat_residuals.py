@@ -1,0 +1,286 @@
+"""A recomputed segment keeps what its attention kernels wrote
+(`ops/residuals.py`): the output and the softmax statistics are named in
+the forward rule of each kernel route's custom VJP, every `jax.checkpoint`
+segment of `gluon/block.py` keeps the named values, and the forward kernel
+runs once a step where it ran twice.
+
+All on the CPU: the TPU branch of `lax.platform_dependent` is in the
+jaxpr whatever the program is lowered for, so the kernels are counted
+there; what they compute is held under the interpreter.
+"""
+import collections
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import mxnet_tpu as mx
+from mxnet_tpu import telemetry
+from mxnet_tpu.gluon import nn
+from mxnet_tpu.gluon.block import CachedOp, HybridBlock
+from mxnet_tpu.ops import pallas_attention as pa
+from mxnet_tpu.ops import residuals
+from mxnet_tpu.telemetry import instruments
+
+B, S, H, H_KV, D = 1, 256, 2, 1, 128
+WINDOW, CHUNK = 128, 2
+
+# route -> (forward kernel, backward kernels, statistics kept beside o);
+# upstream's flash kernels have no name but their function's
+ROUTES = {
+    "flash_causal": ("_flash_attention_kernel",
+                     ("_flash_attention_dkv_kernel",
+                      "_flash_attention_dq_kernel"), 2),
+    "splash_window": ("splash_mqa_fwd_residuals",
+                      ("splash_mqa_dkv_no_residuals",
+                       "splash_mqa_dq_no_residuals"), 1),
+    "eva_splash": ("splash_mha_fwd_residuals",
+                   ("splash_mha_dkv_no_residuals",
+                    "splash_mha_dq_no_residuals"), 1),
+}
+
+
+class Layer(HybridBlock):
+    """A layer that owns its parameters, so one recomputed segment:
+    projections, the attention core of `route`, a projection."""
+
+    def __init__(self, route, **kwargs):
+        super().__init__(**kwargs)
+        self._route = route
+        # eva_attention has no grouped-query form
+        self._kv = H if route == "eva_splash" else H_KV
+        with self.name_scope():
+            for name, rows in (("q", H), ("k", self._kv), ("v", self._kv),
+                               ("o", H)):
+                setattr(self, name, self.params.get(
+                    name, shape=(rows * D, H * D),
+                    init=mx.initializer.Normal(0.05)))
+            for name in ("phi", "mu"):
+                setattr(self, name, self.params.get(
+                    name, shape=(H, D), init=mx.initializer.Normal(0.05)))
+
+    def hybrid_forward(self, F, x, q, k, v, o, phi, mu):
+        q, k, v = (F.FullyConnected(x, w, no_bias=True, flatten=False,
+                                    num_hidden=w.shape[0])
+                   for w in (q, k, v))
+        if self._route == "flash_causal":
+            out = F.dot_product_attention(q, k, v, None, causal=True,
+                                          num_heads=H, num_kv_heads=self._kv)
+        elif self._route == "splash_window":
+            out = F.sliding_window_attention(q, k, v, window=WINDOW,
+                                             num_heads=H,
+                                             num_kv_heads=self._kv)
+        else:
+            ks, vs = F.eva_chunk_summary(k, v, phi, mu, num_heads=H,
+                                         chunk=CHUNK)
+            out = F.eva_attention(q, k, v, ks, vs, num_heads=H,
+                                  window=WINDOW, chunk=CHUNK)
+        return F.FullyConnected(out, o, no_bias=True, flatten=False,
+                                num_hidden=H * D)
+
+
+def _layer(route):
+    layer = Layer(route)
+    np.random.seed(0)
+    layer.initialize()
+    return layer
+
+
+def _gradient(block, dtype=jnp.float32):
+    """(value-and-gradient of a loss through the initialised `block`
+    under gradient mirroring, its parameters, an input)."""
+    op = CachedOp(block, mirror=True)
+    pure = op._make_pure(True)
+    params = tuple(p.data().data.astype(dtype) for _, p in op._param_list())
+    x = jnp.asarray(np.random.RandomState(0).randn(B, S, H * D), dtype)
+    weight = jnp.cos(jnp.arange(H * D, dtype=jnp.float32))
+
+    def loss(params, x):
+        (out,), _ = pure(params, (x,), jnp.zeros((2,), jnp.uint32))
+        return (out.astype(jnp.float32) * weight).sum()
+
+    return jax.value_and_grad(loss, argnums=(0, 1)), params, x
+
+
+def _count(jaxpr, into=None):
+    """Counter of what a jaxpr runs, sub-jaxprs included: Pallas kernels
+    by name, every other primitive as `xla:<name>` (a kernel's body is
+    not entered)."""
+    into = collections.Counter() if into is None else into
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            into[eqn.params["name"]
+                 or eqn.params["jaxpr"].debug_info.func_name] += 1
+            continue
+        into[f"xla:{eqn.primitive.name}"] += 1
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (tuple, list)) else (value,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    _count(sub, into)
+    return into
+
+
+def _drop_policy(monkeypatch):
+    """The segment as it was before the policy: everything recomputed."""
+    monkeypatch.setattr(residuals, "KEEP_NAMED", None)
+
+
+@pytest.mark.parametrize("route", list(ROUTES))
+def test_the_forward_kernel_appears_once_where_it_appeared_twice(
+        route, monkeypatch):
+    forward, backward, statistics = ROUTES[route]
+    before = residuals.kept_residuals()[route]
+    layer = _layer(route)
+    fn, params, x = _gradient(layer)
+    kept = _count(jax.make_jaxpr(fn)(params, x).jaxpr)
+    after = residuals.kept_residuals()[route]
+    _drop_policy(monkeypatch)
+    fn, params, x = _gradient(layer)
+    recomputed = _count(jax.make_jaxpr(fn)(params, x).jaxpr)
+
+    assert recomputed[forward] == 2
+    assert kept[forward] == 1
+    for name in backward:
+        assert kept[name] == recomputed[name] == 1
+    # o and the statistics, named in the forward rule (recomputed, the
+    # forward pass names o and the backward pass all of them again) ...
+    assert kept["xla:name"] == 1 + statistics
+    assert recomputed["xla:name"] == 2 + statistics
+    # ... and counted as kept, with their bytes: o in the layer's dtype,
+    # float32 rows of statistics
+    assert after["values"] - before["values"] == 1 + statistics
+    assert after["bytes"] - before["bytes"] \
+        == B * H * S * (D * 4 + 4 * statistics)
+    # everything else in the segment is still computed again: the
+    # projections, the head split, the XLA twin in the other branch
+    for name in ("xla:dot_general", "xla:transpose", "xla:exp"):
+        assert kept[name] == recomputed[name] > 0, name
+
+
+@pytest.mark.parametrize("route", list(ROUTES))
+def test_the_xla_twins_name_nothing_and_are_recomputed_whole(
+        route, monkeypatch):
+    monkeypatch.setenv("MXNET_USE_PALLAS", "0")
+    before = residuals.kept_residuals()
+    layer = _layer(route)
+    fn, params, x = _gradient(layer)
+    kept = _count(jax.make_jaxpr(fn)(params, x).jaxpr)
+    assert residuals.kept_residuals() == before
+    _drop_policy(monkeypatch)
+    fn, params, x = _gradient(layer)
+    assert kept == _count(jax.make_jaxpr(fn)(params, x).jaxpr)
+    assert kept["xla:name"] == 0 and kept["xla:dot_general"] > 0
+    assert not any(not k.startswith("xla:") for k in kept)
+
+
+@pytest.mark.parametrize("route", ["splash_window", "eva_splash"])
+def test_interpreted_gradients_are_those_of_the_recomputed_kernel(
+        route, monkeypatch):
+    """The kept o and logsumexp are the values the second call of the
+    kernel would have produced: loss and every gradient exactly equal."""
+    monkeypatch.setenv("MXNET_PALLAS_INTERPRET", "1")
+    layer = _layer(route)
+    fn, params, x = _gradient(layer)
+    kept = jax.jit(fn)(params, x)
+    _drop_policy(monkeypatch)
+    fn, params, x = _gradient(layer)
+    recomputed = jax.jit(fn)(params, x)
+    assert np.isfinite(kept[0]) and np.abs(kept[1][1]).max() > 0
+    for a, b in zip(jax.tree_util.tree_leaves(kept),
+                    jax.tree_util.tree_leaves(recomputed)):
+        np.testing.assert_array_equal(a, b)
+
+
+def test_the_flash_route_runs_upstreams_kernels_at_the_same_blocks():
+    """`_flash_core` is the repo's custom VJP around upstream's forward,
+    dK/dV and dQ kernels: value and gradients bit for bit those of
+    upstream's `flash_attention` at the blocks the route had."""
+    from jax.experimental.pallas import tpu as pltpu
+    from jax.experimental.pallas.ops.tpu import flash_attention as fa
+
+    rng = np.random.RandomState(1)
+    q, k, v = (jnp.asarray(rng.randn(B, H, S, D), jnp.float32)
+               for _ in range(3))
+    blk = pa._flash_block(S)
+    sizes = fa.BlockSizes(
+        block_q=blk, block_k_major=blk, block_k=blk, block_b=1,
+        block_q_major_dkv=blk, block_k_major_dkv=blk, block_k_dkv=blk,
+        block_q_dkv=blk, block_k_major_dq=blk, block_k_dq=blk,
+        block_q_dq=blk)
+    weight = jnp.cos(jnp.arange(D, dtype=jnp.float32))
+    cores = (lambda q, k, v: fa.flash_attention(
+                 q, k, v, causal=True, sm_scale=0.1, block_sizes=sizes),
+             lambda q, k, v: pa._flash_core(q, k, v, 0.1))
+    with pltpu.force_tpu_interpret_mode():
+        upstream, ours = (jax.value_and_grad(
+            lambda q, k, v: (core(q, k, v) * weight).sum(),
+            argnums=(0, 1, 2))(q, k, v) for core in cores)
+        np.testing.assert_array_equal(cores[0](q, k, v), cores[1](q, k, v))
+    for a, b in zip(jax.tree_util.tree_leaves(upstream),
+                    jax.tree_util.tree_leaves(ours)):
+        np.testing.assert_array_equal(a, b)
+    want = jax.grad(lambda q, k, v: (pa._causal_xla(q, k, v, 0.1)
+                                     * weight).sum(), argnums=(0, 1, 2))
+    for a, b in zip(ours[1], want(q, k, v)):
+        np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-5)
+
+
+def test_a_segment_that_names_nothing_lowers_to_the_program_it_did(
+        monkeypatch):
+    def program():
+        net = nn.HybridSequential()
+        net.add(nn.Dense(16, activation="relu", in_units=8),
+                nn.Dense(4, in_units=16))
+        np.random.seed(0)
+        net.initialize(mx.initializer.Xavier())
+        op = CachedOp(net, mirror=True)
+        pure = op._make_pure(True)
+        params = tuple(p.data().data for _, p in op._param_list())
+        loss = lambda params, x: (pure(params, (x,), jnp.zeros(
+            (2,), jnp.uint32))[0][0] ** 2).sum()
+        lowered = jax.jit(jax.value_and_grad(loss)).lower(
+            params, jnp.ones((4, 8), jnp.float32))
+        return lowered.as_text(), str(jax.make_jaxpr(jax.grad(loss))(
+            params, jnp.ones((4, 8), jnp.float32)))
+
+    before = residuals.kept_residuals()
+    text, jaxpr = program()
+    assert "save_only_these_names" in jaxpr     # a segment, with the policy
+    assert residuals.kept_residuals() == before
+    _drop_policy(monkeypatch)
+    assert program()[0] == text
+
+
+def test_outside_a_segment_a_name_keeps_nothing_and_counts_nothing():
+    """Inference, and training without gradient mirroring: the forward
+    rule's names are identities and the counter stays where it was."""
+    before = residuals.kept_residuals()
+    op = CachedOp(_layer("splash_window"), mirror=False)
+    pure = op._make_pure(True)
+    params = tuple(p.data().data for _, p in op._param_list())
+    loss = lambda params, x: pure(params, (x,), jnp.zeros(
+        (2,), jnp.uint32))[0][0].sum()
+    counts = _count(jax.make_jaxpr(jax.grad(loss))(
+        params, jnp.ones((B, S, H * D), jnp.float32)).jaxpr)
+    assert counts["splash_mqa_fwd_residuals"] == 1
+    assert counts["xla:remat2"] == 0
+    assert residuals.kept_residuals() == before
+
+
+def test_kept_bytes_are_exported_and_unknown_names_refused():
+    telemetry.enable()
+    try:
+        child = instruments.remat_kept_bytes_total("flash_causal")
+        before = child.value
+        residuals.note("flash_causal", 1, 1536)         # no segment: nothing
+        assert child.value == before
+        with residuals.segment():
+            residuals.note("flash_causal", 2, 3072)
+        assert child.value == before + 3072
+    finally:
+        telemetry.disable()
+    with pytest.raises(ValueError, match="not a residual name"):
+        residuals.note("projection", 1, 1536)
+    assert set(residuals.NAMES) <= set(pa.ROUTES)
