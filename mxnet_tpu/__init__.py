@@ -10,7 +10,14 @@ Conventional import:  import mxnet_tpu as mx
 """
 from __future__ import annotations
 
+import time as _time
+
+# the zero of the set-up recorder (telemetry.tracing.phase): the span
+# `mx.setup.import` runs from here to the last import below
+_T_IMPORT = _time.perf_counter()
+
 import os as _os
+import sys as _sys
 
 __version__ = "0.1.0"
 
@@ -42,6 +49,15 @@ if util.env.get_bool("MXNET_AUTOTUNE"):
 
     _mxtune_startup.apply_startup_overlay(framework_version=__version__)
 
+# jax's own import, where this one is the first to ask for it: most of
+# `mx.setup.import`, and not this package's to shorten (here, after
+# mxsan and mxtune above, is where the submodules would import it)
+_T_JAX = None           # (start, end) of that import, where it ran here
+if "jax" not in _sys.modules:
+    _t = _time.perf_counter()
+    import jax as _jax  # noqa: F401
+    _T_JAX = (_t, _time.perf_counter())
+
 from . import context
 from .context import Context, cpu, gpu, tpu, current_context, num_gpus, num_tpus
 from . import ops
@@ -58,6 +74,13 @@ from . import storage
 from . import initialize as _initialize
 
 _initialize.initialize()
+
+_import_span = telemetry.tracing.record_phase(
+    "mx.setup.import", _T_IMPORT, _time.perf_counter())
+if _T_JAX is not None:
+    telemetry.tracing.record_phase("mx.setup.import.jax", *_T_JAX,
+                                   parent=_import_span["id"])
+del _import_span
 
 if _os.environ.get("DMLC_ROLE") == "server":
     # reference semantics: a server-role process parks inside the import

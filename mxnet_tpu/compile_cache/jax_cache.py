@@ -20,7 +20,7 @@ import os
 import threading
 from typing import Dict
 
-__all__ = ["JaxCache", "configure", "counts", "DEFAULT_DIR"]
+__all__ = ["JaxCache", "configure", "counts", "seconds", "DEFAULT_DIR"]
 
 _REPO = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
@@ -28,10 +28,19 @@ DEFAULT_DIR = os.path.join(_REPO, ".jax_cache")
 
 _HIT = "/jax/compilation_cache/cache_hits"
 _MISS = "/jax/compilation_cache/cache_misses"
+# JAX's own stopwatches, one event a program and stage: every program of
+# the process, whoever built it
+_STAGE = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "backend",
+    "/jax/compilation_cache/cache_retrieval_time_sec": "cache_retrieval",
+}
 
 
 _lock = threading.Lock()
 _counts = {"hits": 0, "misses": 0}
+_seconds = dict.fromkeys(_STAGE.values(), 0.0)
 _listening = False
 
 
@@ -41,6 +50,24 @@ def _on_event(event: str, **_kw) -> None:
             _counts["hits" if event == _HIT else "misses"] += 1
 
 
+def _on_duration(event: str, duration: float, **_kw) -> None:
+    stage = _STAGE.get(event)
+    if stage is not None:
+        with _lock:
+            _seconds[stage] += duration
+
+
+def _listen() -> None:
+    """Start listening to JAX's monitoring events, once (under _lock)."""
+    global _listening
+    if not _listening:
+        import jax
+
+        jax.monitoring.register_event_listener(_on_event)
+        jax.monitoring.register_event_duration_secs_listener(_on_duration)
+        _listening = True
+
+
 def counts() -> Dict[str, int]:
     """{"hits": executables JAX served from its persistent cache,
     "misses": executables compiled and written there} in this process,
@@ -48,14 +75,25 @@ def counts() -> Dict[str, int]:
     under JAX's own thresholds (compile time, entry size) are neither.
     A rise in "hits" across one ``lower().compile()`` is the only sign
     that the executable was loaded and not built."""
-    global _listening
     with _lock:
-        if not _listening:
-            import jax
-
-            jax.monitoring.register_event_listener(_on_event)
-            _listening = True
+        _listen()
         return dict(_counts)
+
+
+def seconds() -> Dict[str, float]:
+    """{"trace", "lower", "backend", "cache_retrieval"}: JAX's own
+    duration events summed over EVERY program this process traced,
+    lowered and built since the first call of this or of :func:`counts`:
+    jaxpr trace, jaxpr to MLIR module, the backend's compile with a
+    cache load inside it, and of that the cache retrieval alone.
+    "trace" is an upper bound: a jitted function called inside another,
+    as most of `jax.numpy` is, counts by itself and again in its
+    caller's.  What the set-up phases `mx.build.*`
+    (`telemetry.tracing.phase`) measure for the framework's own programs
+    is part of these totals; the rest is what else compiled here."""
+    with _lock:
+        _listen()
+        return dict(_seconds)
 
 
 class JaxCache:

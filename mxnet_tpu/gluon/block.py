@@ -38,6 +38,7 @@ from .. import random as rnd
 from ..base import MXNetError
 from ..context import Context, current_context
 from ..ndarray.ndarray import NDArray
+from ..telemetry import tracing as _tracing
 from ..ops import residuals
 from .parameter import (Constant, DeferredInitializationError, Parameter,
                         ParameterDict)
@@ -343,10 +344,13 @@ class Block:
             c.hybridize(active, **kwargs)
 
     def cast(self, dtype):
-        for c in self._children.values():
-            c.cast(dtype)
-        for p in self._reg_params.values():
-            p.cast(dtype)
+        # one set-up record for the whole tree: the children's `cast`,
+        # entered inside this phase, are this phase (tracing.phase)
+        with _tracing.phase("mx.setup.cast"):
+            for c in self._children.values():
+                c.cast(dtype)
+            for p in self._reg_params.values():
+                p.cast(dtype)
 
     def zero_grad(self):
         self.collect_params().zero_grad()

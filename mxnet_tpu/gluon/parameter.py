@@ -15,6 +15,7 @@ import numpy as np
 from ..base import MXNetError
 from ..context import Context, cpu, current_context
 from ..ndarray.ndarray import NDArray, array as nd_array
+from ..telemetry import tracing as _tracing
 from .. import initializer as init_mod
 
 __all__ = ["Parameter", "Constant", "ParameterDict", "DeferredInitializationError"]
@@ -115,16 +116,21 @@ class Parameter:
         self._finish_init(init, ctx, default_init)
 
     def _finish_init(self, init, ctx_list: List[Context], default_init):
-        buf = np.zeros(self._shape, dtype=np.float32)
-        initializer = init_mod.create(init) if init is not None else \
-            (init_mod.create(self.init) if self.init is not None else default_init)
-        if init is not None or self.init is not None:
-            initializer.init_array(self.name, buf)
-        else:
-            initializer(init_mod.InitDesc(self.name), buf)
-        self._data = {}
-        for c in ctx_list:
-            self._data[c] = nd_array(buf, ctx=c, dtype=self.dtype)
+        # one set-up record for a run of parameters (telemetry.tracing.phase):
+        # the initializer's draw on the host and the write to each context
+        with _tracing.phase("mx.setup.init", merge=True, parameters=1,
+                            elements=int(np.prod(self._shape))):
+            buf = np.zeros(self._shape, dtype=np.float32)
+            initializer = init_mod.create(init) if init is not None else \
+                (init_mod.create(self.init) if self.init is not None
+                 else default_init)
+            if init is not None or self.init is not None:
+                initializer.init_array(self.name, buf)
+            else:
+                initializer(init_mod.InitDesc(self.name), buf)
+            self._data = {}
+            for c in ctx_list:
+                self._data[c] = nd_array(buf, ctx=c, dtype=self.dtype)
         if self._grad_req != "null":
             self._init_grad()
 

@@ -34,7 +34,6 @@ cannot take (e.g. a sparse gradient showing up mid-run).
 from __future__ import annotations
 
 import threading
-import time
 from typing import Any, Dict, List, Tuple
 
 import jax
@@ -92,6 +91,88 @@ class _Entry:
         self.program = None
 
 
+class ProgramBuild:
+    """One program's road from its jitted function to an executable, each
+    stage run once and under its set-up phase (`telemetry.tracing.phase`):
+
+        mx.build.trace    `build_traced()`: `jitted.trace(*args)`, the
+                          Python of the whole model, Pallas kernels too
+        mx.build.lower    `.lower()`: the jaxpr to StableHLO
+        mx.build.backend  `.compile()`: XLA's compile, or JAX's load from
+                          its persistent cache (`origin`: "compiled" |
+                          "cache", by a rise in `jax_cache.counts()`)
+        mx.build.audit    the mxir audit, only where MXNET_IR_AUDIT is on
+
+    every record with `site` and `program` (the traced function's name)
+    in its stats.  `records` are the one stopwatch of a build:
+    `seconds` is their sum and `start` the first one's start, which is
+    what `ExecutableCache.stats()`, the compile histogram and the
+    `*-compile` chrome span are fed from."""
+
+    def __init__(self, build_traced, site: str):
+        self._build_traced = build_traced
+        self.site = site
+        self.program = None
+        self.records: List[dict] = []
+        self.loaded = False     # JAX served the executable from its cache
+        self._lowered = None
+        self.rendered_text = None   # the module's text, once asked for
+
+    def lowered(self):
+        if self._lowered is None:
+            with _tracing.phase("mx.build.trace", site=self.site) as rec:
+                traced = self._build_traced()
+                self.program = rec["stats"]["program"] = traced.fun_name
+            self.records.append(rec)
+            with _tracing.phase("mx.build.lower", site=self.site,
+                                program=self.program) as rec:
+                self._lowered = traced.lower()
+            self.records.append(rec)
+        return self._lowered
+
+    def text(self) -> str:
+        """The lowered module's text, rendered once (the `.mxcc` key and
+        the audit read it; no build phase of its own)."""
+        if self.rendered_text is None:
+            self.rendered_text = self.lowered().as_text()
+        return self.rendered_text
+
+    def compile(self):
+        lowered = self.lowered()
+        hits = _jax_cache.counts()["hits"]
+        with _tracing.phase("mx.build.backend", site=self.site,
+                            program=self.program) as rec:
+            compiled = lowered.compile()
+            self.loaded = _jax_cache.counts()["hits"] > hits
+            rec["stats"]["origin"] = "cache" if self.loaded else "compiled"
+        self.records.append(rec)
+        return compiled
+
+    def audit(self, donate: bool) -> None:
+        """mxir program audit (MXNET_IR_AUDIT=1): one boolean check when
+        off; when on, reuses the memoized `text()` render."""
+        if not _ir_audit.enabled():
+            return
+        with _tracing.phase("mx.build.audit", site=self.site,
+                            program=self.program) as rec:
+            _ir_audit.maybe_audit(self.site, self.text,
+                                  expect_donation=donate)
+        self.records.append(rec)
+
+    @property
+    def seconds(self) -> float:
+        return sum(r["seconds"] for r in self.records)
+
+    @property
+    def start(self) -> float:
+        """`perf_counter` at the first stage's start."""
+        return _tracing._T0 + self.records[0]["start"]
+
+    def stage_seconds(self, stage: str) -> float:
+        return sum(r["seconds"] for r in self.records
+                   if r["name"] == "mx.build." + stage)
+
+
 class ExecutableCache:
     """Bounded in-process executable cache + compile accounting for one
     optimizer-step site, shared by the per-replica fused path (site
@@ -121,7 +202,11 @@ class ExecutableCache:
         self._span_name = span_name
         self._metric = metric  # () -> histogram child, lazily resolved
         self.compiles = 0
-        self.seconds = 0.0
+        # seconds of the builds counted in `compiles`, by stage
+        # (`audit` only where MXNET_IR_AUDIT is on); their sum is
+        # stats()["seconds_total"]
+        self.stage_seconds = dict.fromkeys(
+            ("trace", "lower", "backend", "audit"), 0.0)
         self.cache_loads = 0
         self.evictions = 0
 
@@ -147,14 +232,20 @@ class ExecutableCache:
 
     def stats(self) -> Dict[str, float]:
         with self.lock:
-            return {"count": self.compiles, "seconds_total": self.seconds,
+            return {"count": self.compiles,
+                    "seconds_total": sum(self.stage_seconds.values()),
+                    **{f"{stage}_seconds": v
+                       for stage, v in self.stage_seconds.items()},
                     "cache_loads": self.cache_loads,
                     "evictions": self.evictions, "size": len(self.data)}
 
-    def compile(self, sig, build_lowered, optimizer, alias_ok=True,
+    def compile(self, sig, build_traced, optimizer, alias_ok=True,
                 components=None, donate=False):
         """Build (or load from the persistent store) the executable for
         ``sig``; insert, LRU-evict past MXNET_FUSED_CACHE_MAX, count.
+        ``build_traced`` makes the program's traced stage
+        (``jax.jit(f, ...).trace(*args)``); :class:`ProgramBuild` takes
+        it from there, every stage under its set-up phase.
         ``alias_ok=False`` forces the program-text key even for
         first-party optimizers — required when the program embeds USER
         code (e.g. the SPMD trainer's model forward), which the
@@ -165,16 +256,7 @@ class ExecutableCache:
         ``donate`` is the call site's donation decision, forwarded to
         the mxir program auditor so MX014 can verify the lowered
         module actually aliases something."""
-        t0 = time.perf_counter()
-        jax_hits = _jax_cache.counts()["hits"]
-        cell = {}
-
-        def text():
-            t = cell.get("text")
-            if t is None:
-                t = cell["text"] = build_lowered().as_text()
-            return t
-
+        build = ProgramBuild(build_traced, self.site)
         if _cc.enabled():
             alias = _cc.cache_key(
                 f"{self.site}.alias", parts=(sig,)) \
@@ -183,33 +265,30 @@ class ExecutableCache:
 
             def full_key():
                 return _cc.cache_key(
-                    self.site, parts=(sig,), program_text=text(),
+                    self.site, parts=(sig,), program_text=build.text(),
                     components=components)
 
             compiled, origin = _cc.get_or_compile(
-                self.site, full_key,
-                lambda: build_lowered().compile(), alias=alias)
+                self.site, full_key, build.compile, alias=alias)
         else:
             from ..telemetry.mxtriage import provenance as _prov
 
             # record_miss never raises — diagnostics can't break a build
             _prov.record_miss(self.site, _cc.cache_key(
                 self.site, parts=(sig,), components=components))
-            compiled, origin = build_lowered().compile(), "compiled"
-        # mxir program audit (MXNET_IR_AUDIT=1): one boolean check when
-        # off; when on, reuses the memoized text() render.  Runs for
-        # cache loads too — a disk-loaded executable is still this
-        # process's step program and its invariants still hold or not.
-        _ir_audit.maybe_audit(self.site, text, expect_donation=donate)
-        dt = time.perf_counter() - t0
+            compiled, origin = build.compile(), "compiled"
+        # runs for cache loads too — a disk-loaded executable is still
+        # this process's step program and its invariants still hold or
+        # not
+        build.audit(donate)
+        dt = build.seconds
         # static cost analysis for MFU accounting — computed on the
         # executable object, so a persistent-cache load (origin
         # "memory"/"disk") carries the same metadata as a fresh build;
         # the HLO fingerprint rides beside it (rendered text is reused
         # when the key path already produced it)
         cost = _costs.executable_cost(compiled)
-        fp = _costs.hlo_fingerprint(compiled,
-                                    program_text=cell.get("text"))
+        fp = _costs.hlo_fingerprint(compiled, program_text=build.rendered_text)
         _costs.note(self.site, repr(hash(sig)), cost, fingerprint=fp)
         with self.lock:
             # a concurrent compile of the same signature may have won;
@@ -217,13 +296,13 @@ class ExecutableCache:
             prior = self.data.get(sig)
             if prior is not None:
                 return prior.fn
-            loaded = origin != "compiled" \
-                or _jax_cache.counts()["hits"] > jax_hits
+            loaded = origin != "compiled" or build.loaded
             self.data[sig] = _Entry(compiled, cost, fp,
                                     "cache" if loaded else "compiled")
             if origin == "compiled":
                 self.compiles += 1
-                self.seconds += dt
+                for stage in self.stage_seconds:
+                    self.stage_seconds[stage] += build.stage_seconds(stage)
             else:
                 self.cache_loads += 1
             cap = _env.get_int("MXNET_FUSED_CACHE_MAX")
@@ -242,7 +321,8 @@ class ExecutableCache:
             # always counted, never gated (serving-compile precedent):
             # a recompile on the training hot path is the thing to watch
             self._metric().observe(dt)
-            _tracing.record_complete(self._span_name, "training", t0, dt)
+            _tracing.record_complete(self._span_name, "training",
+                                     build.start, dt)
         _mxsan.record_compile(self.site, sig, dt,
                               provenance="build" if origin == "compiled"
                               else "cache")
@@ -511,17 +591,11 @@ class FusedUpdater(Updater):
             _rebind_state(s, ns)
 
     def _compile(self, sig, args, mp_flags, donate, health_mode=None):
-        cell = {}
-
-        def build_lowered():
-            lowered = cell.get("lowered")
-            if lowered is None:
-                step = _build_step(self.optimizer, tuple(mp_flags),
-                                   health_mode)
-                jitted = jax.jit(
-                    step, donate_argnums=(0, 2) if donate else ())
-                lowered = cell["lowered"] = jitted.lower(*args)
-            return lowered
+        def build_traced():
+            step = _build_step(self.optimizer, tuple(mp_flags),
+                               health_mode)
+            return jax.jit(
+                step, donate_argnums=(0, 2) if donate else ()).trace(*args)
 
         # the NAMED sig view compile provenance diffs a miss against
         # (sig layout: see the tuple built in update_multi).  The live
@@ -536,5 +610,5 @@ class FusedUpdater(Updater):
                       "device": sig[4], "health_mode": sig[5],
                       "treedef": sig[6], "avals": sig[7],
                       "wire_encoding": _comm.config().mode}
-        return _FUSED_CACHE.compile(sig, build_lowered, self.optimizer,
+        return _FUSED_CACHE.compile(sig, build_traced, self.optimizer,
                                     components=components, donate=donate)

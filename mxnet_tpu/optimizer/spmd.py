@@ -969,17 +969,10 @@ class SpmdUpdater(Updater):
 
     def _compile(self, sig, args, mp_flags, metas, donate,
                  health_mode=None, qbis=()):
-        cell = {}
-
-        def build_lowered():
-            lowered = cell.get("lowered")
-            if lowered is None:
-                jitted = jax.jit(
-                    self._build_step(mp_flags, metas, health_mode,
-                                     qbis),
-                    donate_argnums=(2,) if donate else ())
-                lowered = cell["lowered"] = jitted.lower(*args)
-            return lowered
+        def build_traced():
+            return jax.jit(
+                self._build_step(mp_flags, metas, health_mode, qbis),
+                donate_argnums=(2,) if donate else ()).trace(*args)
 
         # named sig view for compile provenance (sig layout: the tuple
         # built in update_multi above)
@@ -989,7 +982,7 @@ class SpmdUpdater(Updater):
                       "layout": sig[7], "health_mode": sig[8],
                       "devices": sig[9], "treedef": sig[10],
                       "avals": sig[11], "quant": sig[12]}
-        return _SPMD_CACHE.compile(sig, build_lowered, self.optimizer,
+        return _SPMD_CACHE.compile(sig, build_traced, self.optimizer,
                                    components=components, donate=donate)
 
     # ---- phased variant (tracing only) -----------------------------------

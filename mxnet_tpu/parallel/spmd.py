@@ -52,7 +52,10 @@ _STEP_CACHE = opt_mod.fused.ExecutableCache(
 
 def step_compile_stats():
     """SPMDTrainer step-executable builds/loads in this process (same
-    shape as optimizer.fused.compile_stats)."""
+    shape as optimizer.fused.compile_stats): `seconds_total` is
+    `trace_seconds` + `lower_seconds` + `backend_seconds` (+
+    `audit_seconds`, where the mxir audit is on): the set-up phases
+    `mx.build.*` of the site."""
     return _STEP_CACHE.stats()
 
 
@@ -275,29 +278,38 @@ class SPMDTrainer:
         self.params: Dict[str, jax.Array] = {}
         self._shardings: Dict[str, NamedSharding] = {}
         self._state_shardings: Dict[str, NamedSharding] = {}
-        for n, p in self._plist:
-            v = p.data().data
-            spec = rules.spec_for(n, v.shape, self.mesh)
-            sh = NamedSharding(self.mesh.mesh, spec)
-            self._shardings[n] = sh
-            sspec = zero_state_spec(
-                spec, v.shape, self.mesh,
-                min_size=_envmod.get_int("MXNET_ZERO_MIN_SIZE")) \
-                if self._zero else spec
-            self._state_shardings[n] = NamedSharding(self.mesh.mesh, sspec)
-            self.params[n] = _global_put(v, sh)
         self._state_layouts: Dict[Any, Tuple] = {}
-        self.opt_state = {
-            n: tuple(_global_put(s, self._state_shardings[n])
-                     for s in self._init_state(v))
-            for n, v in self.params.items() if self._trainable[n]}
+        # set-up phase `mx.setup.place`: nothing here waits for a
+        # transfer, so one still in flight is paid for by whichever
+        # later phase first needs its array
+        with _tracing.phase("mx.setup.place") as placed:
+            for n, p in self._plist:
+                v = p.data().data
+                spec = rules.spec_for(n, v.shape, self.mesh)
+                sh = NamedSharding(self.mesh.mesh, spec)
+                self._shardings[n] = sh
+                sspec = zero_state_spec(
+                    spec, v.shape, self.mesh,
+                    min_size=_envmod.get_int("MXNET_ZERO_MIN_SIZE")) \
+                    if self._zero else spec
+                self._state_shardings[n] = NamedSharding(self.mesh.mesh,
+                                                         sspec)
+                self.params[n] = _global_put(v, sh)
+            self.opt_state = {
+                n: tuple(_global_put(s, self._state_shardings[n])
+                         for s in self._init_state(v))
+                for n, v in self.params.items() if self._trainable[n]}
+            arrays = list(self.params.values()) + [
+                s for states in self.opt_state.values() for s in states]
+            placed["stats"].update(arrays=len(arrays),
+                                   bytes=sum(a.nbytes for a in arrays))
 
         # per-shape fast path over _STEP_CACHE; LRU-bounded because
         # each value strong-refs a whole-step executable — an unbounded
         # dict would outlive _STEP_CACHE's own eviction (ragged last
         # batches / variable seq-len mint a new shape per epoch)
         self._step_fns: "OrderedDict[Tuple, Any]" = OrderedDict()
-        self._fwd_fn = None
+        self._fwd_fns: "OrderedDict[Tuple, Any]" = OrderedDict()
         self._param_by_name = {n: p for n, p in self._plist}
         self._t = 0
 
@@ -425,13 +437,6 @@ class SPMDTrainer:
                 in_shardings=(psh, state_sh, None, None, repl, repl, repl),
                 out_shardings=(psh, state_sh, repl, None),
                 donate_argnums=(0, 1) if self._donate else ())
-            cell = {}
-
-            def build_lowered():
-                if "l" not in cell:
-                    cell["l"] = jitted.lower(*args)
-                return cell["l"]
-
             leaves, treedef = jax.tree_util.tree_flatten(args)
             block = self.block
             # the in-process signature pins everything the closure
@@ -461,9 +466,10 @@ class SPMDTrainer:
             sig = ("spmd-train-step",) + tuple(named.items())
             fn = _STEP_CACHE.lookup(sig)
             if fn is None:
-                fn = _STEP_CACHE.compile(sig, build_lowered, opt,
-                                         alias_ok=False, components=named,
-                                         donate=self._donate)
+                fn = _STEP_CACHE.compile(
+                    sig, lambda: jitted.trace(*args), opt, alias_ok=False,
+                    components=named, donate=self._donate)
+                fn = self._timed_first_call(ikey, fn, _STEP_CACHE.cost(sig))
             # per-trainer fast path keyed by input avals: a batch-shape
             # change rebuilds (AOT does not silently retrace), a repeat
             # shape is one dict hit.  The executable's static cost
@@ -474,6 +480,19 @@ class SPMDTrainer:
         else:
             self._step_fns.move_to_end(ikey)
         return self._step_fns[ikey]
+
+    def _timed_first_call(self, ikey, fn, cost):
+        """What `_get_step` files for an executable it has just built or
+        loaded: its first call runs under the set-up phase
+        `mx.step.first_dispatch` (on the TPU it holds the program's load
+        onto the chip) and puts the bare executable in its own place,
+        so that every later step calls `fn` itself."""
+        def first_dispatch(*args):
+            self._step_fns[ikey] = (fn, cost)
+            with _tracing.phase("mx.step.first_dispatch",
+                                site=_STEP_CACHE.site):
+                return fn(*args)
+        return first_dispatch
 
     # ---- data movement ---------------------------------------------------
     def _spec_sharding(self, spec, arr):
@@ -604,33 +623,48 @@ class SPMDTrainer:
             for c in list(p._data or {}):
                 p._data[c]._data = jnp.asarray(gathered)
 
+    def _build_forward(self, ivals, key):
+        """The inference program for inputs like `ivals`, built ahead of
+        time under the set-up phases a step's build has (`mx.build.*`,
+        `program` "forward"; `optimizer.fused.ProgramBuild`)."""
+        from ..gluon.block import ActiveTrace
+
+        plist = self._plist
+        block = self.block
+        trainer = self
+
+        def forward(params, ivals, key):
+            trace = ActiveTrace({id(p): params[n] for n, p in plist},
+                                train=False)
+            # the trainer's mesh scope is active for the whole
+            # traced step, wherever step() was called from — code
+            # consulting current_mesh() at trace time (ring/ulysses
+            # attention, the fused-conv shard_map plan, sharding
+            # constraints) sees THIS mesh, not the caller's ambient
+            # scope
+            with trainer.mesh, trace, \
+                    rnd.key_provider(rnd.KeyProvider(key)):
+                out = block.forward(*ivals)
+            return out
+
+        return opt_mod.fused.ProgramBuild(
+            lambda: jax.jit(forward).trace(self.params, ivals, key),
+            "parallel.spmd_forward").compile()
+
     def forward(self, *inputs) -> NDArray:
-        """Sharded inference with the trainer's current params."""
-        if self._fwd_fn is None:
-            from ..gluon.block import ActiveTrace
-
-            plist = self._plist
-            block = self.block
-            trainer = self
-
-            def fwd(params, ivals, key):
-                trace = ActiveTrace({id(p): params[n] for n, p in plist},
-                                    train=False)
-                # the trainer's mesh scope is active for the whole
-                # traced step, wherever step() was called from — code
-                # consulting current_mesh() at trace time (ring/ulysses
-                # attention, the fused-conv shard_map plan, sharding
-                # constraints) sees THIS mesh, not the caller's ambient
-                # scope
-                with trainer.mesh, trace, \
-                        rnd.key_provider(rnd.KeyProvider(key)):
-                    out = block.forward(*ivals)
-                return out
-
-            self._fwd_fn = jax.jit(fwd)
+        """Sharded inference with the trainer's current params: one
+        program per input shapes and dtypes, kept as the step's are."""
         bspecs = self._batch_spec or [None] * len(inputs)
         ivals = tuple(self._place(x, s) for x, s in zip(inputs, bspecs))
-        out = self._fwd_fn(self.params, ivals, rnd.next_key())
+        key = rnd.next_key()
+        ikey = tuple((tuple(v.shape), str(v.dtype)) for v in ivals)
+        if ikey not in self._fwd_fns:
+            self._fwd_fns[ikey] = self._build_forward(ivals, key)
+            while len(self._fwd_fns) > _STEP_FNS_MAX:
+                self._fwd_fns.popitem(last=False)
+        else:
+            self._fwd_fns.move_to_end(ikey)
+        out = self._fwd_fns[ikey](self.params, ivals, key)
         from ..context import current_context
 
         ctx = current_context()

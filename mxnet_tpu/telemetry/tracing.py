@@ -33,6 +33,7 @@ from typing import Optional
 
 import jax
 
+from .. import _T_IMPORT
 from .. import profiler as _prof
 from ..util import env
 
@@ -40,7 +41,8 @@ __all__ = [
     "enable", "disable", "enabled", "Span", "span", "current_span",
     "new_trace_id", "record_complete", "flow_start", "flow_end",
     "counter_event", "capture_active", "set_sink", "set_rank",
-    "annotation",
+    "annotation", "phase", "record_phase", "startup_spans",
+    "startup_seconds",
 ]
 
 _ENABLED = env.get_bool("MXNET_TELEMETRY")
@@ -213,6 +215,110 @@ def annotation(name: str, **stats):
     gates itself: outside a profiler session entering and leaving one
     costs under a microsecond, so sites use it unconditionally."""
     return jax.profiler.TraceAnnotation(name, **stats)
+
+
+# ---- time to the first step: the set-up recorder ----------------------
+# Always on, for sites that run in set-up only (import, parameter init,
+# cast, placement, a program's trace / lowering / backend build, the
+# first call of a new executable).  No flag, no environment variable, no
+# sink: a site costs two clock reads and a list append, and nothing here
+# is ever entered from the steady path of a step.  See
+# docs/observability.md, "Time to first step".
+
+#: `perf_counter` when `import mxnet_tpu` began: every record's zero
+_T0 = _T_IMPORT
+_STARTUP: list = []     # the records, in the order their phases opened
+_phase_ctx: "contextvars.ContextVar[Optional[dict]]" = \
+    contextvars.ContextVar("mx_startup_phase", default=None)
+
+
+def record_phase(name: str, start: float, end: float,
+                 parent: Optional[int] = None, **stats) -> dict:
+    """File one already-measured set-up record (`start` and `end` as
+    `perf_counter` read them): for a span that closed before this module
+    could be imported, the package's own import."""
+    return _new_record(name, parent, start, end, 1, stats)
+
+
+def _new_record(name, parent, start, end, calls, stats) -> dict:
+    with _seq_lock:     # a record's id is its place in the list
+        rec = {"id": len(_STARTUP), "name": name, "parent": parent,
+               "start": start - _T0,
+               "end": None if end is None else end - _T0,
+               "seconds": 0.0 if end is None else end - start,
+               "calls": calls, "stats": stats}
+        _STARTUP.append(rec)
+    return rec
+
+
+@contextlib.contextmanager
+def phase(name: str, merge: bool = False, **stats):
+    """`with phase("mx.setup.place", arrays=n, bytes=b) as rec:` one
+    phase of set-up: an `annotation` (so it is on the device trace's
+    clock whenever someone profiles a start-up) and one
+    record in the process-wide list behind `startup_spans()`:
+
+        {"id", "name", "parent": id of the enclosing phase or None,
+         "start", "end": seconds on `perf_counter` since `import
+         mxnet_tpu` began, "seconds": end - start, "calls": 1,
+         "stats": {...}}
+
+    The record is yielded, so a site can add to `rec["stats"]` what it
+    learns inside (`origin` of a backend build).  `merge=True` is for a
+    site entered once an item (a parameter's init): an entry whose
+    predecessor under the same parent has the same name accumulates into
+    that record: `seconds` and the numeric `stats` add up, `calls`
+    counts, `start` is the first entry and `end` the last exit.  So one
+    model's parameters make one record, and an init after another phase
+    has run (a second model, a deferred shape) a record of its own.  A phase entered inside a phase of its own
+    name (a block's `cast` reaching its children's) is that phase."""
+    outer = _phase_ctx.get()
+    if outer is not None and outer["name"] == name:
+        yield outer
+        return
+    parent = None if outer is None else outer["id"]
+    rec = None
+    if merge:
+        last = next((r for r in reversed(_STARTUP)
+                     if r["parent"] == parent), None)
+        if last is not None and last["name"] == name:
+            rec = last
+    with annotation(name, **stats):
+        start = time.perf_counter()
+        if rec is None:
+            rec = _new_record(name, parent, start, None, 0, stats)
+        else:
+            for k, v in stats.items():
+                rec["stats"][k] = rec["stats"].get(k, 0) + v
+        token = _phase_ctx.set(rec)
+        try:
+            yield rec
+        finally:
+            _phase_ctx.reset(token)
+            end = time.perf_counter()
+            rec["end"] = end - _T0
+            rec["seconds"] += end - start
+            rec["calls"] += 1
+
+
+def startup_spans() -> list:
+    """Copies of the set-up records so far, oldest first (see `phase`).
+    A phase still open has `end` None."""
+    return [dict(r, stats=dict(r["stats"])) for r in _STARTUP]
+
+
+def startup_seconds() -> dict:
+    """`{name: self-seconds}` over the records: a phase's `seconds` less
+    what its children cover, summed by name."""
+    spans = list(_STARTUP)
+    own = [r["seconds"] for r in spans]
+    for r in spans:
+        if r["parent"] is not None:
+            own[r["parent"]] -= r["seconds"]
+    out: dict = {}
+    for r, s in zip(spans, own):
+        out[r["name"]] = out.get(r["name"], 0.0) + s
+    return out
 
 
 def current_span() -> Optional[Span]:

@@ -512,11 +512,14 @@ class TestListenerLifecycle:
 # the disabled-path zero-overhead gate (mxprof-style)
 # ---------------------------------------------------------------------------
 
-def test_goodput_disabled_overhead_within_3pct_of_step():
+def test_goodput_disabled_overhead_within_3pct_of_step(monkeypatch):
     """With mxgoodput imported but DISABLED and only the mxprof sink
-    attached, the per-step attribution feed must stay within the same
-    3% budget mxprof holds — goodput must add literally nothing to the
-    disabled path (no listener, one falsy module check)."""
+    attached, goodput must add literally nothing to the disabled path:
+    no listener, and N spans of the per-step attribution feed are N
+    `on_event` calls on the sink's minimal path (no Span object, no
+    ambient context) and no other work.  What the 3% budget guarded is
+    asserted as those counts: a ratio of two wall-clock times on a CPU
+    shared by the suite's workers said nothing about this code."""
     net, tr, one_step_train = _train_tools(units=16)
     for _ in range(5):
         one_step_train()
@@ -525,43 +528,52 @@ def test_goodput_disabled_overhead_within_3pct_of_step():
     assert not mxgoodput.enabled()
     mxprof.disable()
 
-    def best_window(loops, reps, fn):
-        best = float("inf")
-        for _ in range(reps):
-            t0 = time.perf_counter()
-            for _ in range(loops):
-                fn()
-            best = min(best, time.perf_counter() - t0)
-        return best
-
-    gc.disable()
+    feed = ["forward", "backward", "grad-allreduce", "optimizer-update",
+            "step"]             # in the order the spans close
+    events, notified, yielded = [], [], []
     try:
-        t_step = best_window(20, 5, one_step_train) / 20
         mxprof.enable(ring=64)
         assert not mxgoodput.enabled()  # imported, idle
-        assert mxgoodput._on_step not in mxprof.recorder()._listeners
+        rec = mxprof.recorder()
+        assert mxgoodput._on_step not in rec._listeners
+        assert _tracing._SINK is rec
+
+        on_event = rec.on_event
+        monkeypatch.setattr(
+            rec, "on_event", lambda name, cat, duration, args:
+            events.append(name) or on_event(name, cat, duration, args))
+        monkeypatch.setattr(mxgoodput, "_on_step", notified.append)
+
+        def no_span(*a, **kw):
+            raise AssertionError("the sink-only path built a Span")
+
+        monkeypatch.setattr(_tracing, "Span", no_span)
 
         def per_step_feed():
-            with _tracing.span("forward", cat="training"):
-                pass
-            with _tracing.span("backward", cat="training"):
-                pass
-            with _tracing.span("step", cat="training"):
-                with _tracing.span("grad-allreduce", cat="training"):
-                    pass
+            with _tracing.span("forward", cat="training") as s:
+                yielded.append(s)
+            with _tracing.span("backward", cat="training") as s:
+                yielded.append(s)
+            with _tracing.span("step", cat="training") as s:
+                yielded.append(s)
+                with _tracing.span("grad-allreduce", cat="training") as s:
+                    yielded.append(_tracing.current_span())
                 with _tracing.span("optimizer-update",
-                                   cat="training"):
-                    pass
+                                   cat="training") as s:
+                    yielded.append(s)
 
-        t_attr = best_window(2000, 7, per_step_feed) / 2000
+        loops = 200
+        for _ in range(loops):
+            per_step_feed()
+        steps = len(mxprof.snapshot(live_hbm=False)["records"])
     finally:
-        gc.enable()
         mxprof.disable()
         mxprof.clear()
-    assert t_attr <= 0.03 * t_step, \
-        (f"per-step feed with goodput imported-but-disabled costs "
-         f"{t_attr * 1e6:.2f}us vs step {t_step * 1e6:.1f}us — "
-         f"{t_attr / t_step * 100:.2f}% exceeds the 3% budget")
+    assert events == feed * loops
+    assert yielded == [None] * (len(feed) * loops)
+    assert notified == []
+    assert steps == 64                  # the ring, full of closed steps
+    assert mxgoodput._on_step not in mxprof.recorder()._listeners
 
 
 # ---------------------------------------------------------------------------
