@@ -420,9 +420,10 @@ def decoder_phase(*, seq=1024, heads=8, kv_heads=2, dim=128, window=256,
                   expect_mosaic=True) -> dict:
     """Value and gradients of the decoders' kernel routes against plain
     XLA on the same operands: causal grouped-query attention through the
-    op (upstream's flash kernels) against the S x S reference, the same
-    under a sliding `window` (upstream's splash kernels over the band)
-    against the banded XLA form, the held experts' stage in both forms
+    op (upstream's splash multi-query kernels over a causal mask) against
+    the S x S reference, the same under a sliding `window` (the same
+    kernels over the band) against the banded XLA form, the held
+    experts' stage in both forms
     (relu^2 and silu-gated) (a loop over chunks of the plan's rows around
     the grouped-matmul kernel; `held_bias` on the router draws enough
     tokens to the held experts that it runs twice, a group across the
@@ -490,7 +491,7 @@ def decoder_phase(*, seq=1024, heads=8, kv_heads=2, dim=128, window=256,
     compare(f"causal_gqa_h{heads}_kv{kv_heads}_s{seq}_d{dim}", attention,
             attention_plain, (q, k, v, ct), out)
     _require(pa.route_counts()["flash_causal"] > before,
-             f"the causal call did not take the flash route: "
+             f"the causal call did not take the splash kernels' route: "
              f"{pa.route_counts()}")
 
     before = pa.route_counts()["splash_window"]

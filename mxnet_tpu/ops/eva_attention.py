@@ -253,7 +253,7 @@ def _eva_attention(query, key, value, key_summary, value_summary,
     if (env.get_bool("MXNET_USE_PALLAS") and d % 128 == 0
             and _splash_block(s, s // chunk)
             and pa._mesh_batch_axes(b) is None):
-        pa._count_kernel_route("eva_splash", b, h, s, d, query.dtype, 1)
+        pa._count_kernel_route("eva_splash", b, h, s, d, query.dtype)
         return _attend_eva(
             *packed, heads=h, **sizes,
             interpret=env.get_bool("MXNET_PALLAS_INTERPRET"))

@@ -19,29 +19,31 @@ lowered for; no switch of its own):
   * Causal self-attention with no dropout and no key mask, heads of 128
     (or a multiple), sq == sk a multiple of 128, training or inference,
     query heads a multiple of the key/value heads (grouped-query):
-    `_attend_causal`, upstream's blocked flash kernels
-    (jax.experimental.pallas.ops.tpu.flash_attention: forward, dK/dV and
-    dQ, blocks above the diagonal skipped) under a custom_vjp of this
-    file's, `_flash_core`.  O(S) memory: the only route that fits a
-    decoder at S = 8192, where `_attend`'s backward would hold 32 x
-    8192^2 scores.
-    The key/value heads are repeated to the query heads in HBM first (a
-    few MB at 2 of 32 heads; autodiff sums their gradient back).  Not
-    under a mesh of several devices (a bare Mosaic call), where the call
-    takes the dropout-free route below.  Counted `flash_causal`.
+    `_attend_causal`, upstream's splash multi-query kernels
+    (jax.experimental.pallas.ops.tpu.splash_attention: forward, dK/dV
+    and dQ under their own custom_vjp, one online-softmax pass over the
+    blocks of a `CausalMask`, those above the diagonal skipped).  O(S)
+    memory: the only route that fits a decoder at S = 8192, where
+    `_attend`'s backward would hold 32 x 8192^2 scores.  The query heads
+    of one key/value head go through the multi-query kernel together:
+    the key/value heads are not repeated in HBM, their gradients leave
+    the kernel summed over the group, and the softmax statistic kept
+    for the backward is one (H, S) float32 logsumexp.  Not under a mesh
+    of several devices (a bare Mosaic call), where the call takes the
+    dropout-free route below.  Counted `flash_causal` (the key is older
+    than the kernels: until PR 38 upstream's `flash_attention` kernels
+    ran here, on key/value heads repeated to the query heads and with
+    row sums and maxima 128 lanes wide).
   * Causal sliding-window self-attention (`sliding_window_attention`, an
     op of its own so that a profile reads the window and the full cores
     apart): query i sees keys j with 0 <= i - j < window.  Shapes as for
-    `flash_causal`, window < S: `_attend_window`, upstream's splash
-    kernels (jax.experimental.pallas.ops.tpu.splash_attention: forward,
-    dQ and dK/dV under their own custom_vjp) over a `LocalMask`, which
-    visit only the blocks the band touches: O(S x window) work where
-    `flash_causal` does O(S^2 / 2).  The query heads of one key/value
-    head go through the multi-query kernel together, so the key/value
-    heads are not repeated in HBM.  Not under a mesh of several devices.
-    Counted `splash_window`; every other windowed call (odd shapes, a
-    mesh, MXNET_USE_PALLAS=0) is the banded XLA form, counted
-    `reference`; window >= S is causal attention and takes that route.
+    `flash_causal`, window < S: the same `_attend_causal` and the same
+    kernels over a `LocalMask`, which visit only the blocks the band
+    touches: O(S x window) work where `flash_causal` does O(S^2 / 2).
+    Not under a mesh of several devices.  Counted `splash_window`;
+    every other windowed call (odd shapes, a mesh, MXNET_USE_PALLAS=0)
+    is the banded XLA form, counted `reference`; window >= S is causal
+    attention and takes that route.
   * `eva_attention` (ops/eva_attention.py: exact attention inside a
     query's window plus one pooled key and value a chunk of every
     earlier window, one softmax over both) counts its routes here too:
@@ -72,10 +74,10 @@ is why the dropout STREAM differs from the threefry `bernoulli` this op
 used before PR 26: same distribution, other draws, so a loss pinned under
 attention dropout moved once.
 
-The three routes that run upstream's kernels (`flash_causal`,
+The three routes that run upstream's splash kernels (`flash_causal`,
 `splash_window`, `eva_splash`) NAME what the forward kernel wrote for the
-backward kernels, the output and the softmax statistics, inside the
-forward rule of the custom VJP (ops/residuals.py): a recomputed segment
+backward kernels, the output and the logsumexp, inside the forward rule
+of the kernels' custom VJP (ops/residuals.py): a recomputed segment
 (gluon/block.py: `SPMDTrainer(remat=True)`) keeps those and runs the
 forward kernel once a step, where it would run it again in the backward
 pass only to get them back; `residuals.kept_residuals()` counts them.
@@ -94,7 +96,6 @@ import math
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.ad_checkpoint import checkpoint_name
 
 from ..telemetry import instruments as _instruments
 from ..util import env
@@ -795,7 +796,8 @@ def _fused_train_shape(heads, sq, sk, d, causal):
 
 
 # ---------------------------------------------------------------------------
-# Causal self-attention without dropout: a decoder's layers
+# Causal self-attention without dropout, over the whole triangle or under
+# a sliding window: a decoder's layers
 # ---------------------------------------------------------------------------
 
 def _split_to_heads(x, heads):
@@ -819,90 +821,10 @@ def _causal_xla(q, k, v, scale):
                                      causal=True).reshape(b, h, s, d)
 
 
-def _flash_block(s):
-    return next(n for n in (512, 256, 128) if s % n == 0)
-
-
-# Upstream's three flash kernels (forward, dK/dV, dQ) at one block size,
-# under a custom VJP of the repo's own: upstream's `flash_attention` has
-# no hook to name what its forward leaves for its backward, so a
-# recomputed segment ran the forward kernel a second time only to get
-# back o, l and m.  The pieces are jitted, so that a stack of layers
-# traces and lowers each kernel once.
-
-@functools.partial(jax.jit, static_argnames=("scale", "statistics"))
-def flash_attention(q, k, v, scale, statistics):
-    """q, k, v (B, H, S, D) -> o, or (o, l, m) with the softmax's row
-    sums and maxima (B, H, S) in float32.  (Public by its name only: a
-    profile reads the forward kernel by the name of the jit it sits
-    in, which was upstream's.)"""
-    from jax.experimental.pallas.ops.tpu import flash_attention as fa
-
-    blk = _flash_block(q.shape[2])
-    return fa._flash_attention_impl(
-        q, k, v, None, None, statistics, True, scale, 1, blk, blk, blk,
-        False)
-
-
-@functools.partial(jax.jit, static_argnames=("scale",))
-def _flash_backward(q, k, v, o, l, m, do, scale):
-    from jax.experimental.pallas.ops.tpu import flash_attention as fa
-
-    blk = _flash_block(q.shape[2])
-    di = jnp.sum(o.astype(jnp.float32) * do.astype(jnp.float32), axis=-1)
-    rest = dict(block_k_major=blk, block_k=blk, sm_scale=scale,
-                causal=True, mask_value=fa.DEFAULT_MASK_VALUE, debug=False)
-    dk, dv = fa._flash_attention_bwd_dkv(
-        q, k, v, None, None, l, m, do, di, block_q_major=blk, block_q=blk,
-        **rest)
-    dq, _ = fa._flash_attention_bwd_dq(
-        q, k, v, None, None, l, m, do, di, block_q_major=blk, **rest)
-    return dq, dk, dv
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _flash_core(q, k, v, scale):
-    return flash_attention(q, k, v, scale=scale, statistics=False)
-
-
-def _flash_core_fwd(q, k, v, scale):
-    # what the backward kernels read and only the forward kernel can
-    # make again: a recomputed segment keeps them (ops/residuals.py);
-    # q, k, v and the key/value repeat it recomputes from its input
-    o, l, m = (checkpoint_name(x, "flash_causal") for x in flash_attention(
-        q, k, v, scale=scale, statistics=True))
-    return o, (q, k, v, o, l, m)
-
-
-def _flash_core_bwd(scale, res, do):
-    return _flash_backward(*res, do, scale=scale)
-
-
-_flash_core.defvjp(_flash_core_fwd, _flash_core_bwd)
-
-
-def _causal_flash(q, k, v, scale):
-    h = q.shape[1]
-    return _flash_core(q, _repeat_kv(k, h), _repeat_kv(v, h), scale)
-
-
-def _attend_causal(q, k, v, scale):
-    """q (B, H, S, D), k and v (B, Hkv, S, D): the flash kernels in a
-    program lowered for the TPU, the XLA reference elsewhere, as
-    `_attend` chooses; autodiff goes through the chosen branch."""
-    return jax.lax.platform_dependent(
-        q, k, v, tpu=functools.partial(_causal_flash, scale=scale),
-        default=functools.partial(_causal_xla, scale=scale))
-
-
 def _causal_flash_shape(heads, kv_heads, sq, sk, d):
     return (sq == sk and sq % 128 == 0 and d % 128 == 0
             and heads % kv_heads == 0)
 
-
-# ---------------------------------------------------------------------------
-# Causal sliding-window self-attention: a decoder's local layers
-# ---------------------------------------------------------------------------
 
 def _window_xla(q, k, v, scale, window):
     """The band 0 <= i - j < window in plain XLA: q (B, H, S, D), k and
@@ -940,47 +862,79 @@ def _window_xla(q, k, v, scale, window):
     return out.reshape(b, h, n * window, d)[:, :, :s]
 
 
-def _window_splash(q, k, v, scale, window, interpret=False):
-    """The splash kernels over the band: the `groups` query heads of one
-    key/value head are one multi-query call, vmapped over batch and
-    key/value heads.  Blocks of 512 where they divide S: a query block
-    visits the 2 key blocks its band touches.  On the v5e at the window
-    of 512, forward + backward (PERF.md, PR 31): 512 23.4 ms, 256 (3
-    blocks, 768 keys for the 512 a query sees, but three times the grid
-    steps) 33.5, 128 69.3, (1024, 512) 30.1, the fused backward slower."""
+def _splash_blocks(s, window):
+    """(rows of queries and of keys a block, rows of keys a product) of
+    the three splash kernels, from the mask's kind and S.  Under a
+    window: 512 where it divides S, so that a query block visits the 2
+    key blocks its band touches; on the v5e at the window of 512,
+    forward + backward of one layer (PERF.md, PR 31): 512 23.4 ms, 256
+    (3 blocks, 768 keys for the 512 a query sees, but three times the
+    grid steps) 33.5, 128 69.3, (1024, 512) 30.1, the fused backward
+    slower.  The whole triangle is mostly whole blocks and takes 1024
+    rows in products of 512 keys where 1024 divides S; one full layer of
+    `laguna_xs2_s8192` (B 2, 48 heads over 8, S 8192) on the v5e
+    (PERF.md, PR 38): 512 70.2 ms, (1024 queries, 512 keys) 64.2, (512,
+    1024) 62.2, (2048, 512) 65.3, 1024 in one product 58.9, 1024 in
+    products of 512 57.6 (the least in each of the three kernels),
+    against 83.2 through upstream's flash kernels at 512 on repeated
+    heads.  The fused backward reads 48.5 there, but sums dQ from S /
+    1024 bfloat16 partials, each as large as q: not taken."""
+    if window is None and s % 1024 == 0:
+        return 1024, 512
+    rows = next(n for n in (512, 256, 128) if s % n == 0)
+    return rows, rows
+
+
+def _causal_splash(q, k, v, scale, window=None, interpret=False):
+    """Upstream's splash multi-query kernels (forward, dK/dV and dQ under
+    their own custom VJP, one online-softmax pass over the blocks the
+    mask touches) over the band 0 <= i - j < window, or over the whole
+    causal triangle (`window` None): q (B, H, S, D), k and v (B, Hkv, S,
+    D).  The H // Hkv query heads of one key/value head are one
+    multi-query call, vmapped over batch and key/value heads: k and v
+    go in as they are, and dK, dV come out summed over the group.  The
+    forward rule names its output and its (H, S) float32 logsumexp by
+    the route, so that a recomputed segment keeps them
+    (ops/residuals.py)."""
     from jax.experimental.pallas.ops.tpu import splash_attention as sa
 
     b, h, s, d = q.shape
     kv = k.shape[1]
     groups = h // kv
-    blk = next(n for n in (512, 256, 128) if s % n == 0)
-    mask = sa.MultiHeadMask([sa.LocalMask((s, s), (window - 1, 0), 0)
-                             for _ in range(groups)])
+    rows, compute = _splash_blocks(s, window)
+    mask = (sa.CausalMask((s, s)) if window is None
+            else sa.LocalMask((s, s), (window - 1, 0), 0))
     kernel = sa.make_splash_mqa_single_device(
-        mask, block_sizes=sa.BlockSizes(
-            block_q=blk, block_kv=blk, block_kv_compute=blk,
-            block_q_dkv=blk, block_kv_dkv=blk, block_kv_dkv_compute=blk,
-            block_q_dq=blk, block_kv_dq=blk),
-        # the forward rule names its output and logsumexp: a recomputed
-        # segment keeps them (ops/residuals.py)
-        residual_checkpoint_name="splash_window", interpret=interpret)
-    # the kernels apply no scale of their own
-    q = (q * jnp.asarray(scale, q.dtype)).reshape(b, kv, groups, s, d)
+        sa.MultiHeadMask([mask] * groups),
+        block_sizes=sa.BlockSizes(
+            block_q=rows, block_kv=rows, block_kv_compute=compute,
+            block_q_dkv=rows, block_kv_dkv=rows,
+            block_kv_dkv_compute=compute, block_q_dq=rows, block_kv_dq=rows),
+        residual_checkpoint_name=("flash_causal" if window is None
+                                  else "splash_window"),
+        interpret=interpret)
+    # the kernels apply no scale of their own; in float32, so that the
+    # scale is not rounded to the operands' type before it is applied
+    q = (q.astype(jnp.float32) * scale).astype(q.dtype).reshape(
+        b, kv, groups, s, d)
     return jax.vmap(jax.vmap(kernel))(q, k, v).reshape(b, h, s, d)
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "window", "interpret"))
-def _attend_window(q, k, v, scale, window, interpret):
-    """q (B, H, S, D), k and v (B, Hkv, S, D) -> (B, H, S, D): the splash
-    kernels in a program lowered for the TPU (or anywhere under the
-    interpreter), the banded XLA form elsewhere.  Jitted, so that a
-    stack of window layers traces and lowers the kernels once."""
+def _attend_causal(q, k, v, scale, window, interpret):
+    """q (B, H, S, D), k and v (B, Hkv, S, D) -> (B, H, S, D), causal,
+    under a sliding `window` or none (None): the splash kernels in a
+    program lowered for the TPU (or anywhere under the interpreter), the
+    XLA form elsewhere, as `_attend` chooses; autodiff goes through the
+    chosen branch.  Jitted, so that a stack of layers traces and lowers
+    the kernels once a kind of layer."""
     if interpret:
-        return _window_splash(q, k, v, scale, window, interpret=True)
+        return _causal_splash(q, k, v, scale, window, interpret=True)
+    xla = (functools.partial(_causal_xla, scale=scale) if window is None
+           else functools.partial(_window_xla, scale=scale, window=window))
     return jax.lax.platform_dependent(
-        q, k, v,
-        tpu=functools.partial(_window_splash, scale=scale, window=window),
-        default=functools.partial(_window_xla, scale=scale, window=window))
+        q, k, v, default=xla,
+        tpu=functools.partial(_causal_splash, scale=scale, window=window))
 
 
 # Routes CHOSEN, counted where the branch is chosen: at TRACE time (once a
@@ -1006,12 +960,11 @@ def _count_route(route):
     _instruments.attention_route_total(route).inc()
 
 
-def _count_kernel_route(route, b, h, s, d, dtype, statistics):
+def _count_kernel_route(route, b, h, s, d, dtype):
     """A route whose forward rule names what its kernel wrote for the
-    backward kernels: o (b, h, s, d) and `statistics` float32 rows."""
+    backward kernels: o (b, h, s, d) and its float32 logsumexp rows."""
     _count_route(route)
-    residuals.note(route, 1 + statistics,
-                   b * h * s * (d * np.dtype(dtype).itemsize + 4 * statistics))
+    residuals.note(route, 2, b * h * s * (d * np.dtype(dtype).itemsize + 4))
 
 
 @register_op("dot_product_attention",
@@ -1050,13 +1003,14 @@ def _dot_product_attention(query, key, value, valid_mask=None, rng_key=None,
             and env.get_bool("MXNET_USE_PALLAS")
             and _causal_flash_shape(h, h_kv, sq, sk, d)
             and _mesh_batch_axes(b) is None):
-        _count_kernel_route("flash_causal", b, h, sq, d, query.dtype, 2)
+        _count_kernel_route("flash_causal", b, h, sq, d, query.dtype)
         if packed:
             qh, kh, vh = (_split_to_heads(x, n) for x, n in (
                 (query, h), (key, h_kv), (value, h_kv)))
         else:
             qh, kh, vh = query, key, value
-        oh = _attend_causal(qh, kh, vh, float(scale))
+        oh = _attend_causal(qh, kh, vh, float(scale), None,
+                            env.get_bool("MXNET_PALLAS_INTERPRET"))
         return oh.transpose(0, 2, 1, 3).reshape(b, sq, h * d) if packed \
             else oh
     shard = _mesh_batch_axes(b) if dropping else None
@@ -1129,8 +1083,8 @@ def _sliding_window_attention(query, key, value, num_heads=1, window=0,
     if (env.get_bool("MXNET_USE_PALLAS")
             and _causal_flash_shape(h, h_kv, s, s, d)
             and _mesh_batch_axes(b) is None):
-        _count_kernel_route("splash_window", b, h, s, d, query.dtype, 1)
-        oh = _attend_window(qh, kh, vh, float(scale), int(window),
+        _count_kernel_route("splash_window", b, h, s, d, query.dtype)
+        oh = _attend_causal(qh, kh, vh, float(scale), int(window),
                             env.get_bool("MXNET_PALLAS_INTERPRET"))
     else:
         _count_route("reference")

@@ -5,8 +5,8 @@ True)`) every parameter-bearing block is one `jax.checkpoint` segment
 (gluon/block.py): the forward keeps its input, the backward runs its
 forward again.  Some of what that forward computes only a kernel can
 compute again, and the kernel had already written it to HBM: an
-attention kernel's output and softmax statistics, which its backward
-kernels read.  Those values are NAMED here, inside the forward rule of
+attention kernel's output and logsumexp, which its backward kernels
+read.  Those values are NAMED here, inside the forward rule of
 the kernel's custom VJP (`jax.ad_checkpoint.checkpoint_name`), and a
 segment keeps the named values and recomputes everything else:
 `KEEP_NAMED` is the policy of every segment.  A segment that names

@@ -143,7 +143,8 @@ _spec("mx_attention_route_total", "counter",
       "Attention op calls TRACED through each route "
       "(fused_train = the fused training kernels, xla_dropout = the XLA "
       "path with saved probabilities, kernel_infer / reference = the "
-      "dropout-free call, flash_causal = the causal flash kernels, "
+      "dropout-free call, flash_causal = the splash multi-query kernels "
+      "over a causal mask, "
       "splash_window = sliding_window_attention's splash kernels, "
       "eva_splash / eva_xla = eva_attention's splash kernels over keys "
       "and summaries, or its windowed XLA form): "

@@ -303,8 +303,11 @@ arg = lambda shape, dt=jnp.bfloat16: jax.ShapeDtypeStruct(
     shape, dt, sharding=sh)
 
 def mosaic_names(fn, *args):
+    # splash's custom calls are printed over three lines: the table's
+    # own reader joins them
+    from mxnet_tpu.parallel.spmd import _whole_instructions
     text = jax.jit(fn).lower(*args).compile().as_text()
-    calls = [ln for ln in text.splitlines()
+    calls = [ln for ln in _whole_instructions(text)
              if 'custom_call_target="tpu_custom_call"' in ln]
     return [re.search(r'op_name="([^"]*)"', ln).group(1) for ln in calls]
 
@@ -501,8 +504,10 @@ def test_window_and_gated_expert_routes_compile_for_v5e_ahead_of_time():
 
 
 def test_decoder_kernel_routes_compile_for_v5e_ahead_of_time():
-    """Causal grouped-query training attention through the O(S) flash
-    route, the expert layer's grouped products through the grouped-
+    """Causal grouped-query training attention through the O(S)
+    `flash_causal` route (the splash multi-query kernels, sixteen query
+    heads a key/value head), the expert layer's grouped products
+    through the grouped-
     matmul kernel, and the Mamba-2 scan through its forward and backward
     kernels, at the hybrid decoder cell's shapes: Mosaic takes them, and
     every call keeps its op scope and, in the backward, `transpose(`:

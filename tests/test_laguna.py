@@ -117,6 +117,84 @@ def test_window_attention_matches_the_dense_masked_oracle(monkeypatch, case):
         np.testing.assert_allclose(g, w, rtol=2e-4, atol=2e-4)
 
 
+# the causal route's own kernels: (query heads a key/value head, S); the
+# blocks are 1024 rows where 1024 divides S and the fall-back elsewhere
+_CAUSAL = [(1, 1024), (6, 2048), (16, 1024), (1, 384), (6, 384), (16, 640)]
+
+
+@pytest.mark.parametrize("groups, s", _CAUSAL)
+def test_causal_splash_kernels_match_the_xla_form(groups, s):
+    """`flash_causal`'s kernels under the interpreter against
+    `_causal_xla` on the same head-split operands: the output and the
+    gradients of q, k and v, the key/value heads' summed over their
+    group inside the kernel."""
+    assert pa._splash_blocks(s, None)[0] == (1024 if s % 1024 == 0 else 128)
+    rng = np.random.RandomState(groups + s)
+    b, kv, d, scale = 1, 2, 128, 0.1
+    q = jnp.asarray(rng.randn(b, kv * groups, s, d), jnp.float32)
+    k, v = (jnp.asarray(rng.randn(b, kv, s, d), jnp.float32)
+            for _ in range(2))
+    ct = jnp.asarray(rng.randn(*q.shape), jnp.float32)
+    cores = (lambda q, k, v: pa._attend_causal(q, k, v, scale, None, True),
+             lambda q, k, v: pa._causal_xla(q, k, v, scale))
+    got, want = (jax.value_and_grad(
+        lambda q, k, v: (core(q, k, v) * ct).sum(), argnums=(0, 1, 2))(
+            q, k, v) for core in cores)
+    np.testing.assert_allclose(cores[0](q, k, v), cores[1](q, k, v),
+                               rtol=2e-5, atol=2e-5)
+    for g, w in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        np.testing.assert_allclose(g, w, rtol=2e-4, atol=2e-4 * groups)
+
+
+def _reached_by(jaxpr, marks):
+    """(shapes of the arrays that XLA computes from the marked inputs of
+    `jaxpr`, which of its outputs are such arrays); sub-jaxprs are
+    entered, and a Pallas kernel's results are the kernel's own."""
+    reached = {v for v, m in zip(jaxpr.invars, marks) if m}
+    shapes = []
+    for eqn in jaxpr.eqns:
+        marks = [isinstance(v, jax.extend.core.Var) and v in reached
+                 for v in eqn.invars]
+        if not any(marks) or eqn.primitive.name == "pallas_call":
+            continue
+        subs = [getattr(p, "jaxpr", p) for p in eqn.params.values()]
+        subs = [j for j in subs if hasattr(j, "eqns")
+                and len(j.invars) == len(marks)
+                and len(j.outvars) == len(eqn.outvars)]
+        if subs:
+            inner, out_marks = _reached_by(subs[0], marks)
+            shapes += inner
+        else:
+            out_marks = [True] * len(eqn.outvars)
+            shapes += [v.aval.shape for v in eqn.outvars]
+        reached.update(v for v, m in zip(eqn.outvars, out_marks) if m)
+    return shapes, [isinstance(v, jax.extend.core.Var) and v in reached
+                    for v in jaxpr.outvars]
+
+
+def test_causal_splash_program_repeats_no_key_or_value_head():
+    """Forward and backward, traced: nothing XLA makes of k or v is as
+    large as q (the six copies a key/value head had before the kernels
+    took grouped heads), where the XLA form does hold such arrays."""
+    b, h, kv, s, d = 2, 12, 2, 256, 128
+    q = jnp.zeros((b, h, s, d), jnp.bfloat16)
+    k = v = jnp.zeros((b, kv, s, d), jnp.bfloat16)
+
+    def from_kv(core):
+        program = jax.make_jaxpr(jax.grad(
+            lambda q, k, v: core(q, k, v).astype(jnp.float32).sum(),
+            argnums=(0, 1, 2)))(q, k, v)
+        return _reached_by(program.jaxpr, [False, True, True])[0]
+
+    as_q = lambda shapes: [x for x in shapes if math.prod(x) >= q.size]
+    kernels = from_kv(lambda q, k, v: pa._causal_splash(
+        q, k, v, 0.1, interpret=True))
+    assert as_q(kernels) == [], kernels
+    assert (b, h, s, d) in as_q(from_kv(
+        lambda q, k, v: pa._causal_xla(q, k, v, 0.1)))
+
+
 def test_window_attention_counts_its_route_in_telemetry():
     from mxnet_tpu import telemetry
     from mxnet_tpu.telemetry import instruments
@@ -521,20 +599,18 @@ def test_step_program_holds_the_new_op_scopes_forward_and_backward(
     assert after["splash_window"] == before["splash_window"] + 3
     assert after["flash_causal"] == before["flash_causal"] + 2
     # each layer's segment keeps what its kernel wrote for the backward:
-    # o and the logsumexp of a window layer, o, l and m of a full one
+    # o and the logsumexp, of a window layer and of a full one alike
     now = residuals.kept_residuals()
     heads = small_config["num_attention_heads_per_layer"]
     kinds = small_config["layer_types"][:len(heads)]
     rows = {kind: 256 * sum(h for h, k in zip(heads, kinds) if k == kind)
             for kind in set(kinds)}
     width = small_config["head_dim"] * 2      # bfloat16
-    for name, kind, statistics in (
-            ("splash_window", "sliding_attention", 1),
-            ("flash_causal", "full_attention", 2)):
+    for name, kind in (("splash_window", "sliding_attention"),
+                       ("flash_causal", "full_attention")):
         grown = {k: now[name][k] - kept[name][k] for k in now[name]}
-        assert grown == {
-            "values": (1 + statistics) * kinds.count(kind),
-            "bytes": rows[kind] * (width + 4 * statistics)}, name
+        assert grown == {"values": 2 * kinds.count(kind),
+                         "bytes": rows[kind] * (width + 4)}, name
     names = set(spmd.step_programs()[-1]["ops"].values())
 
     def holds(*parts):

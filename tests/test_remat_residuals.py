@@ -1,5 +1,5 @@
 """A recomputed segment keeps what its attention kernels wrote
-(`ops/residuals.py`): the output and the softmax statistics are named in
+(`ops/residuals.py`): the output and the logsumexp are named in
 the forward rule of each kernel route's custom VJP, every `jax.checkpoint`
 segment of `gluon/block.py` keeps the named values, and the forward kernel
 runs once a step where it ran twice.
@@ -26,18 +26,18 @@ from mxnet_tpu.telemetry import instruments
 B, S, H, H_KV, D = 1, 256, 2, 1, 128
 WINDOW, CHUNK = 128, 2
 
-# route -> (forward kernel, backward kernels, statistics kept beside o);
-# upstream's flash kernels have no name but their function's
+# route -> (forward kernel, backward kernels); each keeps o and one
+# logsumexp.  The causal and the window cores run the same kernels
 ROUTES = {
-    "flash_causal": ("_flash_attention_kernel",
-                     ("_flash_attention_dkv_kernel",
-                      "_flash_attention_dq_kernel"), 2),
+    "flash_causal": ("splash_mqa_fwd_residuals",
+                     ("splash_mqa_dkv_no_residuals",
+                      "splash_mqa_dq_no_residuals")),
     "splash_window": ("splash_mqa_fwd_residuals",
                       ("splash_mqa_dkv_no_residuals",
-                       "splash_mqa_dq_no_residuals"), 1),
+                       "splash_mqa_dq_no_residuals")),
     "eva_splash": ("splash_mha_fwd_residuals",
                    ("splash_mha_dkv_no_residuals",
-                    "splash_mha_dq_no_residuals"), 1),
+                    "splash_mha_dq_no_residuals")),
 }
 
 
@@ -130,7 +130,7 @@ def _drop_policy(monkeypatch):
 @pytest.mark.parametrize("route", list(ROUTES))
 def test_the_forward_kernel_appears_once_where_it_appeared_twice(
         route, monkeypatch):
-    forward, backward, statistics = ROUTES[route]
+    forward, backward = ROUTES[route]
     before = residuals.kept_residuals()[route]
     layer = _layer(route)
     fn, params, x = _gradient(layer)
@@ -144,15 +144,14 @@ def test_the_forward_kernel_appears_once_where_it_appeared_twice(
     assert kept[forward] == 1
     for name in backward:
         assert kept[name] == recomputed[name] == 1
-    # o and the statistics, named in the forward rule (recomputed, the
-    # forward pass names o and the backward pass all of them again) ...
-    assert kept["xla:name"] == 1 + statistics
-    assert recomputed["xla:name"] == 2 + statistics
+    # o and the logsumexp, named in the forward rule (recomputed, the
+    # forward pass names o and the backward pass both again) ...
+    assert kept["xla:name"] == 2
+    assert recomputed["xla:name"] == 3
     # ... and counted as kept, with their bytes: o in the layer's dtype,
-    # float32 rows of statistics
-    assert after["values"] - before["values"] == 1 + statistics
-    assert after["bytes"] - before["bytes"] \
-        == B * H * S * (D * 4 + 4 * statistics)
+    # float32 rows of logsumexp
+    assert after["values"] - before["values"] == 2
+    assert after["bytes"] - before["bytes"] == B * H * S * (D * 4 + 4)
     # everything else in the segment is still computed again: the
     # projections, the head split, the XLA twin in the other branch
     for name in ("xla:dot_general", "xla:transpose", "xla:exp"):
@@ -175,7 +174,7 @@ def test_the_xla_twins_name_nothing_and_are_recomputed_whole(
     assert not any(not k.startswith("xla:") for k in kept)
 
 
-@pytest.mark.parametrize("route", ["splash_window", "eva_splash"])
+@pytest.mark.parametrize("route", list(ROUTES))
 def test_interpreted_gradients_are_those_of_the_recomputed_kernel(
         route, monkeypatch):
     """The kept o and logsumexp are the values the second call of the
@@ -193,31 +192,33 @@ def test_interpreted_gradients_are_those_of_the_recomputed_kernel(
         np.testing.assert_array_equal(a, b)
 
 
-def test_the_flash_route_runs_upstreams_kernels_at_the_same_blocks():
-    """`_flash_core` is the repo's custom VJP around upstream's forward,
-    dK/dV and dQ kernels: value and gradients bit for bit those of
-    upstream's `flash_attention` at the blocks the route had."""
-    from jax.experimental.pallas import tpu as pltpu
-    from jax.experimental.pallas.ops.tpu import flash_attention as fa
+def test_the_causal_route_runs_upstreams_kernels_at_the_routes_blocks():
+    """`_causal_splash` is upstream's multi-query kernel over a
+    `CausalMask` at the blocks `_splash_blocks` gives, on q scaled
+    beforehand: value and gradients bit for bit those of upstream's
+    kernel called directly, and `_causal_xla`'s to rounding."""
+    from jax.experimental.pallas.ops.tpu import splash_attention as sa
 
     rng = np.random.RandomState(1)
-    q, k, v = (jnp.asarray(rng.randn(B, H, S, D), jnp.float32)
-               for _ in range(3))
-    blk = pa._flash_block(S)
-    sizes = fa.BlockSizes(
-        block_q=blk, block_k_major=blk, block_k=blk, block_b=1,
-        block_q_major_dkv=blk, block_k_major_dkv=blk, block_k_dkv=blk,
-        block_q_dkv=blk, block_k_major_dq=blk, block_k_dq=blk,
-        block_q_dq=blk)
+    q = jnp.asarray(rng.randn(B, H, S, D), jnp.float32)
+    k, v = (jnp.asarray(rng.randn(B, H_KV, S, D), jnp.float32)
+            for _ in range(2))
+    rows, compute = pa._splash_blocks(S, None)
+    kernel = sa.make_splash_mqa_single_device(
+        sa.MultiHeadMask([sa.CausalMask((S, S))] * (H // H_KV)),
+        block_sizes=sa.BlockSizes(
+            block_q=rows, block_kv=rows, block_kv_compute=compute,
+            block_q_dkv=rows, block_kv_dkv=rows,
+            block_kv_dkv_compute=compute, block_q_dq=rows, block_kv_dq=rows),
+        interpret=True)
     weight = jnp.cos(jnp.arange(D, dtype=jnp.float32))
-    cores = (lambda q, k, v: fa.flash_attention(
-                 q, k, v, causal=True, sm_scale=0.1, block_sizes=sizes),
-             lambda q, k, v: pa._flash_core(q, k, v, 0.1))
-    with pltpu.force_tpu_interpret_mode():
-        upstream, ours = (jax.value_and_grad(
-            lambda q, k, v: (core(q, k, v) * weight).sum(),
-            argnums=(0, 1, 2))(q, k, v) for core in cores)
-        np.testing.assert_array_equal(cores[0](q, k, v), cores[1](q, k, v))
+    cores = (lambda q, k, v: kernel(q[0] * jnp.float32(0.1), k[0, 0],
+                                    v[0, 0])[None],
+             lambda q, k, v: pa._causal_splash(q, k, v, 0.1, interpret=True))
+    upstream, ours = (jax.value_and_grad(
+        lambda q, k, v: (core(q, k, v) * weight).sum(),
+        argnums=(0, 1, 2))(q, k, v) for core in cores)
+    np.testing.assert_array_equal(cores[0](q, k, v), cores[1](q, k, v))
     for a, b in zip(jax.tree_util.tree_leaves(upstream),
                     jax.tree_util.tree_leaves(ours)):
         np.testing.assert_array_equal(a, b)
