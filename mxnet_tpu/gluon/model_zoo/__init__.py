@@ -3,6 +3,7 @@ from . import vision
 from . import nemotron_h
 from . import laguna
 from . import evabyte
+from . import joyai
 from .vision import get_model
 
-__all__ = ["vision", "nemotron_h", "laguna", "evabyte", "get_model"]
+__all__ = ["vision", "nemotron_h", "laguna", "evabyte", "joyai", "get_model"]

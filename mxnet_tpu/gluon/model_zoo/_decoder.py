@@ -1,10 +1,13 @@
 """What the zoo's decoder stacks share (`nemotron_h.py`, `laguna.py`,
-`evabyte.py`): the pre-norm residual sub-layer, the bias-free projection,
-the gated MLP, the final norm and untied head, and the rule that named
-parameters keep float32 under `cast`.  A norm's `offset` is added to its
-stored gain (1: the unit offset, the gain stored from zero)."""
+`evabyte.py`, `joyai.py`): the pre-norm residual sub-layer, the bias-free
+projection, the gated MLP, a layer's MLP half (dense, or the gated
+mixture of experts with a shared expert), the final norm and untied
+head, and the rule that named parameters keep float32 under `cast`.  A
+norm's `offset` is added to its stored gain (1: the unit offset, the
+gain stored from zero)."""
 from __future__ import annotations
 
+from ...base import MXNetError
 from ..block import HybridBlock
 
 FP32 = "float32"
@@ -59,6 +62,87 @@ class Layer(HybridBlock):
     def hybrid_forward(self, F, x, norm_weight, **params):
         return norm_residual(F, x, norm_weight, self._eps, self.mix,
                              offset=self._offset, **params)
+
+
+class MLPLayer(Layer):
+    """A layer whose second pre-norm sub-layer is a dense gated MLP or a
+    gated mixture of experts with a shared expert (`laguna.py`,
+    `joyai.py`); subclasses bring the first sub-layer and call
+    `_mlp_params` last in their `name_scope`, `mlp` last in their
+    `hybrid_forward`.  A sparse layer HOLDS `experts_held` of the
+    `num_experts` its router scores (ids from `first_expert`) and tells
+    `moe_experts` the rows it expects under even routing."""
+
+    _FLOAT32 = ("router_weight", "router_bias")
+
+    def _matrix(self, name, shape):
+        setattr(self, name, self.params.get(name, shape=shape))
+
+    def _gated(self, name, width):
+        self._matrix(f"{name}_gate_weight", (width, self._hidden))
+        self._matrix(f"{name}_up_weight", (width, self._hidden))
+        self._matrix(f"{name}_down_weight", (self._hidden, width))
+
+    def _mlp_params(self, mlp_size=None, num_experts=0, top_k=0,
+                    expert_size=0, shared_size=0, scale=1.0,
+                    experts_held=None, first_expert=0):
+        """`mlp_size`: the dense MLP's width; None: the expert layer,
+        `num_experts` scored, `top_k` chosen, `expert_size` and
+        `shared_size` wide."""
+        d = self._hidden
+        self._sparse = mlp_size is None
+        self.mlp_norm_weight = self.params.get(
+            "mlp_norm_weight", shape=(d,), init="ones")
+        if not self._sparse:
+            self._gated("mlp", mlp_size)
+            return
+        held = num_experts if experts_held is None else experts_held
+        if first_expert + held > num_experts:
+            raise MXNetError(
+                f"experts {first_expert}..{first_expert + held - 1} "
+                f"of {num_experts}")
+        self._top_k, self._scale = top_k, float(scale)
+        self._held, self._first = held, first_expert
+        # a token's assignments that land here under even routing
+        self._held_share = top_k * held / num_experts
+        self.router_weight = self.params.get(
+            "router_weight", shape=(num_experts, d), dtype=FP32)
+        # moves the selection only, never trained by the optimizer
+        self.router_bias = self.params.get(
+            "router_bias", shape=(num_experts,), dtype=FP32,
+            init="zeros", grad_req="null")
+        # gate and up side by side: one grouped product for both
+        self._matrix("experts_w1", (held, d, 2 * expert_size))
+        self._matrix("experts_w2", (held, expert_size, d))
+        self._gated("shared", shared_size)
+
+    def mlp(self, F, h, mlp_norm_weight, **params):
+        """h + MLP(RMSNorm(h)), and from a sparse layer [rows of each
+        held expert..., dropped] beside it."""
+        return norm_residual(F, h, mlp_norm_weight, self._eps,
+                             self.experts if self._sparse else self.dense,
+                             **params)
+
+    def dense(self, F, u, mlp_gate_weight, mlp_up_weight, mlp_down_weight):
+        return gated_mlp(F, u, mlp_gate_weight, mlp_up_weight,
+                         mlp_down_weight)
+
+    def experts(self, F, u, router_weight, router_bias, experts_w1,
+                experts_w2, shared_gate_weight, shared_up_weight,
+                shared_down_weight):
+        b, s = u.shape[0], u.shape[1]
+        tokens = F.reshape(u, shape=(b * s, self._hidden))
+        token, weight, group_sizes, dropped = F.moe_route(
+            tokens, router_weight, router_bias, top_k=self._top_k,
+            scale=self._scale, first_expert=self._first,
+            num_local=self._held)
+        out = F.moe_experts(tokens, token, weight, group_sizes, experts_w1,
+                            experts_w2, form="silu_gated",
+                            expected_rows=int(b * s * self._held_share)) \
+            + gated_mlp(F, tokens, shared_gate_weight, shared_up_weight,
+                        shared_down_weight)
+        stats = F.concat(group_sizes, F.reshape(dropped, shape=(1,)), dim=0)
+        return F.reshape(out, shape=(b, s, self._hidden)), stats
 
 
 class Head(HybridBlock):

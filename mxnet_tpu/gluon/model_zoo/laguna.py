@@ -27,7 +27,8 @@ computes everything else again.  The rotary tables are made once a
 forward pass, in float32, and handed to every layer.
 
 A sparse layer HOLDS `experts_held` of the `num_experts` the router
-scores (ids from `first_expert`), as `nemotron_h.LatentMoELayer` does.
+scores (ids from `first_expert`), as `nemotron_h.LatentMoELayer` does
+(`_decoder.MLPLayer`, which `joyai.py` shares).
 """
 from __future__ import annotations
 
@@ -35,31 +36,27 @@ from ...base import MXNetError
 from ...ops import rotary
 from .. import nn
 from ..block import HybridBlock
-from ._decoder import FP32, Head, Layer, gated_mlp, norm_residual, project
+from ._decoder import Head, MLPLayer, norm_residual, project
 
 __all__ = ["LagunaModel", "LagunaLayer"]
 
 KINDS = ("full_attention", "sliding_attention")
 
 
-class LagunaLayer(Layer):
+class LagunaLayer(MLPLayer):
     """Attention (full or windowed, rotary, gated a head), then a dense
     gated MLP or the expert layer.  forward(h, cos, sin) -> h, or (h,
     [rows of each held expert..., dropped]) from a sparse layer."""
-
-    _FLOAT32 = ("router_weight", "router_bias")
 
     def __init__(self, hidden_size, num_heads, num_kv_heads, head_dim,
                  eps, window=None, mlp_size=None, num_experts=0, top_k=0,
                  expert_size=0, shared_size=0, scale=1.0,
                  experts_held=None, first_expert=0, **kwargs):
-        """`window` None: full causal attention.  `mlp_size`: the dense
-        MLP's width; None: the expert layer, `num_experts` scored,
-        `top_k` chosen, `expert_size` and `shared_size` wide."""
+        """`window` None: full causal attention.  The rest:
+        `MLPLayer._mlp_params`."""
         super().__init__(hidden_size, eps, **kwargs)
         self._heads, self._kv_heads = num_heads, num_kv_heads
         self._attn_scale, self._window = head_dim ** -0.5, window
-        self._sparse = mlp_size is None
         d = hidden_size
         with self.name_scope():
             for name, rows in (("q", num_heads * head_dim),
@@ -68,38 +65,8 @@ class LagunaLayer(Layer):
                                ("gate", num_heads)):
                 self._matrix(f"{name}_proj_weight", (rows, d))
             self._matrix("o_proj_weight", (d, num_heads * head_dim))
-            self.mlp_norm_weight = self.params.get(
-                "mlp_norm_weight", shape=(d,), init="ones")
-            if not self._sparse:
-                self._gated("mlp", mlp_size)
-                return
-            held = num_experts if experts_held is None else experts_held
-            if first_expert + held > num_experts:
-                raise MXNetError(
-                    f"experts {first_expert}..{first_expert + held - 1} "
-                    f"of {num_experts}")
-            self._top_k, self._scale = top_k, float(scale)
-            self._held, self._first = held, first_expert
-            # a token's assignments that land here under even routing
-            self._held_share = top_k * held / num_experts
-            self.router_weight = self.params.get(
-                "router_weight", shape=(num_experts, d), dtype=FP32)
-            # moves the selection only, never trained by the optimizer
-            self.router_bias = self.params.get(
-                "router_bias", shape=(num_experts,), dtype=FP32,
-                init="zeros", grad_req="null")
-            # gate and up side by side: one grouped product for both
-            self._matrix("experts_w1", (held, d, 2 * expert_size))
-            self._matrix("experts_w2", (held, expert_size, d))
-            self._gated("shared", shared_size)
-
-    def _matrix(self, name, shape):
-        setattr(self, name, self.params.get(name, shape=shape))
-
-    def _gated(self, name, width):
-        self._matrix(f"{name}_gate_weight", (width, self._hidden))
-        self._matrix(f"{name}_up_weight", (width, self._hidden))
-        self._matrix(f"{name}_down_weight", (self._hidden, width))
+            self._mlp_params(mlp_size, num_experts, top_k, expert_size,
+                             shared_size, scale, experts_held, first_expert)
 
     def hybrid_forward(self, F, x, cos, sin, norm_weight, q_proj_weight,
                        k_proj_weight, v_proj_weight, gate_proj_weight,
@@ -107,9 +74,7 @@ class LagunaLayer(Layer):
         h = norm_residual(F, x, norm_weight, self._eps, self.attend, cos,
                           sin, q_proj_weight, k_proj_weight, v_proj_weight,
                           gate_proj_weight, o_proj_weight)
-        return norm_residual(F, h, mlp_norm_weight, self._eps,
-                             self.experts if self._sparse else self.dense,
-                             **mlp)
+        return self.mlp(F, h, mlp_norm_weight, **mlp)
 
     def attend(self, F, u, cos, sin, q_proj_weight, k_proj_weight,
                v_proj_weight, gate_proj_weight, o_proj_weight):
@@ -132,27 +97,6 @@ class LagunaLayer(Layer):
         out = F.reshape(out, shape=(b, s, self._heads, -1)) \
             * F.expand_dims(gate, axis=3)
         return project(F, F.reshape(out, shape=(b, s, -1)), o_proj_weight)
-
-    def dense(self, F, u, mlp_gate_weight, mlp_up_weight, mlp_down_weight):
-        return gated_mlp(F, u, mlp_gate_weight, mlp_up_weight,
-                         mlp_down_weight)
-
-    def experts(self, F, u, router_weight, router_bias, experts_w1,
-                experts_w2, shared_gate_weight, shared_up_weight,
-                shared_down_weight):
-        b, s = u.shape[0], u.shape[1]
-        tokens = F.reshape(u, shape=(b * s, self._hidden))
-        token, weight, group_sizes, dropped = F.moe_route(
-            tokens, router_weight, router_bias, top_k=self._top_k,
-            scale=self._scale, first_expert=self._first,
-            num_local=self._held)
-        out = F.moe_experts(tokens, token, weight, group_sizes, experts_w1,
-                            experts_w2, form="silu_gated",
-                            expected_rows=int(b * s * self._held_share)) \
-            + gated_mlp(F, tokens, shared_gate_weight, shared_up_weight,
-                        shared_down_weight)
-        stats = F.concat(group_sizes, F.reshape(dropped, shape=(1,)), dim=0)
-        return F.reshape(out, shape=(b, s, self._hidden)), stats
 
 
 def _inv_freq(head_dim, rope_type="default", rope_theta=10000.0,

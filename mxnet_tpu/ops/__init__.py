@@ -17,6 +17,7 @@ from . import random_ops  # noqa: F401
 from . import contrib  # noqa: F401
 from . import pallas_attention  # noqa: F401
 from . import eva_attention  # noqa: F401
+from . import latent_attention  # noqa: F401
 from . import pallas_convbn  # noqa: F401
 from . import linalg  # noqa: F401
 from . import image_ops  # noqa: F401

@@ -49,6 +49,11 @@ lowered for; no switch of its own):
     earlier window, one softmax over both) counts its routes here too:
     `eva_splash`, the splash kernels over [keys ; summaries] and a mask
     computed in the kernel, and `eva_xla`, its windowed XLA form.
+  * `latent_attention` (ops/latent_attention.py: causal attention whose
+    queries and keys are 192 wide, 64 of every key one rotated vector
+    all heads share, and whose values are 128 wide) counts here too:
+    `latent_splash`, `_attend_causal`'s kernels with a value size of
+    their own, and `latent_xla`.
   * Training with dropout on the probabilities, self-attention shaped as
     BERT's (not causal, sq == sk, a multiple of 128 up to 1024, heads of
     64, 128 or 256 filling whole 128-lane blocks): `_attend_train`, two
@@ -814,15 +819,19 @@ def _repeat_kv(x, heads):
 
 
 def _causal_xla(q, k, v, scale):
-    b, h, s, d = q.shape
-    flat = [x.reshape(b * h, s, d)
+    b, h, s, _ = q.shape
+    flat = [x.reshape(b * h, s, x.shape[-1])
             for x in (q, _repeat_kv(k, h), _repeat_kv(v, h))]
     return dot_product_attention_ref(*flat, None, scale,
-                                     causal=True).reshape(b, h, s, d)
+                                     causal=True).reshape(b, h, s, -1)
 
 
-def _causal_flash_shape(heads, kv_heads, sq, sk, d):
-    return (sq == sk and sq % 128 == 0 and d % 128 == 0
+def _causal_flash_shape(heads, kv_heads, sq, sk, d, d_v=None):
+    """Heads of `d` for queries and keys and of `d_v` for values (None:
+    `d`): the values fill whole 128-lane blocks, queries and keys whole
+    or half ones (latent attention's 192 = 128 + its 64 rotary)."""
+    d_v = d if d_v is None else d_v
+    return (sq == sk and sq % 128 == 0 and d_v % 128 == 0 and d % 64 == 0
             and heads % kv_heads == 0)
 
 
@@ -885,17 +894,19 @@ def _splash_blocks(s, window):
     return rows, rows
 
 
-def _causal_splash(q, k, v, scale, window=None, interpret=False):
+def _causal_splash(q, k, v, scale, window=None, interpret=False,
+                   name=None):
     """Upstream's splash multi-query kernels (forward, dK/dV and dQ under
     their own custom VJP, one online-softmax pass over the blocks the
     mask touches) over the band 0 <= i - j < window, or over the whole
-    causal triangle (`window` None): q (B, H, S, D), k and v (B, Hkv, S,
-    D).  The H // Hkv query heads of one key/value head are one
-    multi-query call, vmapped over batch and key/value heads: k and v
-    go in as they are, and dK, dV come out summed over the group.  The
-    forward rule names its output and its (H, S) float32 logsumexp by
-    the route, so that a recomputed segment keeps them
-    (ops/residuals.py)."""
+    causal triangle (`window` None): q (B, H, S, D), k (B, Hkv, S, D)
+    and v (B, Hkv, S, Dv), Dv the output's head size.  The H // Hkv
+    query heads of one key/value head are one multi-query call, vmapped
+    over batch and key/value heads: k and v go in as they are, and dK,
+    dV come out summed over the group.  The forward rule names its
+    output and its (H, S) float32 logsumexp by the route (`name`; None:
+    `flash_causal` or `splash_window` by the mask), so that a recomputed
+    segment keeps them (ops/residuals.py)."""
     from jax.experimental.pallas.ops.tpu import splash_attention as sa
 
     b, h, s, d = q.shape
@@ -910,31 +921,34 @@ def _causal_splash(q, k, v, scale, window=None, interpret=False):
             block_q=rows, block_kv=rows, block_kv_compute=compute,
             block_q_dkv=rows, block_kv_dkv=rows,
             block_kv_dkv_compute=compute, block_q_dq=rows, block_kv_dq=rows),
-        residual_checkpoint_name=("flash_causal" if window is None
-                                  else "splash_window"),
+        residual_checkpoint_name=name or ("flash_causal" if window is None
+                                          else "splash_window"),
         interpret=interpret)
     # the kernels apply no scale of their own; in float32, so that the
     # scale is not rounded to the operands' type before it is applied
     q = (q.astype(jnp.float32) * scale).astype(q.dtype).reshape(
         b, kv, groups, s, d)
-    return jax.vmap(jax.vmap(kernel))(q, k, v).reshape(b, h, s, d)
+    return jax.vmap(jax.vmap(kernel))(q, k, v).reshape(b, h, s, -1)
 
 
-@functools.partial(jax.jit, static_argnames=("scale", "window", "interpret"))
-def _attend_causal(q, k, v, scale, window, interpret):
-    """q (B, H, S, D), k and v (B, Hkv, S, D) -> (B, H, S, D), causal,
-    under a sliding `window` or none (None): the splash kernels in a
-    program lowered for the TPU (or anywhere under the interpreter), the
-    XLA form elsewhere, as `_attend` chooses; autodiff goes through the
-    chosen branch.  Jitted, so that a stack of layers traces and lowers
-    the kernels once a kind of layer."""
+@functools.partial(jax.jit, static_argnames=("scale", "window", "interpret",
+                                             "name"))
+def _attend_causal(q, k, v, scale, window, interpret, name=None):
+    """q (B, H, S, D), k (B, Hkv, S, D) and v (B, Hkv, S, Dv) -> (B, H,
+    S, Dv), causal, under a sliding `window` or none (None): the splash
+    kernels in a program lowered for the TPU (or anywhere under the
+    interpreter), the XLA form elsewhere, as `_attend` chooses; autodiff
+    goes through the chosen branch.  Jitted, so that a stack of layers
+    traces and lowers the kernels once a kind of layer."""
     if interpret:
-        return _causal_splash(q, k, v, scale, window, interpret=True)
+        return _causal_splash(q, k, v, scale, window, interpret=True,
+                              name=name)
     xla = (functools.partial(_causal_xla, scale=scale) if window is None
            else functools.partial(_window_xla, scale=scale, window=window))
     return jax.lax.platform_dependent(
         q, k, v, default=xla,
-        tpu=functools.partial(_causal_splash, scale=scale, window=window))
+        tpu=functools.partial(_causal_splash, scale=scale, window=window,
+                              name=name))
 
 
 # Routes CHOSEN, counted where the branch is chosen: at TRACE time (once a
@@ -945,7 +959,8 @@ def _attend_causal(q, k, v, scale, window, interpret):
 # store; the telemetry counter `mx_attention_route_total{route}` is its
 # export and counts only while telemetry is enabled.
 ROUTES = ("fused_train", "xla_dropout", "kernel_infer", "reference",
-          "flash_causal", "splash_window", "eva_splash", "eva_xla")
+          "flash_causal", "splash_window", "eva_splash", "eva_xla",
+          "latent_splash", "latent_xla")
 _route_counts = dict.fromkeys(ROUTES, 0)
 
 

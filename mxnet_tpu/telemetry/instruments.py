@@ -147,7 +147,9 @@ _spec("mx_attention_route_total", "counter",
       "over a causal mask, "
       "splash_window = sliding_window_attention's splash kernels, "
       "eva_splash / eva_xla = eva_attention's splash kernels over keys "
-      "and summaries, or its windowed XLA form): "
+      "and summaries, or its windowed XLA form, latent_splash / "
+      "latent_xla = latent_attention's splash kernels with a value size "
+      "of their own, or its XLA form): "
       "counted once a compiled program, never per "
       "step. fused_train over fused_train + xla_dropout is the share of "
       "training attention that engaged the kernels.", ("route",))

@@ -575,3 +575,57 @@ def test_eva_routes_compile_for_v5e_ahead_of_time():
         pytest.skip(p.stdout.strip()[:200])
     assert p.returncode == 0 and "AOT_OK" in p.stdout, \
         p.stdout[-2000:] + p.stderr[-3000:]
+
+
+_AOT_LATENT = r"""
+import re, sys
+import jax, jax.numpy as jnp
+from jax.experimental import topologies
+from jax.sharding import SingleDeviceSharding
+try:
+    topo = topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
+except Exception as e:
+    print("NO_TPU_COMPILER", type(e).__name__, e)
+    sys.exit(0)
+from mxnet_tpu.ops import pallas_attention as pa
+from mxnet_tpu.ops.registry import apply_pure
+from mxnet_tpu.parallel.spmd import _whole_instructions
+sh = SingleDeviceSharding(topo.devices[0])
+arg = lambda shape, dt=jnp.bfloat16: jax.ShapeDtypeStruct(
+    shape, dt, sharding=sh)
+
+b, s, h, nope, rope, vd = 2, 8192, 32, 128, 64, 128
+def loss(q, k_nope, k_rope, v):
+    o = apply_pure("latent_attention", q, k_nope, k_rope, v, num_heads=h)
+    return o.astype(jnp.float32).sum()
+compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3))).lower(
+    arg((b, s, h * (nope + rope))), arg((b, s, h * nope)),
+    arg((b, s, rope)), arg((b, s, h * vd))).compile()
+calls = [ln for ln in _whole_instructions(compiled.as_text())
+         if 'custom_call_target="tpu_custom_call"' in ln]
+names = [re.search(r'op_name="([^"]*)"', ln).group(1) for ln in calls]
+print("MOSAIC latent", names)
+assert len(names) == 3, names       # forward, dK/dV, dQ
+assert all("latent_attention" in n for n in names), names
+assert sum("transpose(" in n for n in names) == 2, names
+assert pa.route_counts()["latent_splash"] == 1, pa.route_counts()
+assert pa.route_counts()["latent_xla"] == 0, pa.route_counts()
+# O(S): dense (2, 32, 8192, 8192) float32 scores would be 16 GiB
+assert compiled.memory_analysis().temp_size_in_bytes < 4 * 2 ** 30
+print("AOT_OK")
+"""
+
+
+def test_latent_attention_route_compiles_for_v5e_ahead_of_time():
+    """`latent_attention` at `joyai_llm_flash_s8192`'s shapes (2 x 8192
+    positions, 32 heads of 192 for queries and keys, 64 of every key one
+    shared vector, and of 128 for values), value and the four gradients:
+    Mosaic takes the splash kernels at a value size of their own
+    (forward, dK/dV, dQ), every call keeps the op scope and, in the
+    backward, `transpose(`: what `mla_attention_device_ms` is read by."""
+    p = _run(["-c", _AOT_LATENT], timeout=300)
+    if "NO_TPU_COMPILER" in p.stdout:
+        pytest.skip(p.stdout.strip()[:200])
+    assert p.returncode == 0 and "AOT_OK" in p.stdout, \
+        p.stdout[-2000:] + p.stderr[-3000:]

@@ -38,7 +38,12 @@ ROUTES = {
     "eva_splash": ("splash_mha_fwd_residuals",
                    ("splash_mha_dkv_no_residuals",
                     "splash_mha_dq_no_residuals")),
+    # latent attention: `flash_causal`'s kernels, one query head a key
+    "latent_splash": ("splash_mqa_fwd_residuals",
+                      ("splash_mqa_dkv_no_residuals",
+                       "splash_mqa_dq_no_residuals")),
 }
+ROPE = 64      # the latent queries' and keys' rotary part, beside D
 
 
 class Layer(HybridBlock):
@@ -48,23 +53,28 @@ class Layer(HybridBlock):
     def __init__(self, route, **kwargs):
         super().__init__(**kwargs)
         self._route = route
-        # eva_attention has no grouped-query form
-        self._kv = H if route == "eva_splash" else H_KV
+        # eva_attention and latent_attention have no grouped-query form
+        self._kv = H_KV if route in ("flash_causal", "splash_window") else H
+        q_size = D + ROPE if route == "latent_splash" else D
         with self.name_scope():
-            for name, rows in (("q", H), ("k", self._kv), ("v", self._kv),
-                               ("o", H)):
+            for name, rows in (("q", H * q_size), ("k", self._kv * D),
+                               ("v", self._kv * D), ("o", H * D),
+                               ("k_rope", ROPE)):
                 setattr(self, name, self.params.get(
-                    name, shape=(rows * D, H * D),
+                    name, shape=(rows, H * D),
                     init=mx.initializer.Normal(0.05)))
             for name in ("phi", "mu"):
                 setattr(self, name, self.params.get(
                     name, shape=(H, D), init=mx.initializer.Normal(0.05)))
 
-    def hybrid_forward(self, F, x, q, k, v, o, phi, mu):
-        q, k, v = (F.FullyConnected(x, w, no_bias=True, flatten=False,
-                                    num_hidden=w.shape[0])
-                   for w in (q, k, v))
-        if self._route == "flash_causal":
+    def hybrid_forward(self, F, x, q, k, v, o, k_rope, phi, mu):
+        q, k, v, k_rope = (F.FullyConnected(x, w, no_bias=True,
+                                            flatten=False,
+                                            num_hidden=w.shape[0])
+                           for w in (q, k, v, k_rope))
+        if self._route == "latent_splash":
+            out = F.latent_attention(q, k, k_rope, v, num_heads=H)
+        elif self._route == "flash_causal":
             out = F.dot_product_attention(q, k, v, None, causal=True,
                                           num_heads=H, num_kv_heads=self._kv)
         elif self._route == "splash_window":
