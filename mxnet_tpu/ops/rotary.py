@@ -13,6 +13,22 @@ of that kind.  r may be less than the head size: the other dimensions
 pass through, those past r or, with `rotate_last`, those before the
 last r (a latent-attention query is [unrotated ; rotated]).  Query and
 key may differ in head size; a key all heads share is `num_kv_heads=1`.
+
+Which operand takes which form (`route_counts()`; chosen at trace time
+from what the op can observe in its arguments, one operand at a time):
+
+  * `kernel`: `_turn`, one Pallas pass that reads x once and writes it
+    once in its own dtype, float32 only in VMEM, with a backward rule of
+    its own (the same pass on the cotangent).  In a program lowered for
+    the TPU (`jax.lax.platform_dependent`; everywhere under
+    MXNET_PALLAS_INTERPRET=1), for heads of a whole number of 64 lanes
+    and a sequence that is a multiple of a row block.  A program
+    lowered for the CPU runs `_rotate` in its place.
+  * `xla`: `_rotate`, the product with a signed permutation in plain
+    XLA, whose float32 product and sum XLA writes to HBM and reads back
+    (4-6 times the bytes of one pass: PERF.md, PR 40): every other shape,
+    a mesh of several devices (GSPMD cannot partition a Mosaic call),
+    MXNET_USE_PALLAS=0.
 """
 from __future__ import annotations
 
@@ -24,9 +40,13 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+from ..telemetry import instruments as _instruments
+from ..util import env
+from .pallas_attention import _mesh_batch_axes
 from .registry import register_op
 
-__all__ = ["default_inv_freq", "yarn_inv_freq", "rotary_tables"]
+__all__ = ["default_inv_freq", "yarn_inv_freq", "rotary_tables",
+           "route_counts"]
 
 
 def default_inv_freq(theta: float, rotary_dim: int) -> np.ndarray:
@@ -86,12 +106,17 @@ def _partner(d: int, r: int, interleaved: bool = False,
 @functools.partial(jax.jit, static_argnames=("heads", "interleaved",
                                              "rotate_last"))
 def _rotate(x, cos, sin, heads, interleaved=False, rotate_last=False):
-    """x (B, S, heads * D): r = cos.shape[-1] dimensions of each head
-    rotated, in float32, the rest passed through.  The partner of every
-    dimension comes from a product with a signed permutation (exact: one
-    term a sum), so that nothing is sliced or concatenated along the
-    lanes: on the v5e the sliced form took 3.1-3.7 times as long
-    (PERF.md, PR 31)."""
+    """The XLA form: what runs on the CPU, under a mesh and for shapes
+    the kernel cannot tile.  x (B, S, heads * D): r = cos.shape[-1]
+    dimensions of each head rotated, in float32, the rest passed through.
+    The partner of every dimension comes from a product with a signed
+    permutation (exact: one term a sum), so that nothing is sliced or
+    concatenated along the lanes: on the v5e the sliced form took 3.1-3.7
+    times as long (PERF.md, PR 31), which is why this form, and the
+    kernel's body after it, is a product.  XLA writes the float32
+    product to HBM and a second fusion reads it back, and autodiff's
+    transpose does the same on a float32 cotangent: `_turn` keeps both
+    in VMEM."""
     b, s, u = x.shape
     d, r = u // heads, cos.shape[-1]
     x = x.reshape(b, s, heads, d)
@@ -105,6 +130,196 @@ def _rotate(x, cos, sin, heads, interleaved=False, rotate_last=False):
     sin = jnp.pad(sin, ((0, 0), still))
     out = x.astype(jnp.float32) * cos[:, None, :] + partner * sin[:, None, :]
     return out.astype(x.dtype).reshape(b, s, u)
+
+
+# ---- the kernel --------------------------------------------------------
+#
+# One pass over x seen head-major, (B, heads, S, D): a block of rows of all
+# heads is read once, every head is turned in float32 with `_rotate`'s own
+# arithmetic (`x * cos + (x @ P) * sin`, the product on the MXU from the
+# block in VMEM: it never reaches HBM), rounded once and written once.  The
+# tables are read as (rows, r) blocks as they came and, in the kernel, laid
+# over a head's D lanes once a block and broadcast over the heads.  Where
+# whole 128-lane tiles of a head pass through (the first 128 of a latent
+# query's 192), the call reads and writes the other tiles alone, in place.
+# Head-major because that is what XLA makes of this op's operands and
+# results between a projection and an attention kernel anyway: the
+# transposes around the call are views of (B, S, heads * D) that layout
+# assignment turns into bitcasts, where a kernel over rows of heads * D
+# lanes would force a copy on either side (PERF.md, PR 40).  The backward
+# is the same body on the cotangent: `g * cos + (g * sin) P^T` written as
+# `g * cos + (g @ P) * t` with t the negated sine of each lane's partner.
+
+_BLOCK_BYTES = 2 << 20      # of x a grid step: ~5 us of HBM beside its ~0.35 fixed
+_MAX_ROWS = 1024
+
+
+def _lane_window(d, r, rotate_last):
+    """(first lane, lanes) of what the kernel reads and writes of a head:
+    the 128-lane tiles that hold rotated lanes, where they make a block
+    of their own (the last 64 of 192: the second tile, half of it past
+    the head's end), else the whole head.  The other tiles are never
+    touched: the result is written over the operand."""
+    first = d - r if rotate_last else 0
+    t0, t1 = first // 128, -(-(first + r) // 128)
+    if t1 - t0 < -(-d // 128) and t0 % (t1 - t0) == 0:
+        return t0 * 128, (t1 - t0) * 128
+    return 0, d
+
+
+def _tiling(s, heads, d, r, rotate_last, itemsize):
+    """(rows a block, first lane, lanes): all heads of as many positions
+    as `_BLOCK_BYTES` hold, of a head its `_lane_window`.  None where the
+    kernel does not apply: a head that fills no whole number of 64 lanes,
+    a sequence no multiple of a row block, nothing to turn."""
+    if d % 64 or not r:
+        return None
+    lo, lanes = _lane_window(d, r, rotate_last)
+    held = -(-lanes // 128) * 128       # what VMEM holds of a row
+    rows = _MAX_ROWS
+    while rows >= 16 and (s % rows
+                          or heads * rows * held * itemsize > _BLOCK_BYTES):
+        rows //= 2
+    return (rows, lo, lanes) if rows >= 16 else None
+
+
+def _turn_kernel(x_ref, cos_ref, sin_ref, p_ref, *rest, valid):
+    *spread_ref, o_ref = rest
+    p = p_ref[...]
+    exact = lax.Precision.HIGHEST if p.dtype == jnp.float32 else None
+    cos, sin = cos_ref[...], sin_ref[...]
+    if spread_ref:
+        # the (rows, r) tables laid over the block's lanes by products
+        # with 0 / 1 / -1 matrices, exact in float32: cos with 1 where a
+        # lane passes through, sin with 0 there
+        place, place_sin = spread_ref[0][0], spread_ref[0][1]
+        still = 1.0 - jnp.sum(place, axis=0, keepdims=True)
+        cos = jnp.dot(cos, place, precision=lax.Precision.HIGHEST) + still
+        sin = jnp.dot(sin, place_sin, precision=lax.Precision.HIGHEST)
+    lanes = x_ref.shape[3]
+    inside = valid == lanes or \
+        lax.broadcasted_iota(jnp.int32, x_ref.shape[2:], 1) < valid
+
+    def turn(h, _):
+        x = x_ref[0, h]
+        if valid < lanes:       # past the head's end a block holds anything
+            x = jnp.where(inside, x, jnp.zeros_like(x))
+        partner = jnp.dot(x, p, preferred_element_type=jnp.float32,
+                          precision=exact)
+        o_ref[0, h] = (x.astype(jnp.float32) * cos + partner * sin
+                       ).astype(o_ref.dtype)
+
+    lax.fori_loop(0, x_ref.shape[1], turn, None)
+
+
+def _turn_pass(x, cos, sin, heads, interleaved, rotate_last, interpret,
+               transpose):
+    from jax.experimental import pallas as pl
+
+    b, s, u = x.shape
+    d, r = u // heads, cos.shape[-1]
+    rows, lo, lanes = _tiling(s, heads, d, r, rotate_last, x.dtype.itemsize)
+    valid = min(lanes, d - lo)
+    p = np.zeros((lanes, lanes), np.float32)
+    p[:valid, :valid] = _partner(d, r, interleaved, rotate_last)[
+        lo:lo + valid, lo:lo + valid]
+    table = pl.BlockSpec((rows, r), lambda i, j: (j, 0))
+    block = pl.BlockSpec((1, heads, rows, lanes),
+                         lambda i, j: (i, 0, j, lo // lanes))
+    operands = [x.reshape(b, s, heads, d).transpose(0, 2, 1, 3), cos, sin,
+                jnp.asarray(p, x.dtype)]
+    in_specs = [block, table, table,
+                pl.BlockSpec(p.shape, lambda i, j: (0, 0))]
+    if transpose or r < lanes:
+        first = d - r if rotate_last else 0
+        place = np.eye(r, lanes, first - lo, dtype=np.float32)
+        # P^T = -P: in the backward each lane takes its partner's -sin
+        spread = np.stack([place, place @ -np.abs(p) if transpose else place])
+        operands.append(jnp.asarray(spread))
+        in_specs.append(pl.BlockSpec(spread.shape, lambda i, j: (0, 0, 0)))
+    out = pl.pallas_call(
+        functools.partial(_turn_kernel, valid=valid),
+        grid=(b, s // rows),
+        in_specs=in_specs,
+        out_specs=block,
+        out_shape=jax.ShapeDtypeStruct((b, heads, s, d), x.dtype),
+        # the lanes outside the block are the operand's own
+        input_output_aliases={0: 0} if lanes < d else {},
+        interpret=interpret,
+        name="mx_rotary_turn",      # the kernel's name in a device profile
+    )(*operands)
+    return out.transpose(0, 2, 1, 3).reshape(b, s, u)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _turn(x, cos, sin, heads, interleaved, rotate_last, interpret):
+    """`_rotate`'s function as one Pallas pass, x's dtype in and out,
+    float32 inside; the backward is the same pass on the cotangent, in
+    the dtype it arrives in.  The tables get no gradient."""
+    return _turn_pass(x, cos, sin, heads, interleaved, rotate_last,
+                      interpret, transpose=False)
+
+
+def _turn_fwd(x, cos, sin, *statics):
+    return _turn(x, cos, sin, *statics), (cos, sin)
+
+
+def _turn_bwd(heads, interleaved, rotate_last, interpret, tables, g):
+    return (_turn_pass(g, *tables, heads, interleaved, rotate_last,
+                       interpret, transpose=True), None, None)
+
+
+_turn.defvjp(_turn_fwd, _turn_bwd)
+
+
+@functools.partial(jax.jit, static_argnames=("heads", "interleaved",
+                                             "rotate_last", "interpret"))
+def _rotate_on_tpu(x, cos, sin, heads, interleaved, rotate_last, interpret):
+    """The kernel in a program lowered for the TPU (everywhere under the
+    interpreter), `_rotate` elsewhere, as `pallas_attention._attend`
+    chooses; autodiff goes through the chosen branch.  Jitted, so that a
+    stack of layers traces and lowers the kernel once a kind of layer."""
+    pairing = dict(heads=heads, interleaved=interleaved,
+                   rotate_last=rotate_last)
+    kernel = functools.partial(_turn, **pairing, interpret=interpret)
+    if interpret:
+        return kernel(x, cos, sin)
+    return jax.lax.platform_dependent(
+        x, cos, sin, tpu=kernel,
+        default=functools.partial(_rotate, **pairing))
+
+
+# Routes CHOSEN, one an operand turned, counted where the branch is chosen:
+# at TRACE time (once a compiled program, never per step), not kernels run:
+# a `kernel` call in a program lowered for the CPU runs `_rotate`.  The
+# telemetry counter `mx_rotary_route_total{route}` is this dict's export.
+ROUTES = ("kernel", "xla")
+_route_counts = dict.fromkeys(ROUTES, 0)
+
+
+def route_counts():
+    """{route: operands traced through it} since import, in the form of
+    `pallas_attention.route_counts()`."""
+    return dict(_route_counts)
+
+
+def _rotate_routed(x, cos, sin, heads, interleaved, rotate_last):
+    """One operand by the kernel where it applies: MXNET_USE_PALLAS, a
+    shape `_tiling` can tile, and no mesh of several devices (GSPMD cannot
+    partition a Mosaic call)."""
+    kernel = (env.get_bool("MXNET_USE_PALLAS")
+              and _mesh_batch_axes(x.shape[0]) is None
+              and _tiling(x.shape[1], heads, x.shape[-1] // heads,
+                          cos.shape[-1], rotate_last,
+                          x.dtype.itemsize) is not None)
+    route = "kernel" if kernel else "xla"
+    _route_counts[route] += 1
+    _instruments.rotary_route_total(route).inc()
+    if not kernel:
+        return _rotate(x, cos, sin, heads=heads, interleaved=interleaved,
+                       rotate_last=rotate_last)
+    return _rotate_on_tpu(x, cos, sin, heads, interleaved, rotate_last,
+                          env.get_bool("MXNET_PALLAS_INTERPRET"))
 
 
 @register_op("rotary_embedding", num_outputs=2)
@@ -124,7 +339,6 @@ def _rotary_embedding(query, key, cos, sin, num_heads=1, num_kv_heads=0,
         raise ValueError(f"rotary_embedding: tables {cos.shape} / "
                          f"{sin.shape} for {query.shape[1]} positions and "
                          f"heads of {d}")
-    pairing = dict(interleaved=bool(interleaved),
-                   rotate_last=bool(rotate_last))
-    return (_rotate(query, cos, sin, heads=num_heads, **pairing),
-            _rotate(key, cos, sin, heads=kv_heads, **pairing))
+    pairing = (bool(interleaved), bool(rotate_last))
+    return (_rotate_routed(query, cos, sin, num_heads, *pairing),
+            _rotate_routed(key, cos, sin, kv_heads, *pairing))

@@ -159,6 +159,19 @@ def attention_route_total(route: str):
     return _child("mx_attention_route_total", (route,))
 
 
+_spec("mx_rotary_route_total", "counter",
+      "Operands of rotary_embedding (a call turns two: query and key) "
+      "TRACED through each route (kernel = the one-pass Pallas rotation "
+      "in a program lowered for the TPU, xla = the signed-permutation "
+      "product: MXNET_USE_PALLAS=0, a mesh of several devices, a shape "
+      "the kernel cannot tile): counted once a compiled program, never "
+      "per step.", ("route",))
+
+
+def rotary_route_total(route: str):
+    return _child("mx_rotary_route_total", (route,))
+
+
 _spec("mx_remat_kept_bytes_total", "counter",
       "Bytes that the recomputed segments TRACED (gradient mirroring: "
       "SPMDTrainer(remat=True), hybridize(mirror=True)) keep for their "

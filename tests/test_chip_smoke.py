@@ -629,3 +629,77 @@ def test_latent_attention_route_compiles_for_v5e_ahead_of_time():
         pytest.skip(p.stdout.strip()[:200])
     assert p.returncode == 0 and "AOT_OK" in p.stdout, \
         p.stdout[-2000:] + p.stderr[-3000:]
+
+
+_AOT_ROTARY = r"""
+import re, sys
+import numpy as np
+import jax, jax.numpy as jnp
+from jax.experimental import topologies
+from jax.sharding import SingleDeviceSharding
+try:
+    topo = topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
+except Exception as e:
+    print("NO_TPU_COMPILER", type(e).__name__, e)
+    sys.exit(0)
+from mxnet_tpu.ops import rotary
+from mxnet_tpu.ops.registry import apply_pure
+from mxnet_tpu.parallel.spmd import _whole_instructions
+sh = SingleDeviceSharding(topo.devices[0])
+arg = lambda shape, dt=jnp.bfloat16: jax.ShapeDtypeStruct(
+    shape, dt, sharding=sh)
+
+# cell's layer kind: B, S, heads, kv heads, D, Dk, r, pairing
+for name, b, s, h, kv, d, dk, r, pairing in (
+        ("laguna_window", 2, 8192, 64, 8, 128, 128, 128, {}),
+        ("laguna_full", 2, 8192, 48, 8, 128, 128, 64, {}),
+        ("evabyte", 1, 32768, 32, 32, 128, 128, 128, {}),
+        ("joyai", 2, 8192, 32, 1, 192, 64, 64,
+         dict(interleaved=True, rotate_last=True))):
+    def turned(q, k, cos, sin):
+        return apply_pure("rotary_embedding", q, k, cos, sin, num_heads=h,
+                          num_kv_heads=kv, **pairing)
+    def both(q, k, cos, sin, gq, gk):
+        out, vjp = jax.vjp(lambda q, k: turned(q, k, cos, sin), q, k)
+        return out, vjp((gq, gk))
+    before = rotary.route_counts()
+    q, k = arg((b, s, h * d)), arg((b, s, kv * dk))
+    table = arg((s, r), jnp.float32)
+    compiled = jax.jit(both).lower(q, k, table, table, q, k).compile()
+    routes = {key: n - before[key]
+              for key, n in rotary.route_counts().items()}
+    assert routes == {"kernel": 2, "xla": 0}, (name, routes)
+    calls = [ln for ln in _whole_instructions(compiled.as_text())
+             if 'custom_call_target="tpu_custom_call"' in ln]
+    names = [re.search(r'op_name="([^"]*)"', ln).group(1) for ln in calls]
+    print("MOSAIC", name, names)
+    # q and k, forward and backward: one pass each
+    assert len(names) == 4, (name, names)
+    assert all("rotary_embedding" in n and "mx_rotary_turn" in n
+               for n in names), (name, names)
+    assert sum("transpose(" in n for n in names) == 2, (name, names)
+    # nothing of q's size in float32 reaches HBM
+    text = compiled.as_text()
+    entry = text[text.index("\nENTRY "):]
+    wide = [m for m in re.findall(r"f32\[([\d,]+)\]", entry)
+            if np.prod([int(n) for n in m.split(",")]) >= b * s * h * d]
+    assert not wide, (name, wide[:3])
+print("AOT_OK")
+"""
+
+
+def test_rotary_kernel_compiles_for_v5e_ahead_of_time():
+    """`rotary_embedding` at the shapes of the three decoder cells' four
+    layer kinds, value and gradient of query and key under cotangents of
+    their own: Mosaic takes the rotation kernel for all four pairings
+    (whole head, part of it, interleaved on the last 64 of 192 in place
+    over the second lane tile, the one shared key of 64), every operand takes the `kernel`
+    route, every call keeps the op scope (`rotary_device_ms` reads it)
+    and, in the backward, `transpose(`; and no float32 array as large
+    as the queries is written to HBM (the XLA form writes two)."""
+    p = _run(["-c", _AOT_ROTARY], timeout=300)
+    if "NO_TPU_COMPILER" in p.stdout:
+        pytest.skip(p.stdout.strip()[:200])
+    assert p.returncode == 0 and "AOT_OK" in p.stdout, \
+        p.stdout[-2000:] + p.stderr[-3000:]
