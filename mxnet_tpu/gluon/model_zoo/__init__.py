@@ -4,6 +4,8 @@ from . import nemotron_h
 from . import laguna
 from . import evabyte
 from . import joyai
+from . import lfm2
 from .vision import get_model
 
-__all__ = ["vision", "nemotron_h", "laguna", "evabyte", "joyai", "get_model"]
+__all__ = ["vision", "nemotron_h", "laguna", "evabyte", "joyai", "lfm2",
+           "get_model"]

@@ -1,10 +1,11 @@
 """What the zoo's decoder stacks share (`nemotron_h.py`, `laguna.py`,
-`evabyte.py`, `joyai.py`): the pre-norm residual sub-layer, the bias-free
-projection, the gated MLP, a layer's MLP half (dense, or the gated
-mixture of experts with a shared expert), the final norm and untied
-head, and the rule that named parameters keep float32 under `cast`.  A
-norm's `offset` is added to its stored gain (1: the unit offset, the
-gain stored from zero)."""
+`evabyte.py`, `joyai.py`, `lfm2.py`): the pre-norm residual sub-layer,
+the bias-free projection, the gated MLP, a layer's MLP half (dense, or
+the gated mixture of experts, with a shared expert or without one), the
+final norm and the head (an array of its own, or the embedding's: tied),
+and the rule that named parameters keep float32 under `cast`.  A norm's
+`offset` is added to its stored gain (1: the unit offset, the gain
+stored from zero)."""
 from __future__ import annotations
 
 from ...base import MXNetError
@@ -66,8 +67,9 @@ class Layer(HybridBlock):
 
 class MLPLayer(Layer):
     """A layer whose second pre-norm sub-layer is a dense gated MLP or a
-    gated mixture of experts with a shared expert (`laguna.py`,
-    `joyai.py`); subclasses bring the first sub-layer and call
+    gated mixture of experts, with a shared expert (`laguna.py`,
+    `joyai.py`) or without one (`lfm2.py`: `shared_size` 0); subclasses
+    bring the first sub-layer and call
     `_mlp_params` last in their `name_scope`, `mlp` last in their
     `hybrid_forward`.  A sparse layer HOLDS `experts_held` of the
     `num_experts` its router scores (ids from `first_expert`) and tells
@@ -87,8 +89,8 @@ class MLPLayer(Layer):
                     expert_size=0, shared_size=0, scale=1.0,
                     experts_held=None, first_expert=0):
         """`mlp_size`: the dense MLP's width; None: the expert layer,
-        `num_experts` scored, `top_k` chosen, `expert_size` and
-        `shared_size` wide."""
+        `num_experts` scored, `top_k` chosen, `expert_size` wide, beside
+        a shared expert `shared_size` wide (0: none)."""
         d = self._hidden
         self._sparse = mlp_size is None
         self.mlp_norm_weight = self.params.get(
@@ -114,7 +116,8 @@ class MLPLayer(Layer):
         # gate and up side by side: one grouped product for both
         self._matrix("experts_w1", (held, d, 2 * expert_size))
         self._matrix("experts_w2", (held, expert_size, d))
-        self._gated("shared", shared_size)
+        if shared_size:
+            self._gated("shared", shared_size)
 
     def mlp(self, F, h, mlp_norm_weight, **params):
         """h + MLP(RMSNorm(h)), and from a sparse layer [rows of each
@@ -128,8 +131,7 @@ class MLPLayer(Layer):
                          mlp_down_weight)
 
     def experts(self, F, u, router_weight, router_bias, experts_w1,
-                experts_w2, shared_gate_weight, shared_up_weight,
-                shared_down_weight):
+                experts_w2, **shared):
         b, s = u.shape[0], u.shape[1]
         tokens = F.reshape(u, shape=(b * s, self._hidden))
         token, weight, group_sizes, dropped = F.moe_route(
@@ -138,27 +140,31 @@ class MLPLayer(Layer):
             num_local=self._held)
         out = F.moe_experts(tokens, token, weight, group_sizes, experts_w1,
                             experts_w2, form="silu_gated",
-                            expected_rows=int(b * s * self._held_share)) \
-            + gated_mlp(F, tokens, shared_gate_weight, shared_up_weight,
-                        shared_down_weight)
+                            expected_rows=int(b * s * self._held_share))
+        if shared:
+            out = out + gated_mlp(F, tokens, shared["shared_gate_weight"],
+                                  shared["shared_up_weight"],
+                                  shared["shared_down_weight"])
         stats = F.concat(group_sizes, F.reshape(dropped, shape=(1,)), dim=0)
         return F.reshape(out, shape=(b, s, self._hidden)), stats
 
 
 class Head(HybridBlock):
-    """Final RMSNorm and the untied vocabulary projection; with
+    """Final RMSNorm and the vocabulary projection, by an array of the
+    head's own or by `tied`, the embedding's (vocab_size, hidden_size)
+    Parameter: one array, which the gradients of both uses reach; with
     `logits_dtype` the product's operands are cast to it first (float32
     logits from bfloat16 weights)."""
 
     def __init__(self, hidden_size, vocab_size, eps, norm_offset=0.0,
-                 logits_dtype=None, **kwargs):
+                 logits_dtype=None, tied=None, **kwargs):
         super().__init__(**kwargs)
         self._eps, self._offset, self._dtype = eps, norm_offset, logits_dtype
         with self.name_scope():
             self.norm_weight = self.params.get(
                 "norm_weight", shape=(hidden_size,),
                 init="zeros" if norm_offset else "ones")
-            self.weight = self.params.get(
+            self.weight = tied if tied is not None else self.params.get(
                 "weight", shape=(vocab_size, hidden_size))
 
     def hybrid_forward(self, F, x, norm_weight, weight):

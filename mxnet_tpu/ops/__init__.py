@@ -25,6 +25,7 @@ from . import quantization  # noqa: F401
 from . import ssm  # noqa: F401
 from . import moe  # noqa: F401
 from . import rotary  # noqa: F401
+from . import short_conv  # noqa: F401
 
 __all__ = ["registry", "OP_REGISTRY", "Operator", "apply_pure", "get_op",
            "invoke", "list_ops", "register_op"]

@@ -17,7 +17,8 @@ lowered for; no switch of its own):
     custom_vjp.  Counted `kernel_infer` (`reference` under
     MXNET_USE_PALLAS=0).
   * Causal self-attention with no dropout and no key mask, heads of 128
-    (or a multiple), sq == sk a multiple of 128, training or inference,
+    (or a multiple) or of 64, sq == sk a multiple of 128, training or
+    inference,
     query heads a multiple of the key/value heads (grouped-query):
     `_attend_causal`, upstream's splash multi-query kernels
     (jax.experimental.pallas.ops.tpu.splash_attention: forward, dK/dV
@@ -829,10 +830,12 @@ def _causal_xla(q, k, v, scale):
 def _causal_flash_shape(heads, kv_heads, sq, sk, d, d_v=None):
     """Heads of `d` for queries and keys and of `d_v` for values (None:
     `d`): the values fill whole 128-lane blocks, queries and keys whole
-    or half ones (latent attention's 192 = 128 + its 64 rotary)."""
+    or half ones (latent attention's 192 = 128 + its 64 rotary); or all
+    three are 64 wide, half a block each (upstream's kernels cut their
+    128-lane statistics to the output's 64)."""
     d_v = d if d_v is None else d_v
-    return (sq == sk and sq % 128 == 0 and d_v % 128 == 0 and d % 64 == 0
-            and heads % kv_heads == 0)
+    return (sq == sk and sq % 128 == 0 and heads % kv_heads == 0
+            and (d_v % 128 == 0 and d % 64 == 0 or d == d_v == 64))
 
 
 def _window_xla(q, k, v, scale, window):

@@ -339,6 +339,16 @@ class TestTunedConfigStamp:
         from mxnet_tpu.telemetry import mxprof
 
         mxprof.enable()
+        try:
+            self._dump_carries_the_stamp(mxprof)
+        finally:
+            # the sink is the process's: left attached, `tracing.active()`
+            # stays true for every test this worker runs afterwards
+            env.clear_overlay()
+            mxprof.disable()
+
+    @staticmethod
+    def _dump_carries_the_stamp(mxprof):
         cfg = {"MXNET_ZERO_MIN_SIZE": 4096}
         env.apply_overlay(cfg, fingerprint=autotune.config_fingerprint(
             cfg), source="test-store")

@@ -631,6 +631,92 @@ def test_latent_attention_route_compiles_for_v5e_ahead_of_time():
         p.stdout[-2000:] + p.stderr[-3000:]
 
 
+_AOT_LFM2 = r"""
+import re, sys
+import jax, jax.numpy as jnp
+from jax.experimental import topologies
+from jax.sharding import SingleDeviceSharding
+try:
+    topo = topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
+except Exception as e:
+    print("NO_TPU_COMPILER", type(e).__name__, e)
+    sys.exit(0)
+from mxnet_tpu.ops import pallas_attention as pa
+from mxnet_tpu.ops import rotary
+from mxnet_tpu.ops.registry import apply_pure
+from mxnet_tpu.parallel.spmd import _whole_instructions
+sh = SingleDeviceSharding(topo.devices[0])
+arg = lambda shape, dt=jnp.bfloat16: jax.ShapeDtypeStruct(
+    shape, dt, sharding=sh)
+
+def mosaic(compiled):
+    calls = [ln for ln in _whole_instructions(compiled.as_text())
+             if 'custom_call_target="tpu_custom_call"' in ln]
+    return [re.search(r'op_name="([^"]*)"', ln).group(1) for ln in calls]
+
+b, s, h, kv, d = 2, 8192, 32, 8, 64
+cos = arg((s, d), jnp.float32)
+def loss(q, k, v, cos, sin):
+    q, k = apply_pure("rotary_embedding", q, k, cos, sin, num_heads=h,
+                      num_kv_heads=kv)
+    o = apply_pure("dot_product_attention", q, k, v, None, causal=True,
+                   num_heads=h, num_kv_heads=kv)
+    return o.astype(jnp.float32).sum()
+before, turned = pa.route_counts(), rotary.route_counts()
+compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+    arg((b, s, h * d)), arg((b, s, kv * d)), arg((b, s, kv * d)),
+    cos, cos).compile()
+names = mosaic(compiled)
+print("MOSAIC lfm2", names)
+cores = [n for n in names if "dot_product_attention" in n]
+turns = [n for n in names if "rotary_embedding" in n]
+assert len(cores) == 3, names           # forward, dK/dV, dQ
+assert sum("transpose(" in n for n in cores) == 2, names
+assert len(turns) == 4 and all("mx_rotary_turn" in n for n in turns), names
+after = pa.route_counts()
+assert after["flash_causal"] == before["flash_causal"] + 1, after
+assert after["reference"] == before["reference"], after
+assert after["kernel_infer"] == before["kernel_infer"], after
+assert rotary.route_counts() == {"kernel": turned["kernel"] + 2,
+                                 "xla": turned["xla"]}
+# O(S): dense (2, 32, 8192, 8192) float32 scores would be 16 GiB
+assert compiled.memory_analysis().temp_size_in_bytes < 2 * 2 ** 30
+
+dm = 2048
+conv = jax.jit(lambda x, w: apply_pure("short_conv", x, w)).lower(
+    arg((b, s, 3 * dm)), arg((dm, 3))).compile()
+# one pass: nothing of a stream's size between the operands and y
+assert conv.memory_analysis().temp_size_in_bytes < 2 ** 20, \
+    conv.memory_analysis().temp_size_in_bytes
+assert not mosaic(conv)
+both = jax.jit(lambda x, w, g: jax.vjp(
+    lambda x, w: apply_pure("short_conv", x, w), x, w)[1](g)).lower(
+    arg((b, s, 3 * dm)), arg((dm, 3)), arg((b, s, dm))).compile()
+# the backward lags ONE float32 stream, z (128 MiB), beside a bfloat16 one
+assert both.memory_analysis().temp_size_in_bytes <= 200 * 2 ** 20, \
+    both.memory_analysis().temp_size_in_bytes
+print("AOT_OK")
+"""
+
+
+def test_lfm2_routes_compile_for_v5e_ahead_of_time():
+    """`lfm2_8b_a1b_s8192`'s attention layer at its shapes (2 x 8192
+    positions, 32 query heads over 8 key/value heads of 64): the
+    rotation takes the kernel at d = 64 (four passes: q and k, forward
+    and backward) and the causal core the splash multi-query kernels at
+    64-lane operands (forward, dK/dV, dQ), every call under its op scope
+    and, in the backward, `transpose(`: what `head64_attention_device_ms`
+    is read by; nothing of S x S is planned.  `short_conv` at the cell's
+    streams is plain XLA that plans no temporary in its forward pass and
+    one float32 stream in its backward."""
+    p = _run(["-c", _AOT_LFM2], timeout=300)
+    if "NO_TPU_COMPILER" in p.stdout:
+        pytest.skip(p.stdout.strip()[:200])
+    assert p.returncode == 0 and "AOT_OK" in p.stdout, \
+        p.stdout[-2000:] + p.stderr[-3000:]
+
+
 _AOT_ROTARY = r"""
 import re, sys
 import numpy as np

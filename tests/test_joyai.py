@@ -127,10 +127,11 @@ def test_latent_attention_refuses_keys_that_do_not_fit_the_queries():
                    num_heads=2)
     assert set(residuals.NAMES) <= set(pa.ROUTES)
     assert pa._causal_flash_shape(32, 32, 8192, 8192, 192, 128)
-    # one size as before: whole 128-lane blocks only
+    # one size as before: whole 128-lane blocks, or (PR 41) 64 + 64
     assert pa._causal_flash_shape(48, 8, 8192, 8192, 128)
     assert not pa._causal_flash_shape(48, 8, 8192, 8192, 192)
-    assert not pa._causal_flash_shape(48, 8, 8192, 8192, 64)
+    assert pa._causal_flash_shape(48, 8, 8192, 8192, 64)
+    assert not pa._causal_flash_shape(48, 8, 8192, 8192, 128, 64)
 
 
 def test_latent_projection_is_the_two_low_rank_chains(reference):

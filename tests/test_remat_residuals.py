@@ -238,12 +238,86 @@ def test_the_causal_route_runs_upstreams_kernels_at_the_routes_blocks():
         np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-5)
 
 
+class _Lfm2Segment(HybridBlock):
+    """One `lfm2.Lfm2Layer` (it owns its parameters: one segment) under a
+    container without any, which hands it the rotary tables."""
+
+    def __init__(self, kind, **kwargs):
+        super().__init__(**kwargs)
+        from mxnet_tpu.gluon.model_zoo.lfm2 import Lfm2Layer
+
+        with self.name_scope():
+            self.layer = Lfm2Layer(H * D, 1e-5, kind, num_heads=H * D // 64,
+                                   num_kv_heads=H_KV, mlp_size=64)
+
+    def hybrid_forward(self, F, x):
+        from mxnet_tpu.ops import rotary
+
+        return self.layer(x, *rotary.rotary_tables(
+            rotary.default_inv_freq(1e6, 64), x.shape[1]))
+
+
+def _lfm2_segment(kind):
+    block = _Lfm2Segment(kind)
+    np.random.seed(0)
+    block.initialize(mx.initializer.Normal(0.05))
+    return block
+
+
+def test_a_conv_layers_segment_keeps_nothing_and_is_recomputed_whole(
+        monkeypatch):
+    """`short_conv` is plain XLA with a backward rule of its own: it
+    names nothing, so the layer's segment keeps its input alone and the
+    policy changes nothing of what it runs."""
+    before = residuals.kept_residuals()
+    block = _lfm2_segment("conv")
+    fn, params, x = _gradient(block)
+    kept = _count(jax.make_jaxpr(fn)(params, x).jaxpr)
+    assert residuals.kept_residuals() == before
+    _drop_policy(monkeypatch)
+    fn, params, x = _gradient(block)
+    assert kept == _count(jax.make_jaxpr(fn)(params, x).jaxpr)
+    assert kept["xla:name"] == 0 and kept["xla:remat2"] == 1
+    assert kept["xla:custom_vjp_call"] + kept["xla:custom_vjp_call_jaxpr"] \
+        >= 1                                    # short_conv's own rule
+    assert not any(not k.startswith("xla:") for k in kept)
+
+
+def test_an_attention_layers_segment_at_head_size_64_keeps_o_and_logsumexp(
+        monkeypatch):
+    """The 64-wide grouped causal core takes `flash_causal`'s kernels:
+    the layer's segment (q/k norms, rotation, core, projections, MLP)
+    keeps o and the logsumexp and runs the forward kernel once."""
+    forward, backward = ROUTES["flash_causal"]
+    heads = H * D // 64
+    before = residuals.kept_residuals()["flash_causal"]
+    block = _lfm2_segment("full_attention")
+    fn, params, x = _gradient(block)
+    kept = _count(jax.make_jaxpr(fn)(params, x).jaxpr)
+    after = residuals.kept_residuals()["flash_causal"]
+    _drop_policy(monkeypatch)
+    fn, params, x = _gradient(block)
+    recomputed = _count(jax.make_jaxpr(fn)(params, x).jaxpr)
+    assert (kept[forward], recomputed[forward]) == (1, 2)
+    for name in backward:
+        assert kept[name] == recomputed[name] == 1
+    assert after["values"] - before["values"] == 2
+    assert after["bytes"] - before["bytes"] == B * heads * S * (64 * 4 + 4)
+    # the norms a head and the rotation are computed again
+    for name in ("xla:rsqrt", "xla:dot_general"):
+        assert kept[name] == recomputed[name] > 0, name
+
+
 def test_a_segment_that_names_nothing_lowers_to_the_program_it_did(
         monkeypatch):
     def program():
-        net = nn.HybridSequential()
-        net.add(nn.Dense(16, activation="relu", in_units=8),
-                nn.Dense(4, in_units=16))
+        # named, not numbered: the parameters go in by sorted name, and
+        # Gluon's counter would put `dense10_` before `dense9_` where an
+        # earlier test of the same process left it at 9 (PR 40's run)
+        net = nn.HybridSequential(prefix="net_")
+        net.add(nn.Dense(16, activation="relu", in_units=8,
+                         prefix="net_first_"),
+                nn.Dense(4, in_units=16, prefix="net_second_"))
         np.random.seed(0)
         net.initialize(mx.initializer.Xavier())
         op = CachedOp(net, mirror=True)

@@ -8,6 +8,7 @@ import json
 import os
 import subprocess
 import sys
+import threading
 import time
 
 import numpy as np
@@ -258,10 +259,23 @@ def test_the_steady_step_reads_no_clock(first_step, monkeypatch):
     """With telemetry, profiler and sink off, `step()` and what it calls
     in this package read `perf_counter` not once."""
     _net, trainer, batch, _records, _s0, _s1 = first_step
+    # the three switches are the process's: a test this worker ran before
+    # may have left one on (PR 40's run: an mxprof sink), so they are put
+    # off here, and only the reads `step()` makes are counted: those on
+    # this thread whose caller is a module of the package (another test's
+    # threads read the clock too)
+    monkeypatch.setattr(tracing, "_ENABLED", False)
+    monkeypatch.setattr(tracing, "_SINK", None)
     assert not tracing.active()
-    real, reads = time.perf_counter, []
-    monkeypatch.setattr(time, "perf_counter",
-                        lambda: reads.append(None) or real())
+    real, reads, me = time.perf_counter, [], threading.get_ident()
+
+    def counted():
+        caller = sys._getframe(1).f_globals.get("__name__", "")
+        if threading.get_ident() == me and caller.startswith("mxnet_tpu"):
+            reads.append(caller)
+        return real()
+
+    monkeypatch.setattr(time, "perf_counter", counted)
     trainer.step(*batch).asnumpy()
     assert reads == []
 
