@@ -20,15 +20,27 @@ one its gradient, and nothing in `route` is a scatter (on the TPU a
 scatter runs update by update: the scatter-built plan cost 300 ms of a
 1,445 ms step at 16,384 tokens a layer, PERF.md PR 32).
 
-`rows` is a bound and not the work.  `experts` goes over the plan in
-chunks of `ROW_CHUNK` rows under a trip count it reads from `group_sizes`
-on the device (`plan_chunks`): gather, products, relu^2, combine and
-their gradients touch the chunks that hold assignments and no others, so
-a layer that holds 8 of 512 experts pays for the ~4% of its rows that
-are used and a layer under the worst imbalance pays for all of them.
-A model that expects more than a chunk of assignments says so
-(`expected_rows`), and the chunk grows to hold twice that in one trip
-(`row_chunk`).
+`rows` is a bound and not the work, for every stage.  `experts` goes over
+the plan in chunks of `ROW_CHUNK` rows under a trip count it reads from
+`group_sizes` on the device (`plan_chunks`), so a layer that holds 8 of
+512 experts pays for the ~4% of its rows that are used and a layer under
+the worst imbalance pays for all of them.  A model that expects more than
+a chunk of assignments says so (`expected_rows`), and the chunk grows to
+hold twice that in one trip (`row_chunk`): then the chunk is a bound too,
+about half of it empty, and inside it every stage follows the assignments
+again.  The grouped products skip the empty tail themselves; the gather,
+the activation and the gradients of gather, activation, mask and scale go
+over the chunk in blocks of `row_block(chunk)` rows under a second count
+read from `group_sizes` (`plan_blocks`) and stop after the last block that
+holds an assignment; nothing they make is zero-filled first.  The two
+scatter-adds into (T, K) are the exception, one call a chunk, because that
+is the form in which XLA's scatter is cheapest a row (`experts`).
+
+The two loops, forward and backward, are each ONE traced function for a
+set of operand shapes and dtypes, `form`, chunk and block: a model's
+layers, each a recomputed segment of its own, bind the trace the first of
+them made (`route_counts()["expert_stage_traces"]` counts the real
+traces), and XLA inlines the calls.
 
     route(x, w_router, bias, ...)   -> RoutePlan for the held experts
     experts(u, plan, w1, w2, form, expected_rows)
@@ -52,6 +64,8 @@ elsewhere; `route_counts()` says at trace time which was asked for.
 """
 from __future__ import annotations
 
+import functools
+import math
 from typing import NamedTuple, Optional
 
 import jax
@@ -65,23 +79,34 @@ from ._compat import shard_map_unchecked
 from .mesh import DeviceMesh, current_mesh
 
 __all__ = ["RoutePlan", "route", "experts", "moe_apply", "plan_rows",
-           "plan_chunks", "row_chunk", "route_counts"]
+           "plan_chunks", "plan_blocks", "row_chunk", "row_block",
+           "route_counts"]
 
 ROW_TILE = 512      # rows a grouped-product tile takes: `rows` is a multiple
 ROW_CHUNK = 4096    # rows `experts` handles a trip of its loop: a multiple
+# rows a row-wise stage handles a trip of ITS loop inside a chunk (see
+# `row_block`), a multiple.  One layer forward + backward on the chip
+# (PERF.md, PR 43): at a 32,768-row chunk half full, blocks of 2,048 rows
+# read 25.95 and 18.77 ms (lfm2's and laguna's shapes), of 1,024 rows
+# 26.79 and 19.21; PR 42's sweep read 4,096 no better than 2,048
+ROW_BLOCK = 2048
 
 FORMS = ("relu2", "silu_gated")   # an expert's activation: see `experts`
 ROUTES = ("grouped_kernel", "ragged_dot")
-_route_counts = dict.fromkeys(ROUTES + ("sorted_layout",), 0)
+_route_counts = dict.fromkeys(
+    ROUTES + ("sorted_layout", "expert_stage_traces"), 0)
 
 
 def route_counts():
     """Since import: {route: grouped products of the `experts` calls
-    traced through it (two a call, whatever its gradient traces)}, and
-    under `sorted_layout` the `route` calls traced (every one lays its
-    rows out by a sort: there is no other form).  As for attention,
-    `grouped_kernel` in a program lowered for the CPU runs its
-    `ragged_dot` twin."""
+    made through it while a program was traced (two a call, whatever its
+    gradient traces)}, under `sorted_layout` the `route` calls traced
+    (every one lays its rows out by a sort: there is no other form), and
+    under `expert_stage_traces` how often one of `experts`' two loops
+    (forward, backward) was really traced: once a signature, so products
+    / 2 over traces says how many calls bound a trace that was there.
+    As for attention, `grouped_kernel` in a program lowered for the CPU
+    runs its `ragged_dot` twin."""
     return dict(_route_counts)
 
 
@@ -121,6 +146,32 @@ def plan_chunks(group_sizes, expected_rows: int = 0):
     (an array, traced or not): the chunks of `row_chunk(expected_rows)`
     rows that hold an assignment."""
     return -(-group_sizes.sum() // row_chunk(expected_rows))
+
+
+def row_block(chunk: int) -> int:
+    """Rows a trip of a row-wise stage's loop handles inside a chunk of
+    `chunk` rows: the largest multiple of `ROW_TILE` up to `ROW_BLOCK` and
+    up to a quarter of the chunk that divides the chunk, so that no block
+    straddles its end and the count can follow the rows used (a chunk of
+    two blocks that is 56% full visits both: the whole chunk again and
+    the loops' cost besides)."""
+    step = math.gcd(chunk, ROW_TILE, ROW_BLOCK)
+    most = max(min(ROW_BLOCK, chunk // 4), step)
+    return next(b for b in range(most - most % step, 0, -step)
+                if chunk % b == 0)
+
+
+def plan_blocks(group_sizes, expected_rows: int = 0, rows=None):
+    """Blocks that each row-wise stage of `experts` visits under a plan
+    with these `group_sizes` (an array, traced or not), over all its
+    trips: the blocks of `row_block(chunk)` rows that hold an assignment,
+    where the chunk is `row_chunk(expected_rows)` or, for a plan of
+    fewer `rows`, the plan."""
+    chunk = row_chunk(expected_rows)
+    chunk = chunk if rows is None else min(chunk, rows)
+    block = row_block(chunk)
+    whole, rest = jnp.divmod(group_sizes.sum(), chunk)
+    return whole * (chunk // block) + -(-rest // block)
 
 
 def _fit(a, n: int, fill):
@@ -226,38 +277,75 @@ def _use_kernel() -> bool:
     return env.get_bool("MXNET_USE_PALLAS")
 
 
-def _grouped(lhs, rhs, group_sizes):
+def _grouped(lhs, rhs, group_sizes, kernel):
     """lhs (rows, K) @ rhs[g] (K, N) for the rows of group g.  Rows past
     the last group come back undefined from the kernel: the caller masks
     them."""
-    if not _use_kernel():
-        return _ragged(lhs, rhs, group_sizes)
-    return lax.platform_dependent(lhs, rhs, group_sizes, tpu=_gmm,
-                                  default=_ragged)
+    with jax.named_scope("products"):
+        if not kernel:
+            return _ragged(lhs, rhs, group_sizes)
+        return lax.platform_dependent(lhs, rhs, group_sizes, tpu=_gmm,
+                                      default=_ragged)
 
 
-def _chunk_rows(x, used, weight, sizes, w1, w2, form):
-    """One chunk's rows through their experts: x (C, K) gathered tokens,
-    `used` (C,) which rows hold an assignment, sizes (n_local,) the rows
-    of each group inside the chunk.  Returns (C, K) float32, weighed."""
-    hidden = _grouped(x, w1, sizes)
+def _rows_of(u, tok):
+    """u's rows (T, K) of the tokens `tok`; 0 for token T, no assignment."""
+    return jnp.take(u, tok, axis=0, mode="fill", fill_value=0)
+
+
+def _activate(hidden, form):
+    """The first product's rows (rows, N) or (rows, 2N) -> (rows, N)."""
     if form == "relu2":
-        hidden = jnp.square(jnp.maximum(hidden, 0))
-    else:
-        gate, up = jnp.split(hidden, 2, axis=1)
-        hidden = jax.nn.silu(gate) * up
-    out = _grouped(hidden, w2, sizes)
+        return jnp.square(jnp.maximum(hidden, 0))
+    gate, up = jnp.split(hidden, 2, axis=1)
+    return jax.nn.silu(gate) * up
+
+
+def _weigh(out, used, weight):
+    """The second product's rows, those that hold no assignment masked
+    (the kernel leaves them undefined), in float32, weighed."""
     return (jnp.where(used[:, None], out, 0).astype(jnp.float32)
             * weight[:, None])
 
 
-def _chunks(u, token, weight, group_sizes, expected_rows):
+def _by_block(name, count, block, fn, *rows):
+    """`fn(*the block of each of rows) -> a tuple of blocks` over the
+    first `count` (traced) blocks of `block` rows of `rows`, arrays
+    (chunk, ...): a tuple of arrays (chunk, ...), made in a loop under the
+    scope `name`.  The rows of a block that was not visited are UNDEFINED,
+    as the rows past the last group are that a grouped product returns
+    (`lax.empty`: memory as it was found on the TPU, where zeros would be
+    a pass over the whole chunk at every call; 0 elsewhere)."""
+    made = jax.eval_shape(fn, *(jax.ShapeDtypeStruct(
+        (block,) + r.shape[1:], r.dtype) for r in rows))
+
+    def body(b, outs):
+        lo = b * block
+        blocks = fn(*(lax.dynamic_slice_in_dim(r, lo, block) for r in rows))
+        return tuple(lax.dynamic_update_slice_in_dim(o, v, lo, 0)
+                     for o, v in zip(outs, blocks))
+
+    with jax.named_scope(name):
+        return lax.fori_loop(0, count, body, tuple(
+            lax.empty(rows[0].shape[:1] + m.shape[1:], m.dtype)
+            for m in made))
+
+
+class _Stage(NamedTuple):
+    """What a trace of the expert stage is made for, beside its operands'
+    shapes and dtypes: `experts` reads these where it is called."""
+    form: str
+    chunk: int      # rows a trip of the loop over the plan handles
+    block: int      # rows a trip of a row-wise stage's loop inside it
+    kernel: bool    # `MXNET_USE_PALLAS`
+
+
+def _chunks(token, weight, group_sizes, t, stage):
     """How `experts`' two loops cut a plan: (its rows rounded up to whole
     chunks, the trip count, window(c) -> (chunk c's first row, its
     tokens, its weights, the rows of each group that lie inside it, its
-    tokens' rows of u))."""
-    t, rows = u.shape[0], token.shape[0]
-    chunk = min(row_chunk(expected_rows), rows)
+    blocks that hold an assignment))."""
+    rows, chunk = token.shape[0], stage.chunk
     most = -(-rows // chunk)
     pad = most * chunk - rows
     token = jnp.pad(token, (0, pad), constant_values=t)
@@ -267,24 +355,32 @@ def _chunks(u, token, weight, group_sizes, expected_rows):
 
     def window(c):
         lo = c * chunk
-        tok = lax.dynamic_slice(token, (lo,), (chunk,))
-        return (lo, tok, lax.dynamic_slice(weight, (lo,), (chunk,)),
+        return (lo, lax.dynamic_slice(token, (lo,), (chunk,)),
+                lax.dynamic_slice(weight, (lo,), (chunk,)),
                 jnp.clip(ends, lo, lo + chunk)
                 - jnp.clip(starts, lo, lo + chunk),
-                jnp.take(u, tok, axis=0, mode="fill", fill_value=0))
+                -(-jnp.clip(ends[-1] - lo, 0, chunk) // stage.block))
 
-    return most * chunk, jnp.minimum(
-        plan_chunks(group_sizes, expected_rows), most), window
+    return most * chunk, jnp.minimum(-(-ends[-1] // chunk), most), window
 
 
-def _forward(u, token, weight, group_sizes, w1, w2, form, expected_rows):
-    t = u.shape[0]
-    _, trips, window = _chunks(u, token, weight, group_sizes, expected_rows)
+@functools.partial(jax.jit, static_argnums=6)
+def _forward(u, token, weight, group_sizes, w1, w2, stage):
+    _route_counts["expert_stage_traces"] += 1
+    t, block = u.shape[0], stage.block
+    product = functools.partial(_grouped, kernel=stage.kernel)
+    _, trips, window = _chunks(token, weight, group_sizes, t, stage)
 
     def body(c, acc):
-        _, tok, wt, sizes, x = window(c)
-        return acc.at[tok].add(
-            _chunk_rows(x, tok < t, wt, sizes, w1, w2, form), mode="drop")
+        _, tok, wt, sizes, count = window(c)
+        x, = _by_block("gather", count, block,
+                       lambda tok: (_rows_of(u, tok),), tok)
+        hidden, = _by_block(
+            "activate", count, block, lambda h: (_activate(h, stage.form),),
+            product(x, w1, sizes))
+        out = product(hidden, w2, sizes)
+        with jax.named_scope("combine"):    # ONE scatter-add: see `experts`
+            return acc.at[tok].add(_weigh(out, tok < t, wt), mode="drop")
 
     return lax.fori_loop(0, trips, body,
                          jnp.zeros(u.shape, jnp.float32)).astype(u.dtype)
@@ -293,26 +389,49 @@ def _forward(u, token, weight, group_sizes, w1, w2, form, expected_rows):
 def _forward_and_inputs(*inputs):
     # the residuals are the inputs and nothing of size rows x N: the
     # backward's loop makes each chunk's hidden again from its rows
-    return _forward(*inputs), inputs[:-2]
+    return _forward(*inputs), inputs[:-1]
 
 
-def _backward(form, expected_rows, res, g):
+@functools.partial(jax.jit, static_argnums=0)
+def _backward(stage, res, g):
+    """The gradient, written out as the forward is (a loop with a traced
+    trip count has no reverse mode of its own): the same chunks, the
+    same blocks, each product and each row-wise stage under `jax.vjp` by
+    itself."""
+    _route_counts["expert_stage_traces"] += 1
     u, token, weight, group_sizes, w1, w2 = res
-    t = u.shape[0]
-    padded, trips, window = _chunks(u, token, weight, group_sizes,
-                                    expected_rows)
-    g = g.astype(jnp.float32)
+    t, block = u.shape[0], stage.block
+    product = functools.partial(_grouped, kernel=stage.kernel)
+    padded, trips, window = _chunks(token, weight, group_sizes, t, stage)
+
+    def activate(hidden):
+        return _activate(hidden, stage.form),
+
+    def activate_back(hidden, dact):
+        return jax.vjp(activate, hidden)[1]((dact,))
+
+    def weigh_back(tok, wt, out):
+        _, pull = jax.vjp(lambda out, wt: _weigh(out, tok < t, wt), out, wt)
+        return pull(_rows_of(g, tok).astype(jnp.float32))
 
     def body(c, carry):
         du, dweight, dw1, dw2 = carry
-        lo, tok, wt, sizes, x = window(c)
-        _, pull = jax.vjp(
-            lambda x, wt, w1, w2: _chunk_rows(x, tok < t, wt, sizes, w1, w2,
-                                              form), x, wt, w1, w2)
-        dx, dwt, d1, d2 = pull(
-            jnp.take(g, tok, axis=0, mode="fill", fill_value=0))
-        return (du.at[tok].add(dx.astype(jnp.float32), mode="drop"),
-                lax.dynamic_update_slice(dweight, dwt, (lo,)),
+        lo, tok, wt, sizes, count = window(c)
+        x, = _by_block("gather", count, block,
+                       lambda tok: (_rows_of(u, tok),), tok)
+        hidden, pull1 = jax.vjp(lambda x, w: product(x, w, sizes), x, w1)
+        act, = _by_block("activate", count, block, activate, hidden)
+        out, pull2 = jax.vjp(lambda a, w: product(a, w, sizes), act, w2)
+        dout, dwt = _by_block("combine", count, block, weigh_back, tok, wt,
+                              out)
+        dact, d2 = pull2(dout)
+        dx, d1 = pull1(*_by_block("activate", count, block, activate_back,
+                                  hidden, dact))
+        # a row that no block visited: 0, not what memory held
+        dwt = jnp.where(jnp.arange(stage.chunk) < count * block, dwt, 0)
+        with jax.named_scope("gather"):     # ONE scatter-add, as `combine`
+            du = du.at[tok].add(dx.astype(jnp.float32), mode="drop")
+        return (du, lax.dynamic_update_slice(dweight, dwt, (lo,)),
                 dw1 + d1.astype(jnp.float32), dw2 + d2.astype(jnp.float32))
 
     du, dweight, dw1, dw2 = lax.fori_loop(0, trips, body, (
@@ -323,8 +442,10 @@ def _backward(form, expected_rows, res, g):
             dw1.astype(w1.dtype), dw2.astype(w2.dtype))
 
 
-# a loop with a traced trip count has no reverse mode of its own
-_experts = jax.custom_vjp(_forward, nondiff_argnums=(6, 7))
+# the two loops are jitted by themselves, so each is traced ONCE for a
+# signature (the operands' shapes and dtypes, `_Stage`): a model's layers,
+# each under its own `jax.checkpoint`, bind that trace, and XLA inlines it
+_experts = jax.custom_vjp(_forward, nondiff_argnums=(6,))
 _experts.defvjp(_forward_and_inputs, _backward)
 
 
@@ -340,18 +461,34 @@ def experts(u, plan: RoutePlan, w1, w2, form: str = "relu2",
     The plan's rows are handled `row_chunk(expected_rows)` at a time
     (`expected_rows`, static: the assignments the model expects on the
     held experts; 0 leaves the chunk at `ROW_CHUNK`), `plan_chunks(
-    plan.group_sizes, expected_rows)` times: per chunk gather, grouped product, the
-    activation, grouped product, mask, scale in float32, add into a
-    (T, K) float32 sum.  The gradient is a second loop of the same trip count over the
-    same chunks; nothing of a forward pass is kept for it but the
-    inputs."""
+    plan.group_sizes, expected_rows)` times: per chunk gather, grouped
+    product, the activation, grouped product, mask, scale in float32,
+    add into a (T, K) float32 sum.  The products skip the chunk's empty
+    tail themselves.  The gather, the activation and, in the gradient,
+    the gather of the cotangent, the weights' gradient and the
+    activation's go over the chunk `row_block(chunk)` rows at a time and
+    stop after the last block that holds an assignment (`plan_blocks`).
+    The two scatter-adds (the result into (T, K), the gradient into u's)
+    stay ONE call a chunk: XLA sorts a large scatter's indices and the
+    rows of one token then share the tile they update, ~100 ns a row
+    over the whole chunk where a block of 2,048 rows costs ~245 ns a row
+    (PERF.md, PR 43); a row past the assignments carries token T and is
+    dropped.  The gradient is a second loop of the same trip counts over
+    the same chunks and blocks; nothing of a forward pass is kept for it
+    but the inputs.
+
+    The two loops are traced once for a set of operand shapes and
+    dtypes, `form`, the chunk and the block (`route_counts()`
+    ["expert_stage_traces"]); every further call binds that trace."""
     if form not in FORMS or w1.shape[2] != w2.shape[1] * (
             2 if form == "silu_gated" else 1):
         raise MXNetError(f"experts: form {form!r} (of {FORMS}) with w1 "
                          f"{w1.shape} and w2 {w2.shape}")
-    _route_counts["grouped_kernel" if _use_kernel() else "ragged_dot"] += 2
+    kernel = _use_kernel()
+    _route_counts["grouped_kernel" if kernel else "ragged_dot"] += 2
+    chunk = min(row_chunk(int(expected_rows)), plan.token.shape[0])
     return _experts(u, plan.token, plan.weight, plan.group_sizes, w1, w2,
-                    form, int(expected_rows))
+                    _Stage(form, chunk, row_block(chunk), kernel))
 
 
 def moe_apply(x, u, w_router, bias, w1, w2, *, top_k: int,

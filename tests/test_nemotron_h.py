@@ -480,29 +480,39 @@ def test_experts_in_chunks_match_the_dense_form(monkeypatch, load, wrap):
         assert not any(np.asarray(g).any() for g in (out, *grads))
 
 
-def test_plan_chunks_is_the_trip_count_of_both_loops(monkeypatch):
-    """`plan_chunks` says how often the loops ran: the forward's body and
-    the backward's each run that many times, counted on the host."""
+def test_plan_chunks_and_plan_blocks_are_the_trip_counts_of_the_loops(
+        monkeypatch):
+    """`plan_chunks` and `plan_blocks` say how often the loops ran: the
+    forward weighs a chunk a trip (its scatter-add is one call a chunk),
+    the backward a block a trip of its inner loop, counted on the
+    host."""
     w, plan = _loaded_plan(monkeypatch, "every_token_several_chunks")
+    monkeypatch.setattr(moe, "ROW_BLOCK", 8)
     assert int(plan.dropped) == 0
-    assert plan.token.shape[0] == moe.plan_rows(40, 3, 2)   # the bound
-    ran, real = [], moe._chunk_rows
+    rows = plan.token.shape[0]
+    assert rows == moe.plan_rows(40, 3, 2)   # the bound
+    ran, real = [], moe._weigh
 
     def counted(*args):
         jax.debug.callback(lambda: ran.append(1))
         return real(*args)
 
-    monkeypatch.setattr(moe, "_chunk_rows", counted)
-    args = (jnp.asarray(w["u"]), plan, jnp.asarray(w["w1"][2:4]),
-            jnp.asarray(w["w2"][2:4]))
-    jax.block_until_ready(jax.jit(moe.experts)(*args))
-    jax.effects_barrier()
-    chunks = int(moe.plan_chunks(plan.group_sizes))
-    assert len(ran) == chunks == 5
-    jax.block_until_ready(jax.jit(jax.grad(
-        lambda u: moe.experts(u, *args[1:]).sum()))(args[0]))
-    jax.effects_barrier()
-    assert len(ran) == 3 * chunks
+    monkeypatch.setattr(moe, "_weigh", counted)
+    jax.clear_caches()      # the stage is traced once a signature
+    try:
+        args = (jnp.asarray(w["u"]), plan, jnp.asarray(w["w1"][2:4]),
+                jnp.asarray(w["w2"][2:4]))
+        jax.block_until_ready(jax.jit(moe.experts)(*args))
+        jax.effects_barrier()
+        chunks = int(moe.plan_chunks(plan.group_sizes))
+        blocks = int(moe.plan_blocks(plan.group_sizes, rows=rows))
+        assert len(ran) == chunks == 5 and blocks == 10
+        jax.block_until_ready(jax.jit(jax.grad(
+            lambda u: moe.experts(u, *args[1:]).sum()))(args[0]))
+        jax.effects_barrier()
+        assert len(ran) == 2 * chunks + blocks
+    finally:
+        jax.clear_caches()  # and no later test binds the counted one
 
 
 def _layer(held=None, first=0, **kw):
