@@ -32,9 +32,20 @@ again.  The grouped products skip the empty tail themselves; the gather,
 the activation and the gradients of gather, activation, mask and scale go
 over the chunk in blocks of `row_block(chunk)` rows under a second count
 read from `group_sizes` (`plan_blocks`) and stop after the last block that
-holds an assignment; nothing they make is zero-filled first.  The two
-scatter-adds into (T, K) are the exception, one call a chunk, because that
-is the form in which XLA's scatter is cheapest a row (`experts`).
+holds an assignment; nothing they make is zero-filled first.
+
+Nothing in `experts` is a scatter either.  The sum of a chunk's rows into
+their tokens, which the combine is and in the gradient the transpose of
+the gather of `u`, is computed by the TOKEN reading its rows
+(`_token_sums`): one sort puts the chunk's rows in token order, a product
+of one-hot digits counts each token's rows, which says where its run
+begins, the rows are gathered in that order in the same blocks under the
+same count, a token's at most min(top_k, n_local)
+neighbouring rows are added in float32 by shifted, masked adds, and one
+gather of T head rows makes the chunk's (T, K) part.  Its cost follows
+the rows used and T; XLA's scatter-add cost ~100 ns a row over the WHOLE
+chunk, half of it empty, where a gathered row costs 12-21 ns (PERF.md,
+PRs 43-44).
 
 The two loops, forward and backward, are each ONE traced function for a
 set of operand shapes and dtypes, `form`, chunk and block: a model's
@@ -94,17 +105,21 @@ ROW_BLOCK = 2048
 FORMS = ("relu2", "silu_gated")   # an expert's activation: see `experts`
 ROUTES = ("grouped_kernel", "ragged_dot")
 _route_counts = dict.fromkeys(
-    ROUTES + ("sorted_layout", "expert_stage_traces"), 0)
+    ROUTES + ("sorted_layout", "expert_stage_traces", "token_sums"), 0)
 
 
 def route_counts():
     """Since import: {route: grouped products of the `experts` calls
     made through it while a program was traced (two a call, whatever its
     gradient traces)}, under `sorted_layout` the `route` calls traced
-    (every one lays its rows out by a sort: there is no other form), and
+    (every one lays its rows out by a sort: there is no other form),
     under `expert_stage_traces` how often one of `experts`' two loops
     (forward, backward) was really traced: once a signature, so products
-    / 2 over traces says how many calls bound a trace that was there.
+    / 2 over traces says how many calls bound a trace that was there, and
+    under `token_sums` how often a loop's sum over a token's rows was
+    traced as the token reading them (`_token_sums`: the forward's
+    combine, the backward's transpose of the gather; there is no other
+    form, so it follows the loops' traces).
     As for attention, `grouped_kernel` in a program lowered for the CPU
     runs its `ragged_dot` twin."""
     return dict(_route_counts)
@@ -301,34 +316,126 @@ def _activate(hidden, form):
     return jax.nn.silu(gate) * up
 
 
-def _weigh(out, used, weight):
-    """The second product's rows, those that hold no assignment masked
-    (the kernel leaves them undefined), in float32, weighed."""
-    return (jnp.where(used[:, None], out, 0).astype(jnp.float32)
-            * weight[:, None])
+def _weigh(out, used, weight=None):
+    """A product's rows, those that hold no assignment masked (the
+    kernel leaves them undefined), in float32, weighed where a weight is
+    given."""
+    out = jnp.where(used[:, None], out, 0).astype(jnp.float32)
+    return out if weight is None else out * weight[:, None]
 
 
-def _by_block(name, count, block, fn, *rows):
+def _by_block(name, count, block, fn, *rows, halo=0, spare=0):
     """`fn(*the block of each of rows) -> a tuple of blocks` over the
     first `count` (traced) blocks of `block` rows of `rows`, arrays
-    (chunk, ...): a tuple of arrays (chunk, ...), made in a loop under the
-    scope `name`.  The rows of a block that was not visited are UNDEFINED,
-    as the rows past the last group are that a grouped product returns
-    (`lax.empty`: memory as it was found on the TPU, where zeros would be
-    a pass over the whole chunk at every call; 0 elsewhere)."""
+    (chunk + halo, ...): a tuple of arrays (chunk + spare, ...), made in a
+    loop under the scope `name`; with a `halo`, `fn` is also handed the
+    `halo` rows that follow its block; the `spare` rows after the chunk's
+    are the caller's to fill.  The rows of a block that was not visited
+    are UNDEFINED, as the rows past the last group are that a grouped
+    product returns (`lax.empty`: memory as it was found on the TPU, where
+    zeros would be a pass over the whole chunk at every call; 0
+    elsewhere)."""
     made = jax.eval_shape(fn, *(jax.ShapeDtypeStruct(
-        (block,) + r.shape[1:], r.dtype) for r in rows))
+        (block + halo,) + r.shape[1:], r.dtype) for r in rows))
 
     def body(b, outs):
         lo = b * block
-        blocks = fn(*(lax.dynamic_slice_in_dim(r, lo, block) for r in rows))
+        blocks = fn(*(lax.dynamic_slice_in_dim(r, lo, block + halo)
+                      for r in rows))
         return tuple(lax.dynamic_update_slice_in_dim(o, v, lo, 0)
                      for o, v in zip(outs, blocks))
 
     with jax.named_scope(name):
         return lax.fori_loop(0, count, body, tuple(
-            lax.empty(rows[0].shape[:1] + m.shape[1:], m.dtype)
-            for m in made))
+            lax.empty((rows[0].shape[0] - halo + spare,) + m.shape[1:],
+                      m.dtype) for m in made))
+
+
+def _in_token_order(tok, wt, t):
+    """A chunk's rows put in the order of their tokens, by one sort and
+    no scatter: tok (chunk,) the rows' tokens (`t`: no assignment), wt
+    (chunk,) their weights or None.  -> (the tokens ascending, the row
+    each came from, its weight or None, rows (t,): how many of the
+    chunk's rows are a token's, start (t,): how many belong to a token
+    before it, which is where its run begins).
+
+    The count is a product of two one-hot matrices: with a token's
+    number split in two digits, `high` and `low`, the rows' one-hot
+    digits (chunk, highs) and (chunk, 128) multiply to the (highs, 128)
+    table of the tokens' rows, exact in float32 (JAX's histogram is a
+    scatter-add)."""
+    chunk, highs = tok.shape[0], -(-t // 128)
+    token, row, *weight = lax.sort(
+        (tok, jnp.arange(chunk, dtype=jnp.int32))
+        + (() if wt is None else (wt,)), num_keys=2)
+    high = jax.nn.one_hot(jnp.where(tok < t, tok // 128, highs), highs,
+                          dtype=jnp.bfloat16)
+    low = jax.nn.one_hot(tok % 128, 128, dtype=jnp.bfloat16)
+    rows = jnp.einsum("rh,rl->hl", high, low,
+                      preferred_element_type=jnp.float32)
+    rows = rows.reshape(-1)[:t].astype(jnp.int32)
+    return (token, row, weight[0] if weight else None, rows,
+            jnp.cumsum(rows) - rows)
+
+
+def _token_sums(name, into, first, values, tok, wt, count, most, block):
+    """`into` (T, K) float32 plus every token's sum over the rows of a
+    chunk that are its own, or where `first` (traced) the sums alone,
+    `into` not read (it may hold anything): values (chunk, K) a product's
+    rows (those past the assignments undefined), tok (chunk,) their
+    tokens (T: none), wt (chunk,) float32 weights or None, `count`
+    (traced) the blocks of `block` rows that hold an assignment, `most`
+    the rows a token can have.  A token with no row here adds 0.
+
+    The TOKEN reads its rows; nothing is scattered.  The rows are taken
+    in token order (`_in_token_order`), a block at a time under the
+    count; a token's rows are then a run of at most `most` neighbours, so
+    `most` shifted windows of the block (a halo of `most` - 1 rows at its
+    end), masked and weighed in float32 and added in one pass, leave the
+    token's sum at the head of its run, always in the same order of its
+    terms; one gather of T head rows makes the chunk's part.  The cost
+    follows the rows used and T, not the chunk."""
+    _route_counts["token_sums"] += 1
+    (t, _), chunk, halo, spare = into.shape, tok.shape[0], most - 1, 8
+    with jax.named_scope(name):
+        token, row, weight, rows, start = _in_token_order(tok, wt, t)
+
+        def sums(token, row, *weight):
+            rows = values.at[row].get(mode="promise_in_bounds")
+            head, total = token[:block], None
+            for j in range(most):
+                term = _weigh(rows[j:j + block],
+                              (token[j:j + block] == head) & (head < t),
+                              *(w[j:j + block] for w in weight))
+                total = term if total is None else total + term
+            return total,
+
+        heads, = _by_block(
+            "sums", count, block, sums,
+            jnp.pad(token, (0, halo), constant_values=t),
+            jnp.pad(row, (0, halo)),
+            *(() if weight is None else (jnp.pad(weight, (0, halo)),)),
+            halo=halo, spare=spare)
+        # a tile of zeros after the chunk: what a token with no row reads
+        heads = lax.dynamic_update_slice_in_dim(
+            heads, jnp.zeros((spare,) + heads.shape[1:], heads.dtype),
+            chunk, 0)
+        index = jnp.where(rows > 0, start, chunk)
+
+        def part():
+            return heads.at[index].get(mode="promise_in_bounds")
+
+        # the first trip's part IS the sum: nothing is zero-filled for it
+        # and nothing added to it (most layers take one trip)
+        return lax.cond(first, lambda into: part(),
+                        lambda into: into + part(), into)
+
+
+def _most_rows_a_token(rows: int, t: int, n_local: int) -> int:
+    """The rows one token can have in a plan of `rows` rows for `t` tokens
+    on `n_local` held experts: one an expert, and `rows` holds `t` times
+    min(top_k, n_local) (`plan_rows`)."""
+    return min(n_local, -(-rows // t))
 
 
 class _Stage(NamedTuple):
@@ -364,14 +471,31 @@ def _chunks(token, weight, group_sizes, t, stage):
     return most * chunk, jnp.minimum(-(-ends[-1] // chunk), most), window
 
 
+def _over_trips(trips, body, sums, *others):
+    """`body(c, carry) -> carry` for c in 0 .. `trips` - 1 (traced), the
+    carry float32 arrays: first a (T, K) sum of shape `sums`, then arrays
+    of the shapes `others` that start as zeros.  The sum's start is
+    UNDEFINED (`lax.empty`) where there is a trip: the first one writes it
+    (`_token_sums`); a plan with no trip gives zeros.  The start is made
+    in a branch for its lifetime's sake: an uninitialised buffer made in
+    the program's entry has no operand, so XLA makes every layer's at the
+    program's start and keeps them all to their use (laguna's step
+    planned 0.78 GiB more, PERF.md PR 44); the branch waits for `trips`."""
+    start = lax.cond(trips > 0, lambda: lax.empty(sums, jnp.float32),
+                     lambda: jnp.zeros(sums, jnp.float32))
+    return lax.fori_loop(0, trips, body, (start,) + tuple(
+        jnp.zeros(shape, jnp.float32) for shape in others))
+
+
 @functools.partial(jax.jit, static_argnums=6)
 def _forward(u, token, weight, group_sizes, w1, w2, stage):
     _route_counts["expert_stage_traces"] += 1
     t, block = u.shape[0], stage.block
     product = functools.partial(_grouped, kernel=stage.kernel)
     _, trips, window = _chunks(token, weight, group_sizes, t, stage)
+    most = _most_rows_a_token(token.shape[0], t, w1.shape[0])
 
-    def body(c, acc):
+    def body(c, carry):
         _, tok, wt, sizes, count = window(c)
         x, = _by_block("gather", count, block,
                        lambda tok: (_rows_of(u, tok),), tok)
@@ -379,11 +503,10 @@ def _forward(u, token, weight, group_sizes, w1, w2, stage):
             "activate", count, block, lambda h: (_activate(h, stage.form),),
             product(x, w1, sizes))
         out = product(hidden, w2, sizes)
-        with jax.named_scope("combine"):    # ONE scatter-add: see `experts`
-            return acc.at[tok].add(_weigh(out, tok < t, wt), mode="drop")
+        return _token_sums("combine", carry[0], c == 0, out, tok, wt, count,
+                           most, block),
 
-    return lax.fori_loop(0, trips, body,
-                         jnp.zeros(u.shape, jnp.float32)).astype(u.dtype)
+    return _over_trips(trips, body, u.shape)[0].astype(u.dtype)
 
 
 def _forward_and_inputs(*inputs):
@@ -403,6 +526,7 @@ def _backward(stage, res, g):
     t, block = u.shape[0], stage.block
     product = functools.partial(_grouped, kernel=stage.kernel)
     padded, trips, window = _chunks(token, weight, group_sizes, t, stage)
+    most = _most_rows_a_token(token.shape[0], t, w1.shape[0])
 
     def activate(hidden):
         return _activate(hidden, stage.form),
@@ -429,14 +553,14 @@ def _backward(stage, res, g):
                                   hidden, dact))
         # a row that no block visited: 0, not what memory held
         dwt = jnp.where(jnp.arange(stage.chunk) < count * block, dwt, 0)
-        with jax.named_scope("gather"):     # ONE scatter-add, as `combine`
-            du = du.at[tok].add(dx.astype(jnp.float32), mode="drop")
+        # the gather of u's rows, transposed: a token sums its rows of dx
+        du = _token_sums("gather", du, c == 0, dx, tok, None, count, most,
+                         block)
         return (du, lax.dynamic_update_slice(dweight, dwt, (lo,)),
                 dw1 + d1.astype(jnp.float32), dw2 + d2.astype(jnp.float32))
 
-    du, dweight, dw1, dw2 = lax.fori_loop(0, trips, body, (
-        jnp.zeros(u.shape, jnp.float32), jnp.zeros((padded,), jnp.float32),
-        jnp.zeros(w1.shape, jnp.float32), jnp.zeros(w2.shape, jnp.float32)))
+    du, dweight, dw1, dw2 = _over_trips(trips, body, u.shape, (padded,),
+                                        w1.shape, w2.shape)
     return (du.astype(u.dtype), None,
             dweight[:weight.shape[0]].astype(weight.dtype), None,
             dw1.astype(w1.dtype), dw2.astype(w2.dtype))
@@ -468,14 +592,17 @@ def experts(u, plan: RoutePlan, w1, w2, form: str = "relu2",
     the gather of the cotangent, the weights' gradient and the
     activation's go over the chunk `row_block(chunk)` rows at a time and
     stop after the last block that holds an assignment (`plan_blocks`).
-    The two scatter-adds (the result into (T, K), the gradient into u's)
-    stay ONE call a chunk: XLA sorts a large scatter's indices and the
-    rows of one token then share the tile they update, ~100 ns a row
-    over the whole chunk where a block of 2,048 rows costs ~245 ns a row
-    (PERF.md, PR 43); a row past the assignments carries token T and is
-    dropped.  The gradient is a second loop of the same trip counts over
-    the same chunks and blocks; nothing of a forward pass is kept for it
-    but the inputs.
+    The two sums over a token's rows (the result into (T, K), the
+    gradient into u's) are no scatter-adds: the token reads its rows in
+    token order (`_token_sums`; `route_counts()["token_sums"]`), in the
+    same blocks, always in the same order of its float32 terms, so the
+    result is deterministic; a row past the assignments carries token T
+    and weight 0 and is read as 0, and a plan of several trips adds each
+    trip's part in float32 (a token's rows may lie in two chunks).  The
+    gradient is a second loop of the same trip counts over the same
+    chunks and blocks; nothing of a forward pass is kept for it but the
+    inputs.  The plan is `route`'s: its rows hold T x min(top_k, n_local)
+    assignments, which is how many rows a token can have.
 
     The two loops are traced once for a set of operand shapes and
     dtypes, `form`, the chunk and the block (`route_counts()`

@@ -1,6 +1,7 @@
 """`parallel.moe.experts`: the row-wise stages go over a chunk in blocks
-of `row_block(chunk)` rows under a count read from `group_sizes`, and the
-two loops are traced once a signature.  CPU, the `ragged_dot` route."""
+of `row_block(chunk)` rows under a count read from `group_sizes`, a token
+sums its own rows (`_token_sums`: no scatter), and the two loops are
+traced once a signature.  CPU, the `ragged_dot` route."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -104,6 +105,137 @@ def test_experts_in_blocks_match_the_per_row_form(case, form):
         np.testing.assert_allclose(got, ref, rtol=2e-4, atol=2e-4)
     if not used:
         assert not any(np.asarray(g).any() for g in (out, *grads))
+
+
+# `_token_sums` alone: a chunk of 64 rows in blocks of 16, 20 tokens, at
+# most 4 rows a token.  A case is the run of each token that has rows, in
+# ascending token order: (token, rows), ...; the rows in token order are
+# then 0, 1, 2, ... so a run's place against a block's edge is its sum
+_T, _CHUNK, _BLOCK, _MOST, _K = 20, 64, 16, 4, 8
+
+
+def _runs(lengths, first=0, step=1):
+    return [(first + step * i, n) for i, n in enumerate(lengths)]
+
+
+_SUMS = {
+    "none": [],
+    "one_row": [(7, 1)],
+    "block_less_one": _runs([4, 4, 4, 3]),
+    "block": _runs([4, 4, 4, 4]),
+    "block_and_one": _runs([4, 4, 4, 4, 1]),
+    # tokens with no row, with one and with the most a token can have
+    "zero_one_and_most_rows": [(0, 1), (2, 4), (3, 1), (9, 4), (19, 4)],
+    # rows 12-15 are one token's: its run ends on the block's last row
+    "a_run_ends_on_a_blocks_last_row": _runs([4, 4, 4, 4, 2, 3]),
+    # rows 14-17 and 30-33: runs that cross a block's edge
+    "a_run_crosses_a_blocks_edge": _runs([4, 4, 4, 2, 4, 4, 4, 4, 4], 1, 2),
+    "full_chunk": _runs([4] * 12 + [3] * 4 + [1] * 4),
+}
+
+
+@pytest.mark.parametrize("first", [True, False],
+                         ids=["first_trip", "later_trip"])
+@pytest.mark.parametrize("weighted", [True, False],
+                         ids=["weighted", "unweighted"])
+@pytest.mark.parametrize("case", list(_SUMS))
+def test_token_sums_are_the_plain_segment_sum(case, weighted, first):
+    """Every token's sum over its own rows against `np.add.at` in
+    float32, whatever the rows' order in the chunk; the rows that hold no
+    assignment are NaN, as a kernel may leave them.  A later trip adds to
+    the sum so far; the first one does not read it (NaN here)."""
+    runs = _SUMS[case]
+    used = sum(n for _, n in runs)
+    assert all(0 <= tok < _T and 0 < n <= _MOST for tok, n in runs)
+    if case == "full_chunk":
+        assert used == _CHUNK
+    if case == "a_run_ends_on_a_blocks_last_row":
+        assert sum(n for _, n in runs[:4]) == _BLOCK
+    if case == "a_run_crosses_a_blocks_edge":
+        assert sum(n for _, n in runs[:4]) == _BLOCK - 2 and runs[4][1] == 4
+        assert sum(n for _, n in runs[:8]) == 2 * _BLOCK - 2
+    rng = np.random.RandomState(used)
+    tok = np.full(_CHUNK, _T, np.int32)
+    tok[:used] = np.repeat([tok for tok, _ in runs], [n for _, n in runs])
+    tok = tok[rng.permutation(_CHUNK)]
+    values = rng.randn(_CHUNK, _K).astype(np.float32)
+    values[tok == _T] = np.nan
+    wt = np.where(tok < _T, rng.rand(_CHUNK) + 0.5, 0).astype(np.float32)
+
+    so_far = np.full((_T, _K), np.nan, np.float32) if first \
+        else rng.randn(_T, _K).astype(np.float32)
+
+    before = moe.route_counts()["token_sums"]
+    sums = jax.jit(lambda so_far, first, values, tok, wt, count:
+                   moe._token_sums(
+                       "sums", so_far, first, values, tok,
+                       wt if weighted else None, count, _MOST, _BLOCK))
+    args = (jnp.asarray(so_far), jnp.bool_(first),
+            jnp.asarray(values, jnp.bfloat16), jnp.asarray(tok),
+            jnp.asarray(wt), jnp.int32(-(-used // _BLOCK)))
+    got = sums(*args)
+    assert moe.route_counts()["token_sums"] == before + 1
+    assert got.shape == (_T, _K) and got.dtype == jnp.float32
+    bf16 = np.asarray(jnp.asarray(values, jnp.bfloat16), np.float32)
+    want = np.zeros((_T, _K), np.float32)
+    np.add.at(want, tok[tok < _T], bf16[tok < _T] * (
+        wt[tok < _T, None] if weighted else 1))
+    absent = np.setdiff1d(np.arange(_T), tok)
+    assert not want[absent].any()
+    if not first:
+        want = so_far + want
+    # at most four float32 terms a token, added in another order
+    np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-6)
+    np.testing.assert_array_equal(np.asarray(got)[absent], want[absent])
+    text = sums.lower(*args).as_text()
+    assert "scatter" not in text and text.count("stablehlo.sort") == 1
+
+
+@pytest.mark.parametrize("t, chunk", [(1, 8), (128, 256), (129, 512),
+                                      (200, 64), (16384, 4096)])
+def test_rows_in_token_order_and_each_tokens_count(t, chunk):
+    """One stable sort by token; the count of a token's rows from the
+    one-hot digits' product, exact whether or not T fills its last row
+    of 128, and the run's start from its cumulative sum; token T (no
+    assignment) is counted nowhere and sorts last."""
+    rng = np.random.RandomState(t)
+    tok = rng.randint(0, t + 1, size=chunk).astype(np.int32)
+    order = jax.jit(lambda tok, wt: moe._in_token_order(tok, wt, t))
+    wt = rng.rand(chunk).astype(np.float32)
+    token, row, weight, rows, start = order(jnp.asarray(tok), jnp.asarray(wt))
+    want = np.bincount(tok[tok < t], minlength=t)
+    by_token = np.argsort(tok, kind="stable")
+    np.testing.assert_array_equal(rows, want)
+    np.testing.assert_array_equal(start, np.cumsum(want) - want)
+    np.testing.assert_array_equal(row, by_token)
+    np.testing.assert_array_equal(token, tok[by_token])
+    np.testing.assert_array_equal(weight, wt[by_token])
+    text = order.lower(jnp.asarray(tok), jnp.asarray(wt)).as_text()
+    assert "scatter" not in text and text.count("stablehlo.sort") == 1
+
+
+@pytest.mark.parametrize("form", moe.FORMS)
+def test_a_token_whose_rows_straddle_two_trips_is_summed_over_both(form):
+    """5,000 assignments in chunks of 4,096 rows: the tokens with a row in
+    each of the two trips get both parts, in float32, and their gradient
+    reaches u from both."""
+    rng = np.random.RandomState(44)
+    plan = _hand_plan(5000, 8192, rng)
+    assert int(moe.plan_chunks(plan.group_sizes)) == 2
+    token = np.asarray(plan.token)
+    both = np.intersect1d(token[:4096], token[4096:5000])
+    assert both.size > 100
+    u, w1, w2 = _weights(rng, form)
+
+    def total(fn):
+        return jax.jit(jax.value_and_grad(
+            lambda u: fn(u)[both].sum(), has_aux=False))
+
+    got = total(lambda u: moe.experts(u, plan, w1, w2, form))(u)
+    want = total(lambda u: _per_row(u, plan, plan.weight, w1, w2, form))(u)
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(a, b, rtol=2e-4, atol=2e-4)
+    assert np.asarray(got[1])[both].any(axis=1).all()
 
 
 @pytest.mark.parametrize("form", moe.FORMS)
@@ -222,12 +354,16 @@ def test_the_stage_is_traced_once_a_signature_not_once_a_layer():
         one = _stack(1, "relu2")
         assert one["ragged_dot"] == 2 and one["sorted_layout"] == 1
         assert 2 <= one["expert_stage_traces"] <= 3     # forward, backward
+        # the sums are traced where a loop is: once a trace of either
+        assert one["token_sums"] == one["expert_stage_traces"]
         jax.clear_caches()
         three = _stack(3, "relu2")
         assert three["ragged_dot"] == 6 and three["sorted_layout"] == 3
         assert three["expert_stage_traces"] == one["expert_stage_traces"]
+        assert three["token_sums"] == three["expert_stage_traces"]
         again = _stack(3, "relu2")              # every call binds a trace
         assert again["ragged_dot"] == 6 and again["expert_stage_traces"] == 0
+        assert again["token_sums"] == 0
         gated = _stack(3, "silu_gated")
         assert gated["expert_stage_traces"] == one["expert_stage_traces"]
         # a plan of 5,632 rows: row_chunk(2048) is the 4,096 it already

@@ -339,6 +339,48 @@ def test_route_and_its_gradient_lower_without_a_scatter(cell):
     assert f"tensor<{moe.plan_rows(t, top_k, n_local)}xi32>" in forward
 
 
+# tokens, held experts, top_k, K, N, form, expected_rows
+_STAGE_SHAPES = {
+    "lfm2_8b_a1b_s8192": (16384, 8, 4, 2048, 1792, "silu_gated", 16384),
+    "laguna_xs2_s8192": (16384, 32, 8, 2048, 512, "silu_gated", 16384),
+    "joyai_llm_flash_s8192": (16384, 32, 8, 2048, 768, "silu_gated", 16384),
+    "nemotron3_super_s8192": (8192, 8, 22, 1024, 2688, "relu2", 0),
+}
+
+
+@pytest.mark.parametrize("cell", list(_STAGE_SHAPES))
+def test_the_expert_stage_and_its_gradient_lower_without_a_scatter(
+        cell, monkeypatch):
+    """At the four cells' shapes, from abstract arrays: a token sums its
+    own rows, in the forward's combine and in the backward's transpose of
+    the gather of u (one sort each), and nothing in either loop is a
+    scatter (PERF.md, PR 44: the two whole-chunk scatter-adds were ~10 of
+    a layer's 19-26 ms)."""
+    monkeypatch.setenv("MXNET_USE_PALLAS", "0")
+    t, held, top_k, k, n, form, expected = _STAGE_SHAPES[cell]
+    rows = moe.plan_rows(t, top_k, held)
+    chunk = min(moe.row_chunk(expected), rows)
+    stage = moe._Stage(form, chunk, moe.row_block(chunk), False)
+    wide = (2 if form == "silu_gated" else 1) * n
+    operands = (jax.ShapeDtypeStruct((t, k), jnp.bfloat16),
+                jax.ShapeDtypeStruct((rows,), jnp.int32),
+                jax.ShapeDtypeStruct((rows,), jnp.float32),
+                jax.ShapeDtypeStruct((held,), jnp.int32),
+                jax.ShapeDtypeStruct((held, k, wide), jnp.bfloat16),
+                jax.ShapeDtypeStruct((held, n, k), jnp.bfloat16))
+    before = moe.route_counts()
+    forward = moe._forward.lower(*operands, stage).as_text()
+    backward = moe._backward.lower(stage, operands, operands[0]).as_text()
+    after = moe.route_counts()
+    assert after["token_sums"] - before["token_sums"] == 2
+    assert (after["expert_stage_traces"]
+            - before["expert_stage_traces"]) == 2
+    for text in (forward, backward):
+        assert "scatter" not in text
+        assert text.count("stablehlo.sort") == 1
+        assert f"tensor<{t}x{k}xf32>" in text       # the chunk's (T, K) part
+
+
 def test_four_expert_parallel_shares_equal_one_device_with_gradients():
     """`moe_apply` over four virtual `ep` devices (each sorts its own
     share's rows under a traced `first_expert`) against the layer in one
@@ -483,9 +525,11 @@ def test_experts_in_chunks_match_the_dense_form(monkeypatch, load, wrap):
 def test_plan_chunks_and_plan_blocks_are_the_trip_counts_of_the_loops(
         monkeypatch):
     """`plan_chunks` and `plan_blocks` say how often the loops ran: the
-    forward weighs a chunk a trip (its scatter-add is one call a chunk),
-    the backward a block a trip of its inner loop, counted on the
-    host."""
+    forward weighs a block's rows a trip of the loop in which a token
+    sums them, once a row a token can have (two held experts: two
+    shifted windows), the backward a block a trip of two such loops (the
+    weights' gradient, once; the sums of `dx` into u's gradient, which
+    mask only, twice), counted on the host."""
     w, plan = _loaded_plan(monkeypatch, "every_token_several_chunks")
     monkeypatch.setattr(moe, "ROW_BLOCK", 8)
     assert int(plan.dropped) == 0
@@ -506,11 +550,12 @@ def test_plan_chunks_and_plan_blocks_are_the_trip_counts_of_the_loops(
         jax.effects_barrier()
         chunks = int(moe.plan_chunks(plan.group_sizes))
         blocks = int(moe.plan_blocks(plan.group_sizes, rows=rows))
-        assert len(ran) == chunks == 5 and blocks == 10
+        assert chunks == 5 and blocks == 10 and len(ran) == 2 * blocks
         jax.block_until_ready(jax.jit(jax.grad(
             lambda u: moe.experts(u, *args[1:]).sum()))(args[0]))
         jax.effects_barrier()
-        assert len(ran) == 2 * chunks + blocks
+        # forward; forward again, the weights' gradient, the sums of dx
+        assert len(ran) == (2 + 2 + 1 + 2) * blocks
     finally:
         jax.clear_caches()  # and no later test binds the counted one
 
