@@ -23,21 +23,18 @@ scores and statistics in float32.  At S = 32768, window 2048, chunk 16 a
 query reads at most 2048 keys and 1920 summaries where causal attention
 reads up to 32768 keys.
 
-Which call takes which route (counted by `pallas_attention.route_counts()`
-at trace time, chosen from what the op can observe; no switch of its own):
+Which call takes which route (counted by `pallas_attention.route_counts()`;
+how a route is chosen is `ops/kernel_route.py`'s business):
 
-  * heads of 128 (or a multiple), S and S / chunk multiples of 128, no
-    mesh of several devices: `eva_splash`, upstream's splash kernels
-    (forward, dQ, dK/dV under their own custom_vjp) over the keys
-    [k ; K~] and a mask computed in the kernel, `local | remote` of
-    shape (S, S + S / chunk); only the blocks the mask touches are
-    visited.  Lowered for the TPU a failure raises; lowered for the CPU
-    the call runs the XLA form below (or the Pallas interpreter under
-    MXNET_PALLAS_INTERPRET=1).
-  * everything else (other shapes, a mesh, MXNET_USE_PALLAS=0):
-    `eva_xla`, windows as a batch dimension, each against its own keys
-    and against all the summaries: scores (B, H, S, window + S / chunk)
-    where a dense mask would hold (B, H, S, S + S / chunk).
+  * heads of 128 (or a multiple), S and S / chunk multiples of 128:
+    `eva_splash`, upstream's splash kernels (forward, dQ, dK/dV under
+    their own custom_vjp) over the keys [k ; K~] and a mask computed in
+    the kernel, `local | remote` of shape (S, S + S / chunk); only the
+    blocks the mask touches are visited.
+  * everything else: `eva_xla`, windows as a batch dimension, each
+    against its own keys and against all the summaries: scores (B, H, S,
+    window + S / chunk) where a dense mask would hold (B, H, S, S + S /
+    chunk).
   * window >= S has no earlier window: causal attention, handed to
     `dot_product_attention`'s routes.
 
@@ -52,7 +49,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..util import env
+from . import kernel_route
 from . import pallas_attention as pa
 from .registry import register_op
 
@@ -214,17 +211,17 @@ def _packed(core, heads, **sizes):
 @functools.partial(jax.jit, static_argnames=("heads", "scale", "window",
                                              "chunk", "interpret"))
 def _attend_eva(q, k, v, ks, vs, heads, scale, window, chunk, interpret):
-    """Packed in, packed out: the splash kernels in a program lowered
-    for the TPU (or anywhere under the interpreter), the windowed XLA
-    form elsewhere.  Jitted, so that a stack of layers traces and lowers
-    the kernels once."""
+    """Packed in, packed out: the splash kernels or the windowed XLA
+    form.  Jitted, so that a stack of layers traces and lowers the
+    kernels once."""
     sizes = dict(scale=scale, window=window, chunk=chunk)
-    if interpret:
-        return _packed(_eva_splash, heads, interpret=True, **sizes)(
-            q, k, v, ks, vs)
-    return jax.lax.platform_dependent(
-        q, k, v, ks, vs, tpu=_packed(_eva_splash, heads, **sizes),
-        default=_packed(_eva_xla, heads, **sizes))
+    return kernel_route.dispatch(
+        _packed(_eva_splash, heads, interpret=interpret, **sizes),
+        _packed(_eva_xla, heads, **sizes), q, k, v, ks, vs,
+        interpret=interpret)
+
+
+_EVA_SPLASH = kernel_route.Kernel("attention", "eva_splash", "eva_xla")
 
 
 @register_op("eva_attention")
@@ -250,12 +247,9 @@ def _eva_attention(query, key, value, key_summary, value_summary,
             f"{value_summary.shape} for {s} positions in chunks of {chunk}")
     packed = (query, key, value, key_summary, value_summary)
     sizes = dict(scale=float(scale), window=int(window), chunk=int(chunk))
-    if (env.get_bool("MXNET_USE_PALLAS") and d % 128 == 0
-            and _splash_block(s, s // chunk)
-            and pa._mesh_batch_axes(b) is None):
-        pa._count_kernel_route("eva_splash", b, h, s, d, query.dtype)
-        return _attend_eva(
-            *packed, heads=h, **sizes,
-            interpret=env.get_bool("MXNET_PALLAS_INTERPRET"))
-    pa._count_route("eva_xla")
+    if kernel_route.choose(
+            _EVA_SPLASH, d % 128 == 0 and _splash_block(s, s // chunk), b,
+            kept=pa._splash_kept(b, h, s, d, query.dtype)):
+        return _attend_eva(*packed, heads=h, **sizes,
+                           interpret=kernel_route.interpret())
     return _packed(_eva_xla, h, **sizes)(*packed)

@@ -21,30 +21,26 @@ apart from the `FullyConnected` of the MLPs and the head.
 `latent_attention`: the causal core.  Every head's key is [its own
 k_nope ; the shared rotated k_r], so queries and keys are nope + rope
 wide and values v_dim wide.  Which call takes which route (counted by
-`pallas_attention.route_counts()` at trace time, chosen from what the op
-can observe; no switch of its own):
+`pallas_attention.route_counts()`; how a route is chosen is
+`ops/kernel_route.py`'s business):
 
   * values in whole 128-lane blocks, queries and keys in whole or half
-    ones, S a multiple of 128, no mesh of several devices:
-    `latent_splash`, the splash kernels `flash_causal` runs
-    (`pallas_attention._attend_causal`) with one query head a key head,
-    on keys concatenated from k_nope and k_r broadcast over the heads
-    (the rotary key is written H times in HBM and its gradient summed
-    over the heads by XLA; a kernel that reads it once is open: PERF.md
-    section 7).  192 goes in as it is: zero-padded to 256 a layer's
-    forward + backward read 63.6 ms against 62.6 on the v5e (PERF.md,
-    PR 39).  The forward rule names its output and logsumexp
-    `latent_splash`, so a recomputed segment keeps them.  Lowered for
-    the CPU the call runs the XLA form (or the Pallas interpreter under
-    MXNET_PALLAS_INTERPRET=1).
-  * everything else (other shapes, a mesh, MXNET_USE_PALLAS=0):
-    `latent_xla`, dense causal scores in float32.
+    ones, S a multiple of 128: `latent_splash`, the splash kernels
+    `flash_causal` runs (`pallas_attention._attend_causal`) with one
+    query head a key head, on keys concatenated from k_nope and k_r
+    broadcast over the heads (the rotary key is written H times in HBM
+    and its gradient summed over the heads by XLA; a kernel that reads
+    it once is open: PERF.md section 7).  192 goes in as it is:
+    zero-padded to 256 a layer's forward + backward read 63.6 ms against
+    62.6 on the v5e (PERF.md, PR 39).  The forward rule names its output
+    and logsumexp `latent_splash`, so a recomputed segment keeps them.
+  * everything else: `latent_xla`, dense causal scores in float32.
 """
 from __future__ import annotations
 
 import jax.numpy as jnp
 
-from ..util import env
+from . import kernel_route
 from . import pallas_attention as pa
 from .nn import _rms_norm
 from .registry import register_op
@@ -88,6 +84,10 @@ def _keys(k_nope, k_rope, heads):
     return jnp.concatenate([k_nope, shared], axis=-1)
 
 
+_LATENT_SPLASH = kernel_route.Kernel("attention", "latent_splash",
+                                     "latent_xla")
+
+
 @register_op("latent_attention")
 def _latent_attention(query, key_nope, key_rope, value, num_heads=1,
                       scale=None):
@@ -106,14 +106,12 @@ def _latent_attention(query, key_nope, key_rope, value, num_heads=1,
         scale = d ** -0.5
     qh, vh = pa._split_to_heads(query, h), pa._split_to_heads(value, h)
     kh = _keys(key_nope, key_rope, h)
-    if (env.get_bool("MXNET_USE_PALLAS")
-            and pa._causal_flash_shape(h, h, s, s, d, d_v)
-            and pa._mesh_batch_axes(b) is None):
-        pa._count_kernel_route("latent_splash", b, h, s, d_v, query.dtype)
+    if kernel_route.choose(
+            _LATENT_SPLASH, pa._causal_flash_shape(h, h, s, s, d, d_v), b,
+            kept=pa._splash_kept(b, h, s, d_v, query.dtype)):
         oh = pa._attend_causal(qh, kh, vh, float(scale), None,
-                               env.get_bool("MXNET_PALLAS_INTERPRET"),
+                               kernel_route.interpret(),
                                name="latent_splash")
     else:
-        pa._count_route("latent_xla")
         oh = pa._causal_xla(qh, kh, vh, float(scale))
     return oh.transpose(0, 2, 1, 3).reshape(b, s, h * d_v)

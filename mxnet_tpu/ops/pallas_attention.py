@@ -6,68 +6,43 @@ _contrib_interleaved_matmul_selfatt_* used by GluonNLP BERT); this is the
 TPU-native equivalent per SURVEY.md §7 ("fused cells (RNN/attention) …
 in Pallas").
 
-Which call takes which route (`route_counts()`; chosen at trace time from
-what the op can observe: shape, train mode, the platform the program is
-lowered for; no switch of its own):
+Which call takes which route (`route_counts()`).  How a route is chosen,
+kernel or XLA twin, is `ops/kernel_route.py`'s business; here is what
+each route covers:
 
   * Dropout-free calls (inference, p=0): `_attend`.  One Pallas kernel per
     (batch*head, q-block), the query block in VMEM, keys/values for the
     whole sequence as one block, scores on the MXU in fp32 and never in
     HBM; backward = recompute through jax.vjp of the XLA reference under
-    custom_vjp.  Counted `kernel_infer` (`reference` under
-    MXNET_USE_PALLAS=0).
+    custom_vjp.  Counted `kernel_infer`; its twin `reference`.
   * Causal self-attention with no dropout and no key mask, heads of 128
-    (or a multiple) or of 64, sq == sk a multiple of 128, training or
-    inference,
-    query heads a multiple of the key/value heads (grouped-query):
-    `_attend_causal`, upstream's splash multi-query kernels
-    (jax.experimental.pallas.ops.tpu.splash_attention: forward, dK/dV
-    and dQ under their own custom_vjp, one online-softmax pass over the
-    blocks of a `CausalMask`, those above the diagonal skipped).  O(S)
-    memory: the only route that fits a decoder at S = 8192, where
-    `_attend`'s backward would hold 32 x 8192^2 scores.  The query heads
-    of one key/value head go through the multi-query kernel together:
-    the key/value heads are not repeated in HBM, their gradients leave
-    the kernel summed over the group, and the softmax statistic kept
-    for the backward is one (H, S) float32 logsumexp.  Not under a mesh
-    of several devices (a bare Mosaic call), where the call takes the
-    dropout-free route below.  Counted `flash_causal` (the key is older
-    than the kernels: until PR 38 upstream's `flash_attention` kernels
-    ran here, on key/value heads repeated to the query heads and with
-    row sums and maxima 128 lanes wide).
+    (or a multiple) or of 64, sq == sk a multiple of 128, query heads a
+    multiple of the key/value heads (grouped-query), training or
+    inference: `_attend_causal`, upstream's splash multi-query kernels
+    over a `CausalMask` (`_causal_splash`).  O(S) memory: the only route
+    that fits a decoder at S = 8192, where `_attend`'s backward would
+    hold 32 x 8192^2 scores.  A call it does not admit takes the
+    dropout-free route.  Counted `flash_causal` (the key is older than
+    the kernels: until PR 38 upstream's `flash_attention` kernels ran
+    here).
   * Causal sliding-window self-attention (`sliding_window_attention`, an
     op of its own so that a profile reads the window and the full cores
     apart): query i sees keys j with 0 <= i - j < window.  Shapes as for
-    `flash_causal`, window < S: the same `_attend_causal` and the same
-    kernels over a `LocalMask`, which visit only the blocks the band
-    touches: O(S x window) work where `flash_causal` does O(S^2 / 2).
-    Not under a mesh of several devices.  Counted `splash_window`;
-    every other windowed call (odd shapes, a mesh, MXNET_USE_PALLAS=0)
-    is the banded XLA form, counted `reference`; window >= S is causal
-    attention and takes that route.
-  * `eva_attention` (ops/eva_attention.py: exact attention inside a
-    query's window plus one pooled key and value a chunk of every
-    earlier window, one softmax over both) counts its routes here too:
-    `eva_splash`, the splash kernels over [keys ; summaries] and a mask
-    computed in the kernel, and `eva_xla`, its windowed XLA form.
-  * `latent_attention` (ops/latent_attention.py: causal attention whose
-    queries and keys are 192 wide, 64 of every key one rotated vector
-    all heads share, and whose values are 128 wide) counts here too:
-    `latent_splash`, `_attend_causal`'s kernels with a value size of
-    their own, and `latent_xla`.
+    `flash_causal`, window < S: `_attend_causal` over a `LocalMask`,
+    O(S x window) work where `flash_causal` does O(S^2 / 2).  Counted
+    `splash_window`; every other windowed call is the banded XLA form,
+    counted `reference`; window >= S is causal attention.
+  * `eva_attention` and `latent_attention` (modules of their own) count
+    their routes here too: `eva_splash` / `eva_xla`, `latent_splash` /
+    `latent_xla`.
   * Training with dropout on the probabilities, self-attention shaped as
     BERT's (not causal, sq == sk, a multiple of 128 up to 1024, heads of
     64, 128 or 256 filling whole 128-lane blocks): `_attend_train`, two
-    fused kernels under one custom_vjp (`mx_attention_train_fwd`;
-    `mx_attention_train_bwd` = dQ, dK and dV together), adapted from
-    jax.experimental.pallas.ops.tpu.flash_attention.  They work in the
-    packed (B, S, H*D) layout: no S x S tensor and no head-split copy
-    reaches HBM; the dropout mask is regenerated in each kernel.  Under a
-    mesh of several devices whose only splitting axes are the batch's
-    (dp / fsdp) the kernels run per batch shard inside a shard_map (GSPMD
-    cannot partition a Mosaic call), the mask still indexed globally;
-    under a mesh that splits anything else the call takes the next route.
-    Counted `fused_train`.
+    fused kernels under one custom_vjp in the packed (B, S, H*D) layout
+    (the comment above `_FUSED_MAX_SEQ`).  The one kernel that takes a
+    batch shard (`kernel_route.BATCH_SHARDS`): its seed holds the
+    shard's first row, so the mask is indexed globally.  Counted
+    `fused_train`.
   * Every other training call with dropout (causal, cross, ragged
     lengths: the NMT model, toy shapes; tensor- or sequence-parallel
     meshes): `_attention_with_prob_dropout`, XLA, probabilities saved for
@@ -80,19 +55,8 @@ is why the dropout STREAM differs from the threefry `bernoulli` this op
 used before PR 26: same distribution, other draws, so a loss pinned under
 attention dropout moved once.
 
-The three routes that run upstream's splash kernels (`flash_causal`,
-`splash_window`, `eva_splash`) NAME what the forward kernel wrote for the
-backward kernels, the output and the logsumexp, inside the forward rule
-of the kernels' custom VJP (ops/residuals.py): a recomputed segment
-(gluon/block.py: `SPMDTrainer(remat=True)`) keeps those and runs the
-forward kernel once a step, where it would run it again in the backward
-pass only to get them back; `residuals.kept_residuals()` counts them.
-Their XLA twins name nothing and are recomputed whole.
-
-A program lowered for CPU has no Mosaic and takes the pure-XLA path of
-the same function (or the Pallas interpreter under
-MXNET_PALLAS_INTERPRET=1); MXNET_USE_PALLAS=0 selects the XLA paths
-anywhere.  Lowered for TPU, a lowering or compile failure raises.
+The splash routes NAME what the forward kernel wrote for the backward,
+output and logsumexp (ops/residuals.py); their XLA twins name nothing.
 """
 from __future__ import annotations
 
@@ -103,9 +67,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..telemetry import instruments as _instruments
-from ..util import env
-from . import residuals
+from . import kernel_route
 from .registry import register_op
 
 __all__ = ["dot_product_attention_ref", "dropout_keep_mask", "route_counts"]
@@ -183,7 +145,7 @@ def _attention_pallas(q, k, v, mask, scale, causal=False):
         ],
         out_specs=pl.BlockSpec((1, bq, d), lambda b, i: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, s_pad, d), q.dtype),
-        interpret=env.get_bool("MXNET_PALLAS_INTERPRET"),
+        interpret=kernel_route.interpret(),
         name="mx_attention_fwd",    # the kernel's name in a device profile
     )(q, k, v, mask[:, None, :])
     return out[:, :s]
@@ -191,22 +153,14 @@ def _attention_pallas(q, k, v, mask, scale, causal=False):
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
 def _attend(q, k, v, mask, scale, causal=False):
-    """Kernel or reference, chosen from what the code can observe: the
-    platform the enclosing program is LOWERED for (a cpu()-resident warm
-    pass on a TPU host lowers for CPU and takes the reference; the same
-    call inside the TPU step takes the kernel).  Nothing is probed and
-    nothing latches: when the program lowers for TPU the kernel runs or
-    the call raises with Mosaic's own message."""
-    if not env.get_bool("MXNET_USE_PALLAS"):
-        return dot_product_attention_ref(q, k, v, mask, scale, causal)
-    if env.get_bool("MXNET_PALLAS_INTERPRET"):
-        return _attention_pallas(q, k, v, mask, scale, causal)
-    return jax.lax.platform_dependent(
-        q, k, v, mask,
-        tpu=functools.partial(_attention_pallas, scale=scale,
-                              causal=causal),
-        default=functools.partial(dot_product_attention_ref, scale=scale,
-                                  causal=causal))
+    """`_attention_pallas` or the reference, as `kernel_route` chooses."""
+    reference = functools.partial(dot_product_attention_ref, scale=scale,
+                                  causal=causal)
+    if not kernel_route.admit(_KERNEL_INFER, True, q.shape[0]):
+        return reference(q, k, v, mask)
+    return kernel_route.dispatch(
+        functools.partial(_attention_pallas, scale=scale, causal=causal),
+        reference, q, k, v, mask, interpret=kernel_route.interpret())
 
 
 def _attend_fwd(q, k, v, mask, scale, causal):
@@ -479,22 +433,7 @@ def _train_specs(pl, batch, heads, seq, d, blocks):
 _STATICS = ("heads", "scale", "keep")
 
 
-def _shared_kernel(fn):
-    """`fn` jitted, so that the twelve layers of a step trace and lower
-    each kernel ONCE: a Pallas kernel costs ~0.15 s to trace and lower, on
-    every start of the process, with or without a compile-cache hit (7 s
-    of `setup_s` in PR 26 before this).  The interpreter switch is read
-    per call and is part of the jit's key."""
-    jitted = jax.jit(fn, static_argnames=_STATICS + ("blocks", "interpret"))
-
-    @functools.wraps(fn)
-    def call(*operands, **statics):
-        return jitted(*operands, **statics,
-                      interpret=env.get_bool("MXNET_PALLAS_INTERPRET"))
-    return call
-
-
-@_shared_kernel
+@kernel_route.shared_kernel(*_STATICS, "blocks")
 def _attention_train_fwd_pallas(q, k, v, mask, seed, heads, scale, keep,
                                 blocks=None, interpret=False):
     """Forward kernel: q, k, v (B, S, H*D), mask (B, S), seed uint32[3]
@@ -556,7 +495,7 @@ def _attention_train_fwd_pallas(q, k, v, mask, seed, heads, scale, keep,
     )(seed, q, k, v, mask.astype(jnp.float32)[:, None, :])
 
 
-@_shared_kernel
+@kernel_route.shared_kernel(*_STATICS, "blocks")
 def _attention_train_bwd_pallas(q, k, v, mask, seed, o, lse, do, heads,
                                 scale, keep, blocks=None, interpret=False):
     """Backward kernel, same grid; dK and dV accumulate over the query
@@ -705,45 +644,25 @@ def _train_bwd_xla(q, k, v, mask, seed, o, lse, do, heads, scale, keep):
     return vjp(do)
 
 
-def _on_tpu_else(kernel, reference, shard, q, k, v, mask, key_words, *rest):
-    """The kernel in a program lowered for the TPU (or everywhere under
-    the interpreter), the XLA reference of the same function elsewhere —
-    as `_attend` chooses, from the platform the program is lowered for.
-    Every operand and result but `key_words` leads with the batch.  With
-    `shard` = (mesh, batch axes) from `_mesh_batch_axes` each device runs
-    this on its batch shard inside a shard_map, as `pallas_convbn` does:
-    GSPMD cannot partition a Mosaic call.  The shard's first global row
-    goes into the seed, so the mask does not depend on the mesh."""
+def _on_tpu_else(kernel, reference, shard, *operands):
+    """`kernel` or the XLA `reference` of the same function on operands
+    (q, k, v, mask, key_words, ...), one call or one a batch shard as
+    `kernel_route.admit` said (`shard`): the first global row a call
+    holds goes into its seed, so the mask does not depend on the mesh."""
     def run(first_row, q, k, v, mask, key_words, *rest):
-        operands = (q, k, v, mask, _seed(key_words, first_row), *rest)
-        if env.get_bool("MXNET_PALLAS_INTERPRET"):
-            return kernel(*operands)
-        return jax.lax.platform_dependent(*operands, tpu=kernel,
-                                          default=reference)
+        return kernel_route.dispatch(
+            kernel, reference, q, k, v, mask, _seed(key_words, first_row),
+            *rest, interpret=kernel_route.interpret())
 
-    if shard is None:
-        return run(0, q, k, v, mask, key_words, *rest)
-    from jax.sharding import PartitionSpec as P
-
-    from ..parallel._compat import shard_map_unchecked
-
-    mesh, axes = shard
-    rows = P(axes)
-
-    def per_shard(q, *more):
-        return run(jax.lax.axis_index(axes) * q.shape[0], q, *more)
-
-    return shard_map_unchecked(
-        per_shard, mesh=mesh,
-        in_specs=(rows,) * 4 + (P(),) + (rows,) * len(rest),
-        out_specs=rows)(q, k, v, mask, key_words, *rest)
+    return kernel_route.per_batch_shard(run, shard, *operands,
+                                        replicated=(4,))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8))
-def _attend_train(q, k, v, mask, key_words, heads, scale, keep, shard=None):
+def _attend_train(q, k, v, mask, key_words, heads, scale, keep, shard=True):
     """Self-attention with dropout on the probabilities through the fused
     kernels: q, k, v (B, S, H*D); mask (B, S); key_words uint32[2]; shard
-    from `_mesh_batch_axes`."""
+    from `kernel_route.admit`."""
     return _attend_train_fwd(q, k, v, mask, key_words, heads, scale, keep,
                              shard)[0]
 
@@ -769,26 +688,6 @@ def _attend_train_bwd(heads, scale, keep, shard, res, do):
 
 
 _attend_train.defvjp(_attend_train_fwd, _attend_train_bwd)
-
-
-def _mesh_batch_axes(batch):
-    """How the fused kernels run under the active mesh (`with mesh:`, as
-    `SPMDTrainer` holds it around the traced step): None where no mesh of
-    several devices is active (one bare call); (jax mesh, batch axes)
-    where dp / fsdp are the only axes that split anything and split
-    `batch` evenly (one call a batch shard, `_on_tpu_else`); False where
-    the mesh splits otherwise (tp, sp, ...: the heads or the sequence may
-    be sharded, and the XLA route is the one GSPMD can partition)."""
-    from ..parallel.mesh import current_mesh
-
-    m = current_mesh()
-    if m is None or m.mesh.size == 1:
-        return None
-    axes = tuple(a for a in ("dp", "fsdp") if m.axis_sizes.get(a, 1) > 1)
-    shards = math.prod(m.axis_sizes[a] for a in axes)
-    if shards != m.mesh.size or batch % shards:
-        return False
-    return m.mesh, axes
 
 
 def _fused_train_shape(heads, sq, sk, d, causal):
@@ -908,8 +807,7 @@ def _causal_splash(q, k, v, scale, window=None, interpret=False,
     over batch and key/value heads: k and v go in as they are, and dK,
     dV come out summed over the group.  The forward rule names its
     output and its (H, S) float32 logsumexp by the route (`name`; None:
-    `flash_causal` or `splash_window` by the mask), so that a recomputed
-    segment keeps them (ops/residuals.py)."""
+    `flash_causal` or `splash_window` by the mask)."""
     from jax.experimental.pallas.ops.tpu import splash_attention as sa
 
     b, h, s, d = q.shape
@@ -939,50 +837,42 @@ def _causal_splash(q, k, v, scale, window=None, interpret=False,
 def _attend_causal(q, k, v, scale, window, interpret, name=None):
     """q (B, H, S, D), k (B, Hkv, S, D) and v (B, Hkv, S, Dv) -> (B, H,
     S, Dv), causal, under a sliding `window` or none (None): the splash
-    kernels in a program lowered for the TPU (or anywhere under the
-    interpreter), the XLA form elsewhere, as `_attend` chooses; autodiff
-    goes through the chosen branch.  Jitted, so that a stack of layers
-    traces and lowers the kernels once a kind of layer."""
-    if interpret:
-        return _causal_splash(q, k, v, scale, window, interpret=True,
-                              name=name)
+    kernels or the XLA form.  Jitted, so that a stack of layers traces
+    and lowers the kernels once a kind of layer."""
     xla = (functools.partial(_causal_xla, scale=scale) if window is None
            else functools.partial(_window_xla, scale=scale, window=window))
-    return jax.lax.platform_dependent(
-        q, k, v, default=xla,
-        tpu=functools.partial(_causal_splash, scale=scale, window=window,
-                              name=name))
+    return kernel_route.dispatch(
+        functools.partial(_causal_splash, scale=scale, window=window,
+                          interpret=interpret, name=name),
+        xla, q, k, v, interpret=interpret)
 
 
-# Routes CHOSEN, counted where the branch is chosen: at TRACE time (once a
-# compiled program, never per step), not kernels run: a `fused_train` or
-# `kernel_infer` call in a program lowered for the CPU runs the XLA twin of
-# the same function.  The share of training calls that engaged the fused
-# route is fused_train / (fused_train + xla_dropout).  This dict is the
-# store; the telemetry counter `mx_attention_route_total{route}` is its
-# export and counts only while telemetry is enabled.
+# fused_train / (fused_train + xla_dropout) is the share of training calls
+# that engaged the fused route
 ROUTES = ("fused_train", "xla_dropout", "kernel_infer", "reference",
           "flash_causal", "splash_window", "eva_splash", "eva_xla",
           "latent_splash", "latent_xla")
-_route_counts = dict.fromkeys(ROUTES, 0)
+kernel_route.declare("attention", ROUTES)
+_FLASH_CAUSAL = kernel_route.Kernel("attention", "flash_causal", None)
+_FUSED_TRAIN = kernel_route.Kernel("attention", "fused_train", "xla_dropout",
+                                   kernel_route.BATCH_SHARDS)
+# never asked the mesh (ROADMAP.md Queue 3 item 3: no cell runs it)
+_KERNEL_INFER = kernel_route.Kernel("attention", "kernel_infer", "reference",
+                                    kernel_route.ANY_MESH)
+_SPLASH_WINDOW = kernel_route.Kernel("attention", "splash_window",
+                                     "reference")
 
 
 def route_counts():
     """{route: calls traced through it} since import: routes chosen at
     trace time, not kernels run.  Read it before and after to count."""
-    return dict(_route_counts)
+    return kernel_route.counts("attention")
 
 
-def _count_route(route):
-    _route_counts[route] += 1
-    _instruments.attention_route_total(route).inc()
-
-
-def _count_kernel_route(route, b, h, s, d, dtype):
-    """A route whose forward rule names what its kernel wrote for the
-    backward kernels: o (b, h, s, d) and its float32 logsumexp rows."""
-    _count_route(route)
-    residuals.note(route, 2, b * h * s * (d * np.dtype(dtype).itemsize + 4))
+def _splash_kept(b, h, s, d, dtype):
+    """What a splash route's forward rule names (`kernel_route.choose`'s
+    `kept`): o (b, h, s, d) and its float32 logsumexp rows."""
+    return 2, b * h * s * (d * np.dtype(dtype).itemsize + 4)
 
 
 @register_op("dot_product_attention",
@@ -1017,25 +907,25 @@ def _dot_product_attention(query, key, value, valid_mask=None, rng_key=None,
     if scale is None:
         scale = 1.0 / np.sqrt(d)
     dropping = _train and dropout > 0.0 and rng_key is not None
-    if (causal and not dropping and valid_mask is None
-            and env.get_bool("MXNET_USE_PALLAS")
-            and _causal_flash_shape(h, h_kv, sq, sk, d)
-            and _mesh_batch_axes(b) is None):
-        _count_kernel_route("flash_causal", b, h, sq, d, query.dtype)
+    if kernel_route.choose(
+            _FLASH_CAUSAL, causal and not dropping and valid_mask is None
+            and _causal_flash_shape(h, h_kv, sq, sk, d), b,
+            kept=_splash_kept(b, h, sq, d, query.dtype)):
         if packed:
             qh, kh, vh = (_split_to_heads(x, n) for x, n in (
                 (query, h), (key, h_kv), (value, h_kv)))
         else:
             qh, kh, vh = query, key, value
         oh = _attend_causal(qh, kh, vh, float(scale), None,
-                            env.get_bool("MXNET_PALLAS_INTERPRET"))
+                            kernel_route.interpret())
         return oh.transpose(0, 2, 1, 3).reshape(b, sq, h * d) if packed \
             else oh
-    shard = _mesh_batch_axes(b) if dropping else None
-    if (dropping and env.get_bool("MXNET_USE_PALLAS") and shard is not False
-            and h_kv == h and _fused_train_shape(h, sq, sk, d, causal)):
+    # a call that drops is counted here, `fused_train` or `xla_dropout`
+    shard = dropping and kernel_route.choose(
+        _FUSED_TRAIN, h_kv == h and _fused_train_shape(h, sq, sk, d, causal),
+        b)
+    if shard:
         # the kernels work in the packed layout: no head is split off
-        _count_route("fused_train")
         pack = (lambda x: x) if packed else (
             lambda x: x.transpose(0, 2, 1, 3).reshape(b, -1, h * d))
         mask = (jnp.ones((b, sk), query.dtype) if valid_mask is None
@@ -1059,13 +949,11 @@ def _dot_product_attention(query, key, value, valid_mask=None, rng_key=None,
     else:
         maskf = jnp.repeat(valid_mask.astype(qf.dtype), h, axis=0)
     if dropping:
-        _count_route("xla_dropout")
         of = _attention_with_prob_dropout(qf, kf, vf, maskf, float(scale),
                                           float(dropout), rng_key,
                                           causal=causal)
     else:
-        _count_route("kernel_infer" if env.get_bool("MXNET_USE_PALLAS")
-                     else "reference")
+        kernel_route.choose(_KERNEL_INFER, True, b)     # `_attend` admits
         of = _attend(qf, kf, vf, maskf, float(scale), bool(causal))
     oh = of.reshape(b, h, sq, d)
     if packed:
@@ -1098,13 +986,11 @@ def _sliding_window_attention(query, key, value, num_heads=1, window=0,
             causal=True)
     qh, kh, vh = (_split_to_heads(x, n) for x, n in (
         (query, h), (key, h_kv), (value, h_kv)))
-    if (env.get_bool("MXNET_USE_PALLAS")
-            and _causal_flash_shape(h, h_kv, s, s, d)
-            and _mesh_batch_axes(b) is None):
-        _count_kernel_route("splash_window", b, h, s, d, query.dtype)
+    if kernel_route.choose(
+            _SPLASH_WINDOW, _causal_flash_shape(h, h_kv, s, s, d), b,
+            kept=_splash_kept(b, h, s, d, query.dtype)):
         oh = _attend_causal(qh, kh, vh, float(scale), int(window),
-                            env.get_bool("MXNET_PALLAS_INTERPRET"))
+                            kernel_route.interpret())
     else:
-        _count_route("reference")
         oh = _window_xla(qh, kh, vh, float(scale), int(window))
     return oh.transpose(0, 2, 1, 3).reshape(b, s, u)

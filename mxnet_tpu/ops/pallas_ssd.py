@@ -41,7 +41,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from ..util import env
+from . import kernel_route
 
 _NT = (((1,), (1,)), ((), ()))      # A @ B^T
 _TN = (((0,), (0,)), ((), ()))      # A^T @ B
@@ -227,23 +227,7 @@ class _Tiles:
                 for m in masks]
 
 
-def _shared_kernel(*statics):
-    """The decorated function jitted so that a model's layers trace and
-    lower each kernel once (`ops.pallas_attention._shared_kernel`: 7 s of
-    `setup_s` in PR 26 before it); the interpreter switch is read per
-    call."""
-    def wrap(fn):
-        jitted = jax.jit(fn, static_argnames=statics + ("interpret",))
-
-        @functools.wraps(fn)
-        def call(*operands, **kw):
-            return jitted(*operands, **kw,
-                          interpret=env.get_bool("MXNET_PALLAS_INTERPRET"))
-        return call
-    return wrap
-
-
-@_shared_kernel("groups", "chunk", "hb", "keep_states")
+@kernel_route.shared_kernel("groups", "chunk", "hb", "keep_states")
 def ssd_forward(x, dt, cs, b, c, d, groups, chunk, hb=None,
                 keep_states=False, interpret=False):
     """x (B, S, H*P); dt, cs (B, S, H) float32, cs the cumulative sum of
@@ -339,7 +323,7 @@ def ssd_forward(x, dt, cs, b, c, d, groups, chunk, hb=None,
     return tuple(out) if keep_states else out[0]
 
 
-@_shared_kernel("groups", "chunk", "hb")
+@kernel_route.shared_kernel("groups", "chunk", "hb")
 def ssd_backward(x, dt, cs, b, c, d, states, dy, groups, chunk, hb=None,
                  interpret=False):
     """The forward's operands, its `states` and y's cotangent dy (B, S,

@@ -33,12 +33,10 @@ NAMES = ("flash_causal", "splash_window", "eva_splash", "latent_splash")
 KEEP_NAMED = jax.checkpoint_policies.save_only_these_names(*NAMES)
 
 # Counted where a route that names its residuals is CHOSEN inside a
-# segment, at TRACE time (once a compiled program, never per step), from
-# the shapes the kernel writes: what the segments traced since import
-# keep.  Not counted in the policy: `lax.cond`'s rule (every route sits
-# in a `platform_dependent`) consults a policy twice an equation.  This
-# dict is the store; the telemetry counter `mx_remat_kept_bytes_total
-# {name}` is its export and counts only while telemetry is enabled.
+# segment (`kernel_route.choose`), from the shapes the kernel writes.  Not
+# counted in the policy: `lax.cond`'s rule (every route sits in a
+# `platform_dependent`) consults a policy twice an equation.  The
+# telemetry counter `mx_remat_kept_bytes_total{name}` is the export.
 _kept = {name: {"values": 0, "bytes": 0} for name in NAMES}
 _lock = threading.Lock()
 _tracing = threading.local()    # .depth: segments this thread is tracing

@@ -14,21 +14,16 @@ pass through, those past r or, with `rotate_last`, those before the
 last r (a latent-attention query is [unrotated ; rotated]).  Query and
 key may differ in head size; a key all heads share is `num_kv_heads=1`.
 
-Which operand takes which form (`route_counts()`; chosen at trace time
-from what the op can observe in its arguments, one operand at a time):
+Which operand takes which form (`route_counts()`; one operand at a time,
+how a route is chosen is `ops/kernel_route.py`'s business):
 
   * `kernel`: `_turn`, one Pallas pass that reads x once and writes it
     once in its own dtype, float32 only in VMEM, with a backward rule of
-    its own (the same pass on the cotangent).  In a program lowered for
-    the TPU (`jax.lax.platform_dependent`; everywhere under
-    MXNET_PALLAS_INTERPRET=1), for heads of a whole number of 64 lanes
-    and a sequence that is a multiple of a row block.  A program
-    lowered for the CPU runs `_rotate` in its place.
+    its own (the same pass on the cotangent): heads of a whole number of
+    64 lanes and a sequence that is a multiple of a row block.
   * `xla`: `_rotate`, the product with a signed permutation in plain
     XLA, whose float32 product and sum XLA writes to HBM and reads back
-    (4-6 times the bytes of one pass: PERF.md, PR 40): every other shape,
-    a mesh of several devices (GSPMD cannot partition a Mosaic call),
-    MXNET_USE_PALLAS=0.
+    (4-6 times the bytes of one pass: PERF.md, PR 40): every other shape.
 """
 from __future__ import annotations
 
@@ -40,9 +35,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from ..telemetry import instruments as _instruments
-from ..util import env
-from .pallas_attention import _mesh_batch_axes
+from . import kernel_route
 from .registry import register_op
 
 __all__ = ["default_inv_freq", "yarn_inv_freq", "rotary_tables",
@@ -275,51 +268,38 @@ _turn.defvjp(_turn_fwd, _turn_bwd)
 @functools.partial(jax.jit, static_argnames=("heads", "interleaved",
                                              "rotate_last", "interpret"))
 def _rotate_on_tpu(x, cos, sin, heads, interleaved, rotate_last, interpret):
-    """The kernel in a program lowered for the TPU (everywhere under the
-    interpreter), `_rotate` elsewhere, as `pallas_attention._attend`
-    chooses; autodiff goes through the chosen branch.  Jitted, so that a
-    stack of layers traces and lowers the kernel once a kind of layer."""
+    """`_turn` or `_rotate`.  Jitted, so that a stack of layers traces
+    and lowers the kernel once a kind of layer."""
     pairing = dict(heads=heads, interleaved=interleaved,
                    rotate_last=rotate_last)
-    kernel = functools.partial(_turn, **pairing, interpret=interpret)
-    if interpret:
-        return kernel(x, cos, sin)
-    return jax.lax.platform_dependent(
-        x, cos, sin, tpu=kernel,
-        default=functools.partial(_rotate, **pairing))
+    return kernel_route.dispatch(
+        functools.partial(_turn, **pairing, interpret=interpret),
+        functools.partial(_rotate, **pairing), x, cos, sin,
+        interpret=interpret)
 
 
-# Routes CHOSEN, one an operand turned, counted where the branch is chosen:
-# at TRACE time (once a compiled program, never per step), not kernels run:
-# a `kernel` call in a program lowered for the CPU runs `_rotate`.  The
-# telemetry counter `mx_rotary_route_total{route}` is this dict's export.
+# one count an operand turned
 ROUTES = ("kernel", "xla")
-_route_counts = dict.fromkeys(ROUTES, 0)
+kernel_route.declare("rotary", ROUTES)
+_KERNEL = kernel_route.Kernel("rotary", "kernel", "xla")
 
 
 def route_counts():
     """{route: operands traced through it} since import, in the form of
     `pallas_attention.route_counts()`."""
-    return dict(_route_counts)
+    return kernel_route.counts("rotary")
 
 
 def _rotate_routed(x, cos, sin, heads, interleaved, rotate_last):
-    """One operand by the kernel where it applies: MXNET_USE_PALLAS, a
-    shape `_tiling` can tile, and no mesh of several devices (GSPMD cannot
-    partition a Mosaic call)."""
-    kernel = (env.get_bool("MXNET_USE_PALLAS")
-              and _mesh_batch_axes(x.shape[0]) is None
-              and _tiling(x.shape[1], heads, x.shape[-1] // heads,
-                          cos.shape[-1], rotate_last,
-                          x.dtype.itemsize) is not None)
-    route = "kernel" if kernel else "xla"
-    _route_counts[route] += 1
-    _instruments.rotary_route_total(route).inc()
-    if not kernel:
+    """One operand by the kernel where `_tiling` can tile its shape."""
+    if not kernel_route.choose(
+            _KERNEL, _tiling(x.shape[1], heads, x.shape[-1] // heads,
+                             cos.shape[-1], rotate_last, x.dtype.itemsize),
+            x.shape[0]):
         return _rotate(x, cos, sin, heads=heads, interleaved=interleaved,
                        rotate_last=rotate_last)
     return _rotate_on_tpu(x, cos, sin, heads, interleaved, rotate_last,
-                          env.get_bool("MXNET_PALLAS_INTERPRET"))
+                          kernel_route.interpret())
 
 
 @register_op("rotary_embedding", num_outputs=2)

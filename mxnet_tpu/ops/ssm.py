@@ -17,18 +17,14 @@ accumulate in float32, as the published kernels do.
 `ssd_scan_sequential` is the recurrence above, step by step: the oracle
 both routes are tested against.
 
-Two routes, one algorithm, chosen at trace time from what the op can
-observe (`route_counts()` counts them, as
-`ops.pallas_attention.route_counts()` does for attention):
+Two routes, one algorithm (`route_counts()`; how a route is chosen is
+`ops/kernel_route.py`'s business):
 
 - `fused_kernel` (PR 28): `ops.pallas_ssd`'s forward and backward kernels
-  under a custom_vjp, every per-chunk intermediate in VMEM, in a program
-  lowered for the TPU (its XLA twin elsewhere, the kernels themselves
-  under `MXNET_PALLAS_INTERPRET`); taken when the widths fill whole lanes
-  (`pallas_ssd.supports`) and no mesh of several devices is active.
+  under a custom_vjp, every per-chunk intermediate in VMEM; taken when
+  the widths fill whole lanes (`pallas_ssd.supports`).
 - `chunked_xla`: plain XLA einsums, backward by autodiff; every other
-  shape, any mesh of several devices, and the reference the kernels are
-  tested against.
+  shape, and the reference the kernels are tested against.
 """
 from __future__ import annotations
 
@@ -39,20 +35,19 @@ import jax.numpy as jnp
 from jax import lax
 
 from ..base import MXNetError
-from ..util import env
-from . import pallas_ssd
-from .pallas_attention import _mesh_batch_axes
+from . import kernel_route, pallas_ssd
 from .registry import register_op
 
 __all__ = ["ssd_scan_sequential", "route_counts"]
 
 ROUTES = ("chunked_xla", "fused_kernel")
-_route_counts = dict.fromkeys(ROUTES, 0)
+kernel_route.declare("ssd_scan", ROUTES)
+_FUSED_KERNEL = kernel_route.Kernel("ssd_scan", "fused_kernel", "chunked_xla")
 
 
 def route_counts():
     """{route: `ssd_scan` calls traced through it} since import."""
-    return dict(_route_counts)
+    return kernel_route.counts("ssd_scan")
 
 
 @register_op("causal_conv1d")
@@ -164,20 +159,16 @@ def _ssd_scan(x, dt, a_log, b, c, d, dt_bias, chunk=128):
         raise MXNetError(
             f"ssd_scan: sequence {s} must be a multiple of the chunk "
             f"{chunk}, heads {h} of the groups {g}")
-    # GSPMD cannot partition a Mosaic call: no mesh of several devices
-    fused = (env.get_bool("MXNET_USE_PALLAS")
-             and _mesh_batch_axes(x.shape[0]) is None
-             and pallas_ssd.supports(h, x.shape[3], g, b.shape[3], s, chunk))
-    _route_counts["fused_kernel" if fused else "chunked_xla"] += 1
     operands = (x, dt, a_log, b, c, d, dt_bias)
     xla = functools.partial(_scan_xla, chunk=chunk)
-    kernels = functools.partial(_scan_kernels, chunk=chunk)
-    if not fused:
+    if not kernel_route.choose(
+            _FUSED_KERNEL,
+            pallas_ssd.supports(h, x.shape[3], g, b.shape[3], s, chunk),
+            x.shape[0]):
         return xla(*operands)
-    if env.get_bool("MXNET_PALLAS_INTERPRET"):
-        return kernels(*operands)
-    # autodiff goes through the chosen branch, as in `_attend_causal`
-    return jax.lax.platform_dependent(*operands, tpu=kernels, default=xla)
+    return kernel_route.dispatch(
+        functools.partial(_scan_kernels, chunk=chunk), xla, *operands,
+        interpret=kernel_route.interpret())
 
 
 def _scan_xla(x, dt, a_log, b, c, d, dt_bias, chunk):

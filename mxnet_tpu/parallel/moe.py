@@ -70,8 +70,8 @@ moves selection only), weights scale * s / sum of the chosen s.
 
 The two grouped products run through the TPU's grouped-matmul kernel
 (`jax.experimental.pallas.ops.tpu.megablox`, which skips the empty tail)
-in a program lowered for the TPU and through `jax.lax.ragged_dot`
-elsewhere; `route_counts()` says at trace time which was asked for.
+or through `jax.lax.ragged_dot`, as `ops/kernel_route.py` chooses;
+`route_counts()` says at trace time which was asked for.
 """
 from __future__ import annotations
 
@@ -85,7 +85,7 @@ from jax import lax
 from jax.sharding import PartitionSpec as P
 
 from ..base import MXNetError
-from ..util import env
+from ..ops import kernel_route
 from ._compat import shard_map_unchecked
 from .mesh import DeviceMesh, current_mesh
 
@@ -104,8 +104,11 @@ ROW_BLOCK = 2048
 
 FORMS = ("relu2", "silu_gated")   # an expert's activation: see `experts`
 ROUTES = ("grouped_kernel", "ragged_dot")
-_route_counts = dict.fromkeys(
-    ROUTES + ("sorted_layout", "expert_stage_traces", "token_sums"), 0)
+kernel_route.declare("moe_experts", ROUTES + (
+    "sorted_layout", "expert_stage_traces", "token_sums"))
+# asks neither the mesh nor the interpreter (ROADMAP.md names the debt)
+_GROUPED_KERNEL = kernel_route.Kernel("moe_experts", "grouped_kernel",
+                                      "ragged_dot", kernel_route.ANY_MESH)
 
 
 def route_counts():
@@ -119,10 +122,8 @@ def route_counts():
     under `token_sums` how often a loop's sum over a token's rows was
     traced as the token reading them (`_token_sums`: the forward's
     combine, the backward's transpose of the gather; there is no other
-    form, so it follows the loops' traces).
-    As for attention, `grouped_kernel` in a program lowered for the CPU
-    runs its `ragged_dot` twin."""
-    return dict(_route_counts)
+    form, so it follows the loops' traces)."""
+    return kernel_route.counts("moe_experts")
 
 
 class RoutePlan(NamedTuple):
@@ -260,7 +261,7 @@ def route(x, w_router, bias, *, top_k: int, scale: float = 1.0,
 
     local = chosen - first_expert
     col = jnp.where((local >= 0) & (local < n_local), local, n_local)
-    _route_counts["sorted_layout"] += 1
+    kernel_route.count("moe_experts", "sorted_layout")
     token, weight, group_sizes = _layout(
         weights.reshape(-1), col.reshape(-1), top_k, n_local,
         plan_rows(t, top_k, n_local))
@@ -288,10 +289,6 @@ def _gmm(lhs, rhs, group_sizes):
                        _tile(rhs.shape[2], 512)))
 
 
-def _use_kernel() -> bool:
-    return env.get_bool("MXNET_USE_PALLAS")
-
-
 def _grouped(lhs, rhs, group_sizes, kernel):
     """lhs (rows, K) @ rhs[g] (K, N) for the rows of group g.  Rows past
     the last group come back undefined from the kernel: the caller masks
@@ -299,8 +296,8 @@ def _grouped(lhs, rhs, group_sizes, kernel):
     with jax.named_scope("products"):
         if not kernel:
             return _ragged(lhs, rhs, group_sizes)
-        return lax.platform_dependent(lhs, rhs, group_sizes, tpu=_gmm,
-                                      default=_ragged)
+        return kernel_route.dispatch(_gmm, _ragged, lhs, rhs, group_sizes,
+                                     interpret=False)
 
 
 def _rows_of(u, tok):
@@ -395,7 +392,7 @@ def _token_sums(name, into, first, values, tok, wt, count, most, block):
     token's sum at the head of its run, always in the same order of its
     terms; one gather of T head rows makes the chunk's part.  The cost
     follows the rows used and T, not the chunk."""
-    _route_counts["token_sums"] += 1
+    kernel_route.count("moe_experts", "token_sums")
     (t, _), chunk, halo, spare = into.shape, tok.shape[0], most - 1, 8
     with jax.named_scope(name):
         token, row, weight, rows, start = _in_token_order(tok, wt, t)
@@ -444,7 +441,7 @@ class _Stage(NamedTuple):
     form: str
     chunk: int      # rows a trip of the loop over the plan handles
     block: int      # rows a trip of a row-wise stage's loop inside it
-    kernel: bool    # `MXNET_USE_PALLAS`
+    kernel: bool    # whether `kernel_route` admits the grouped kernel
 
 
 def _chunks(token, weight, group_sizes, t, stage):
@@ -489,7 +486,7 @@ def _over_trips(trips, body, sums, *others):
 
 @functools.partial(jax.jit, static_argnums=6)
 def _forward(u, token, weight, group_sizes, w1, w2, stage):
-    _route_counts["expert_stage_traces"] += 1
+    kernel_route.count("moe_experts", "expert_stage_traces")
     t, block = u.shape[0], stage.block
     product = functools.partial(_grouped, kernel=stage.kernel)
     _, trips, window = _chunks(token, weight, group_sizes, t, stage)
@@ -521,7 +518,7 @@ def _backward(stage, res, g):
     trip count has no reverse mode of its own): the same chunks, the
     same blocks, each product and each row-wise stage under `jax.vjp` by
     itself."""
-    _route_counts["expert_stage_traces"] += 1
+    kernel_route.count("moe_experts", "expert_stage_traces")
     u, token, weight, group_sizes, w1, w2 = res
     t, block = u.shape[0], stage.block
     product = functools.partial(_grouped, kernel=stage.kernel)
@@ -611,8 +608,8 @@ def experts(u, plan: RoutePlan, w1, w2, form: str = "relu2",
             2 if form == "silu_gated" else 1):
         raise MXNetError(f"experts: form {form!r} (of {FORMS}) with w1 "
                          f"{w1.shape} and w2 {w2.shape}")
-    kernel = _use_kernel()
-    _route_counts["grouped_kernel" if kernel else "ragged_dot"] += 2
+    kernel = kernel_route.choose(_GROUPED_KERNEL, True, u.shape[0],
+                                 times=2)     # two grouped products a call
     chunk = min(row_chunk(int(expected_rows)), plan.token.shape[0])
     return _experts(u, plan.token, plan.weight, plan.group_sizes, w1, w2,
                     _Stage(form, chunk, row_block(chunk), kernel))

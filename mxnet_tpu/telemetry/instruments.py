@@ -140,19 +140,12 @@ def op_dispatch_total(op_name: str):
 
 
 _spec("mx_attention_route_total", "counter",
-      "Attention op calls TRACED through each route "
-      "(fused_train = the fused training kernels, xla_dropout = the XLA "
-      "path with saved probabilities, kernel_infer / reference = the "
-      "dropout-free call, flash_causal = the splash multi-query kernels "
-      "over a causal mask, "
-      "splash_window = sliding_window_attention's splash kernels, "
-      "eva_splash / eva_xla = eva_attention's splash kernels over keys "
-      "and summaries, or its windowed XLA form, latent_splash / "
-      "latent_xla = latent_attention's splash kernels with a value size "
-      "of their own, or its XLA form): "
-      "counted once a compiled program, never per "
-      "step. fused_train over fused_train + xla_dropout is the share of "
-      "training attention that engaged the kernels.", ("route",))
+      "Attention op calls TRACED through each route (the export of "
+      "ops.pallas_attention.route_counts(), whose module says what each "
+      "route covers; ops/kernel_route.py chooses): counted once a compiled "
+      "program, never per step. fused_train over fused_train + xla_dropout "
+      "is the share of training attention that engaged the kernels.",
+      ("route",))
 
 
 def attention_route_total(route: str):
@@ -161,11 +154,10 @@ def attention_route_total(route: str):
 
 _spec("mx_rotary_route_total", "counter",
       "Operands of rotary_embedding (a call turns two: query and key) "
-      "TRACED through each route (kernel = the one-pass Pallas rotation "
-      "in a program lowered for the TPU, xla = the signed-permutation "
-      "product: MXNET_USE_PALLAS=0, a mesh of several devices, a shape "
-      "the kernel cannot tile): counted once a compiled program, never "
-      "per step.", ("route",))
+      "TRACED through each route (kernel = the one-pass Pallas rotation, "
+      "xla = the signed-permutation product; ops/kernel_route.py "
+      "chooses): counted once a compiled program, never per step.",
+      ("route",))
 
 
 def rotary_route_total(route: str):
