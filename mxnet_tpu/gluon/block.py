@@ -139,6 +139,13 @@ class ActiveTrace:
     def __init__(self, param_values: Dict[int, Any], train: bool):
         self.param_values = param_values     # id(Parameter) -> traced value
         self.train = train
+        # id(Parameter) -> reads of its value in this program: a block
+        # called four times reads each of its parameters four times, a
+        # segment's inner trace (`nested`) counts into this table, and a
+        # read inside a body that is traced once and run `trips` times
+        # (`repeated`) counts once a trip
+        self.uses: Dict[int, int] = {}
+        self.trips = 1
         self.aux_params: List[Parameter] = []
         self.aux_values: List[Any] = []
         self._extra_params: List[Parameter] = []
@@ -149,7 +156,33 @@ class ActiveTrace:
             raise MXNetError(
                 f"Parameter {param.name} used in hybrid forward but not "
                 "captured by the CachedOp trace")
+        self.uses[id(param)] = self.uses.get(id(param), 0) + self.trips
         return v
+
+    def nested(self) -> "ActiveTrace":
+        """The trace of a recomputed segment inside this one: the same
+        values and mode, aux updates of its own (they leave through the
+        segment's boundary), reads counted into this trace's table."""
+        inner = ActiveTrace(self.param_values, self.train)
+        inner.uses, inner.trips = self.uses, self.trips
+        return inner
+
+    @contextlib.contextmanager
+    def repeated(self, trips: int):
+        """Around the trace of a loop body (`lax.scan`) that runs `trips`
+        times: what it reads, it reads once a trip."""
+        outer, self.trips = self.trips, self.trips * trips
+        try:
+            yield
+        finally:
+            self.trips = outer
+
+    def use_counts(self) -> Dict[int, int]:
+        """{reads: how many Parameters were read that often} so far."""
+        counts: Dict[int, int] = {}
+        for n in self.uses.values():
+            counts[n] = counts.get(n, 0) + 1
+        return dict(sorted(counts.items()))
 
     def add_aux_update(self, param: Parameter, new_value):
         self.aux_params.append(param)
@@ -659,7 +692,7 @@ class HybridBlock(Block):
                     aux_params_cell = [()]
 
                     def seg(xx, pp, *targs):
-                        inner = ActiveTrace(outer.param_values, outer.train)
+                        inner = outer.nested()
                         inner.mirror = True
                         with inner:
                             out = self.hybrid_forward(F_PURE, xx, *targs,

@@ -5,7 +5,8 @@ from . import laguna
 from . import evabyte
 from . import joyai
 from . import lfm2
+from . import ouro
 from .vision import get_model
 
 __all__ = ["vision", "nemotron_h", "laguna", "evabyte", "joyai", "lfm2",
-           "get_model"]
+           "ouro", "get_model"]

@@ -1,6 +1,7 @@
 """What the zoo's decoder stacks share (`nemotron_h.py`, `laguna.py`,
-`evabyte.py`, `joyai.py`, `lfm2.py`): the pre-norm residual sub-layer,
-the bias-free projection, the gated MLP, a layer's MLP half (dense, or
+`evabyte.py`, `joyai.py`, `lfm2.py`, `ouro.py`): the pre-norm residual
+sub-layer (with a second gain, normed on both sides: `ouro.py`), the
+bias-free projection, the gated MLP, a layer's MLP half (dense, or
 the gated mixture of experts, with a shared expert or without one), the
 final norm and the head (an array of its own, or the embedding's: tied),
 and the rule that named parameters keep float32 under `cast`.  A norm's
@@ -27,22 +28,33 @@ def gated_mlp(F, x, gate_weight, up_weight, down_weight):
 
 
 def norm_residual(F, x, norm_weight, eps, mix, *args, offset=0.0,
-                  **params):
-    """x + mix(F, RMSNorm(x), ...).  Where `mix` gives (output,
-    statistics...) the statistics pass through beside the sum."""
+                  post=None, **params):
+    """x + mix(F, RMSNorm(x), ...), or with a second gain `post` the
+    sub-layer normed on both sides, x + RMSNorm(mix(...); post).  Where
+    `mix` gives (output, statistics...) the statistics pass through
+    beside the sum."""
     mixed = mix(F, F.RMSNorm(x, norm_weight, eps=eps, offset=offset),
                 *args, **params)
-    if isinstance(mixed, (list, tuple)):
-        return (x + mixed[0], *mixed[1:])
-    return x + mixed
+    mixed, *stats = mixed if isinstance(mixed, (list, tuple)) else (mixed,)
+    if post is not None:
+        mixed = F.RMSNorm(mixed, post, eps=eps, offset=offset)
+    return (x + mixed, *stats) if stats else x + mixed
 
 
-class Layer(HybridBlock):
-    """h + mixer(RMSNorm(h)); subclasses give `mix`.  Parameters named in
-    `_FLOAT32` keep float32 under `cast`, as the published model keeps
-    them."""
+class KeepsFloat32(HybridBlock):
+    """A block whose parameters named in `_FLOAT32` keep float32 under
+    `cast`, as the published model keeps them."""
 
     _FLOAT32 = ()
+
+    def cast(self, dtype):
+        self._clear_cached_op()
+        for name, p in self._reg_params.items():
+            p.cast(FP32 if name in self._FLOAT32 else dtype)
+
+
+class Layer(KeepsFloat32):
+    """h + mixer(RMSNorm(h)); subclasses give `mix`."""
 
     def __init__(self, hidden_size, eps, norm_offset=0.0, **kwargs):
         super().__init__(**kwargs)
@@ -54,11 +66,6 @@ class Layer(HybridBlock):
         """A gain that starts the norm at identity."""
         return self.params.get(name, shape=(self._hidden,),
                                init="zeros" if self._offset else "ones")
-
-    def cast(self, dtype):
-        self._clear_cached_op()
-        for name, p in self._reg_params.items():
-            p.cast(FP32 if name in self._FLOAT32 else dtype)
 
     def hybrid_forward(self, F, x, norm_weight, **params):
         return norm_residual(F, x, norm_weight, self._eps, self.mix,

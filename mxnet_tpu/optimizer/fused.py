@@ -78,9 +78,13 @@ class _Entry:
     "compiled" where XLA built it in this process and "cache" where it
     was loaded (the ``.mxcc`` store or JAX's persistent cache).
     ``program`` is the executable's scope table, built on the first
-    call of ``parallel.spmd.step_programs()`` and never before."""
+    call of ``parallel.spmd.step_programs()`` and never before;
+    ``param_uses`` what the trace that built it counted (the SPMD step's
+    ``{reads: Parameters read that often}``), None where nothing was
+    traced."""
 
-    __slots__ = ("fn", "tick", "cost", "fingerprint", "origin", "program")
+    __slots__ = ("fn", "tick", "cost", "fingerprint", "origin", "program",
+                 "param_uses")
 
     def __init__(self, fn, cost=None, fingerprint=None, origin="compiled"):
         self.fn = fn
@@ -89,6 +93,7 @@ class _Entry:
         self.fingerprint = fingerprint
         self.origin = origin
         self.program = None
+        self.param_uses = None
 
 
 class ProgramBuild:

@@ -128,10 +128,14 @@ def program_table(hlo_text: str) -> Dict[str, Any]:
 def step_programs() -> List[Dict[str, Any]]:
     """The scope table of every step executable built or loaded in this
     process and still cached, oldest first: ``{"module", "origin":
-    "compiled" | "cache", "scoped", "ops": {instruction: op_name}}``
-    (see :func:`program_table`).  A device trace names instructions
-    (``%fusion.14``) and not scopes; this is the program's own join
-    from the one to the other, for whoever reads a profile.
+    "compiled" | "cache", "scoped", "ops": {instruction: op_name},
+    "param_uses"}`` (see :func:`program_table`).  A device trace names
+    instructions (``%fusion.14``) and not scopes; this is the program's
+    own join from the one to the other, for whoever reads a profile.
+    ``param_uses`` is what the trace of the step counted, ``{reads:
+    Parameters whose value the model read that often}``: a stack applied
+    four times reads each of its parameters four times in one program
+    (None where the executable was loaded without a trace).
 
     Built on the first call and memoised per executable: the text of a
     ResNet-50 step is megabytes, so nothing here runs in ``step()`` or
@@ -140,7 +144,8 @@ def step_programs() -> List[Dict[str, Any]]:
     for ent in list(_STEP_CACHE.data.values()):
         if ent.program is None:
             ent.program = program_table(ent.fn.as_text())
-        out.append({"origin": ent.origin, **ent.program})
+        out.append({"origin": ent.origin, **ent.program,
+                    "param_uses": ent.param_uses})
     return out
 
 
@@ -311,6 +316,7 @@ class SPMDTrainer:
         self._step_fns: "OrderedDict[Tuple, Any]" = OrderedDict()
         self._fwd_fns: "OrderedDict[Tuple, Any]" = OrderedDict()
         self._param_by_name = {n: p for n, p in self._plist}
+        self._traced_param_uses = None  # set by the step's trace
         self._t = 0
 
     # ---- optimizer state -------------------------------------------------
@@ -379,6 +385,7 @@ class SPMDTrainer:
                 # skew (round-1 weak #10)
                 aux_named = {name_of[id(p)]: v for p, v in
                              zip(trace.aux_params, trace.aux_values)}
+                trainer._traced_param_uses = trace.use_counts()
                 return lval, aux_named
 
             (lval, aux), grads = jax.value_and_grad(
@@ -466,9 +473,11 @@ class SPMDTrainer:
             sig = ("spmd-train-step",) + tuple(named.items())
             fn = _STEP_CACHE.lookup(sig)
             if fn is None:
+                self._traced_param_uses = None  # a load traces nothing
                 fn = _STEP_CACHE.compile(
                     sig, lambda: jitted.trace(*args), opt, alias_ok=False,
                     components=named, donate=self._donate)
+                _STEP_CACHE.data[sig].param_uses = self._traced_param_uses
                 fn = self._timed_first_call(ikey, fn, _STEP_CACHE.cost(sig))
             # per-trainer fast path keyed by input avals: a batch-shape
             # change rebuilds (AOT does not silently retrace), a repeat
