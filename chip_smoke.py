@@ -560,6 +560,10 @@ def decoder_phase(*, seq=1024, heads=8, kv_heads=2, dim=128, window=256,
                 grouped(form), dense(form),
                 (u, first, w2, ct, plan.token, plan.weight,
                  plan.group_sizes), out, looped=True)
+    # every grouped product ran on a tile that divides its own k and n
+    _require(not expect_mosaic or (moe.route_counts()["exact_tiles"] and not
+             moe.route_counts()["padded_tiles"]), f"{moe.route_counts()}")
+    print(f"[tiles] {moe.tile_choices()}")
 
     s, h, p, g, n = scan
     x, ct = (jnp.asarray(rng.randn(1, s, h, p), dtype) for _ in range(2))

@@ -68,15 +68,28 @@ Routing is the sigmoid-score form of DeepSeek-V3 / Nemotron-H: scores
 s = sigmoid(x W_r^T) in float32, the top_k of s + bias chosen (the bias
 moves selection only), weights scale * s / sum of the chosen s.
 
-The two grouped products run through the TPU's grouped-matmul kernel
-(`jax.experimental.pallas.ops.tpu.megablox`, which skips the empty tail)
+The two grouped products run through the TPU's grouped-matmul kernels
+(`jax.experimental.pallas.ops.tpu.megablox`, which skip the empty tail)
 or through `jax.lax.ragged_dot`, as `ops/kernel_route.py` chooses;
-`route_counts()` says at trace time which was asked for.
+`route_counts()` says at trace time which was asked for.  Through the
+kernels a product and its two gradients are three kernel calls (`_gmm`:
+lhs @ rhs, grad @ rhs^T, lhs^T @ grad), and each runs on the tile (tm, tk,
+tn) that `choose_tile` works out from ITS kind and dimensions, the groups
+and the rows a group is expected to hold (`_Stage.rows`): tiles that divide
+the product's own k and n, one k tile wherever VMEM holds it (an expert's
+weights then cross HBM once a group, not once a row tile), a row tile
+that follows the rows a group holds (a tile across a group boundary is
+visited once a group), the cheapest by a count of MXU time, HBM bytes and
+grid steps.  `tile_choices()` says which tile each product traced took,
+`route_counts()["padded_tiles"]` how many did not divide.  `ROW_TILE` is
+what the plan's rows, the chunk and the block are padded to, a multiple of
+the row tiles the kernels take, and no longer their row tile.
 """
 from __future__ import annotations
 
 import functools
 import math
+import threading
 from typing import NamedTuple, Optional
 
 import jax
@@ -91,9 +104,11 @@ from .mesh import DeviceMesh, current_mesh
 
 __all__ = ["RoutePlan", "route", "experts", "moe_apply", "plan_rows",
            "plan_chunks", "plan_blocks", "row_chunk", "row_block",
-           "route_counts"]
+           "route_counts", "choose_tile", "tile_choices"]
 
-ROW_TILE = 512      # rows a grouped-product tile takes: `rows` is a multiple
+# what `plan_rows`, `row_chunk` and `row_block` pad to: a multiple of every
+# row tile below 1,024 that the kernels may take (`ROW_TILES`)
+ROW_TILE = 512
 ROW_CHUNK = 4096    # rows `experts` handles a trip of its loop: a multiple
 # rows a row-wise stage handles a trip of ITS loop inside a chunk (see
 # `row_block`), a multiple.  One layer forward + backward on the chip
@@ -105,7 +120,8 @@ ROW_BLOCK = 2048
 FORMS = ("relu2", "silu_gated")   # an expert's activation: see `experts`
 ROUTES = ("grouped_kernel", "ragged_dot")
 kernel_route.declare("moe_experts", ROUTES + (
-    "sorted_layout", "expert_stage_traces", "token_sums"))
+    "sorted_layout", "expert_stage_traces", "token_sums", "exact_tiles",
+    "padded_tiles"))
 # asks neither the mesh nor the interpreter (ROADMAP.md names the debt)
 _GROUPED_KERNEL = kernel_route.Kernel("moe_experts", "grouped_kernel",
                                       "ragged_dot", kernel_route.ANY_MESH)
@@ -122,7 +138,11 @@ def route_counts():
     under `token_sums` how often a loop's sum over a token's rows was
     traced as the token reading them (`_token_sums`: the forward's
     combine, the backward's transpose of the gather; there is no other
-    form, so it follows the loops' traces)."""
+    form, so it follows the loops' traces), and under `exact_tiles` /
+    `padded_tiles` the grouped products traced through the kernels
+    (forward, gradient to lhs, gradient to rhs: each counts) by whether
+    the tile `choose_tile` gave them divides their k and n: a padded one
+    runs a masked last k tile or a partly empty last n tile."""
     return kernel_route.counts("moe_experts")
 
 
@@ -274,30 +294,179 @@ def _ragged(lhs, rhs, group_sizes):
                           preferred_element_type=lhs.dtype)
 
 
-def _tile(dim: int, cap: int) -> int:
-    """The largest multiple of 128 up to `cap` that divides `dim`, or
-    `dim` itself where there is none."""
-    return next((t for t in range(cap - cap % 128, 0, -128)
-                 if dim % t == 0), dim)
+# A grouped product's tile (tm, tk, tn) is chosen a PRODUCT, from that
+# product's own kind and shapes (`choose_tile`), and never handed from one
+# product to another: the forward's tile cuts the backward's products,
+# whose K and N change places, into masked and partly empty tiles.
+KINDS = ("gmm", "dlhs", "tgmm")     # lhs @ rhs[g]; grad @ rhs[g]^T; lhs^T @ grad
+ROW_TILES = (128, 256, 512, 1024)   # the kernels' row tiles to choose from
+# of the 16 MiB of scoped VMEM a kernel gets on the v5e: what `_vmem_bytes`
+# counts may take this much, the rest is the compiler's own
+VMEM_BUDGET = 12 * 2 ** 20
+# the count's constants (`_tile_cost`): the v5e's two peaks, what a grid
+# step costs beside its work, the share of the smaller of MXU and HBM
+# time that the larger does not hide, and the rows an MXU pass over a row
+# tile costs beside the tile's own.  The last is the chip's: over 290
+# timings of the four cells' 24 products (PERF.md, PR 47) the count without
+# it put 128-row tiles ahead of the 256-row tiles that ran 1-3% faster; of
+# 0, 32 .. 256 rows, 64 picks the tiles whose times sum lowest (17.32 ms
+# against 17.21 for each product's fastest tile; 17.35 at 0, 17.79 at 128)
+_MXU_FLOPS, _HBM_BYTES, _GRID_STEP_S, _UNHIDDEN = 197e12, 819e9, 0.35e-6, 0.3
+_ROW_TILE_EXTRA = 64
+
+_tiles = {}     # (kind, m, k, n, groups) -> (tm, tk, tn) since import
+_tiles_lock = threading.Lock()
 
 
-def _gmm(lhs, rhs, group_sizes):
-    from jax.experimental.pallas.ops.tpu.megablox import gmm
+def tile_choices():
+    """Since import: {(kind, m, k, n, groups): the (tm, tk, tn) that the
+    grouped product of that kind (`KINDS`) and those dimensions was traced
+    with}."""
+    with _tiles_lock:
+        return dict(_tiles)
 
-    return gmm(lhs, rhs, group_sizes, preferred_element_type=lhs.dtype,
-               tiling=(ROW_TILE, _tile(lhs.shape[1], 1024),
-                       _tile(rhs.shape[2], 512)))
+
+def _cuts(dim: int, exact: bool = True):
+    """The tiles a dimension may be cut in: the multiples of 128 that
+    divide it, or where there is none the whole of it; with `exact` off
+    every multiple of 128 up to 1,024 (the last tile is then masked or
+    partly empty)."""
+    if not exact:
+        return list(range(128, 1024 + 1, 128))
+    return [t for t in range(128, dim + 1, 128) if dim % t == 0] or [dim]
 
 
-def _grouped(lhs, rhs, group_sizes, kernel):
-    """lhs (rows, K) @ rhs[g] (K, N) for the rows of group g.  Rows past
-    the last group come back undefined from the kernel: the caller masks
-    them."""
+def _vmem_bytes(kind, tm, tk, tn, itemsize):
+    """What a kernel of `kind` holds in VMEM at this tile: its two
+    operands' blocks and its out block, double-buffered, and the float32
+    accumulator the size of the out block."""
+    out = tk * tn if kind == "tgmm" else tm * tn
+    other = tm * tn if kind == "tgmm" else tk * tn
+    return 2 * itemsize * (tm * tk + other + out) + 4 * out
+
+
+def _tile_cost(kind, tile, m, k, n, groups, itemsize, rows):
+    """Seconds that a grouped product of `kind` is counted to take at
+    `tile`, `rows` rows a group expected: the MXU's time on the tiles
+    visited (a row tile that straddles a group boundary is visited once a
+    group; a last k or n tile that does not divide is paid whole), the
+    HBM's time (an operand's block is fetched again whenever its index
+    changes, so with ONE k tile a group's weights cross once a group and
+    not once a visit), the larger of the two plus `_UNHIDDEN` of the
+    smaller, and `_GRID_STEP_S` a grid step.  It orders tiles; it is not a
+    time anyone measured."""
+    tm, tk, tn = tile
+    used = min(m, rows * groups)
+    visits = min(-(-used // tm) + groups - 1, m // tm + groups - 1)
+    nk, nn = -(-k // tk), -(-n // tn)
+    mxu = 2 * visits * (tm + _ROW_TILE_EXTRA) * nk * tk * nn * tn / _MXU_FLOPS
+    if kind == "tgmm":      # grid (n tiles, k tiles, visits): out (tk, tn)
+        moved = visits * tm * (k * nn + n * nk) + groups * k * n
+    else:                   # grid (n tiles, visits, k tiles): out (tm, tn)
+        moved = (visits * tm * k * nn + used * n
+                 + (groups if nk == 1 else visits) * k * n)
+    hbm = moved * itemsize / _HBM_BYTES
+    return (max(mxu, hbm) + _UNHIDDEN * min(mxu, hbm)
+            + _GRID_STEP_S * visits * nk * nn)
+
+
+def choose_tile(kind: str, m: int, k: int, n: int, groups: int,
+                itemsize: int = 2, rows: int = 0):
+    """The tile (tm, tk, tn) of one grouped product, from what its call
+    sees: `kind` (`KINDS`), the rows m, the contraction k and the width n
+    AS THAT PRODUCT RUNS (for `dlhs` the forward's N and K; for `tgmm` the
+    out is (groups, k, n) and m is contracted), the groups, the operands'
+    itemsize, and the `rows` a group is expected to hold (0: m / groups).
+
+    tk and tn are multiples of 128 that divide k and n, or the whole
+    dimension: no k tile is masked and no n tile partly empty, for any
+    shape with such a divisor whose tiles fit; tm is one of `ROW_TILES`
+    that divides m (m itself where none does); the blocks fit
+    `VMEM_BUDGET` (`_vmem_bytes`).  Of these the cheapest by `_tile_cost`,
+    and of equals the one with the largest tk, tn, tm: so tk = k (for
+    `tgmm` the largest out tile) wherever that fits beside the same tm
+    and tn.  Only where no exact tile fits (a dimension with no such
+    divisor and too large to hold whole) the cuts are multiples of 128 that
+    do not divide, which `route_counts()["padded_tiles"]` counts."""
+    if kind not in KINDS:
+        raise MXNetError(f"choose_tile: kind {kind!r} is none of {KINDS}")
+    rows = rows or -(-m // groups)
+    row_tiles = [t for t in ROW_TILES if m % t == 0] or [m]
+    for exact in (True, False):
+        fit = [(tm, tk, tn) for tm in row_tiles for tk in _cuts(k, exact)
+               for tn in _cuts(n, exact)
+               if _vmem_bytes(kind, tm, tk, tn, itemsize) <= VMEM_BUDGET]
+        if fit:
+            return min(fit, key=lambda t: (_tile_cost(
+                kind, t, m, k, n, groups, itemsize, rows),
+                -t[1], -t[2], -t[0]))
+    raise MXNetError(f"choose_tile: no tile of a {kind} product "
+                     f"({m}, {k}, {n}) fits {VMEM_BUDGET} bytes of VMEM")
+
+
+def _tile_of(kind, m, k, n, groups, itemsize, rows):
+    """`choose_tile`, noted for `tile_choices()` and counted at trace time
+    by whether the tile divides the product's k and n."""
+    tile = choose_tile(kind, m, k, n, groups, itemsize, rows)
+    with _tiles_lock:
+        _tiles[kind, m, k, n, groups] = tile
+    kernel_route.count("moe_experts", "padded_tiles" if k % tile[1]
+                       or n % tile[2] else "exact_tiles")
+    return tile
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _gmm(lhs, rhs, group_sizes, rows, interpret=False):
+    """lhs (m, K) @ rhs[g] (K, N) through upstream's grouped-matmul
+    kernels, under a rule of the repo's own so that each of the three
+    products (this one, its gradient to lhs: the same kernel with rhs
+    transposed, and to rhs: `tgmm`) runs on the tile its OWN dimensions
+    ask for (`choose_tile`); upstream's rule hands the forward's tile to
+    all three.  `rows`: the rows a group is expected to hold."""
+    from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm
+
+    (m, k), (groups, _, n) = lhs.shape, rhs.shape
+    return gmm(
+        lhs, rhs, group_sizes, lhs.dtype,
+        _tile_of("gmm", m, k, n, groups, lhs.dtype.itemsize, rows),
+        interpret=interpret)
+
+
+def _gmm_forward(lhs, rhs, group_sizes, rows, interpret):
+    return _gmm(lhs, rhs, group_sizes, rows, interpret), (
+        lhs, rhs, group_sizes)
+
+
+def _gmm_backward(rows, interpret, res, grad):
+    from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm, tgmm
+
+    lhs, rhs, group_sizes = res
+    (m, k), (groups, _, n), size = lhs.shape, rhs.shape, lhs.dtype.itemsize
+    dlhs = gmm(
+        grad, rhs, group_sizes, lhs.dtype,
+        _tile_of("dlhs", m, n, k, groups, size, rows),
+        transpose_rhs=True, interpret=interpret)
+    drhs = tgmm(
+        lhs.swapaxes(0, 1), grad, group_sizes, rhs.dtype,
+        _tile_of("tgmm", m, k, n, groups, size, rows),
+        num_actual_groups=groups, interpret=interpret)
+    return dlhs, drhs, None
+
+
+_gmm.defvjp(_gmm_forward, _gmm_backward)
+
+
+def _grouped(lhs, rhs, group_sizes, kernel, rows=0):
+    """lhs (m, K) @ rhs[g] (K, N) for the rows of group g, `rows` of them
+    expected a group (what the kernels' row tile follows; 0: m / groups).
+    Rows past the last group come back undefined from the kernel: the
+    caller masks them."""
     with jax.named_scope("products"):
         if not kernel:
             return _ragged(lhs, rhs, group_sizes)
-        return kernel_route.dispatch(_gmm, _ragged, lhs, rhs, group_sizes,
-                                     interpret=False)
+        return kernel_route.dispatch(
+            lambda lhs, rhs, sizes: _gmm(lhs, rhs, sizes, rows), _ragged,
+            lhs, rhs, group_sizes, interpret=False)
 
 
 def _rows_of(u, tok):
@@ -442,6 +611,7 @@ class _Stage(NamedTuple):
     chunk: int      # rows a trip of the loop over the plan handles
     block: int      # rows a trip of a row-wise stage's loop inside it
     kernel: bool    # whether `kernel_route` admits the grouped kernel
+    rows: int = 0   # rows a held expert is expected to hold (0: chunk / held)
 
 
 def _chunks(token, weight, group_sizes, t, stage):
@@ -488,7 +658,8 @@ def _over_trips(trips, body, sums, *others):
 def _forward(u, token, weight, group_sizes, w1, w2, stage):
     kernel_route.count("moe_experts", "expert_stage_traces")
     t, block = u.shape[0], stage.block
-    product = functools.partial(_grouped, kernel=stage.kernel)
+    product = functools.partial(_grouped, kernel=stage.kernel,
+                                rows=stage.rows)
     _, trips, window = _chunks(token, weight, group_sizes, t, stage)
     most = _most_rows_a_token(token.shape[0], t, w1.shape[0])
 
@@ -521,7 +692,8 @@ def _backward(stage, res, g):
     kernel_route.count("moe_experts", "expert_stage_traces")
     u, token, weight, group_sizes, w1, w2 = res
     t, block = u.shape[0], stage.block
-    product = functools.partial(_grouped, kernel=stage.kernel)
+    product = functools.partial(_grouped, kernel=stage.kernel,
+                                rows=stage.rows)
     padded, trips, window = _chunks(token, weight, group_sizes, t, stage)
     most = _most_rows_a_token(token.shape[0], t, w1.shape[0])
 
@@ -611,8 +783,12 @@ def experts(u, plan: RoutePlan, w1, w2, form: str = "relu2",
     kernel = kernel_route.choose(_GROUPED_KERNEL, True, u.shape[0],
                                  times=2)     # two grouped products a call
     chunk = min(row_chunk(int(expected_rows)), plan.token.shape[0])
+    # what the model expects, or a full chunk where it states nothing;
+    # the twin has no tile, and no signature of its own for it
+    rows = -(-min(int(expected_rows) or chunk, chunk)
+             // w1.shape[0]) if kernel else 0
     return _experts(u, plan.token, plan.weight, plan.group_sizes, w1, w2,
-                    _Stage(form, chunk, row_block(chunk), kernel))
+                    _Stage(form, chunk, row_block(chunk), kernel, rows))
 
 
 def moe_apply(x, u, w_router, bias, w1, w2, *, top_k: int,

@@ -350,6 +350,11 @@ back = [n for n in names
         if "transpose(jvp(moe_experts))/jit(_backward)/while/body/" in n]
 assert len(back) == 6 and sum("jit(tgmm)" in n for n in back) == 2, names
 assert moe.route_counts()["grouped_kernel"] == 2, moe.route_counts()
+# each of the six kernel calls on a tile of its own that divides its k and n
+print("TILES experts", moe.tile_choices())
+assert moe.route_counts()["padded_tiles"] == 0, moe.route_counts()
+assert moe.route_counts()["exact_tiles"] >= 6, moe.route_counts()
+assert {kind for kind, *_ in moe.tile_choices()} == set(moe.KINDS)
 
 from mxnet_tpu.ops import ssm
 s, h, p, g, n = 8192, 128, 64, 8, 128
@@ -457,6 +462,12 @@ assert sum("transpose(" not in n for n in names) == 2, names
 back = [n for n in names
         if "transpose(jvp(moe_experts))/jit(_backward)/while/body/" in n]
 assert len(back) == 6 and sum("jit(tgmm)" in n for n in back) == 2, names
+# 512 rows an expert expected: no kernel takes the 512-row tile that a
+# group boundary cuts in two, and none pads
+tiles = moe.tile_choices()
+print("TILES gated experts", tiles)
+assert moe.route_counts()["padded_tiles"] == 0, moe.route_counts()
+assert len(tiles) == 6 and all(tm < 512 for tm, _, _ in tiles.values())
 print("AOT_OK")
 """
 

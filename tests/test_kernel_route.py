@@ -145,7 +145,8 @@ def test_one_store_behind_the_four_public_views():
         "chunked_xla", "fused_kernel")
     assert tuple(rotary.route_counts()) == rotary.ROUTES == ("kernel", "xla")
     assert tuple(moe.route_counts()) == moe.ROUTES + (
-        "sorted_layout", "expert_stage_traces", "token_sums")
+        "sorted_layout", "expert_stage_traces", "token_sums", "exact_tiles",
+        "padded_tiles")
     assert set(residuals.NAMES) <= set(pallas_attention.ROUTES)
     views = (pallas_attention, ssm, rotary, moe)
     before = [v.route_counts() for v in views]

@@ -411,6 +411,10 @@ def test_row_chunk_holds_the_expected_load_in_one_trip():
     assert moe.row_chunk() == moe.row_chunk(2048) == moe.ROW_CHUNK == 4096
     assert moe.row_chunk(16384) == 32768 and moe.row_chunk(8192) == 16384
     assert moe.row_chunk(2049) == 4608 and 4608 % moe.ROW_TILE == 0
+    # the padding unit, not the kernels' row tile: that follows the 512
+    # rows an expert expects here and divides the chunk
+    tm = moe.choose_tile("gmm", 32768, 2048, 1024, 32, 2, 512)[0]
+    assert tm in moe.ROW_TILES and 32768 % tm == 0 and tm <= 512
     sizes = jnp.full((32,), 1000, jnp.int32)        # 32,000 assignments
     assert int(moe.plan_chunks(sizes)) == 8
     assert int(moe.plan_chunks(sizes, 16384)) == 1

@@ -308,6 +308,11 @@ def test_plan_blocks_against_a_hand_count(sizes, expected, rows, blocks):
 
 def test_row_block_divides_the_chunk():
     assert moe.ROW_BLOCK % moe.ROW_TILE == 0
+    # `ROW_TILE` is what rows, chunks and blocks are padded to, no longer
+    # the kernels' row tile: every chunk is whole tiles of any row tile
+    # `choose_tile` may take below twice the unit
+    assert all(moe.ROW_TILE % tm == 0 for tm in moe.ROW_TILES
+               if tm <= moe.ROW_TILE)
     assert moe.row_block(32768) == moe.row_block(8192) == moe.ROW_BLOCK
     assert moe.row_block(4096) == moe.row_block(5120) == 1024  # a quarter
     assert moe.row_block(4608) == moe.row_block(512 * 11) == 512
@@ -317,6 +322,8 @@ def test_row_block_divides_the_chunk():
         block = moe.row_block(chunk)
         assert chunk % block == 0 == block % moe.ROW_TILE
         assert block <= max(min(moe.ROW_BLOCK, chunk // 4), moe.ROW_TILE)
+        for kind in moe.KINDS:
+            assert chunk % moe.choose_tile(kind, chunk, 256, 256, 4)[0] == 0
 
 
 def _stack(layers, form, expected_rows=0):
