@@ -420,9 +420,11 @@ def decoder_phase(*, seq=1024, heads=8, kv_heads=2, dim=128, window=256,
                   expect_mosaic=True) -> dict:
     """Value and gradients of the decoders' kernel routes against plain
     XLA on the same operands: causal grouped-query attention through the
-    op (upstream's splash multi-query kernels over a causal mask) against
-    the S x S reference, the same under a sliding `window` (the same
-    kernels over the band) against the banded XLA form, the held
+    op (upstream's splash multi-query forward kernel over a causal mask
+    and `mx_causal_attention_bwd`) against the S x S reference, grouped
+    over one key block and one head a key/value head over two, the same
+    under a sliding `window` (upstream's three kernels over the band)
+    against the banded XLA form, the held
     experts' stage in both forms
     (relu^2 and silu-gated) (a loop over chunks of the plan's rows around
     the grouped-matmul kernel; `held_bias` on the router draws enough
@@ -469,30 +471,40 @@ def decoder_phase(*, seq=1024, heads=8, kv_heads=2, dim=128, window=256,
         out[name] = {"max_rel_err": round(max(errs.values()), 5)}
 
     out = {}
-    q, ct = (jnp.asarray(rng.randn(1, seq, heads * dim), dtype)
-             for _ in range(2))
-    k, v = (jnp.asarray(rng.randn(1, seq, kv_heads * dim), dtype)
-            for _ in range(2))
-    before = pa.route_counts()["flash_causal"]
 
-    def attention(q, k, v, ct):
-        return weighed(pa._dot_product_attention(
-            q, k, v, None, None, num_heads=heads, num_kv_heads=kv_heads,
-            causal=True, _train=True), ct)
-
-    def split(q, k, v):
+    def split(q, k, v, heads=heads, kv_heads=kv_heads):
         return (pa._split_to_heads(q, heads), pa._split_to_heads(k, kv_heads),
                 pa._split_to_heads(v, kv_heads))
 
-    def attention_plain(q, k, v, ct):
-        o = pa._causal_xla(*split(q, k, v), dim ** -0.5)
-        return weighed(o.transpose(0, 2, 1, 3).reshape(q.shape), ct)
+    def causal(seq, heads, kv_heads):
+        """The causal op at these sizes against the S x S reference."""
+        q, ct = (jnp.asarray(rng.randn(1, seq, heads * dim), dtype)
+                 for _ in range(2))
+        k, v = (jnp.asarray(rng.randn(1, seq, kv_heads * dim), dtype)
+                for _ in range(2))
+        before = pa.route_counts()["flash_causal"]
 
-    compare(f"causal_gqa_h{heads}_kv{kv_heads}_s{seq}_d{dim}", attention,
-            attention_plain, (q, k, v, ct), out)
-    _require(pa.route_counts()["flash_causal"] > before,
-             f"the causal call did not take the splash kernels' route: "
-             f"{pa.route_counts()}")
+        def attention(q, k, v, ct):
+            return weighed(pa._dot_product_attention(
+                q, k, v, None, None, num_heads=heads, num_kv_heads=kv_heads,
+                causal=True, _train=True), ct)
+
+        def attention_plain(q, k, v, ct):
+            o = pa._causal_xla(*split(q, k, v, heads, kv_heads), dim ** -0.5)
+            return weighed(o.transpose(0, 2, 1, 3).reshape(q.shape), ct)
+
+        compare(f"causal_gqa_h{heads}_kv{kv_heads}_s{seq}_d{dim}", attention,
+                attention_plain, (q, k, v, ct), out)
+        _require(pa.route_counts()["flash_causal"] > before,
+                 f"the causal call did not take the splash kernels' route: "
+                 f"{pa.route_counts()}")
+        return q, k, v, ct
+
+    # one query head a key/value head over two key blocks: the backward
+    # kernel reads the last query block's dQ in the step after the one
+    # that wrote it (`pallas_attention._triangle_walk`'s `again`)
+    causal(2 * seq, kv_heads, kv_heads)
+    q, k, v, ct = causal(seq, heads, kv_heads)
 
     before = pa.route_counts()["splash_window"]
 

@@ -150,6 +150,23 @@ def per_batch_shard(fn, how, *operands, replicated=()):
         out_specs=rows)(*operands)
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2))
+def counted_backward(x, family, key):
+    """`x`, and `count(family, key)` where the backward of the program
+    that holds it is traced: once a call that is differentiated, never
+    for a call that is not.  Nothing in the lowered program."""
+    return x
+
+
+def _counted_backward_bwd(family, key, _, ct):
+    count(family, key)
+    return (ct,)
+
+
+counted_backward.defvjp(lambda x, family, key: (x, None),
+                        _counted_backward_bwd)
+
+
 # (family, key) -> times chosen since import
 _EXPORTS = {"attention": _instruments.attention_route_total,
             "rotary": _instruments.rotary_route_total}

@@ -25,8 +25,12 @@ wide and values v_dim wide.  Which call takes which route (counted by
 `ops/kernel_route.py`'s business):
 
   * values in whole 128-lane blocks, queries and keys in whole or half
-    ones, S a multiple of 128: `latent_splash`, the splash kernels
-    `flash_causal` runs (`pallas_attention._attend_causal`) with one
+    ones, S a multiple of 128: `latent_splash`, the kernels
+    `flash_causal` runs (`pallas_attention._attend_causal`: upstream's
+    splash forward kernel and, where 1,024 divides S, the one backward
+    kernel `mx_causal_attention_bwd`, which forms each score block once
+    for dK, dV and dQ; dQ's float32 blocks lie in 256 lanes for the 192
+    of a head, as XLA's own layout of them would) with one
     query head a key head, on keys concatenated from k_nope and k_r
     broadcast over the heads (the rotary key is written H times in HBM
     and its gradient summed over the heads by XLA; a kernel that reads

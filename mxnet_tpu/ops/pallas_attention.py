@@ -18,18 +18,30 @@ each route covers:
   * Causal self-attention with no dropout and no key mask, heads of 128
     (or a multiple) or of 64, sq == sk a multiple of 128, query heads a
     multiple of the key/value heads (grouped-query), training or
-    inference: `_attend_causal`, upstream's splash multi-query kernels
-    over a `CausalMask` (`_causal_splash`).  O(S) memory: the only route
-    that fits a decoder at S = 8192, where `_attend`'s backward would
-    hold 32 x 8192^2 scores.  A call it does not admit takes the
-    dropout-free route.  Counted `flash_causal` (the key is older than
-    the kernels: until PR 38 upstream's `flash_attention` kernels ran
-    here).
+    inference: `_attend_causal`, upstream's splash multi-query forward
+    kernel over a `CausalMask` (`_causal_splash`) and, where the block of
+    1,024 rows divides S, a backward of the repo's own under a custom
+    VJP of the repo's own: ONE kernel, `mx_causal_attention_bwd`, walks
+    the block pairs the triangle touches, forms each score block once
+    and gives dK, dV and dQ from it (6 block products a pair for the 6
+    a causal pair needs, where upstream's dK/dV and dQ kernels execute
+    9), dQ summed over the key blocks in float32 in ONE array in HBM
+    that the kernel adds to in place and rounded once (upstream's fused
+    form rounds a part a key block to the operands' type; PERF.md,
+    PR 48).  Other S: upstream's two backward kernels.  O(S) memory: the
+    only route that fits a decoder at S = 8192, where `_attend`'s
+    backward would hold 32 x 8192^2 scores.  A call it does not admit
+    takes the dropout-free route.  Counted `flash_causal` (the key is
+    older than the kernels: until PR 38 upstream's `flash_attention`
+    kernels ran here); `backward_counts()` says which form a traced
+    backward took.
   * Causal sliding-window self-attention (`sliding_window_attention`, an
     op of its own so that a profile reads the window and the full cores
     apart): query i sees keys j with 0 <= i - j < window.  Shapes as for
     `flash_causal`, window < S: `_attend_causal` over a `LocalMask`,
-    O(S x window) work where `flash_causal` does O(S^2 / 2).  Counted
+    O(S x window) work where `flash_causal` does O(S^2 / 2); forward,
+    dK/dV and dQ are upstream's three kernels (a band visits mostly
+    partial blocks and read slower fused: `_fused_backward`).  Counted
     `splash_window`; every other windowed call is the banded XLA form,
     counted `reference`; window >= S is causal attention.
   * `eva_attention` and `latent_attention` (modules of their own) count
@@ -70,7 +82,8 @@ import numpy as np
 from . import kernel_route
 from .registry import register_op
 
-__all__ = ["dot_product_attention_ref", "dropout_keep_mask", "route_counts"]
+__all__ = ["dot_product_attention_ref", "dropout_keep_mask", "route_counts",
+           "backward_counts"]
 
 
 def dot_product_attention_ref(q, k, v, mask, scale, causal=False):
@@ -775,76 +788,370 @@ def _window_xla(q, k, v, scale, window):
 
 def _splash_blocks(s, window):
     """(rows of queries and of keys a block, rows of keys a product) of
-    the three splash kernels, from the mask's kind and S.  Under a
-    window: 512 where it divides S, so that a query block visits the 2
-    key blocks its band touches; on the v5e at the window of 512,
-    forward + backward of one layer (PERF.md, PR 31): 512 23.4 ms, 256
-    (3 blocks, 768 keys for the 512 a query sees, but three times the
-    grid steps) 33.5, 128 69.3, (1024, 512) 30.1, the fused backward
-    slower.  The whole triangle is mostly whole blocks and takes 1024
-    rows in products of 512 keys where 1024 divides S; one full layer of
+    the splash kernels, from the mask's kind and S.  Under a window: 512
+    where it divides S, so that a query block visits the 2 key blocks
+    its band touches; on the v5e at the window of 512, forward +
+    backward of one layer (PERF.md, PR 31): 512 23.4 ms, 256 (3 blocks,
+    768 keys for the 512 a query sees, but three times the grid steps)
+    33.5, 128 69.3, (1024, 512) 30.1, the fused backward slower.  The
+    whole triangle is mostly whole blocks and takes 1024 rows in
+    products of 512 keys where 1024 divides S; one full layer of
     `laguna_xs2_s8192` (B 2, 48 heads over 8, S 8192) on the v5e
     (PERF.md, PR 38): 512 70.2 ms, (1024 queries, 512 keys) 64.2, (512,
     1024) 62.2, (2048, 512) 65.3, 1024 in one product 58.9, 1024 in
     products of 512 57.6 (the least in each of the three kernels),
     against 83.2 through upstream's flash kernels at 512 on repeated
-    heads.  The fused backward reads 48.5 there, but sums dQ from S /
-    1024 bfloat16 partials, each as large as q: not taken."""
+    heads.  The backward of such a call is one kernel of the repo's own
+    at the same blocks (`_fused_backward`)."""
     if window is None and s % 1024 == 0:
         return 1024, 512
     rows = next(n for n in (512, 256, 128) if s % n == 0)
     return rows, rows
 
 
-def _causal_splash(q, k, v, scale, window=None, interpret=False,
-                   name=None):
-    """Upstream's splash multi-query kernels (forward, dK/dV and dQ under
-    their own custom VJP, one online-softmax pass over the blocks the
-    mask touches) over the band 0 <= i - j < window, or over the whole
-    causal triangle (`window` None): q (B, H, S, D), k (B, Hkv, S, D)
-    and v (B, Hkv, S, Dv), Dv the output's head size.  The H // Hkv
-    query heads of one key/value head are one multi-query call, vmapped
-    over batch and key/value heads: k and v go in as they are, and dK,
-    dV come out summed over the group.  The forward rule names its
-    output and its (H, S) float32 logsumexp by the route (`name`; None:
-    `flash_causal` or `splash_window` by the mask)."""
+def _fused_backward(window, s, d, d_v, groups):
+    """Whether a call's backward is `mx_causal_attention_bwd`, from what
+    `_causal_splash` sees: the mask's kind, S, the head sizes of queries
+    / keys and of values, the query heads a key/value head.  A
+    separation by mask kind: the triangle visits mostly whole blocks,
+    each of whose scores the one kernel forms once where upstream's two
+    form them twice; a window and EVA's mask visit mostly partial
+    blocks and read slower fused (PERF.md, PRs 31, 34), and an S the
+    1,024-row block does not divide runs upstream's kernels at their
+    fall-back blocks: both keep upstream's split backward.  Every size a
+    cell runs at S 8192 read faster fused on the v5e (PERF.md, PR 48),
+    one layer forward + backward, ms, upstream's split kernels /
+    upstream's fused form with its bfloat16 partials / the one kernel:
+    48 heads over 8 of 128 (B 2) 54.90 / 45.69 / 40.70; 32 heads of 192
+    and 128 (B 2) 58.08 / 50.66 / 44.90; a group of one, 16 heads of 128
+    (B 1) 8.99 / 7.58 / 6.87; 32 over 8 of 64 (B 2) 37.66 / 31.44 /
+    27.85; 32 over 2 of 128 (B 2) 36.32 / 30.13 / 27.11: so neither the
+    head sizes nor the group decide anything yet."""
+    del d, d_v, groups
+    return window is None and s % 1024 == 0
+
+
+# upstream's value for a masked score: exp(it - logsumexp) is 0
+_MASKED = -0.7 * float(np.finfo(np.float32).max)
+
+
+def _triangle_walk(blocks, groups):
+    """int32 (4, steps): the (key block, query head of the group, query
+    block) of every step of `mx_causal_attention_bwd` over one key/value
+    head, and whether the step before it wrote the same dQ block.  Key
+    block outermost, so dK and dV of a key block are one sum over the
+    group's heads and the query blocks at or after it: 36 x groups
+    steps at 8 blocks where the square has 64."""
+    steps = [(kb, g, qb) for kb in range(blocks) for g in range(groups)
+             for qb in range(kb, blocks)]
+    again = [0] + [int(a[1:] == b[1:]) for a, b in zip(steps, steps[1:])]
+    return np.concatenate([np.asarray(steps, np.int32).T,
+                           np.asarray([again], np.int32)])
+
+
+def _causal_bwd_vmem_bytes(rows, compute, d, d_v, itemsize):
+    """The scoped VMEM `mx_causal_attention_bwd` asks Mosaic for, from
+    the tiles it holds (a head of 192 lies in 256 lanes): the operand
+    and result blocks q, dO, k, v, dK, dV double buffered, the float32
+    sums of dK and dV, the step's part of dQ and the two dQ blocks, some
+    eight (keys a product, rows) float32 tiles (scores, probabilities,
+    dP, dS and their casts), and as much again for what Mosaic allocates
+    itself.  43 MiB at (1024, 512) and heads of 128 or 64, 50 at 192 + 128:
+    the v5e has 128."""
+    lanes = lambda n: -(-n // 128) * 128
+    d, d_v = lanes(d), lanes(d_v)
+    blocks = 2 * 3 * rows * (d + d_v) * itemsize
+    sums = rows * (d + d_v) * 4 + 3 * rows * d * 4
+    tiles = 8 * compute * rows * 4
+    return 2 * (blocks + sums + tiles)
+
+
+def _causal_bwd_pallas(q, k, v, do, lse, di, rows, compute, interpret):
+    """`mx_causal_attention_bwd`: dQ (float32), dK and dV of causal
+    attention from ONE pass over the blocks the triangle touches.  q (B,
+    Hkv, G, S, D) scaled already, k (B, Hkv, S, D), v (B, Hkv, S, Dv),
+    dO (B, Hkv, G, S, Dv), the forward kernel's logsumexp and di =
+    rowsum(dO * o), both (B, Hkv, G, S) float32; blocks of `rows`
+    queries and keys, `compute` keys a product.
+
+    Grid (B, Hkv, steps of `_triangle_walk`).  A step forms the scores
+    of one (key block, query block) pair of one query head once, keys on
+    sublanes and queries on lanes as upstream's dK/dV kernel does (the
+    row statistics broadcast as rows, dV and dK need no transposed
+    operand), the diagonal blocks masked from an iota and without the
+    queries before a chunk's first key (a quarter of such a block's
+    products; one layer 41.8 -> 40.7 ms at 48 heads over 8 on the v5e),
+    and gives all three gradients their part of it:
+
+      * dK, dV: float32 VMEM sums over the group's heads and the query
+        blocks of one key block, written once, in the operands' type;
+      * dQ: ONE float32 array the size of q in HBM, never zero-filled:
+        the step reads its block (but at key block 0, every query
+        block's first visit), adds its part and writes it back, by
+        copies of its own that overlap the products (the read starts
+        with the step, the write runs into the next step).  Two VMEM
+        blocks in turn; a block is free again once the write of two
+        steps before has landed, which is waited for one step before.
+        A read must see the last write of its block: a block comes back
+        a whole sweep of heads and query blocks later, except in a
+        group of one, where the last two key blocks visit the last
+        query block in consecutive steps; there (`again` in the walk)
+        the write is waited for BEFORE the read starts.
+    """
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    b, kv, groups, s, d = q.shape
+    d_v = v.shape[-1]
+    blocks, chunks = s // rows, rows // compute
+    # a copy of the kernel's own cuts whole 128-lane tiles alone: dQ's
+    # blocks lie in as many lanes as XLA's layout of a head gives them
+    d_whole = -(-d // 128) * 128
+    walk = _triangle_walk(blocks, groups)
+    steps = walk.shape[1]
+
+    def kernel(walk_ref, q_ref, k_ref, v_ref, do_ref, stat_ref, dq_hbm,
+               dk_ref, dv_ref, dk_sum, dv_sum, dq_part, dq_even, dq_odd,
+               read_sem, write_sem):
+        bi, hi, step = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+        kb, g, qb = walk_ref[0, step], walk_ref[1, step], walk_ref[2, step]
+        again = walk_ref[3, step] == 1
+        mine = dq_hbm.at[bi, hi, g, qb]
+        turns = (dq_even, dq_odd)
+
+        def read(i):
+            return pltpu.make_async_copy(mine, turns[i], read_sem.at[i])
+
+        def write(i):
+            return pltpu.make_async_copy(turns[i], mine, write_sem.at[i])
+
+        def in_turn(when, body):
+            """`body(i)` where `when` holds, i the step's VMEM block of
+            dQ: a static index (Mosaic cuts no 64- or 192-lane array by
+            a dynamic one)."""
+            for i in range(2):
+                pl.when(jnp.logical_and(when, jax.lax.rem(step, 2) == i))(
+                    functools.partial(body, i))
+
+        # the write that the step before started, from the other block
+        in_turn(again, lambda i: write(1 - i).wait())
+        in_turn(kb > 0, lambda i: read(i).start())
+
+        @pl.when(jnp.logical_and(g == 0, qb == kb))
+        def _():
+            dk_sum[...] = jnp.zeros_like(dk_sum)
+            dv_sum[...] = jnp.zeros_like(dv_sum)
+
+        def products(diagonal):
+            """The five products of the step's block pair, a chunk of
+            keys at a time, keys on sublanes: the diagonal block is cut
+            by the triangle, and the queries before a chunk's first key
+            see none of it, so they stay out of its products."""
+            for c in range(chunks):
+                keys = pl.ds(c * compute, compute)
+                seen = pl.ds(c * compute, rows - c * compute) if diagonal \
+                    else pl.ds(0, rows)
+                kc, vc = k_ref[keys, :], v_ref[keys, :]
+                qs, dos = q_ref[seen, :], do_ref[seen, :]
+                st = jax.lax.dot_general(kc, qs, _NT,
+                                         preferred_element_type=jnp.float32)
+                if diagonal:    # query seen.start + j sees key keys.start + i
+                    st = jnp.where(
+                        jax.lax.broadcasted_iota(jnp.int32, st.shape, 1)
+                        >= jax.lax.broadcasted_iota(jnp.int32, st.shape, 0),
+                        st, _MASKED)
+                pt = jnp.exp(st - stat_ref[:1, seen])           # logsumexp
+                dv_sum[keys, :] += jnp.dot(pt.astype(dos.dtype), dos,
+                                           preferred_element_type=jnp.float32)
+                dpt = jax.lax.dot_general(vc, dos, _NT,
+                                          preferred_element_type=jnp.float32)
+                dst = ((dpt - stat_ref[1:, seen]) * pt).astype(qs.dtype)
+                dk_sum[keys, :] += jnp.dot(dst, qs,
+                                           preferred_element_type=jnp.float32)
+                part = jax.lax.dot_general(dst, kc, _TN,
+                                           preferred_element_type=jnp.float32)
+                if c == 0:
+                    dq_part[...] = part
+                else:
+                    dq_part[seen, :] += part
+
+        pl.when(qb > kb)(functools.partial(products, False))
+        pl.when(qb == kb)(functools.partial(products, True))
+
+        in_turn(jnp.logical_and(step > 0, jnp.logical_not(again)),
+                lambda i: write(1 - i).wait())
+
+        def add(i):
+            read(i).wait()
+            turns[i][:, :d] += dq_part[...]
+
+        def put(i):
+            turns[i][:, :d] = dq_part[...]
+
+        in_turn(kb > 0, add)
+        in_turn(kb == 0, put)
+        in_turn(True, lambda i: write(i).start())
+
+        @pl.when(jnp.logical_and(g == groups - 1, qb == blocks - 1))
+        def _():
+            dk_ref[...] = dk_sum[...].astype(dk_ref.dtype)
+            dv_ref[...] = dv_sum[...].astype(dv_ref.dtype)
+
+        in_turn(step == steps - 1, lambda i: write(i).wait())
+
+    at_query = lambda bi, hi, step, walk: (bi, hi, walk[1, step],
+                                           walk[2, step], 0)
+    at_key = lambda bi, hi, step, walk: (bi, hi, walk[0, step], 0)
+    heads = lambda width: pl.BlockSpec((None, None, None, rows, width),
+                                       at_query)
+    keys = lambda width: pl.BlockSpec((None, None, rows, width), at_key)
+    dq, dk, dv = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(b, kv, steps),
+            in_specs=[heads(d), keys(d), keys(d_v), heads(d_v),
+                      # logsumexp and di of a query block, as two rows
+                      pl.BlockSpec((None, None, None, 2, rows),
+                                   lambda bi, hi, step, walk: (
+                                       bi, hi, walk[1, step], 0,
+                                       walk[2, step]))],
+            out_specs=[pl.BlockSpec(memory_space=pl.ANY), keys(d),
+                       keys(d_v)],
+            scratch_shapes=[pltpu.VMEM((rows, d), jnp.float32),
+                            pltpu.VMEM((rows, d_v), jnp.float32),
+                            pltpu.VMEM((rows, d), jnp.float32),
+                            pltpu.VMEM((rows, d_whole), jnp.float32),
+                            pltpu.VMEM((rows, d_whole), jnp.float32),
+                            pltpu.SemaphoreType.DMA((2,)),
+                            pltpu.SemaphoreType.DMA((2,))]),
+        out_shape=[jax.ShapeDtypeStruct(
+                       (b, kv, groups, blocks, rows, d_whole), jnp.float32),
+                   jax.ShapeDtypeStruct(k.shape, k.dtype),
+                   jax.ShapeDtypeStruct(v.shape, v.dtype)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=_causal_bwd_vmem_bytes(
+                rows, compute, d, d_v, q.dtype.itemsize)),
+        interpret=interpret,
+        name="mx_causal_attention_bwd",
+    )(jnp.asarray(walk), q, k, v, do, jnp.stack([lse, di], axis=-2))
+    return dq[..., :d].reshape(q.shape), dk, dv
+
+
+def _splash_forward(q, k, v, scale, window, interpret, backward, **how):
+    """Upstream's splash multi-query kernel at `_splash_blocks`' blocks,
+    vmapped over batch and key/value heads, on q (B, H, S, D) scaled
+    here, k (B, Hkv, S, D) and v (B, Hkv, S, Dv) as they are: (scaled q
+    as (B, Hkv, G, S, D), what the kernel returns).  `backward`: with
+    upstream's two backward kernels under upstream's custom VJP, for
+    which the mask's blocks are laid out twice more at trace time;
+    `how`: `make_splash_mqa_single_device`'s further arguments."""
     from jax.experimental.pallas.ops.tpu import splash_attention as sa
 
     b, h, s, d = q.shape
     kv = k.shape[1]
     groups = h // kv
     rows, compute = _splash_blocks(s, window)
+    blocks = dict(block_q=rows, block_kv=rows, block_kv_compute=compute)
+    if backward:
+        blocks.update(block_q_dkv=rows, block_kv_dkv=rows,
+                      block_kv_dkv_compute=compute, block_q_dq=rows,
+                      block_kv_dq=rows)
     mask = (sa.CausalMask((s, s)) if window is None
             else sa.LocalMask((s, s), (window - 1, 0), 0))
     kernel = sa.make_splash_mqa_single_device(
         sa.MultiHeadMask([mask] * groups),
-        block_sizes=sa.BlockSizes(
-            block_q=rows, block_kv=rows, block_kv_compute=compute,
-            block_q_dkv=rows, block_kv_dkv=rows,
-            block_kv_dkv_compute=compute, block_q_dq=rows, block_kv_dq=rows),
-        residual_checkpoint_name=name or ("flash_causal" if window is None
-                                          else "splash_window"),
-        interpret=interpret)
+        block_sizes=sa.BlockSizes(**blocks), interpret=interpret, **how)
     # the kernels apply no scale of their own; in float32, so that the
     # scale is not rounded to the operands' type before it is applied
     q = (q.astype(jnp.float32) * scale).astype(q.dtype).reshape(
         b, kv, groups, s, d)
-    return jax.vmap(jax.vmap(kernel))(q, k, v).reshape(b, h, s, -1)
+    return q, jax.vmap(jax.vmap(kernel))(q, k, v)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def _causal_fused(q, k, v, scale, name, interpret):
+    """The whole triangle with the backward of the repo's own: upstream's
+    forward kernel, and `mx_causal_attention_bwd` as the rule."""
+    out = _splash_forward(q, k, v, scale, None, interpret, False)[1]
+    return out.reshape(q.shape[:3] + out.shape[-1:])
+
+
+def _causal_fused_fwd(q, k, v, scale, name, interpret):
+    scaled, (out, (lse,)) = _splash_forward(
+        q, k, v, scale, None, interpret, False, save_residuals=True)
+    # named, so that a recomputed segment keeps them (ops/residuals.py)
+    out = jax.ad_checkpoint.checkpoint_name(out, name)
+    lse = jax.ad_checkpoint.checkpoint_name(lse, name)
+    return (out.reshape(q.shape[:3] + out.shape[-1:]),
+            (scaled, k, v, out, lse))
+
+
+def _causal_fused_bwd(scale, name, interpret, res, do):
+    scaled, k, v, out, lse = res
+    do = do.reshape(out.shape)
+    di = jnp.einsum("bhgsd,bhgsd->bhgs", out.astype(jnp.float32),
+                    do.astype(jnp.float32))
+    rows, compute = _splash_blocks(scaled.shape[3], None)
+    dq, dk, dv = _causal_bwd_pallas(scaled, k, v, do, lse, di, rows,
+                                    compute, interpret)
+    # the float32 sum over the key blocks leaves as q's type after ONE
+    # rounding, the scale applied before it
+    b, kv, groups, s, d = scaled.shape
+    return ((dq * scale).astype(scaled.dtype).reshape(b, kv * groups, s, d),
+            dk, dv)
+
+
+_causal_fused.defvjp(_causal_fused_fwd, _causal_fused_bwd)
+
+
+def _causal_splash(q, k, v, scale, window=None, interpret=False,
+                   name=None):
+    """Upstream's splash multi-query forward kernel (one online-softmax
+    pass over the blocks the mask touches) over the band 0 <= i - j <
+    window, or over the whole causal triangle (`window` None): q (B, H,
+    S, D), k (B, Hkv, S, D) and v (B, Hkv, S, Dv), Dv the output's head
+    size.  The H // Hkv query heads of one key/value head are one
+    multi-query call, vmapped over batch and key/value heads: k and v
+    go in as they are, and dK, dV come out summed over the group.  The
+    forward rule names its output and its (H, S) float32 logsumexp by
+    the route (`name`; None: `flash_causal` or `splash_window` by the
+    mask).  The backward, by `_fused_backward`: `mx_causal_attention_bwd`
+    under a custom VJP of the repo's own, or upstream's two kernels
+    (dK/dV and dQ) under upstream's."""
+    b, h, s, d = q.shape
+    name = name or ("flash_causal" if window is None else "splash_window")
+    if _fused_backward(window, s, d, v.shape[-1], h // k.shape[1]):
+        return _causal_fused(q, k, v, scale, name, interpret)
+    out = _splash_forward(q, k, v, scale, window, interpret, True,
+                          residual_checkpoint_name=name)[1]
+    return out.reshape(b, h, s, -1)
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "window", "interpret",
                                              "name"))
-def _attend_causal(q, k, v, scale, window, interpret, name=None):
-    """q (B, H, S, D), k (B, Hkv, S, D) and v (B, Hkv, S, Dv) -> (B, H,
-    S, Dv), causal, under a sliding `window` or none (None): the splash
-    kernels or the XLA form.  Jitted, so that a stack of layers traces
-    and lowers the kernels once a kind of layer."""
+def _attend_causal_once(q, k, v, scale, window, interpret, name):
     xla = (functools.partial(_causal_xla, scale=scale) if window is None
            else functools.partial(_window_xla, scale=scale, window=window))
     return kernel_route.dispatch(
         functools.partial(_causal_splash, scale=scale, window=window,
                           interpret=interpret, name=name),
         xla, q, k, v, interpret=interpret)
+
+
+def _attend_causal(q, k, v, scale, window, interpret, name=None):
+    """q (B, H, S, D), k (B, Hkv, S, D) and v (B, Hkv, S, Dv) -> (B, H,
+    S, Dv), causal, under a sliding `window` or none (None): the splash
+    kernels or the XLA form.  Jitted, so that a stack of layers traces
+    and lowers the kernels once a kind of layer; the form the kernels'
+    backward takes is counted outside the jit, once a call whose
+    backward is traced (`backward_counts`)."""
+    form = "fused" if _fused_backward(
+        window, q.shape[2], q.shape[3], v.shape[3],
+        q.shape[1] // k.shape[1]) else "split"
+    return kernel_route.counted_backward(
+        _attend_causal_once(q, k, v, scale, window, interpret, name),
+        "attention_backward", form)
 
 
 # fused_train / (fused_train + xla_dropout) is the share of training calls
@@ -863,10 +1170,22 @@ _SPLASH_WINDOW = kernel_route.Kernel("attention", "splash_window",
                                      "reference")
 
 
+# the form of a causal splash call's backward (`_fused_backward`), counted
+# where that backward is traced
+kernel_route.declare("attention_backward", ("fused", "split"))
+
+
 def route_counts():
     """{route: calls traced through it} since import: routes chosen at
     trace time, not kernels run.  Read it before and after to count."""
     return kernel_route.counts("attention")
+
+
+def backward_counts():
+    """{"fused", "split"}: the causal and window splash calls whose
+    backward was traced since import, by the form it took: the repo's one
+    kernel or upstream's two.  `route_counts()`'s sibling."""
+    return kernel_route.counts("attention_backward")
 
 
 def _splash_kept(b, h, s, d, dtype):

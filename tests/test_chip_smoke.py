@@ -103,13 +103,16 @@ def test_attention_train_stage_toy_interpret(monkeypatch, dtype, n_dev):
 
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
 def test_decoder_phase_toy(dtype):
-    """The decoder stage's five comparisons at toy sizes, lowered for
+    """The decoder stage's six comparisons at toy sizes, lowered for
     the CPU (the routes' XLA twins against the stage's plain oracles)."""
     out = chip_smoke.decoder_phase(seq=128, heads=4, window=64, tokens=64,
                                    experts=8, held=2, top_k=3, latent=16,
                                    width=24, scan=(256, 4, 64, 2, 128),
                                    dtype=dtype, expect_mosaic=False)
-    assert len(out) == 5 and "ssd_scan_s256_h4_p64_g2_n128" in out
+    assert len(out) == 6 and "ssd_scan_s256_h4_p64_g2_n128" in out
+    # grouped heads over `seq`, and one head a key/value head over twice it
+    assert {"causal_gqa_h4_kv2_s128_d128", "causal_gqa_h2_kv2_s256_d128"} \
+        <= set(out)
     assert "window64_gqa_h4_kv2_s128_d128" in out
     assert "gated_experts_t64_held2_k16_n24" in out
     assert max(v["max_rel_err"] for v in out.values()) < (
@@ -322,10 +325,13 @@ names = mosaic_names(jax.grad(attention_loss, argnums=(0, 1, 2)),
                      arg((1, s, h * d)), arg((1, s, kv * d)),
                      arg((1, s, kv * d)))
 print("MOSAIC attention", names)
-assert len(names) == 3, names       # forward, dK/dV, dQ
+# forward, and ONE backward: dK, dV and dQ from each score block once
+assert len(names) == 2, names
 assert all("dot_product_attention" in n for n in names), names
-assert sum("transpose(" in n for n in names) == 2, names
+assert [n for n in names if "transpose(" in n] == [
+    n for n in names if "mx_causal_attention_bwd" in n] != [], names
 assert pa.route_counts()["flash_causal"] == 1, pa.route_counts()
+assert pa.backward_counts() == {"fused": 1, "split": 0}
 
 t, held, top_k, latent, width = 8192, 8, 22, 1024, 2688
 rows = moe.plan_rows(t, top_k, held)
@@ -439,6 +445,7 @@ assert all("sliding_window_attention" in n for n in names), names
 assert sum("transpose(" in n for n in names) == 2, names
 assert pa.route_counts()["splash_window"] == 1, pa.route_counts()
 assert pa.route_counts()["flash_causal"] == 0, pa.route_counts()
+assert pa.backward_counts() == {"fused": 0, "split": 1}
 
 t, held, top_k, hidden, width = 16384, 32, 8, 2048, 512
 rows = moe.plan_rows(t, top_k, held)
@@ -518,8 +525,9 @@ def test_window_and_gated_expert_routes_compile_for_v5e_ahead_of_time():
 
 def test_decoder_kernel_routes_compile_for_v5e_ahead_of_time():
     """Causal grouped-query training attention through the O(S)
-    `flash_causal` route (the splash multi-query kernels, sixteen query
-    heads a key/value head), the expert layer's grouped products
+    `flash_causal` route (upstream's splash multi-query forward kernel
+    and `mx_causal_attention_bwd`, sixteen query heads a key/value
+    head), the expert layer's grouped products
     through the grouped-
     matmul kernel, and the Mamba-2 scan through its forward and backward
     kernels, at the hybrid decoder cell's shapes: Mosaic takes them, and
@@ -619,9 +627,12 @@ calls = [ln for ln in _whole_instructions(compiled.as_text())
          if 'custom_call_target="tpu_custom_call"' in ln]
 names = [re.search(r'op_name="([^"]*)"', ln).group(1) for ln in calls]
 print("MOSAIC latent", names)
-assert len(names) == 3, names       # forward, dK/dV, dQ
+# forward, and ONE backward: dK, dV and dQ from each score block once
+assert len(names) == 2, names
 assert all("latent_attention" in n for n in names), names
-assert sum("transpose(" in n for n in names) == 2, names
+assert [n for n in names if "transpose(" in n] == [
+    n for n in names if "mx_causal_attention_bwd" in n] != [], names
+assert pa.backward_counts() == {"fused": 1, "split": 0}
 assert pa.route_counts()["latent_splash"] == 1, pa.route_counts()
 assert pa.route_counts()["latent_xla"] == 0, pa.route_counts()
 # O(S): dense (2, 32, 8192, 8192) float32 scores would be 16 GiB
@@ -634,9 +645,10 @@ def test_latent_attention_route_compiles_for_v5e_ahead_of_time():
     """`latent_attention` at `joyai_llm_flash_s8192`'s shapes (2 x 8192
     positions, 32 heads of 192 for queries and keys, 64 of every key one
     shared vector, and of 128 for values), value and the four gradients:
-    Mosaic takes the splash kernels at a value size of their own
-    (forward, dK/dV, dQ), every call keeps the op scope and, in the
-    backward, `transpose(`: what `mla_attention_device_ms` is read by."""
+    Mosaic takes upstream's forward kernel at a value size of its own
+    and `mx_causal_attention_bwd` at heads of 192 (dQ's blocks in 256
+    lanes), every call keeps the op scope and, in the backward,
+    `transpose(`: what `mla_attention_device_ms` is read by."""
     p = _run(["-c", _AOT_LATENT], timeout=300)
     if "NO_TPU_COMPILER" in p.stdout:
         pytest.skip(p.stdout.strip()[:200])
@@ -684,8 +696,10 @@ names = mosaic(compiled)
 print("MOSAIC lfm2", names)
 cores = [n for n in names if "dot_product_attention" in n]
 turns = [n for n in names if "rotary_embedding" in n]
-assert len(cores) == 3, names           # forward, dK/dV, dQ
-assert sum("transpose(" in n for n in cores) == 2, names
+# forward, and ONE backward: dK, dV and dQ from each score block once
+assert len(cores) == 2, names
+assert [n for n in cores if "transpose(" in n] == [
+    n for n in cores if "mx_causal_attention_bwd" in n] != [], names
 assert len(turns) == 4 and all("mx_rotary_turn" in n for n in turns), names
 after = pa.route_counts()
 assert after["flash_causal"] == before["flash_causal"] + 1, after
@@ -717,8 +731,8 @@ def test_lfm2_routes_compile_for_v5e_ahead_of_time():
     """`lfm2_8b_a1b_s8192`'s attention layer at its shapes (2 x 8192
     positions, 32 query heads over 8 key/value heads of 64): the
     rotation takes the kernel at d = 64 (four passes: q and k, forward
-    and backward) and the causal core the splash multi-query kernels at
-    64-lane operands (forward, dK/dV, dQ), every call under its op scope
+    and backward) and the causal core upstream's forward kernel and
+    `mx_causal_attention_bwd` at 64-lane operands, every call under its op scope
     and, in the backward, `transpose(`: what `head64_attention_device_ms`
     is read by; nothing of S x S is planned.  `short_conv` at the cell's
     streams is plain XLA that plans no temporary in its forward pass and

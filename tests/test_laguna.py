@@ -173,11 +173,15 @@ def _reached_by(jaxpr, marks):
                     for v in jaxpr.outvars]
 
 
-def test_causal_splash_program_repeats_no_key_or_value_head():
+@pytest.mark.parametrize("s", [256, 2048])
+def test_causal_splash_program_repeats_no_key_or_value_head(s):
     """Forward and backward, traced: nothing XLA makes of k or v is as
     large as q (the six copies a key/value head had before the kernels
-    took grouped heads), where the XLA form does hold such arrays."""
-    b, h, kv, s, d = 2, 12, 2, 256, 128
+    took grouped heads), where the XLA form does hold such arrays; under
+    upstream's split backward (S 256) and under the one kernel of the
+    repo's own (S 2048, two key blocks)."""
+    b, h, kv, d = 2, 12, 2, 128
+    assert pa._fused_backward(None, s, d, d, h // kv) is (s == 2048)
     q = jnp.zeros((b, h, s, d), jnp.bfloat16)
     k = v = jnp.zeros((b, kv, s, d), jnp.bfloat16)
 
@@ -193,6 +197,55 @@ def test_causal_splash_program_repeats_no_key_or_value_head():
     assert as_q(kernels) == [], kernels
     assert (b, h, s, d) in as_q(from_kv(
         lambda q, k, v: pa._causal_xla(q, k, v, 0.1)))
+
+
+def _arrays(jaxpr, into=None):
+    """(bytes, primitive) of every array a jaxpr makes, sub-jaxprs
+    included; a Pallas kernel's results are the kernel's own."""
+    into = [] if into is None else into
+    for eqn in jaxpr.eqns:
+        into += [(math.prod(v.aval.shape) * v.aval.dtype.itemsize,
+                  eqn.primitive.name) for v in eqn.outvars
+                 if hasattr(v.aval, "shape")]
+        if eqn.primitive.name == "pallas_call":
+            continue
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (tuple, list)) else (value,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    _arrays(sub, into)
+    return into
+
+
+def test_causal_splash_backward_holds_no_stack_of_partials():
+    """The traced backward of a causal call over 4 key blocks makes no
+    array larger than q in float32: dQ is ONE float32 array that the
+    kernel adds to in place, where upstream's fused form writes a part
+    a key block, each as large as q (the same check finds those)."""
+    from jax.experimental.pallas.ops.tpu import splash_attention as sa
+
+    b, h, kv, s, d = 1, 4, 2, 4096, 128
+    q = jnp.zeros((b, h, s, d), jnp.bfloat16)
+    k = v = jnp.zeros((b, kv, s, d), jnp.bfloat16)
+
+    def largest(core):
+        program = jax.make_jaxpr(jax.grad(
+            lambda q, k, v: core(q, k, v).astype(jnp.float32).sum(),
+            argnums=(0, 1, 2)))(q, k, v)
+        return max(_arrays(program.jaxpr))
+
+    ours = largest(lambda q, k, v: pa._causal_splash(q, k, v, 0.1,
+                                                     interpret=True))
+    assert ours[0] <= q.size * 4, ours
+    partials = sa.make_splash_mqa_single_device(
+        sa.MultiHeadMask([sa.CausalMask((s, s))] * (h // kv)),
+        block_sizes=sa.BlockSizes(
+            block_q=1024, block_kv=1024, block_kv_compute=512,
+            block_q_dkv=1024, block_kv_dkv=1024, block_kv_dkv_compute=512,
+            use_fused_bwd_kernel=True), interpret=True)
+    theirs = largest(lambda q, k, v: jax.vmap(jax.vmap(partials))(
+        q.reshape(b, kv, h // kv, s, d), k, v))
+    assert theirs[0] >= 4 * q.size * 2, theirs
 
 
 def test_window_attention_counts_its_route_in_telemetry():

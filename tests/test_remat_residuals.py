@@ -27,11 +27,12 @@ B, S, H, H_KV, D = 1, 256, 2, 1, 128
 WINDOW, CHUNK = 128, 2
 
 # route -> (forward kernel, backward kernels); each keeps o and one
-# logsumexp.  The causal and the window cores run the same kernels
+# logsumexp.  The causal and the window cores run the same forward kernel;
+# the whole triangle's backward is one kernel of the repo's own where the
+# block of 1,024 rows divides S (PR 48), a window's and EVA's upstream's two
 ROUTES = {
     "flash_causal": ("splash_mqa_fwd_residuals",
-                     ("splash_mqa_dkv_no_residuals",
-                      "splash_mqa_dq_no_residuals")),
+                     ("mx_causal_attention_bwd",)),
     "splash_window": ("splash_mqa_fwd_residuals",
                       ("splash_mqa_dkv_no_residuals",
                        "splash_mqa_dq_no_residuals")),
@@ -40,9 +41,11 @@ ROUTES = {
                     "splash_mha_dq_no_residuals")),
     # latent attention: `flash_causal`'s kernels, one query head a key
     "latent_splash": ("splash_mqa_fwd_residuals",
-                      ("splash_mqa_dkv_no_residuals",
-                       "splash_mqa_dq_no_residuals")),
+                      ("mx_causal_attention_bwd",)),
 }
+# the rows a route's layer is run at: one block of the triangle's kernels
+ROWS = {"flash_causal": 1024, "latent_splash": 1024, "splash_window": S,
+        "eva_splash": S}
 ROPE = 64      # the latent queries' and keys' rotary part, beside D
 
 
@@ -97,13 +100,14 @@ def _layer(route):
     return layer
 
 
-def _gradient(block, dtype=jnp.float32):
+def _gradient(block, dtype=jnp.float32, rows=S):
     """(value-and-gradient of a loss through the initialised `block`
-    under gradient mirroring, its parameters, an input)."""
+    under gradient mirroring, its parameters, an input of `rows`
+    positions)."""
     op = CachedOp(block, mirror=True)
     pure = op._make_pure(True)
     params = tuple(p.data().data.astype(dtype) for _, p in op._param_list())
-    x = jnp.asarray(np.random.RandomState(0).randn(B, S, H * D), dtype)
+    x = jnp.asarray(np.random.RandomState(0).randn(B, rows, H * D), dtype)
     weight = jnp.cos(jnp.arange(H * D, dtype=jnp.float32))
 
     def loss(params, x):
@@ -143,11 +147,11 @@ def test_the_forward_kernel_appears_once_where_it_appeared_twice(
     forward, backward = ROUTES[route]
     before = residuals.kept_residuals()[route]
     layer = _layer(route)
-    fn, params, x = _gradient(layer)
+    fn, params, x = _gradient(layer, rows=ROWS[route])
     kept = _count(jax.make_jaxpr(fn)(params, x).jaxpr)
     after = residuals.kept_residuals()[route]
     _drop_policy(monkeypatch)
-    fn, params, x = _gradient(layer)
+    fn, params, x = _gradient(layer, rows=ROWS[route])
     recomputed = _count(jax.make_jaxpr(fn)(params, x).jaxpr)
 
     assert recomputed[forward] == 2
@@ -161,7 +165,8 @@ def test_the_forward_kernel_appears_once_where_it_appeared_twice(
     # ... and counted as kept, with their bytes: o in the layer's dtype,
     # float32 rows of logsumexp
     assert after["values"] - before["values"] == 2
-    assert after["bytes"] - before["bytes"] == B * H * S * (D * 4 + 4)
+    assert after["bytes"] - before["bytes"] == B * H * ROWS[route] * (
+        D * 4 + 4)
     # everything else in the segment is still computed again: the
     # projections, the head split, the XLA twin in the other branch
     for name in ("xla:dot_general", "xla:transpose", "xla:exp"):
@@ -174,11 +179,11 @@ def test_the_xla_twins_name_nothing_and_are_recomputed_whole(
     monkeypatch.setenv("MXNET_USE_PALLAS", "0")
     before = residuals.kept_residuals()
     layer = _layer(route)
-    fn, params, x = _gradient(layer)
+    fn, params, x = _gradient(layer, rows=ROWS[route])
     kept = _count(jax.make_jaxpr(fn)(params, x).jaxpr)
     assert residuals.kept_residuals() == before
     _drop_policy(monkeypatch)
-    fn, params, x = _gradient(layer)
+    fn, params, x = _gradient(layer, rows=ROWS[route])
     assert kept == _count(jax.make_jaxpr(fn)(params, x).jaxpr)
     assert kept["xla:name"] == 0 and kept["xla:dot_general"] > 0
     assert not any(not k.startswith("xla:") for k in kept)
@@ -191,10 +196,10 @@ def test_interpreted_gradients_are_those_of_the_recomputed_kernel(
     kernel would have produced: loss and every gradient exactly equal."""
     monkeypatch.setenv("MXNET_PALLAS_INTERPRET", "1")
     layer = _layer(route)
-    fn, params, x = _gradient(layer)
+    fn, params, x = _gradient(layer, rows=ROWS[route])
     kept = jax.jit(fn)(params, x)
     _drop_policy(monkeypatch)
-    fn, params, x = _gradient(layer)
+    fn, params, x = _gradient(layer, rows=ROWS[route])
     recomputed = jax.jit(fn)(params, x)
     assert np.isfinite(kept[0]) and np.abs(kept[1][1]).max() > 0
     for a, b in zip(jax.tree_util.tree_leaves(kept),
@@ -292,17 +297,17 @@ def test_an_attention_layers_segment_at_head_size_64_keeps_o_and_logsumexp(
     heads = H * D // 64
     before = residuals.kept_residuals()["flash_causal"]
     block = _lfm2_segment("full_attention")
-    fn, params, x = _gradient(block)
+    fn, params, x = _gradient(block, rows=1024)
     kept = _count(jax.make_jaxpr(fn)(params, x).jaxpr)
     after = residuals.kept_residuals()["flash_causal"]
     _drop_policy(monkeypatch)
-    fn, params, x = _gradient(block)
+    fn, params, x = _gradient(block, rows=1024)
     recomputed = _count(jax.make_jaxpr(fn)(params, x).jaxpr)
     assert (kept[forward], recomputed[forward]) == (1, 2)
     for name in backward:
         assert kept[name] == recomputed[name] == 1
     assert after["values"] - before["values"] == 2
-    assert after["bytes"] - before["bytes"] == B * heads * S * (64 * 4 + 4)
+    assert after["bytes"] - before["bytes"] == B * heads * 1024 * (64 * 4 + 4)
     # the norms a head and the rotation are computed again
     for name in ("xla:rsqrt", "xla:dot_general"):
         assert kept[name] == recomputed[name] > 0, name
