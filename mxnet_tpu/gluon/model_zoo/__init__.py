@@ -6,7 +6,8 @@ from . import evabyte
 from . import joyai
 from . import lfm2
 from . import ouro
+from . import phi4flash
 from .vision import get_model
 
 __all__ = ["vision", "nemotron_h", "laguna", "evabyte", "joyai", "lfm2",
-           "ouro", "get_model"]
+           "ouro", "phi4flash", "get_model"]

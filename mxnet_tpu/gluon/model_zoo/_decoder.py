@@ -1,7 +1,8 @@
 """What the zoo's decoder stacks share (`nemotron_h.py`, `laguna.py`,
-`evabyte.py`, `joyai.py`, `lfm2.py`, `ouro.py`): the pre-norm residual
-sub-layer (with a second gain, normed on both sides: `ouro.py`), the
-bias-free projection, the gated MLP, a layer's MLP half (dense, or
+`evabyte.py`, `joyai.py`, `lfm2.py`, `ouro.py`, `phi4flash.py`): the
+pre-norm residual sub-layer (with a second gain, normed on both sides:
+`ouro.py`), the norm itself (RMSNorm, or with a bias LayerNorm:
+`phi4flash.py`), the bias-free projection, the gated MLP, a layer's MLP half (dense, or
 the gated mixture of experts, with a shared expert or without one), the
 final norm and the head (an array of its own, or the embedding's: tied),
 and the rule that named parameters keep float32 under `cast`.  A norm's
@@ -27,13 +28,27 @@ def gated_mlp(F, x, gate_weight, up_weight, down_weight):
                    * project(F, x, up_weight), down_weight)
 
 
+NORMS = ("rms", "layer")
+
+
+def normed(F, x, weight, eps, offset=0.0, bias=None):
+    """RMSNorm(x; weight), or with a `bias` LayerNorm(x; weight, bias):
+    the mean subtracted, gain and bias; float32 inside either way (the
+    `LayerNorm` op computes in its input's type, so the input is cast)."""
+    if bias is None:
+        return F.RMSNorm(x, weight, eps=eps, offset=offset)
+    return F.cast(F.LayerNorm(F.cast(x, dtype=FP32), weight, bias, eps=eps),
+                  dtype=x.dtype)
+
+
 def norm_residual(F, x, norm_weight, eps, mix, *args, offset=0.0,
-                  post=None, **params):
-    """x + mix(F, RMSNorm(x), ...), or with a second gain `post` the
-    sub-layer normed on both sides, x + RMSNorm(mix(...); post).  Where
-    `mix` gives (output, statistics...) the statistics pass through
-    beside the sum."""
-    mixed = mix(F, F.RMSNorm(x, norm_weight, eps=eps, offset=offset),
+                  post=None, norm_bias=None, **params):
+    """x + mix(F, norm(x), ...), the norm `normed`'s (LayerNorm where a
+    `norm_bias` comes), or with a second gain `post` the sub-layer normed
+    on both sides, x + RMSNorm(mix(...); post).  Where `mix` gives
+    (output, statistics...) the statistics pass through beside the
+    sum."""
+    mixed = mix(F, normed(F, x, norm_weight, eps, offset, norm_bias),
                 *args, **params)
     mixed, *stats = mixed if isinstance(mixed, (list, tuple)) else (mixed,)
     if post is not None:
@@ -54,16 +69,27 @@ class KeepsFloat32(HybridBlock):
 
 
 class Layer(KeepsFloat32):
-    """h + mixer(RMSNorm(h)); subclasses give `mix`."""
+    """h + mixer(norm(h)); subclasses give `mix`.  `norm`: "rms", or
+    "layer" for LayerNorm, which brings a `norm_bias` beside every gain
+    `_norm_gain` makes."""
 
-    def __init__(self, hidden_size, eps, norm_offset=0.0, **kwargs):
+    def __init__(self, hidden_size, eps, norm_offset=0.0, norm="rms",
+                 **kwargs):
         super().__init__(**kwargs)
+        if norm not in NORMS:
+            raise MXNetError(f"norm {norm!r} (of {NORMS})")
         self._hidden, self._eps, self._offset = hidden_size, eps, norm_offset
+        self._layer_norm = norm == "layer"
         with self.name_scope():
             self.norm_weight = self._norm_gain("norm_weight")
 
     def _norm_gain(self, name):
-        """A gain that starts the norm at identity."""
+        """A gain that starts the norm at identity, and under LayerNorm
+        its bias `<name less _weight>_bias`, zero."""
+        if self._layer_norm:
+            bias = name[:-len("weight")] + "bias"
+            setattr(self, bias, self.params.get(
+                bias, shape=(self._hidden,), init="zeros"))
         return self.params.get(name, shape=(self._hidden,),
                                init="zeros" if self._offset else "ones")
 
@@ -100,8 +126,7 @@ class MLPLayer(Layer):
         a shared expert `shared_size` wide (0: none)."""
         d = self._hidden
         self._sparse = mlp_size is None
-        self.mlp_norm_weight = self.params.get(
-            "mlp_norm_weight", shape=(d,), init="ones")
+        self.mlp_norm_weight = self._norm_gain("mlp_norm_weight")
         if not self._sparse:
             self._gated("mlp", mlp_size)
             return
@@ -126,9 +151,11 @@ class MLPLayer(Layer):
         if shared_size:
             self._gated("shared", shared_size)
 
-    def mlp(self, F, h, mlp_norm_weight, **params):
-        """h + MLP(RMSNorm(h)), and from a sparse layer [rows of each
-        held expert..., dropped] beside it."""
+    def mlp(self, F, h, mlp_norm_weight, mlp_norm_bias=None, **params):
+        """h + MLP(norm(h)) (with `mlp_norm_bias` LayerNorm), and from a
+        sparse layer [rows of each held expert..., dropped] beside it."""
+        if mlp_norm_bias is not None:
+            params["norm_bias"] = mlp_norm_bias
         return norm_residual(F, h, mlp_norm_weight, self._eps,
                              self.experts if self._sparse else self.dense,
                              **params)
@@ -157,25 +184,31 @@ class MLPLayer(Layer):
 
 
 class Head(HybridBlock):
-    """Final RMSNorm and the vocabulary projection, by an array of the
+    """Final norm (`norm`: "rms", or "layer" for LayerNorm with its
+    bias) and the vocabulary projection, by an array of the
     head's own or by `tied`, the embedding's (vocab_size, hidden_size)
     Parameter: one array, which the gradients of both uses reach; with
     `logits_dtype` the product's operands are cast to it first (float32
     logits from bfloat16 weights)."""
 
     def __init__(self, hidden_size, vocab_size, eps, norm_offset=0.0,
-                 logits_dtype=None, tied=None, **kwargs):
+                 logits_dtype=None, tied=None, norm="rms", **kwargs):
         super().__init__(**kwargs)
+        if norm not in NORMS:
+            raise MXNetError(f"norm {norm!r} (of {NORMS})")
         self._eps, self._offset, self._dtype = eps, norm_offset, logits_dtype
         with self.name_scope():
             self.norm_weight = self.params.get(
                 "norm_weight", shape=(hidden_size,),
                 init="zeros" if norm_offset else "ones")
+            if norm == "layer":
+                self.norm_bias = self.params.get(
+                    "norm_bias", shape=(hidden_size,), init="zeros")
             self.weight = tied if tied is not None else self.params.get(
                 "weight", shape=(vocab_size, hidden_size))
 
-    def hybrid_forward(self, F, x, norm_weight, weight):
-        x = F.RMSNorm(x, norm_weight, eps=self._eps, offset=self._offset)
+    def hybrid_forward(self, F, x, norm_weight, weight, norm_bias=None):
+        x = normed(F, x, norm_weight, self._eps, self._offset, norm_bias)
         if self._dtype is not None:
             x, weight = (F.cast(a, dtype=self._dtype) for a in (x, weight))
         return project(F, x, weight)

@@ -18,11 +18,13 @@ from . import contrib  # noqa: F401
 from . import pallas_attention  # noqa: F401
 from . import eva_attention  # noqa: F401
 from . import latent_attention  # noqa: F401
+from . import differential_attention  # noqa: F401
 from . import pallas_convbn  # noqa: F401
 from . import linalg  # noqa: F401
 from . import image_ops  # noqa: F401
 from . import quantization  # noqa: F401
 from . import ssm  # noqa: F401
+from . import selective_scan  # noqa: F401
 from . import moe  # noqa: F401
 from . import rotary  # noqa: F401
 from . import short_conv  # noqa: F401

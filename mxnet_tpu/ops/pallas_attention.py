@@ -44,9 +44,10 @@ each route covers:
     partial blocks and read slower fused: `_fused_backward`).  Counted
     `splash_window`; every other windowed call is the banded XLA form,
     counted `reference`; window >= S is causal attention.
-  * `eva_attention` and `latent_attention` (modules of their own) count
-    their routes here too: `eva_splash` / `eva_xla`, `latent_splash` /
-    `latent_xla`.
+  * `eva_attention`, `latent_attention` and `differential_attention`
+    (modules of their own) count their routes here too: `eva_splash` /
+    `eva_xla`, `latent_splash` / `latent_xla`, `diff_splash` and
+    `diff_window_splash` / `diff_xla`.
   * Training with dropout on the probabilities, self-attention shaped as
     BERT's (not causal, sq == sk, a multiple of 128 up to 1024, heads of
     64, 128 or 256 filling whole 128-lane blocks): `_attend_train`, two
@@ -751,20 +752,20 @@ def _causal_flash_shape(heads, kv_heads, sq, sk, d, d_v=None):
 
 
 def _window_xla(q, k, v, scale, window):
-    """The band 0 <= i - j < window in plain XLA: q (B, H, S, D), k and
-    v (B, Hkv, S, D); scores and softmax in float32.  Queries in blocks
+    """The band 0 <= i - j < window in plain XLA: q (B, H, S, D), k
+    (B, Hkv, S, D) and v (B, Hkv, S, Dv); scores and softmax in float32.  Queries in blocks
     of `window`, each against its own block of keys and the one before
     it, so the scores are (B, H, S, 2 window) where a dense mask would
     hold (B, H, S, S): the form a mesh of several devices and the CPU
     take stays O(S x window) in memory and work, as the kernels are."""
-    b, h, s, d = q.shape
+    b, h, s, _ = q.shape
     n = -(-s // window)
 
     def blocks(x):
         """(B, H, S, D) -> (B, H, n, window, D), zeros after S: keys no
         query of the S looks ahead to, queries cut off again below."""
         x = jnp.pad(x, ((0, 0), (0, 0), (0, n * window - s), (0, 0)))
-        return x.reshape(b, h, n, window, d)
+        return x.reshape(b, h, n, window, x.shape[-1])
 
     def with_previous(x):
         before = jnp.pad(x[:, :, :-1],
@@ -783,7 +784,7 @@ def _window_xla(q, k, v, scale, window):
     prob = jax.nn.softmax(score, axis=-1).astype(v.dtype)
     out = jnp.einsum("bhnqk,bhnkd->bhnqd", prob,
                      with_previous(blocks(_repeat_kv(v, h))))
-    return out.reshape(b, h, n * window, d)[:, :, :s]
+    return out.reshape(b, h, n * window, -1)[:, :, :s]
 
 
 def _splash_blocks(s, window):
@@ -1158,7 +1159,8 @@ def _attend_causal(q, k, v, scale, window, interpret, name=None):
 # that engaged the fused route
 ROUTES = ("fused_train", "xla_dropout", "kernel_infer", "reference",
           "flash_causal", "splash_window", "eva_splash", "eva_xla",
-          "latent_splash", "latent_xla")
+          "latent_splash", "latent_xla", "diff_splash",
+          "diff_window_splash", "diff_xla")
 kernel_route.declare("attention", ROUTES)
 _FLASH_CAUSAL = kernel_route.Kernel("attention", "flash_causal", None)
 _FUSED_TRAIN = kernel_route.Kernel("attention", "fused_train", "xla_dropout",

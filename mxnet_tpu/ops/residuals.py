@@ -29,7 +29,8 @@ from ..telemetry import instruments as _instruments
 __all__ = ["NAMES", "KEEP_NAMED", "kept_residuals"]
 
 #: every name a forward rule may give a value; one place, one policy
-NAMES = ("flash_causal", "splash_window", "eva_splash", "latent_splash")
+NAMES = ("flash_causal", "splash_window", "eva_splash", "latent_splash",
+         "diff_splash", "diff_window_splash")
 KEEP_NAMED = jax.checkpoint_policies.save_only_these_names(*NAMES)
 
 # Counted where a route that names its residuals is CHOSEN inside a

@@ -816,3 +816,94 @@ def test_rotary_kernel_compiles_for_v5e_ahead_of_time():
         pytest.skip(p.stdout.strip()[:200])
     assert p.returncode == 0 and "AOT_OK" in p.stdout, \
         p.stdout[-2000:] + p.stderr[-3000:]
+
+
+_AOT_PHI4 = r"""
+import re, sys
+import jax, jax.numpy as jnp
+from jax.experimental import topologies
+from jax.sharding import SingleDeviceSharding
+try:
+    topo = topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
+except Exception as e:
+    print("NO_TPU_COMPILER", type(e).__name__, e)
+    sys.exit(0)
+from mxnet_tpu.ops import pallas_attention as pa
+from mxnet_tpu.ops import selective_scan as ss
+from mxnet_tpu.ops.registry import apply_pure
+from mxnet_tpu.parallel.spmd import _whole_instructions
+sh = SingleDeviceSharding(topo.devices[0])
+arg = lambda shape, dt=jnp.bfloat16: jax.ShapeDtypeStruct(
+    shape, dt, sharding=sh)
+f32 = jnp.float32
+
+def mosaic(fn, *args):
+    compiled = jax.jit(fn).lower(*args).compile()
+    calls = [ln for ln in _whole_instructions(compiled.as_text())
+             if 'custom_call_target="tpu_custom_call"' in ln]
+    return compiled, [re.search(r'op_name="([^"]*)"', ln).group(1)
+                      for ln in calls]
+
+b, s, d, n = 1, 16384, 5120, 16
+def scan_loss(*a):
+    return apply_pure("selective_scan", *a).astype(f32).sum()
+compiled, names = mosaic(
+    jax.grad(scan_loss, argnums=tuple(range(7))),
+    arg((b, s, d)), arg((b, s, d)), arg((d, n), f32), arg((b, s, n)),
+    arg((b, s, n)), arg((d,), f32), arg((d,), f32))
+print("MOSAIC scan", names)
+assert len(names) == 2 and all("selective_scan" in x for x in names), names
+assert sum("mx_selective_scan_fwd" in x and "transpose(" not in x
+           for x in names) == 1, names
+assert sum("mx_selective_scan_bwd" in x and "transpose(" in x
+           for x in names) == 1, names
+assert ss.route_counts() == {"chunked_xla": 0, "fused_kernel": 1}
+# the (B, S, D, N) float32 states would be 5 GiB: 1 / 64 of them is kept
+assert compiled.memory_analysis().temp_size_in_bytes < 3 * 2 ** 30
+
+h, kv, hd = 40, 20, 64
+def core_loss(window):
+    def loss(q, k, v, *small):
+        return apply_pure("differential_attention", q, k, v, *small,
+                          num_heads=h, num_kv_heads=kv, window=window,
+                          lambda_init=0.5).astype(f32).sum()
+    return jax.grad(loss, argnums=tuple(range(8)))
+small = [arg((hd,), f32)] * 4 + [arg((2 * hd,), f32)]
+for window, kernels, scope in ((0, 2, "full"), (512, 3, "window")):
+    compiled, names = mosaic(core_loss(window), arg((b, s, h * hd)),
+                             arg((b, s, kv * hd)), arg((b, s, kv * hd)),
+                             *small)
+    print("MOSAIC differential", window, names)
+    assert len(names) == kernels, names
+    # `jvp(` / `transpose(` wrap the outermost scope, here the op's
+    assert all(re.search(rf"differential_attention\)*/{scope}/", x)
+               for x in names), names
+    backward = [x for x in names if "transpose(" in x]
+    assert len(backward) == kernels - 1, names
+    if not window:
+        assert "mx_causal_attention_bwd" in backward[0], names
+    assert compiled.memory_analysis().temp_size_in_bytes < 4 * 2 ** 30
+assert pa.backward_counts() == {"fused": 1, "split": 1}
+counts = pa.route_counts()
+assert counts["diff_splash"] == counts["diff_window_splash"] == 1, counts
+assert counts["diff_xla"] == 0, counts
+print("AOT_OK")
+"""
+
+
+def test_phi4flash_kernels_compile_for_v5e_ahead_of_time():
+    """`phi4_mini_flash_s16384`'s kernels at its shapes (1 x 16,384
+    positions), value and every gradient: Mosaic takes the two
+    selective-scan kernels at 5,120 channels of 16 states (what
+    `selective_scan_device_ms` is read by: the op scope, `transpose(` in
+    the backward), and the causal and the window splash route at 40
+    query heads over 20 of 64 with values of 128 (the full cores'
+    backward the one kernel `mx_causal_attention_bwd`, the window's
+    upstream's two), under `differential_attention/full` and
+    `/window`."""
+    p = _run(["-c", _AOT_PHI4], timeout=600)
+    if "NO_TPU_COMPILER" in p.stdout:
+        pytest.skip(p.stdout.strip()[:200])
+    assert p.returncode == 0 and "AOT_OK" in p.stdout, \
+        p.stdout[-2000:] + p.stderr[-3000:]
