@@ -423,8 +423,9 @@ def decoder_phase(*, seq=1024, heads=8, kv_heads=2, dim=128, window=256,
     op (upstream's splash multi-query forward kernel over a causal mask
     and `mx_causal_attention_bwd`) against the S x S reference, grouped
     over one key block and one head a key/value head over two, the same
-    under a sliding `window` (upstream's three kernels over the band)
-    against the banded XLA form, the held
+    under a sliding `window` at eight query heads a key/value head
+    (upstream's forward kernel over the band and
+    `mx_window_attention_bwd`) against the banded XLA form, the held
     experts' stage in both forms
     (relu^2 and silu-gated) (a loop over chunks of the plan's rows around
     the grouped-matmul kernel; `held_bias` on the router draws enough
@@ -446,12 +447,14 @@ def decoder_phase(*, seq=1024, heads=8, kv_heads=2, dim=128, window=256,
     def weighed(o, ct):
         return (o.astype(jnp.float32) * ct.astype(jnp.float32)).sum(), o
 
-    def compare(name, route, plain, operands, out, looped=False):
+    def compare(name, route, plain, operands, out, looped=False, kernel=""):
         grad = jax.jit(jax.grad(route, argnums=(0, 1, 2), has_aux=True))
         if expect_mosaic:
             traced = grad.trace(*operands)
-            _require("tpu_custom_call" in traced.lower().as_text(),
-                     f"no Mosaic call in the lowered gradient of {name}")
+            text = traced.lower().as_text()
+            _require("tpu_custom_call" in text and kernel in text,
+                     f"no Mosaic call {kernel} in the lowered gradient of "
+                     f"{name}")
             _require(not looped
                      or _holds(traced.jaxpr, "pallas_call", "while"),
                      f"no loop around the Mosaic calls in the gradient of "
@@ -494,7 +497,8 @@ def decoder_phase(*, seq=1024, heads=8, kv_heads=2, dim=128, window=256,
             return weighed(o.transpose(0, 2, 1, 3).reshape(q.shape), ct)
 
         compare(f"causal_gqa_h{heads}_kv{kv_heads}_s{seq}_d{dim}", attention,
-                attention_plain, (q, k, v, ct), out)
+                attention_plain, (q, k, v, ct), out,
+                kernel="mx_causal_attention_bwd")
         _require(pa.route_counts()["flash_causal"] > before,
                  f"the causal call did not take the splash kernels' route: "
                  f"{pa.route_counts()}")
@@ -504,24 +508,34 @@ def decoder_phase(*, seq=1024, heads=8, kv_heads=2, dim=128, window=256,
     # kernel reads the last query block's dQ in the step after the one
     # that wrote it (`pallas_attention._triangle_walk`'s `again`)
     causal(2 * seq, kv_heads, kv_heads)
-    q, k, v, ct = causal(seq, heads, kv_heads)
+    _, k, v, _ = causal(seq, heads, kv_heads)
 
-    before = pa.route_counts()["splash_window"]
+    # the window at laguna's grouping, eight query heads a key/value head:
+    # the band kernel's three gradients against the banded XLA form's
+    grouped = 8 * kv_heads
+    q, ct = (jnp.asarray(rng.randn(1, seq, grouped * dim), dtype)
+             for _ in range(2))
+    before = pa.route_counts()["splash_window"], pa.backward_counts()
 
     def windowed(q, k, v, ct):
         return weighed(pa._sliding_window_attention(
-            q, k, v, num_heads=heads, num_kv_heads=kv_heads,
+            q, k, v, num_heads=grouped, num_kv_heads=kv_heads,
             window=window), ct)
 
     def windowed_plain(q, k, v, ct):
-        o = pa._window_xla(*split(q, k, v), dim ** -0.5, window)
+        o = pa._window_xla(*split(q, k, v, grouped), dim ** -0.5, window)
         return weighed(o.transpose(0, 2, 1, 3).reshape(q.shape), ct)
 
-    compare(f"window{window}_gqa_h{heads}_kv{kv_heads}_s{seq}_d{dim}",
-            windowed, windowed_plain, (q, k, v, ct), out)
-    _require(pa.route_counts()["splash_window"] > before,
+    compare(f"window{window}_gqa_h{grouped}_kv{kv_heads}_s{seq}_d{dim}",
+            windowed, windowed_plain, (q, k, v, ct), out,
+            kernel="mx_window_attention_bwd")
+    _require(pa.route_counts()["splash_window"] > before[0],
              f"the windowed call did not take the splash route: "
              f"{pa.route_counts()}")
+    moved = {form: n - before[1][form]
+             for form, n in pa.backward_counts().items()}
+    _require(moved["band"] > 0 and moved["fused"] == moved["split"] == 0,
+             f"the windowed call's backward is not the band kernel: {moved}")
 
     x = jnp.asarray(rng.randn(tokens, 64), jnp.float32)
     plan = moe.route(x, jnp.asarray(rng.randn(experts, 64), jnp.float32),

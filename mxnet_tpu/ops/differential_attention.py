@@ -26,7 +26,9 @@ under both keys of its pair, so V is written twice in HBM: PERF.md
 section 7), and the H query heads ordered so that the queries of a key
 head are adjacent: under k1 of pair g the q1 of its query pairs, under
 k2 their q2; a1 and a2 are picked back out of the H outputs.  lam, the
-difference, the norm and the factor are float32.
+difference, the norm and the factor are float32.  The backward of either
+call is one kernel of the repo's own (`pallas_attention._fused_backward`:
+`mx_causal_attention_bwd`, under a window `mx_window_attention_bwd`).
 
 Routes (`pallas_attention.route_counts()`): `diff_splash` (no window),
 `diff_window_splash`, and for every other shape, a mesh and the CPU

@@ -39,9 +39,13 @@ each route covers:
     op of its own so that a profile reads the window and the full cores
     apart): query i sees keys j with 0 <= i - j < window.  Shapes as for
     `flash_causal`, window < S: `_attend_causal` over a `LocalMask`,
-    O(S x window) work where `flash_causal` does O(S^2 / 2); forward,
-    dK/dV and dQ are upstream's three kernels (a band visits mostly
-    partial blocks and read slower fused: `_fused_backward`).  Counted
+    O(S x window) work where `flash_causal` does O(S^2 / 2); the
+    forward is upstream's kernel, the backward ONE kernel of the repo's
+    own, `mx_window_attention_bwd`: it walks the block pairs the band
+    touches, leaves the tiles of a pair that no query sees a key of out
+    of its products, masks only the tiles an edge of the band cuts, and
+    sums dQ, dK and dV in float32 in VMEM, each written once (PERF.md,
+    PR 50; `_fused_backward` says which calls).  Counted
     `splash_window`; every other windowed call is the banded XLA form,
     counted `reference`; window >= S is causal attention.
   * `eva_attention`, `latent_attention` and `differential_attention`
@@ -789,12 +793,17 @@ def _window_xla(q, k, v, scale, window):
 
 def _splash_blocks(s, window):
     """(rows of queries and of keys a block, rows of keys a product) of
-    the splash kernels, from the mask's kind and S.  Under a window: 512
-    where it divides S, so that a query block visits the 2 key blocks
-    its band touches; on the v5e at the window of 512, forward +
-    backward of one layer (PERF.md, PR 31): 512 23.4 ms, 256 (3 blocks,
-    768 keys for the 512 a query sees, but three times the grid steps)
-    33.5, 128 69.3, (1024, 512) 30.1, the fused backward slower.  The
+    upstream's splash kernels, from the mask's kind and S.  Under a
+    window: 512 where it divides S, so that a query block visits the 2
+    key blocks its band touches; on the v5e at the window of 512,
+    forward + backward of one layer through upstream's three kernels
+    (PERF.md, PR 31): 512 23.4 ms, 256 (3 blocks, 768 keys for the 512 a
+    query sees, but three times the grid steps) 33.5, 128 69.3, (1024,
+    512) 30.1; the forward alone (PERF.md, PR 50) at laguna's shape /
+    phi4's (`_band_blocks`): 512 5.81 / 3.69 ms, (512, 256) 7.17 / 4.52,
+    (1024, 512) 8.30 / 5.26, (1024, 256) 8.67 / 5.49.  Only the forward
+    of a windowed call runs at these blocks: its backward is a kernel
+    of the repo's own at blocks of its own (`_band_blocks`).  The
     whole triangle is mostly whole blocks and takes 1024 rows in
     products of 512 keys where 1024 divides S; one full layer of
     `laguna_xs2_s8192` (B 2, 48 heads over 8, S 8192) on the v5e
@@ -811,25 +820,33 @@ def _splash_blocks(s, window):
 
 
 def _fused_backward(window, s, d, d_v, groups):
-    """Whether a call's backward is `mx_causal_attention_bwd`, from what
-    `_causal_splash` sees: the mask's kind, S, the head sizes of queries
-    / keys and of values, the query heads a key/value head.  A
-    separation by mask kind: the triangle visits mostly whole blocks,
-    each of whose scores the one kernel forms once where upstream's two
-    form them twice; a window and EVA's mask visit mostly partial
-    blocks and read slower fused (PERF.md, PRs 31, 34), and an S the
-    1,024-row block does not divide runs upstream's kernels at their
-    fall-back blocks: both keep upstream's split backward.  Every size a
-    cell runs at S 8192 read faster fused on the v5e (PERF.md, PR 48),
-    one layer forward + backward, ms, upstream's split kernels /
-    upstream's fused form with its bfloat16 partials / the one kernel:
-    48 heads over 8 of 128 (B 2) 54.90 / 45.69 / 40.70; 32 heads of 192
-    and 128 (B 2) 58.08 / 50.66 / 44.90; a group of one, 16 heads of 128
-    (B 1) 8.99 / 7.58 / 6.87; 32 over 8 of 64 (B 2) 37.66 / 31.44 /
-    27.85; 32 over 2 of 128 (B 2) 36.32 / 30.13 / 27.11: so neither the
-    head sizes nor the group decide anything yet."""
-    del d, d_v, groups
-    return window is None and s % 1024 == 0
+    """The form of a call's backward, from what `_causal_splash` sees (the
+    mask's kind, S, the head sizes of queries / keys and of values, the
+    query heads a key/value head): "fused", `mx_causal_attention_bwd` over
+    the triangle; "band", `mx_window_attention_bwd` over a window; or
+    "split", upstream's two kernels (dK/dV and dQ).  Either kernel of the
+    repo's own forms each score block once where upstream's two form it
+    twice.  The triangle takes its kernel where the 1,024-row block
+    divides S, a window where `_band_blocks` finds a block that divides
+    S and whose sums fit the kernel's VMEM (at a window of 512, every
+    group up to 176 query heads a key/value head of 128); EVA's mask,
+    and an S the blocks do not divide, keep upstream's split backward.
+    Every size a cell runs at S 8192 read faster fused on the v5e
+    (PERF.md, PR 48), one layer forward + backward, ms, upstream's
+    split kernels / upstream's fused form with its bfloat16 partials /
+    the one kernel: 48 heads over 8 of 128 (B 2) 54.90 / 45.69 / 40.70;
+    32 heads of 192 and 128 (B 2) 58.08 / 50.66 / 44.90; a group of
+    one, 16 heads of 128 (B 1) 8.99 / 7.58 / 6.87; 32 over 8 of 64 (B 2)
+    37.66 / 31.44 / 27.85; 32 over 2 of 128 (B 2) 36.32 / 30.13 /
+    27.11.  Under the window of 512
+    (PERF.md, PR 50), one layer forward + backward, split / band: 64
+    heads over 8 of 128 (B 2, S 8192) 22.14 / 13.58; 40 over 20, q and
+    k of 64 and v of 128 (B 1, S 16384) 14.76 / 9.43: a group of 2
+    and 64-lane queries and keys gain as a group of 8 of 128 does, so
+    neither the head sizes nor the group decide anything yet."""
+    if window is None:
+        return "fused" if s % 1024 == 0 else "split"
+    return "band" if _band_blocks(s, window, d, d_v, groups) else "split"
 
 
 # upstream's value for a masked score: exp(it - logsumexp) is 0
@@ -848,6 +865,39 @@ def _triangle_walk(blocks, groups):
     again = [0] + [int(a[1:] == b[1:]) for a, b in zip(steps, steps[1:])]
     return np.concatenate([np.asarray(steps, np.int32).T,
                            np.asarray([again], np.int32)])
+
+
+def _score_products(k_ref, v_ref, q_ref, do_ref, stat_ref, dk_sum, dv_sum,
+                    keys, seen, keep, head=()):
+    """The five products of one tile of scores, the `keys` of the step's
+    key block (sublanes) against its `seen` queries (lanes), formed once:
+    dV's and dK's parts are added to the float32 sums, dQ's part (seen
+    rows, d) float32 is returned.  `keep` (None: a whole tile) gives, from
+    the tile's shape, where a query sees a key; `head`: the index of the
+    query head where a block of q, dO and the statistics holds several."""
+    kc, vc = k_ref[keys, :], v_ref[keys, :]
+    qs, dos = q_ref[(*head, seen, slice(None))], do_ref[(*head, seen,
+                                                         slice(None))]
+    st = jax.lax.dot_general(kc, qs, _NT,
+                             preferred_element_type=jnp.float32)
+    if keep is not None:
+        st = jnp.where(keep(st.shape), st, _MASKED)
+    pt = jnp.exp(st - stat_ref[(*head, slice(None, 1), seen)])  # logsumexp
+    dv_sum[keys, :] += jnp.dot(pt.astype(dos.dtype), dos,
+                               preferred_element_type=jnp.float32)
+    dpt = jax.lax.dot_general(vc, dos, _NT,
+                              preferred_element_type=jnp.float32)
+    dst = ((dpt - stat_ref[(*head, slice(1, None), seen)])      # di
+           * pt).astype(qs.dtype)
+    dk_sum[keys, :] += jnp.dot(dst, qs, preferred_element_type=jnp.float32)
+    return jax.lax.dot_general(dst, kc, _TN,
+                               preferred_element_type=jnp.float32)
+
+
+def _at_or_after(shape):
+    """Where the query (lane) is at or after the key (sublane)."""
+    return (jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+            >= jax.lax.broadcasted_iota(jnp.int32, shape, 0))
 
 
 def _causal_bwd_vmem_bytes(rows, compute, d, d_v, itemsize):
@@ -949,28 +999,13 @@ def _causal_bwd_pallas(q, k, v, do, lse, di, rows, compute, interpret):
             by the triangle, and the queries before a chunk's first key
             see none of it, so they stay out of its products."""
             for c in range(chunks):
-                keys = pl.ds(c * compute, compute)
                 seen = pl.ds(c * compute, rows - c * compute) if diagonal \
                     else pl.ds(0, rows)
-                kc, vc = k_ref[keys, :], v_ref[keys, :]
-                qs, dos = q_ref[seen, :], do_ref[seen, :]
-                st = jax.lax.dot_general(kc, qs, _NT,
-                                         preferred_element_type=jnp.float32)
-                if diagonal:    # query seen.start + j sees key keys.start + i
-                    st = jnp.where(
-                        jax.lax.broadcasted_iota(jnp.int32, st.shape, 1)
-                        >= jax.lax.broadcasted_iota(jnp.int32, st.shape, 0),
-                        st, _MASKED)
-                pt = jnp.exp(st - stat_ref[:1, seen])           # logsumexp
-                dv_sum[keys, :] += jnp.dot(pt.astype(dos.dtype), dos,
-                                           preferred_element_type=jnp.float32)
-                dpt = jax.lax.dot_general(vc, dos, _NT,
-                                          preferred_element_type=jnp.float32)
-                dst = ((dpt - stat_ref[1:, seen]) * pt).astype(qs.dtype)
-                dk_sum[keys, :] += jnp.dot(dst, qs,
-                                           preferred_element_type=jnp.float32)
-                part = jax.lax.dot_general(dst, kc, _TN,
-                                           preferred_element_type=jnp.float32)
+                # query seen.start + j sees key keys.start + i
+                part = _score_products(
+                    k_ref, v_ref, q_ref, do_ref, stat_ref, dk_sum, dv_sum,
+                    pl.ds(c * compute, compute), seen,
+                    _at_or_after if diagonal else None)
                 if c == 0:
                     dq_part[...] = part
                 else:
@@ -1039,6 +1074,217 @@ def _causal_bwd_pallas(q, k, v, do, lse, di, rows, compute, interpret):
     return dq[..., :d].reshape(q.shape), dk, dv
 
 
+def _band_reach(rows, window):
+    """The key blocks before its own that a query block's band touches."""
+    return -(-(window - 1) // rows)
+
+
+def _band_walk(blocks, reach):
+    """int32 (2, steps): the (key block, query block) of every step of
+    `mx_window_attention_bwd` over one key/value head: the block pairs
+    with a pair inside the band, `reach` key blocks before a query
+    block's own.  Key block outermost, so dK and dV of a key block are
+    one sum over consecutive steps; its query blocks from the farthest
+    back to its own, so the diagonal, the last of a dQ block's `reach +
+    1` visits, ends the key block's steps."""
+    return np.asarray([(kb, qb) for kb in range(blocks) for qb in range(
+        min(kb + reach, blocks - 1), kb - 1, -1)], np.int32).T
+
+
+def _band_tiles(offset, rows, compute, window):
+    """((first key, first query, queries, cut below, cut above), ...): for
+    each chunk of `compute` keys of the block pair `offset` blocks apart
+    that some query sees, the queries (in whole chunks) that see one of
+    its keys inside 0 <= i - j < window, and which of the band's two
+    edges cuts that tile: i - j of query row r and key row c of the pair
+    is offset * rows + r - c wherever the pair lies, so this is known
+    when the kernel is traced."""
+    tiles = []
+    for c0 in range(0, rows, compute):
+        # query row r is ahead + r past the chunk's first key, and sees a
+        # key of the chunk where 0 <= ahead + r < window + compute - 1
+        ahead = offset * rows - c0
+        first = max(0, -ahead // compute * compute)
+        last = min(rows, -(-(window + compute - 1 - ahead) // compute)
+                   * compute)
+        if first < last:
+            tiles.append((c0, first, last - first,
+                          ahead + first - (compute - 1) < 0,
+                          ahead + last - 1 >= window))
+    return tuple(tiles)
+
+
+# the scoped VMEM `mx_window_attention_bwd` may ask for: the v5e has 128 MiB
+_BAND_VMEM = 100 << 20
+
+
+def _band_vmem_bytes(rows, compute, window, d, d_v, groups, itemsize):
+    """The scoped VMEM `mx_window_attention_bwd` asks Mosaic for, from
+    what it holds (a head of 64 lies in 128 lanes): the blocks of q, dO
+    and dQ of all the group's heads and of k, v, dK and dV double
+    buffered, the statistics, the float32 sums of dK and dV and of the
+    `reach + 1` dQ blocks a head has in flight, some eight float32
+    tiles of scores, and 8 MiB for what Mosaic allocates itself."""
+    lanes = lambda n: -(-n // 128) * 128
+    d, d_v = lanes(d), lanes(d_v)
+    held = _band_reach(rows, window) + 1
+    blocks = 2 * rows * itemsize * (groups * (2 * d + d_v) + 2 * (d + d_v))
+    stats = 2 * groups * 8 * rows * 4
+    sums = rows * (d + d_v) * 4 + groups * held * rows * d * 4
+    tiles = 8 * compute * min(rows, window + 2 * compute) * 4
+    return blocks + stats + sums + tiles + (8 << 20)
+
+
+def _band_blocks(s, window, d, d_v, groups):
+    """(rows of queries and of keys a block, rows of keys a product) of
+    `mx_window_attention_bwd`, or None where no block that divides S
+    fits its VMEM: the largest block that does, in products of 128
+    keys.  One window layer's backward kernel alone on the v5e, ms
+    (PERF.md, PR 50), at laguna's shape (B 2, 64 heads over 8 of 128, S
+    8192, window 512) / phi4's (B 1, 40 over 20, q and k of 64 and v of
+    128, S 16384, window 512), where upstream's two kernels read 12.85 /
+    8.30: blocks of 512 rows in products of 128 keys 7.20 / 5.98, 1,024
+    5.85 / 4.99, 2,048 5.33 / 4.64, 4,096 5.16 / 4.62 (but 33 MiB of
+    held dQ at a group of 8); in products of 256 keys 6.87 / 5.73, 6.12
+    / 5.13, 5.97 / 5.04 and 5.94 / 5.32, of 512 keys 7.43 / 6.20 at
+    2,048.  Earlier forms of the kernel at (2,048, 128): a product over
+    ONE tile of 128 queries at a time, only the tiles an edge cuts
+    masked, 8.25 / 6.49 (6.69 / 5.74 at tiles of 256: small products
+    cost more than the masks they save); the group's heads as grid
+    steps instead of a loop in the step 5.52 / 4.73."""
+    for rows in (2048, 1024, 512, 256, 128):
+        if s % rows == 0 and _band_vmem_bytes(
+                rows, 128, window, d, d_v, groups, 2) <= _BAND_VMEM:
+            return rows, 128
+    return None
+
+
+def _window_bwd_pallas(q, k, v, do, lse, di, scale, window, rows, compute,
+                       interpret):
+    """`mx_window_attention_bwd`: dQ, dK and dV of attention over the band
+    0 <= i - j < window from ONE pass over the blocks the band touches.
+    Operands as `_causal_bwd_pallas`'s; dQ comes back in q's type, times
+    `scale`; blocks of `rows` queries and keys, `compute` keys a product.
+
+    Grid (B, Hkv, steps of `_band_walk`).  A step takes one (key block,
+    query block) pair and loops over the group's query heads, k and v
+    read once for all of them.  For a head it forms the scores of the
+    pair once, as `_score_products` does for the triangle, a chunk of
+    keys at a time against the queries `_band_tiles` gives for the
+    pair's offset: the queries that see none of the chunk are in no
+    product, and an edge of the band is an iota's compare only in the
+    chunks it crosses.  All three sums are float32 in VMEM and leave
+    once, in the operands' type:
+
+      * dK, dV of a key block over the group's heads and the query
+        blocks at and after it, the key block's consecutive steps;
+      * dQ of a (head, query block) over the `reach + 1` key blocks of
+        its band, which the walk visits in as many key blocks' steps:
+        `groups x (reach + 1)` blocks are held, a head's by the query
+        block modulo `reach + 1`.  The last visit is the diagonal, the
+        key block's last step, and dQ's block index is the key block's
+        over all its steps, so the block is written when it is whole.
+    """
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    b, kv, groups, s, d = q.shape
+    d_v = v.shape[-1]
+    blocks = s // rows
+    reach = _band_reach(rows, window)
+    held = reach + 1
+    # Mosaic cuts no 64-lane array by a dynamic index: the held dQ blocks
+    # lie in whole 128-lane tiles
+    d_whole = -(-d // 128) * 128
+    walk = _band_walk(blocks, reach)
+
+    def kernel(walk_ref, q_ref, k_ref, v_ref, do_ref, stat_ref, dq_ref,
+               dk_ref, dv_ref, dk_sum, dv_sum, dq_sum):
+        step = pl.program_id(2)
+        kb, qb = walk_ref[0, step], walk_ref[1, step]
+        offset = qb - kb
+
+        @pl.when(qb == jnp.minimum(kb + reach, blocks - 1))
+        def _():
+            dk_sum[...] = jnp.zeros_like(dk_sum)
+            dv_sum[...] = jnp.zeros_like(dv_sum)
+
+        def head(g, _):
+            mine = dq_sum.at[g * held + jax.lax.rem(qb, held)]
+
+            @pl.when(jnp.logical_or(offset == reach, kb == 0))
+            def _():
+                mine[...] = jnp.zeros_like(mine)
+
+            def products(o):
+                for c0, r0, n, below, above in _band_tiles(o, rows, compute,
+                                                           window):
+                    def keep(shape, first=o * rows + r0 - c0, below=below,
+                             above=above):
+                        ahead = (       # i - j of lane r and sublane c
+                            jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+                            - jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+                            + first)
+                        if below and above:
+                            return jnp.logical_and(ahead >= 0, ahead < window)
+                        return ahead >= 0 if below else ahead < window
+
+                    seen = pl.ds(r0, n)
+                    mine[seen, :d] += _score_products(
+                        k_ref, v_ref, q_ref, do_ref, stat_ref, dk_sum,
+                        dv_sum, pl.ds(c0, compute), seen,
+                        keep if below or above else None, (g,))
+
+            for o in range(held):
+                pl.when(offset == o)(functools.partial(products, o))
+
+            @pl.when(offset == 0)
+            def _():
+                dq_ref[g] = (mine[:, :d] * scale).astype(dq_ref.dtype)
+
+        jax.lax.fori_loop(0, groups, head, None)
+
+        @pl.when(offset == 0)
+        def _():
+            dk_ref[...] = dk_sum[...].astype(dk_ref.dtype)
+            dv_ref[...] = dv_sum[...].astype(dv_ref.dtype)
+
+    def heads(width, block):
+        """The (G, rows, width) block of a (B, Hkv, G, S, width) array at
+        the step's query block or (dQ) its key block."""
+        return pl.BlockSpec(
+            (None, None, groups, rows, width),
+            lambda bi, hi, step, walk: (bi, hi, 0, walk[block, step], 0))
+
+    keys = lambda width: pl.BlockSpec(
+        (None, None, rows, width),
+        lambda bi, hi, step, walk: (bi, hi, walk[0, step], 0))
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(b, kv, walk.shape[1]),
+            in_specs=[heads(d, 1), keys(d), keys(d_v), heads(d_v, 1),
+                      # logsumexp and di of a query block, as two rows
+                      pl.BlockSpec((None, None, groups, 2, rows),
+                                   lambda bi, hi, step, walk: (
+                                       bi, hi, 0, 0, walk[1, step]))],
+            out_specs=[heads(d, 0), keys(d), keys(d_v)],
+            scratch_shapes=[pltpu.VMEM((rows, d), jnp.float32),
+                            pltpu.VMEM((rows, d_v), jnp.float32),
+                            pltpu.VMEM((groups * held, rows, d_whole),
+                                       jnp.float32)]),
+        out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
+                   jax.ShapeDtypeStruct(k.shape, k.dtype),
+                   jax.ShapeDtypeStruct(v.shape, v.dtype)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=_band_vmem_bytes(
+                rows, compute, window, d, d_v, groups, q.dtype.itemsize)),
+        interpret=interpret,
+        name="mx_window_attention_bwd",
+    )(jnp.asarray(walk), q, k, v, do, jnp.stack([lse, di], axis=-2))
+
+
 def _splash_forward(q, k, v, scale, window, interpret, backward, **how):
     """Upstream's splash multi-query kernel at `_splash_blocks`' blocks,
     vmapped over batch and key/value heads, on q (B, H, S, D) scaled
@@ -1070,17 +1316,18 @@ def _splash_forward(q, k, v, scale, window, interpret, backward, **how):
     return q, jax.vmap(jax.vmap(kernel))(q, k, v)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
-def _causal_fused(q, k, v, scale, name, interpret):
-    """The whole triangle with the backward of the repo's own: upstream's
-    forward kernel, and `mx_causal_attention_bwd` as the rule."""
-    out = _splash_forward(q, k, v, scale, None, interpret, False)[1]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _causal_fused(q, k, v, scale, window, name, interpret):
+    """The whole triangle or a window's band with the backward of the
+    repo's own: upstream's forward kernel, and `mx_causal_attention_bwd`
+    or `mx_window_attention_bwd` as the rule."""
+    out = _splash_forward(q, k, v, scale, window, interpret, False)[1]
     return out.reshape(q.shape[:3] + out.shape[-1:])
 
 
-def _causal_fused_fwd(q, k, v, scale, name, interpret):
+def _causal_fused_fwd(q, k, v, scale, window, name, interpret):
     scaled, (out, (lse,)) = _splash_forward(
-        q, k, v, scale, None, interpret, False, save_residuals=True)
+        q, k, v, scale, window, interpret, False, save_residuals=True)
     # named, so that a recomputed segment keeps them (ops/residuals.py)
     out = jax.ad_checkpoint.checkpoint_name(out, name)
     lse = jax.ad_checkpoint.checkpoint_name(lse, name)
@@ -1088,17 +1335,21 @@ def _causal_fused_fwd(q, k, v, scale, name, interpret):
             (scaled, k, v, out, lse))
 
 
-def _causal_fused_bwd(scale, name, interpret, res, do):
+def _causal_fused_bwd(scale, window, name, interpret, res, do):
     scaled, k, v, out, lse = res
     do = do.reshape(out.shape)
     di = jnp.einsum("bhgsd,bhgsd->bhgs", out.astype(jnp.float32),
                     do.astype(jnp.float32))
-    rows, compute = _splash_blocks(scaled.shape[3], None)
-    dq, dk, dv = _causal_bwd_pallas(scaled, k, v, do, lse, di, rows,
-                                    compute, interpret)
+    b, kv, groups, s, d = scaled.shape
+    if window is not None:
+        dq, dk, dv = _window_bwd_pallas(
+            scaled, k, v, do, lse, di, scale, window,
+            *_band_blocks(s, window, d, v.shape[-1], groups), interpret)
+        return dq.reshape(b, kv * groups, s, d), dk, dv
+    dq, dk, dv = _causal_bwd_pallas(scaled, k, v, do, lse, di,
+                                    *_splash_blocks(s, None), interpret)
     # the float32 sum over the key blocks leaves as q's type after ONE
     # rounding, the scale applied before it
-    b, kv, groups, s, d = scaled.shape
     return ((dq * scale).astype(scaled.dtype).reshape(b, kv * groups, s, d),
             dk, dv)
 
@@ -1117,13 +1368,14 @@ def _causal_splash(q, k, v, scale, window=None, interpret=False,
     go in as they are, and dK, dV come out summed over the group.  The
     forward rule names its output and its (H, S) float32 logsumexp by
     the route (`name`; None: `flash_causal` or `splash_window` by the
-    mask).  The backward, by `_fused_backward`: `mx_causal_attention_bwd`
-    under a custom VJP of the repo's own, or upstream's two kernels
-    (dK/dV and dQ) under upstream's."""
+    mask).  The backward, by `_fused_backward`: one kernel of the repo's
+    own under a custom VJP of the repo's own (`mx_causal_attention_bwd`
+    over the triangle, `mx_window_attention_bwd` over a band), or
+    upstream's two kernels (dK/dV and dQ) under upstream's."""
     b, h, s, d = q.shape
     name = name or ("flash_causal" if window is None else "splash_window")
-    if _fused_backward(window, s, d, v.shape[-1], h // k.shape[1]):
-        return _causal_fused(q, k, v, scale, name, interpret)
+    if _fused_backward(window, s, d, v.shape[-1], h // k.shape[1]) != "split":
+        return _causal_fused(q, k, v, scale, window, name, interpret)
     out = _splash_forward(q, k, v, scale, window, interpret, True,
                           residual_checkpoint_name=name)[1]
     return out.reshape(b, h, s, -1)
@@ -1147,9 +1399,8 @@ def _attend_causal(q, k, v, scale, window, interpret, name=None):
     and lowers the kernels once a kind of layer; the form the kernels'
     backward takes is counted outside the jit, once a call whose
     backward is traced (`backward_counts`)."""
-    form = "fused" if _fused_backward(
-        window, q.shape[2], q.shape[3], v.shape[3],
-        q.shape[1] // k.shape[1]) else "split"
+    form = _fused_backward(window, q.shape[2], q.shape[3], v.shape[3],
+                           q.shape[1] // k.shape[1])
     return kernel_route.counted_backward(
         _attend_causal_once(q, k, v, scale, window, interpret, name),
         "attention_backward", form)
@@ -1174,7 +1425,7 @@ _SPLASH_WINDOW = kernel_route.Kernel("attention", "splash_window",
 
 # the form of a causal splash call's backward (`_fused_backward`), counted
 # where that backward is traced
-kernel_route.declare("attention_backward", ("fused", "split"))
+kernel_route.declare("attention_backward", ("fused", "band", "split"))
 
 
 def route_counts():
@@ -1184,9 +1435,10 @@ def route_counts():
 
 
 def backward_counts():
-    """{"fused", "split"}: the causal and window splash calls whose
-    backward was traced since import, by the form it took: the repo's one
-    kernel or upstream's two.  `route_counts()`'s sibling."""
+    """{"fused", "band", "split"}: the causal and window splash calls
+    whose backward was traced since import, by the form it took: the
+    repo's one kernel over the triangle, its one kernel over a window's
+    band, or upstream's two.  `route_counts()`'s sibling."""
     return kernel_route.counts("attention_backward")
 
 

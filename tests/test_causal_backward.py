@@ -1,8 +1,12 @@
-"""The causal cores' backward of the repo's own (`mx_causal_attention_bwd`,
+"""The causal cores' backward of the repo's own (`mx_causal_attention_bwd`
+over the triangle, `mx_window_attention_bwd` over a window's band,
 `ops/pallas_attention.py`): one kernel that forms each visited score block
-once and gives dK, dV and dQ, dQ summed in float32 in place.  All under
-the Pallas interpreter on the CPU: what the kernel computes, which calls
-take it, and that the sum over the key blocks is rounded once."""
+once and gives dK, dV and dQ, dQ summed in float32.  All under the Pallas
+interpreter on the CPU: what the kernels compute, which calls take them,
+how they walk, and that the sum over the key blocks is rounded once."""
+import functools
+import itertools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -41,8 +45,8 @@ def _xla_gradients(q, k, v, ct):
     return jnp.concatenate(dq, axis=1), dk, dv
 
 
-def _kernels(q, k, v):
-    return pa._attend_causal(q, k, v, SCALE, None, True)
+def _kernels(q, k, v, window=None):
+    return pa._attend_causal(q, k, v, SCALE, window, True)
 
 
 @pytest.mark.parametrize("d, d_v", [(128, 128), (64, 64), (192, 128)])
@@ -58,6 +62,55 @@ def test_fused_backward_matches_the_xla_form(groups, s, d, d_v):
     q, k, v, ct = _operands(groups, s, d, d_v, jnp.float32)
     got = _gradients(_kernels, q, k, v, ct)
     for g, w in zip(got, _xla_gradients(q, k, v, ct)):
+        np.testing.assert_allclose(g, w, rtol=2e-4, atol=2e-4 * groups)
+
+
+@pytest.mark.parametrize("d, d_v", [(128, 128), (64, 128), (64, 64)])
+@pytest.mark.parametrize("s, window", [(1024, 256), (1024, 512), (2048, 512),
+                                       (2048, 1024)])
+@pytest.mark.parametrize("groups", [1, 2, 8])
+def test_band_backward_matches_the_xla_form(groups, s, window, d, d_v):
+    """dQ, dK and dV of a windowed call against `jax.grad` of
+    `_window_xla`, float32 operands: windows of 2, 4 and 8 chunks of
+    keys (an S of 2,048 rows or fewer is one block of the band kernel:
+    every chunk's seen queries and both edges' masks within it), the
+    head sizes of the two cells that run a window and 64 throughout, dK
+    and dV summed over the group."""
+    assert pa._fused_backward(window, s, d, d_v, groups) == "band"
+    q, k, v, ct = _operands(groups, s, d, d_v, jnp.float32, seed=window)
+    got = _gradients(functools.partial(_kernels, window=window), q, k, v, ct)
+    want = _gradients(lambda q, k, v: pa._window_xla(q, k, v, SCALE, window),
+                      q, k, v, ct)
+    for g, w in zip(got, want):
+        np.testing.assert_allclose(g, w, rtol=2e-4, atol=2e-4 * groups)
+
+
+@pytest.mark.parametrize("d, d_v", [(128, 128), (64, 128)])
+@pytest.mark.parametrize("rows, compute, window", [
+    (512, 128, 512), (256, 128, 512), (256, 256, 256), (256, 128, 100),
+    (128, 128, 300), (512, 256, 1024)])
+@pytest.mark.parametrize("groups", [1, 3])
+def test_band_kernel_over_several_blocks_matches_the_xla_form(
+        groups, rows, compute, window, d, d_v):
+    """The kernel itself at blocks smaller than a cell's, S 1,024: 2, 4
+    and 8 key blocks, a query block reaching 1, 2 and 3 key blocks back
+    (the held dQ blocks come back after as many key blocks), windows
+    that are no multiple of a chunk, against `jax.grad` of `_window_xla`
+    with the forward's statistics computed here."""
+    s = 1024
+    q, k, v, ct = _operands(groups, s, d, d_v, jnp.float32, seed=rows)
+    want = _gradients(lambda q, k, v: pa._window_xla(q, k, v, SCALE, window),
+                      q, k, v, ct)
+    scaled = q * SCALE
+    score = jnp.einsum("bhqd,bhkd->bhqk", scaled, jnp.repeat(k, groups, 1))
+    ahead = jnp.arange(s)[:, None] - jnp.arange(s)[None]
+    lse = jax.scipy.special.logsumexp(
+        jnp.where((ahead >= 0) & (ahead < window), score, -1e30), axis=-1)
+    di = (pa._window_xla(q, k, v, SCALE, window) * ct).sum(-1)
+    dq, dk, dv = pa._window_bwd_pallas(
+        scaled[:, None], k, v, ct[:, None], lse[:, None], di[:, None],
+        SCALE, window, rows, compute, True)
+    for g, w in zip((dq[:, 0], dk, dv), want):
         np.testing.assert_allclose(g, w, rtol=2e-4, atol=2e-4 * groups)
 
 
@@ -79,27 +132,33 @@ def _upstream_fused(q, k, v):
     return jax.vmap(kernel)(q, k[:, 0], v[:, 0])
 
 
-def _split(monkeypatch):
-    """`_attend_causal` on upstream's split backward, as before PR 48."""
+def _split(monkeypatch, window=None):
+    """`_attend_causal` on upstream's split backward, as before PRs 48
+    (the triangle) and 50 (a window)."""
     def core(q, k, v):
         with monkeypatch.context() as patch:
-            patch.setattr(pa, "_fused_backward", lambda *a: False)
-            return pa._causal_splash(q, k, v, SCALE, interpret=True)
+            patch.setattr(pa, "_fused_backward", lambda *a: "split")
+            return pa._causal_splash(q, k, v, SCALE, window, interpret=True)
     return core
 
 
-@pytest.mark.parametrize("s", [4096, 8192])
-def test_dq_is_summed_in_float32_and_rounded_once(s, monkeypatch):
-    """bfloat16 operands, 4 and 8 key blocks, the LAST query block's rows
-    (the ones every key block adds to): dQ's largest and root-mean-square
-    error against a float32 oracle on the same operands are no larger
-    than the split kernels' (a float32 sum over the key blocks in each;
-    rounded once here, in the kernel and again after the scale there).
-    Upstream's fused form, which rounds every key block's part to
-    bfloat16 before the sum, fails the same two lines: the test would
-    catch partials."""
+@pytest.mark.parametrize("s, window", [(4096, None), (8192, None),
+                                       (2048, 512), (2048, 1024)])
+def test_dq_is_summed_in_float32_and_rounded_once(s, window, monkeypatch):
+    """bfloat16 operands, 4 and 8 key blocks of the triangle and a band
+    of two and of three, the LAST 1,024 queries' rows (under the
+    triangle the ones every key block adds to): dQ's largest and
+    root-mean-square error against a float32 oracle on the same operands
+    are no larger than the split kernels' (a float32 sum over the key
+    blocks in each; rounded once here, in the kernel and again after the
+    scale there).  Upstream's fused form, which rounds every key
+    block's part to bfloat16 before the sum, fails the same two lines
+    over the triangle: the test would catch partials."""
     q, k, v, ct = _operands(2, s, 128, 128, jnp.bfloat16)
-    exact = _xla_gradients(*(x.astype(jnp.float32) for x in (q, k, v, ct)))[0]
+    as_float32 = [x.astype(jnp.float32) for x in (q, k, v, ct)]
+    exact = _xla_gradients(*as_float32)[0] if window is None else _gradients(
+        lambda q, k, v: pa._window_xla(q, k, v, SCALE, window),
+        *as_float32)[0]
 
     def errors(core):
         dq = _gradients(core, q, k, v, ct)[0]
@@ -108,10 +167,12 @@ def test_dq_is_summed_in_float32_and_rounded_once(s, monkeypatch):
         return np.abs(off).max(), np.sqrt((off ** 2).mean())
 
     held = lambda ours, split: ours[0] <= split[0] and ours[1] <= split[1]
-    split = errors(_split(monkeypatch))
-    assert held(errors(_kernels), split), (errors(_kernels), split)
-    assert not held(errors(_upstream_fused), split)
-    assert errors(_upstream_fused)[1] > 1.05 * split[1]
+    split = errors(_split(monkeypatch, window))
+    ours = errors(functools.partial(_kernels, window=window))
+    assert held(ours, split), (ours, split)
+    if window is None:
+        assert not held(errors(_upstream_fused), split)
+        assert errors(_upstream_fused)[1] > 1.05 * split[1]
 
 
 def _kernel_names(jaxpr, into=None):
@@ -133,7 +194,7 @@ def _kernel_names(jaxpr, into=None):
 _SPLIT = ["splash_mqa_dkv_no_residuals", "splash_mqa_dq_no_residuals"]
 _FORMS = {
     "triangle": (1024, None, "fused", ["mx_causal_attention_bwd"]),
-    "window": (1024, 256, "split", _SPLIT),
+    "window": (1024, 256, "band", ["mx_window_attention_bwd"]),
     "s_the_block_does_not_divide": (384, None, "split", _SPLIT),
 }
 
@@ -141,10 +202,10 @@ _FORMS = {
 @pytest.mark.parametrize("case", list(_FORMS))
 def test_backward_form_is_counted_where_the_backward_is_traced(case):
     """A traced `jax.grad` of a causal call counts `fused` once and runs
-    the one kernel; of a windowed call, and of an S that 1,024 does not
-    divide, `split` once and upstream's two, as before.  The forward
-    routes' counts are not touched, and a call that is not
-    differentiated counts nothing."""
+    the one kernel; of a windowed call `band` once and the band's one
+    kernel; of an S that 1,024 does not divide, `split` once and
+    upstream's two, as before.  The forward routes' counts are not
+    touched, and a call that is not differentiated counts nothing."""
     s, window, form, kernels = _FORMS[case]
     q = jnp.zeros((1, 2, s, 128), jnp.bfloat16)
     k = v = jnp.zeros((1, 1, s, 128), jnp.bfloat16)
@@ -163,16 +224,22 @@ def test_backward_form_is_counted_where_the_backward_is_traced(case):
         "splash_mqa_fwd_residuals"] + kernels
 
 
-@pytest.mark.parametrize("shape, fused", [
-    ((None, 8192, 128, 128, 6), True), ((None, 8192, 192, 128, 1), True),
-    ((None, 8192, 64, 64, 4), True), ((None, 8192, 128, 128, 16), True),
-    ((None, 1024, 128, 128, 1), True), ((512, 8192, 128, 128, 8), False),
-    ((None, 384, 128, 128, 1), False), ((None, 1536, 128, 128, 1), False)])
-def test_which_calls_take_the_fused_backward(shape, fused):
+@pytest.mark.parametrize("shape, form", [
+    ((None, 8192, 128, 128, 6), "fused"), ((None, 8192, 192, 128, 1), "fused"),
+    ((None, 8192, 64, 64, 4), "fused"), ((None, 8192, 128, 128, 16), "fused"),
+    ((None, 1024, 128, 128, 1), "fused"), ((512, 8192, 128, 128, 8), "band"),
+    ((512, 16384, 64, 128, 2), "band"), ((256, 1024, 128, 128, 2), "band"),
+    ((1024, 2048, 128, 128, 2), "band"), ((256, 384, 128, 128, 1), "band"),
+    ((512, 8192, 128, 128, 256), "split"),
+    ((None, 384, 128, 128, 1), "split"), ((None, 1536, 128, 128, 1), "split")])
+def test_which_calls_take_the_fused_backward(shape, form):
     """(window, S, d, d_v, query heads a key/value head) alone decide:
-    every causal core a cell runs at S 8192, no windowed call, no S that
-    the block of 1,024 rows does not divide."""
-    assert pa._fused_backward(*shape) is fused
+    every causal core a cell runs at S 8192 is `fused`; a window is
+    `band` at both cells' shapes, smaller than the block, twice it and
+    at an S of 128-row blocks, but not where a group's dQ blocks fit
+    the kernel's VMEM at no block; no S that the block of 1,024 rows
+    does not divide takes the triangle's kernel."""
+    assert pa._fused_backward(*shape) == form
 
 
 def test_the_walk_visits_the_triangle_key_block_outermost():
@@ -195,6 +262,80 @@ def test_the_walk_visits_the_triangle_key_block_outermost():
     for kb, g, qb, _ in grouped.T:
         first.setdefault((g, qb), kb)
     assert set(first.values()) == {0}
+
+
+def _area(window, n):
+    """The pairs of 0 <= i - j < window among n queries and n keys."""
+    return sum(min(i + 1, window) for i in range(n))
+
+
+@pytest.mark.parametrize("rows, window", [(512, 256), (512, 512),
+                                          (512, 1024), (1024, 512),
+                                          (128, 300)])
+def test_the_band_walk_visits_every_block_pair_of_the_band_once(rows, window):
+    """8 blocks: every (key block, query block) pair with a pair inside
+    the band once and none outside it; a key block's steps are
+    consecutive (its dK and dV are one sum in VMEM) and end on the
+    diagonal, the LAST visit of that query block (dQ's block index is
+    the key block's over those steps, so it is written whole); and of
+    the `reach + 1` dQ blocks a head holds by the query block modulo
+    that, none is begun before the one in its place is done."""
+    blocks, reach = 8, pa._band_reach(rows, window)
+    steps = [tuple(int(x) for x in col)
+             for col in pa._band_walk(blocks, reach).T]
+    inside = {(kb, qb) for kb in range(blocks) for qb in range(blocks)
+              if any(0 <= qb * rows + r - kb * rows - c < window
+                     for r in (0, rows - 1) for c in (0, rows - 1))}
+    assert len(steps) == len(set(steps)) and set(steps) == inside
+    runs = [list(run) for _, run in itertools.groupby(
+        steps, key=lambda step: step[0])]
+    assert [run[0][0] for run in runs] == list(range(blocks))
+    assert all(run[-1] == (run[-1][0],) * 2 for run in runs)
+    alive = {}                                   # place -> query block
+    for kb, qb in steps:
+        assert alive.setdefault(qb % (reach + 1), qb) == qb
+        if qb == kb:
+            del alive[qb % (reach + 1)]
+    assert not alive
+
+
+@pytest.mark.parametrize("rows, compute, window", [
+    (512, 256, 512), (512, 512, 512), (512, 256, 256), (512, 256, 1024),
+    (2048, 128, 512), (1024, 512, 512), (512, 128, 384), (256, 128, 100)])
+def test_the_band_tiles_cover_the_band_and_say_which_edge_cuts(
+        rows, compute, window):
+    """Over 8 blocks, the pairs inside the band that the tiles of every
+    block pair's offset hold, with the edges masked where a tile says
+    it is cut and ONLY there, are the band's area exactly: no pair is
+    lost to queries left out or counted under a mask that was not asked
+    for; a tile says it is cut exactly where an edge crosses it, and
+    starts and ends with a chunk of queries that sees the chunk of
+    keys.  At rows 2,048, window 512 and chunks of 128 keys the tiles
+    hold 1.25 times the band (640 queries a chunk but at the ends)."""
+    blocks, reach = 8, pa._band_reach(rows, window)
+    held = products = 0
+    for offset in range(reach + 1):
+        for c0, r0, n, below, above in pa._band_tiles(
+                offset, rows, compute, window):
+            assert c0 % compute == 0 and r0 % compute == 0 and n % compute == 0
+            ahead = (offset * rows + r0 + np.arange(n)[None]
+                     - c0 - np.arange(compute)[:, None])
+            inside = (ahead >= 0) & (ahead < window)
+            assert inside[:, :compute].any() and inside[:, -compute:].any()
+            assert below == (ahead < 0).any()
+            assert above == (ahead >= window).any()
+            seen = np.ones_like(inside)
+            if below:
+                seen &= ahead >= 0
+            if above:
+                seen &= ahead < window
+            assert (inside == seen).all()
+            held += seen.sum() * (blocks - offset)
+            products += seen.size * (blocks - offset)
+    area = _area(window, blocks * rows)
+    assert held == area
+    if (rows, compute, window) == (2048, 128, 512):
+        assert 1.24 < products / area < 1.26
 
 
 def _simulated():

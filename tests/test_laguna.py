@@ -181,7 +181,8 @@ def test_causal_splash_program_repeats_no_key_or_value_head(s):
     upstream's split backward (S 256) and under the one kernel of the
     repo's own (S 2048, two key blocks)."""
     b, h, kv, d = 2, 12, 2, 128
-    assert pa._fused_backward(None, s, d, d, h // kv) is (s == 2048)
+    assert pa._fused_backward(None, s, d, d, h // kv) == (
+        "fused" if s == 2048 else "split")
     q = jnp.zeros((b, h, s, d), jnp.bfloat16)
     k = v = jnp.zeros((b, kv, s, d), jnp.bfloat16)
 

@@ -229,8 +229,8 @@ def test_window_form_equals_the_dense_mask_at_a_value_size_of_its_own(
 
 def test_the_cells_cores_are_admitted_and_named():
     assert pa._causal_flash_shape(40, 20, 16384, 16384, 64, 128)
-    assert not pa._fused_backward(512, 16384, 64, 128, 2)
-    assert pa._fused_backward(None, 16384, 64, 128, 2)
+    assert pa._fused_backward(512, 16384, 64, 128, 2) == "band"
+    assert pa._fused_backward(None, 16384, 64, 128, 2) == "fused"
     assert {"diff_splash", "diff_window_splash"} <= set(residuals.NAMES)
     assert pa.ROUTES[-3:] == ("diff_splash", "diff_window_splash",
                               "diff_xla")

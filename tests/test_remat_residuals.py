@@ -29,13 +29,13 @@ WINDOW, CHUNK = 128, 2
 # route -> (forward kernel, backward kernels); each keeps o and one
 # logsumexp.  The causal and the window cores run the same forward kernel;
 # the whole triangle's backward is one kernel of the repo's own where the
-# block of 1,024 rows divides S (PR 48), a window's and EVA's upstream's two
+# block of 1,024 rows divides S (PR 48), a window's another (PR 50), EVA's
+# upstream's two
 ROUTES = {
     "flash_causal": ("splash_mqa_fwd_residuals",
                      ("mx_causal_attention_bwd",)),
     "splash_window": ("splash_mqa_fwd_residuals",
-                      ("splash_mqa_dkv_no_residuals",
-                       "splash_mqa_dq_no_residuals")),
+                      ("mx_window_attention_bwd",)),
     "eva_splash": ("splash_mha_fwd_residuals",
                    ("splash_mha_dkv_no_residuals",
                     "splash_mha_dq_no_residuals")),
