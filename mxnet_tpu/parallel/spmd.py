@@ -63,6 +63,13 @@ _HLO_OP_NAME = re.compile(r'op_name="([^"]*)"')
 _HLO_CALLS = re.compile(r"\bcalls=%?([\w.\-]+)")
 _HLO_MATMUL = re.compile(r"(?<![%\w.\-])(?:convolution|dot)\(")
 _HLO_FUSION = re.compile(r"(?<![%\w.\-])fusion\(")
+# `<type> <opcode>(`: a type ends in `]`, `}` or, a tuple's, `)`
+_HLO_OPCODE = re.compile(r"[\]})] ([a-z][\w\-]*)\(")
+_HLO_DIMS = re.compile(r"\w+\[([\d,]*)\]")
+_HLO_CONTRACTED = re.compile(r"lhs_contracting_dims=\{([\d,]*)\}")
+_HLO_DIM_LABELS = re.compile(r"dim_labels=(\w+)_(\w+)->(\w+)")
+_HLO_WINDOW = re.compile(r"window=\{([^}]*)\}")
+_HLO_NUMBERING = re.compile(r"[.\d]+$")
 
 
 def _whole_instructions(hlo_text: str):
@@ -83,12 +90,98 @@ def _whole_instructions(hlo_text: str):
     return whole
 
 
+def _pass_of(name: str) -> str:
+    """Which pass a name stack belongs to.  The forward done again under
+    remat is traced under `checkpoint/rematted_computation/` inside the
+    backward's `transpose(`, so it is asked for first."""
+    if "rematted_computation" in name:
+        return "recomputed"
+    if "transpose(" in name:
+        return "backward"
+    if "jvp(" in name:
+        return "forward"
+    if "mx.update" in name:
+        return "update"
+    return "other"
+
+
+def _dims(type_text: str):
+    m = _HLO_DIMS.match(type_text)
+    return [int(d) for d in m.group(1).split(",") if d] if m else None
+
+
+def _operand_types(text: str, start: int, types: Dict[str, str]):
+    """The types of an instruction's operands, `text[start]` being the
+    first character after the opcode's `(`: printed in place
+    (`f32[4,8]{1,0} %x`) or, inside a computation, by name alone, and
+    then looked up among the computation's own instructions."""
+    depth, cut, out = 0, start, []
+    for i in range(start, len(text)):
+        c = text[i]
+        if c in "([{":
+            depth += 1
+        elif c in ")]}":
+            if depth == 0:
+                out.append(text[cut:i])
+                break
+            depth -= 1
+        elif c == "," and depth == 0:
+            out.append(text[cut:i])
+            cut = i + 1
+    found = []
+    for operand in out:
+        kind, _, name = operand.strip().rpartition(" ")
+        found.append(kind or types.get(name.lstrip("%"), ""))
+    return found
+
+
+def _product_flops(opcode: str, out_type: str, rest: str, start: int,
+                   types: Dict[str, str]) -> int:
+    """2 x the multiply-adds of one `dot` or `convolution`, from the
+    shapes in the text; 0 where a shape cannot be read (a floor counts
+    less, never more).  A dot: the output's elements x the contracted
+    sizes.  A convolution: output batch x output features x the
+    kernel's input-feature dimension x, a spatial dimension, the
+    (output position, tap) pairs that can meet an input element: no
+    more than outputs x taps, than inputs x taps (the zeros `lhs_dilate`
+    puts between the elements of a strided convolution's data gradient
+    are not multiplied) or than inputs x outputs (XLA writes a 1x1
+    convolution as a 56x56 window sliding over one padded element).
+    Padding of the ordinary kind counts, as the configurations count
+    it."""
+    out, operands = _dims(out_type), _operand_types(rest, start, types)
+    try:
+        lhs, rhs = _dims(operands[0]), _dims(operands[1])
+        macs = 1
+        if opcode == "dot":
+            for d in out:
+                macs *= d
+            for k in _HLO_CONTRACTED.search(rest).group(1).split(","):
+                if k:
+                    macs *= lhs[int(k)]
+            return 2 * macs
+        lhs_at, rhs_at, out_at = _HLO_DIM_LABELS.search(rest).groups()
+        window = _HLO_WINDOW.search(rest)
+        taps = dict(f.split("=") for f in window.group(1).split()).get(
+            "size", "").split("x") if window else []
+        macs = out[out_at.index("b")] * out[out_at.index("f")] \
+            * rhs[rhs_at.index("i")]
+        for d in range(sum(c.isdigit() for c in out_at)):
+            n_out = out[out_at.index(str(d))]
+            n_in = lhs[lhs_at.index(str(d))]
+            n_taps = int(taps[d]) if d < len(taps) and taps[d] else 1
+            macs *= min(n_out * n_taps, n_in * n_taps, n_in * n_out)
+        return 2 * macs
+    except (AttributeError, IndexError, TypeError, ValueError):
+        return 0        # a form this reader does not know: count nothing
+
+
 def program_table(hlo_text: str) -> Dict[str, Any]:
-    """``{"module", "scoped", "ops"}`` of one compiled program's text
-    (``Compiled.as_text()``): ``ops`` maps every instruction outside a
-    fused computation, by its name without ``%``, to its ``op_name``
-    (the name stack it was traced under: ``jit(mx_train_step)/
-    jvp(net0)/dense0/FullyConnected/dot_general``).
+    """``{"module", "scoped", "ops", "instructions"}`` of one compiled
+    program's text (``Compiled.as_text()``): ``ops`` maps every
+    instruction outside a fused computation, by its name without ``%``,
+    to its ``op_name`` (the name stack it was traced under:
+    ``jit(mx_train_step)/jvp(net0)/dense0/FullyConnected/dot_general``).
 
     XLA keeps ONE instruction's metadata for a fusion, as a rule its
     root's, and an optimizer update rides in the epilogue of the weight-
@@ -97,39 +190,107 @@ def program_table(hlo_text: str) -> Dict[str, Any]:
     convolution or a dot takes THAT instruction's ``op_name``, and its
     own only where it holds none.
 
+    ``instructions`` says, under the same keys, what each of them is:
+
+    * ``opcode``: the HLO opcode (``fusion``, ``custom-call``, ``while``);
+    * ``scopes``: every distinct name stack the instruction holds, its
+      own first and then those of the fused computations it calls
+      (through fusions nested in them; not the body of a ``while``, a
+      ``conditional`` or a ``call``, whose instructions have rows of
+      their own), the JAX primitive dropped, in the text's order: the
+      names rule B leaves out of ``ops``;
+    * ``pass``: ``forward``, ``recomputed`` (the forward done again
+      under remat), ``backward``, ``update`` or ``other``, of the name
+      ``ops`` books the instruction to; ``passes``: the same over every
+      entry of ``scopes``, sorted (``["other"]`` where there is none);
+    * ``flops``: 2 x the multiply-adds of every ``dot`` and
+      ``convolution`` within the same reach, for ONE execution
+      (:func:`_product_flops`), 0 where there is none;
+    * ``kernel``: a Mosaic call's name (the instruction's, less XLA's
+      numbering: ``mx_causal_attention_bwd``, ``gmm``), else None.
+
     ``scoped`` is false where no ``op_name`` in the text (fused
     instructions included) holds ``mx.update``: an executable from
     before the scopes existed (they are not in JAX's compile-cache key)
     says so instead of reading as zeros."""
     module, comp, scoped = "", None, False
     matmul_of: Dict[str, str] = {}      # fused computation -> op_name
-    rows = []       # (computation, instruction, op_name, fusion's callee)
+    # what a computation holds itself: FLOPs, and its distinct name
+    # stacks in the text's order (a dict kept for its order), a fusion
+    # nested in it as the callee's name in a tuple
+    flops_of: Dict[str, int] = {}
+    held_of: Dict[str, dict] = {}
+    types: Dict[str, str] = {}          # of the computation being read
+    rows = []   # (computation, instruction, op_name, fusion's callee,
+    #              opcode, own FLOPs, kernel)
     for line in _whole_instructions(hlo_text):
         if line.startswith("HloModule "):
             module = line[len("HloModule "):].split(",")[0].strip()
         elif line[:1] not in (" ", "}", "") and line.endswith("{"):
             comp = line.split(" (")[0].replace("ENTRY ", "").lstrip("%")
+            types, flops_of[comp], held_of[comp] = {}, 0, {}
         elif " = " in line and line.startswith("  "):
             lhs, rest = line.split(" = ", 1)
+            name = lhs.split()[-1].lstrip("%")
             m = _HLO_OP_NAME.search(rest)
             own = m.group(1) if m else ""
             scoped = scoped or "mx.update" in own
             if own and comp not in matmul_of and _HLO_MATMUL.search(rest):
                 matmul_of[comp] = own
             fusion = _HLO_FUSION.search(rest) and _HLO_CALLS.search(rest)
-            rows.append((comp, lhs.split()[-1].lstrip("%"), own,
-                         fusion.group(1) if fusion else None))
-    fused = {callee for _c, _n, _o, callee in rows if callee}
+            callee = fusion.group(1) if fusion else None
+            m = _HLO_OPCODE.search(rest)
+            opcode = m.group(1) if m else ""
+            types[name] = rest[:m.start(1) - 1] if m else ""
+            flops = _product_flops(opcode, types[name], rest, m.end(),
+                                   types) \
+                if opcode in ("dot", "convolution") else 0
+            kernel = _HLO_NUMBERING.sub("", name) if (
+                opcode == "custom-call"
+                and 'custom_call_target="tpu_custom_call"' in rest) else None
+            flops_of[comp] += flops
+            if own:
+                held_of[comp][own.rsplit("/", 1)[0]] = None
+            if callee:
+                held_of[comp][(callee,)] = None
+            rows.append((comp, name, own, callee, opcode, flops, kernel))
+    fused = {row[3] for row in rows if row[3]}
     ops = {name: matmul_of.get(callee, own)
-           for comp_, name, own, callee in rows if comp_ not in fused}
-    return {"module": module, "scoped": scoped, "ops": ops}
+           for comp_, name, own, callee, *_ in rows if comp_ not in fused}
+
+    def reach(callee, scopes):
+        """FLOPs of a fused computation and of those nested in it; their
+        name stacks go into `scopes`, a dict as `held_of`'s are."""
+        flops = flops_of.get(callee, 0)
+        for held in held_of.get(callee, ()):
+            if isinstance(held, tuple):
+                flops += reach(held[0], scopes)
+            else:
+                scopes.setdefault(held)
+        return flops
+
+    instructions = {}
+    for comp_, name, own, callee, opcode, flops, kernel in rows:
+        if comp_ in fused:
+            continue
+        scopes = {own.rsplit("/", 1)[0]: None} if own else {}
+        if callee:
+            flops += reach(callee, scopes)
+        instructions[name] = {
+            "opcode": opcode, "scopes": list(scopes),
+            "pass": _pass_of(ops[name]),
+            "passes": sorted({_pass_of(s) for s in scopes}) or ["other"],
+            "flops": flops, "kernel": kernel}
+    return {"module": module, "scoped": scoped, "ops": ops,
+            "instructions": instructions}
 
 
 def step_programs() -> List[Dict[str, Any]]:
     """The scope table of every step executable built or loaded in this
     process and still cached, oldest first: ``{"module", "origin":
     "compiled" | "cache", "scoped", "ops": {instruction: op_name},
-    "param_uses"}`` (see :func:`program_table`).  A device trace names
+    "instructions": {instruction: what it is}, "param_uses"}`` (see
+    :func:`program_table`).  A device trace names
     instructions (``%fusion.14``) and not scopes; this is the program's
     own join from the one to the other, for whoever reads a profile.
     ``param_uses`` is what the trace of the step counted, ``{reads:
@@ -314,6 +475,9 @@ class SPMDTrainer:
         # dict would outlive _STEP_CACHE's own eviction (ragged last
         # batches / variable seq-len mint a new shape per epoch)
         self._step_fns: "OrderedDict[Tuple, Any]" = OrderedDict()
+        # the layout gauges are set once a trainer: its mesh and its
+        # ZeRO choice never change
+        self._layout_published = False
         self._fwd_fns: "OrderedDict[Tuple, Any]" = OrderedDict()
         self._param_by_name = {n: p for n, p in self._plist}
         self._traced_param_uses = None  # set by the step's trace
@@ -572,7 +736,8 @@ class SPMDTrainer:
         """The executable call, inside the telemetry that times it."""
         if not _tracing.active():
             return step(*args)
-        if _tracing._ENABLED:
+        if _tracing._ENABLED and not self._layout_published:
+            self._layout_published = True
             for ax, size in self.mesh.axis_sizes.items():
                 _ins.step_layout_axis_size(ax).set(size)
             factor = 1

@@ -24,7 +24,7 @@ from mxnet_tpu.telemetry import tracing
 _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
-def conv_net():
+def conv_net(remat=False):
     """conv + BatchNorm + dense under SGD; children made inside the
     parent's name scope, so their names repeat its prefix."""
     np.random.seed(0)
@@ -40,7 +40,7 @@ def conv_net():
     trainer = parallel.SPMDTrainer(
         net, gloss.SoftmaxCrossEntropyLoss(), "sgd",
         {"learning_rate": 0.1, "momentum": 0.9},
-        mesh=parallel.make_mesh(dp=1))
+        mesh=parallel.make_mesh(dp=1), remat=remat)
     rng = np.random.RandomState(0)
     return trainer, (rng.rand(8, 8, 8, 3).astype("float32"),
                      rng.randint(0, 4, 8).astype(np.int32))
@@ -62,7 +62,7 @@ class TwoLayers(HybridBlock):
         return self.head(self.layer1(self.layer0(x, mask), mask))
 
 
-def attention_net():
+def attention_net(remat=False):
     """Two post-LN encoder layers with attention dropout under Adam."""
     np.random.seed(0)
     mx.random.seed(0)
@@ -73,7 +73,8 @@ def attention_net():
             mx.nd.ones((1, 8), ctx=mx.cpu()))
     trainer = parallel.SPMDTrainer(
         net, gloss.SoftmaxCrossEntropyLoss(), "adam",
-        {"learning_rate": 1e-3}, mesh=parallel.make_mesh(dp=1))
+        {"learning_rate": 1e-3}, mesh=parallel.make_mesh(dp=1),
+        remat=remat)
     rng = np.random.RandomState(0)
     return trainer, (rng.rand(4, 8, 16).astype("float32"),
                      np.ones((4, 8), "float32"),
@@ -267,6 +268,270 @@ def test_an_instruction_printed_over_several_lines_keeps_its_op_name():
     assert set(ops) == set(spmd.program_table(_HLO)["ops"]) | {"splash.1"}
 
 
+_SGD = "jit(mx_train_step)/mx.update/sgd_update"
+_WGRAD = "jit(mx_train_step)/transpose(jvp(net))/dense0/FullyConnected"
+
+
+def test_an_instruction_says_what_it_is_and_ops_keeps_its_values():
+    table = spmd.program_table(_HLO)
+    assert table["ops"] == {
+        "a": "", "b": "", "add.9": "jit(mx_train_step)/jvp(mx.loss)/reduce_sum",
+        "w": "params[\\'w\\']", "x": "",
+        "fusion.2": "jit(mx_train_step)/jvp(net)/act0/Activation/tanh",
+        "copy.4": "", "fusion.14": _WGRAD + "/dot_general"}
+    records = table["instructions"]
+    assert list(records) == list(table["ops"])
+    # rule B's missing half: the weight gradient's fusion holds the
+    # update too, its own (the root's) name first, the primitive dropped
+    assert records["fusion.14"] == {
+        "opcode": "fusion", "scopes": [_SGD, _WGRAD], "pass": "backward",
+        "passes": ["backward", "update"], "flops": 2 * 8 * 8 * 4,
+        "kernel": None}
+    assert records["fusion.2"] == {
+        "opcode": "fusion",
+        "scopes": ["jit(mx_train_step)/jvp(net)/act0/Activation"],
+        "pass": "forward", "passes": ["forward"], "flops": 0,
+        "kernel": None}
+    assert records["copy.4"] == {
+        "opcode": "copy", "scopes": [], "pass": "other",
+        "passes": ["other"], "flops": 0, "kernel": None}
+    assert records["w"]["opcode"] == "parameter"
+    assert records["w"]["scopes"] == ["params[\\'w\\']"]
+    assert records["add.9"]["opcode"] == "add"
+
+
+def _with(line, text=_HLO):
+    """`text` with one more instruction before the entry's copy."""
+    copy = "  %copy.4 = f32[4,8]{1,0} copy(%fusion.2)\n"
+    assert copy in text
+    return text.replace(copy, line + "\n" + copy)
+
+
+@pytest.mark.parametrize("name, kernel", [
+    ("splash_mqa_fwd_residuals.3", "splash_mqa_fwd_residuals"),
+    ("mx_causal_attention_bwd", "mx_causal_attention_bwd"),
+    ("gmm.12.clone.1", "gmm.12.clone"), ("gmm.7", "gmm")])
+def test_a_mosaic_call_is_a_kernel_by_its_name_less_the_numbering(
+        name, kernel):
+    scope = "jit(mx_train_step)/jvp(net)/layer1/dot_product_attention"
+    # printed over three lines, as splash's `kernel_metadata` is; XLA's
+    # own rewrite of `ragged_dot` has no metadata at all
+    text = _with(
+        f"  %{name} = f32[4,8]{{1,0}} custom-call(%fusion.2), "
+        'custom_call_target="tpu_custom_call", '
+        "frontend_attributes={kernel_metadata={\n"
+        '"xprof_metadata":"{\\"block_q\\": 256}"\n'
+        '}}, metadata={op_name="' + scope + '/pallas_call"}')
+    text = _with('  %ragged.5 = f32[4,8]{1,0} custom-call(%fusion.2), '
+                 'custom_call_target="tpu_custom_call"', text)
+    text = _with('  %concat.1 = f32[4,8]{1,0} custom-call(%fusion.2), '
+                 'custom_call_target="ConcatBitcast"', text)
+    table = spmd.program_table(text)
+    record = table["instructions"][name]
+    assert record == {"opcode": "custom-call", "scopes": [scope],
+                      "pass": "forward", "passes": ["forward"], "flops": 0,
+                      "kernel": kernel}
+    assert table["instructions"]["ragged.5"]["kernel"] == "ragged"
+    assert table["instructions"]["ragged.5"]["scopes"] == []
+    assert table["instructions"]["concat.1"]["kernel"] is None
+    assert table["ops"]["fusion.14"] == _WGRAD + "/dot_general"
+
+
+_CONVOLUTIONS = {
+    # a forward 3x3, stride 2, padded: outputs x taps x input features
+    "fwd": ("bf16[2,4,4,16]", "bf16[2,8,8,8]", "bf16[3,3,8,16]",
+            "window={size=3x3 stride=2x2 pad=1_1x1_1}, "
+            "dim_labels=b01f_01io->b01f", 2 * 2 * 16 * 8 * (4 * 3) ** 2),
+    # its data gradient: the zeros between the 4 elements are not
+    # multiplied, so 4 x 3 pairs a dimension and not 8 x 3
+    "dgrad": ("bf16[2,8,8,8]", "bf16[2,4,4,16]", "bf16[3,3,8,16]",
+              "window={size=3x3 pad=1_2x1_2 lhs_dilate=2x2 "
+              "rhs_reversal=1x1}, dim_labels=b01f_01oi->b01f",
+              2 * 2 * 8 * 16 * (4 * 3) ** 2),
+    # its weight gradient: the output gradient is the window
+    "wgrad": ("bf16[3,3,8,16]", "bf16[8,8,8,2]", "bf16[2,4,4,16]",
+              "window={size=4x4 pad=1_1x1_1 rhs_dilate=2x2}, "
+              "dim_labels=f01b_i01o->01bf", 2 * 8 * 16 * 2 * (3 * 4) ** 2),
+    # XLA's TPU form of a 1x1 convolution: the weights as the input, one
+    # padded element under a window as large as the image
+    "swapped": ("bf16[2,8,8,16]", "bf16[16,8,1,1]", "bf16[2,8,8,8]",
+                "window={size=8x8 pad=7_7x7_7 rhs_reversal=1x1}, "
+                "dim_labels=bf01_o01i->f01b", 2 * 16 * 2 * 8 * 8 * 8),
+    # a matmul as the TPU compiler writes it, the batch as a spatial
+    # dimension, no window to slide
+    "matmul": ("bf16[4,32,64]", "bf16[4,32,48]", "bf16[64,48,1]",
+               "window={size=1}, dim_labels=0bf_oi0->0bf",
+               2 * 4 * 32 * 64 * 48),
+    "grouped": ("f32[2,8,6]", "f32[2,8,6]", "f32[3,1,6]",
+                "window={size=3 pad=2_0}, dim_labels=b0f_0io->b0f, "
+                "feature_group_count=6", 2 * 2 * 6 * 1 * 8 * 3),
+}
+
+
+_CONVOLUTIONS["unreadable"] = (
+    "bf16[2,4,4,16]", "bf16[2,8,8,8]", "bf16[3,3,8,16]",
+    "window={size=3x3}, dim_labels=b01f_01xo->b01f", 0)
+
+
+@pytest.mark.parametrize("form", sorted(_CONVOLUTIONS))
+def test_a_convolution_counts_the_pairs_that_can_meet_an_element(form):
+    out, lhs, rhs, attributes, flops = _CONVOLUTIONS[form]
+    text = _with(f"  %l.1 = {lhs}{{3,2,1,0}} constant(0)\n"
+                 f"  %r.1 = {rhs}{{3,2,1,0:T(8,128)(2,1)}} constant(0)\n"
+                 f"  %conv.1 = {out}{{3,2,1,0:T(8,128)(2,1)S(1)}} "
+                 f"convolution(%l.1, %r.1), {attributes}")
+    record = spmd.program_table(text)["instructions"]["conv.1"]
+    assert record["opcode"] == "convolution" and record["flops"] == flops
+
+
+def test_products_in_nested_fusions_count_once_and_a_while_counts_none():
+    """FLOPs and scopes follow a fusion's `calls=` through the fusions
+    nested in it; the body of a `while` has rows of its own."""
+    body = "jit(mx_train_step)/jvp(net)/while/body/dense1/FullyConnected"
+    text = _HLO.replace("%fused_computation.2 (", '''%inner.1 (q0: f32[4,8], q1: f32[8,8]) -> f32[4,8] {
+  %q0 = f32[4,8]{1,0} parameter(0)
+  %q1 = f32[8,8]{1,0} parameter(1)
+  ROOT %dot.8 = f32[4,8]{1,0} dot(f32[4,8]{1,0} %q0, f32[8,8]{1,0} %q1), lhs_contracting_dims={1}, rhs_contracting_dims={0}, metadata={op_name="''' + body + '''/dot_general"}
+}
+
+%outer.1 (r0: f32[4,8], r1: f32[8,8]) -> f32[4,8] {
+  %r0 = f32[4,8]{1,0} parameter(0)
+  %r1 = f32[8,8]{1,0} parameter(1)
+  %fusion.8 = f32[4,8]{1,0} fusion(%r0, %r1), kind=kOutput, calls=%inner.1
+  ROOT %neg.1 = f32[4,8]{1,0} negate(%fusion.8), metadata={op_name="jit(mx_train_step)/jvp(net)/act1/Activation/neg"}
+}
+
+%body.1 (s0: (f32[4,8], f32[8,8])) -> (f32[4,8], f32[8,8]) {
+  %s0 = (f32[4,8]{1,0}, f32[8,8]{1,0}) parameter(0)
+  %g0 = f32[4,8]{1,0} get-tuple-element(%s0), index=0
+  %g1 = f32[8,8]{1,0} get-tuple-element(%s0), index=1
+  %fusion.9 = f32[4,8]{1,0} fusion(%g0, %g1), kind=kOutput, calls=%outer.1
+  ROOT %tuple.1 = (f32[4,8]{1,0}, f32[8,8]{1,0}) tuple(%fusion.9, %g1)
+}
+
+%fused_computation.2 (''')
+    text = _with("  %while.1 = (f32[4,8]{1,0}, f32[8,8]{1,0}) "
+                 "while(%fusion.2), condition=%region_0.1, body=%body.1",
+                 text)
+    table = spmd.program_table(text)
+    records = table["instructions"]
+    assert records["fusion.9"]["flops"] == 2 * 4 * 8 * 8
+    assert records["fusion.9"]["scopes"] == [
+        body, "jit(mx_train_step)/jvp(net)/act1/Activation"]
+    # `ops` looks for a product in the fusion's own computation only
+    # (rule B as PR 24 wrote it), and `pass` follows `ops`
+    assert table["ops"]["fusion.9"] == ""
+    assert records["fusion.9"]["pass"] == "other"
+    assert records["fusion.9"]["passes"] == ["forward"]
+    assert records["while.1"]["opcode"] == "while"
+    assert records["while.1"]["flops"] == 0
+    assert records["while.1"]["scopes"] == []
+    # what runs inside a fusion has no row, in either key
+    assert "fusion.8" not in records and "dot.8" not in records
+    assert sum(r["flops"] for r in records.values()) == 2 * 4 * 8 * 8 + 512
+
+
+@pytest.mark.parametrize("make", [conv_net, attention_net])
+def test_a_step_under_remat_has_recomputed_instructions_and_no_other(make):
+    def passes(remat):
+        trainer, batch = make(remat=remat)
+        trainer.step(*batch)
+        program = spmd.step_programs()[-1]
+        assert list(program["instructions"]) == list(program["ops"])
+        return [r["pass"] for r in program["instructions"].values()]
+
+    plain, mirrored = passes(False), passes(True)
+    assert "recomputed" not in plain and "recomputed" in mirrored
+    for found in (plain, mirrored):
+        assert {"forward", "backward", "update"} <= set(found)
+        assert set(found) <= {"forward", "recomputed", "backward",
+                              "update", "other"}
+
+
+def dense_net():
+    """Three dense layers under Adam: 32 -> 64 -> 48 -> 10 at batch 16."""
+    np.random.seed(0)
+    mx.random.seed(0)
+    net = nn.HybridSequential(prefix="net_")
+    with net.name_scope():
+        net.add(nn.Dense(64, activation="relu"),
+                nn.Dense(48, activation="relu"), nn.Dense(10))
+    net.initialize(mx.initializer.Xavier(), ctx=mx.cpu())
+    with mx.autograd.pause():
+        net(mx.nd.zeros((1, 32), ctx=mx.cpu()))
+    trainer = parallel.SPMDTrainer(
+        net, gloss.SoftmaxCrossEntropyLoss(), "adam",
+        {"learning_rate": 1e-3}, mesh=parallel.make_mesh(dp=1))
+    rng = np.random.RandomState(0)
+    macs = 16 * (32 * 64 + 64 * 48 + 48 * 10)
+    # forward, the weights' gradients, and the data's but the input's
+    return trainer, (rng.rand(16, 32).astype("float32"),
+                     rng.randint(0, 10, 16).astype(np.int32)), \
+        3 * macs - 16 * 32 * 64
+
+
+def strided_conv_net():
+    """Two unpadded 3x3 convolutions of stride 2 (19 -> 9 -> 4) and a
+    dense head under SGD at batch 8."""
+    np.random.seed(0)
+    mx.random.seed(0)
+    net = nn.HybridSequential(prefix="net_")
+    with net.name_scope():
+        net.add(nn.Conv2D(8, 3, strides=2, layout="NHWC",
+                          activation="relu"),
+                nn.Conv2D(16, 3, strides=2, layout="NHWC",
+                          activation="relu"),
+                nn.GlobalAvgPool2D(layout="NHWC"), nn.Dense(4))
+    net.initialize(mx.initializer.Xavier(), ctx=mx.cpu())
+    with mx.autograd.pause():
+        net(mx.nd.zeros((1, 19, 19, 3), ctx=mx.cpu()))
+    trainer = parallel.SPMDTrainer(
+        net, gloss.SoftmaxCrossEntropyLoss(), "sgd",
+        {"learning_rate": 0.1, "momentum": 0.9},
+        mesh=parallel.make_mesh(dp=1))
+    rng = np.random.RandomState(0)
+    conv0, conv1 = 8 * 9 * 9 * 8 * 27, 8 * 4 * 4 * 16 * 72
+    return trainer, (rng.rand(8, 19, 19, 3).astype("float32"),
+                     rng.randint(0, 4, 8).astype(np.int32)), \
+        3 * (conv0 + conv1 + 8 * 16 * 4) - conv0
+
+
+@pytest.mark.parametrize("make", [dense_net, strided_conv_net])
+def test_the_tables_flops_are_the_layers_products_and_a_floor(make):
+    trainer, batch, macs = make()
+    trainer.step(*batch)
+    records = spmd.step_programs()[-1]["instructions"]
+    flops = sum(r["flops"] for r in records.values())
+    assert abs(flops - 2 * macs) <= 0.02 * 2 * macs
+    cost = trainer.step_executable().cost_analysis()
+    cost = cost[0] if isinstance(cost, (list, tuple)) else cost
+    assert flops <= cost["flops"]
+    # products are forward or backward work, never the update's own
+    assert all(r["pass"] != "update" for r in records.values()
+               if r["flops"])
+
+
+def test_layout_gauges_are_set_once_a_trainer_with_telemetry_on():
+    from mxnet_tpu.telemetry import instruments as ins
+
+    trainer, batch = conv_net()
+    trainer.step(*batch).asnumpy()      # telemetry off: nothing is set
+    assert trainer._layout_published is False
+    tracing.enable()
+    try:
+        trainer.step(*batch).asnumpy()
+        assert trainer._layout_published is True
+        assert ins.step_layout_axis_size("dp").value == 1
+        assert ins.step_state_shard_factor().value == 1
+        # a trainer's layout never changes: a later step leaves the
+        # gauges to whoever set them last
+        ins.step_state_shard_factor().set(7)
+        trainer.step(*batch).asnumpy()
+        assert ins.step_state_shard_factor().value == 7
+    finally:
+        tracing.disable()
+
+
 # first-step losses of the two seeded nets before any scope existed
 # (float32 on this CPU backend): a scope is metadata and changes no
 # arithmetic.  The convolution net's is a225b38's; the attention net's is
@@ -294,6 +559,8 @@ def test_step_builds_no_table_and_scopes_change_no_arithmetic(
     assert mine[0].program is not None
     # memoised per executable, and asking builds or loads nothing
     assert spmd.step_programs()[-1]["ops"] is first["ops"]
+    assert spmd.step_programs()[-1]["instructions"] is first["instructions"]
+    assert list(first["instructions"]) == list(first["ops"])
     assert spmd.step_compile_stats() == compiles
 
 
