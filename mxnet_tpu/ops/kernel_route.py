@@ -22,7 +22,9 @@ Pallas kernel or the XLA twin of the same function.  A route (a module of
     is chosen, at TRACE time (once a compiled program, never per step):
     routes chosen, not kernels run.  `mx_attention_route_total{route}` and
     `mx_rotary_route_total{route}` are its exports, the four public
-    `route_counts()` its views.
+    `route_counts()` its views.  `ops/dropout_mask.py` keeps its count of
+    the dropout sites traced here too (`mx_dropout_sites_total{generator}`,
+    `site_counts()`): a generator is chosen as a route is.
 
 `ops/pallas_convbn.py` stays outside on purpose (ROADMAP.md Queue 3 item 2).
 """
@@ -169,7 +171,8 @@ counted_backward.defvjp(lambda x, family, key: (x, None),
 
 # (family, key) -> times chosen since import
 _EXPORTS = {"attention": _instruments.attention_route_total,
-            "rotary": _instruments.rotary_route_total}
+            "rotary": _instruments.rotary_route_total,
+            "dropout": _instruments.dropout_sites_total}
 _counts = {}
 _lock = threading.Lock()
 

@@ -10,7 +10,10 @@ hand-written kernel or autotune cache (XLA owns scheduling).
 
 Stateful training-mode ops follow a functional contract:
   * Dropout takes an explicit PRNG key input (threaded by the frontend
-    from mxnet_tpu.random's provider) and a static `train` attr.
+    from mxnet_tpu.random's provider) and a static `train` attr; its mask
+    is an integer hash of (the key's words, the element's index): the
+    repo's one dropout generator, ops/dropout_mask.py, which attention's
+    dropout shares.
   * BatchNorm in train mode returns (out, new_running_mean, new_running_var);
     the Gluon layer rebinds its running-stat buffers — the TPU-safe way to
     express the reference's in-place aux-state update.
@@ -25,6 +28,7 @@ import numpy as np
 from jax import lax
 
 from ..base import MXNetError
+from .dropout_mask import inverted_dropout
 from .registry import register_op
 
 
@@ -488,12 +492,7 @@ def _dropout(data, key, p=0.5, mode="training", axes=(), _train=False):
     apply_it = (mode == "always") or _train
     if not apply_it or p == 0.0:
         return data
-    shape = list(data.shape)
-    for a in axes:
-        shape[a] = 1
-    keep = 1.0 - p
-    mask = jax.random.bernoulli(key, keep, tuple(shape)).astype(data.dtype)
-    return data * mask / keep
+    return inverted_dropout(data, key, p, axes)
 
 
 # ---------------------------------------------------------------------------

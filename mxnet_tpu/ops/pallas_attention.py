@@ -65,12 +65,13 @@ each route covers:
     meshes): `_attention_with_prob_dropout`, XLA, probabilities saved for
     the backward.  Counted `xla_dropout`.
 
-The dropout mask is `dropout_keep_mask`: an integer hash of (key words,
-b*h, q, k) by GLOBAL index, the same bits in Mosaic, the interpreter, XLA
-and numpy.  Both training routes take it from there (one generator), which
-is why the dropout STREAM differs from the threefry `bernoulli` this op
-used before PR 26: same distribution, other draws, so a loss pinned under
-attention dropout moved once.
+The dropout mask is `dropout_keep_mask`: the rounds of the repo's one
+generator, `ops/dropout_mask.py` (the op `Dropout` draws from the same),
+over (key words, b*h, q, k) by GLOBAL index, the same bits in Mosaic, the
+interpreter, XLA and numpy.  Both training routes take it from there,
+which is why the dropout STREAM differs from the threefry `bernoulli` this
+op used before PR 26: same distribution, other draws, so a loss pinned
+under attention dropout moved once.
 
 The splash routes NAME what the forward kernel wrote for the backward,
 output and logsumexp (ops/residuals.py); their XLA twins name nothing.
@@ -85,6 +86,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from . import kernel_route
+from .dropout_mask import (_hash_bits, _hash_head, _hash_qk, _keep_threshold,
+                           _key_words)
 from .registry import register_op
 
 __all__ = ["dot_product_attention_ref", "dropout_keep_mask", "route_counts",
@@ -202,56 +205,9 @@ _attend.defvjp(_attend_fwd, _attend_bwd)
 # ---------------------------------------------------------------------------
 # Training with dropout on the attention probabilities
 # ---------------------------------------------------------------------------
-# One mask generator for every route: an integer hash of (key words, b*h,
-# q, k) in plain uint32 arithmetic.  Being a pure function of GLOBAL
-# indices it gives the same bits inside a Mosaic kernel, in the Pallas
-# interpreter, in XLA and in numpy, whatever the block sizes; that is what
-# lets tier-1 hold the kernels' outputs AND gradients to a reference under
-# the identical mask (pltpu.prng_random_bits depends on the block layout
-# and never could).
-
-_M1 = np.uint32(0x7FEB352D)
-_M2 = np.uint32(0x846CA68B)
-_GOLDEN = np.uint32(0x9E3779B9)
-_S15 = np.uint32(15)
-_S16 = np.uint32(16)
-
-
-def _hash_qk(q_idx, k_idx, seq_k, key0):
-    """First round, shared by every head: (q, k) and the key's first word.
-    uint32 operands (numpy, jnp or kernel values); q*seq_k + k cannot wrap
-    below 65536 keys."""
-    x = (q_idx * np.uint32(seq_k) + k_idx) ^ key0
-    x = x ^ (x >> _S16)
-    x = x * _M1
-    return x ^ (x >> _S15)
-
-
-def _hash_head(bh_idx, key1):
-    """Per-head word: b*h and the key's second word through a full
-    two-multiply mix.  b*h enters here, not through bh*S*S, so no shape
-    can wrap 32 bits; in a kernel this is scalar work."""
-    x = bh_idx * _GOLDEN + key1
-    x = x ^ (x >> _S16)
-    x = x * _M1
-    x = x ^ (x >> _S15)
-    x = x * _M2
-    return x ^ (x >> _S16)
-
-
-def _hash_bits(h_qk, h_head):
-    """Second round, per (b*h, q, k): a word whose HIGH bits are uniform.
-    The threshold compare reads the high bits, which the closing
-    xor-shift of a full round would not change, so there is none."""
-    x = h_qk + h_head
-    x = x ^ (x >> _S16)
-    return x * _M2
-
-
-def _keep_threshold(keep):
-    """An element is kept when its bits are below this; the rounding of
-    keep*2^32 (2^-32) is far below bf16."""
-    return np.uint32(min(int(keep * 2.0 ** 32), 2 ** 32 - 1))
+# One mask generator for every route AND for the op `Dropout`:
+# `ops/dropout_mask.py`'s integer hash, here over (key words, b*h, q, k).
+# The kernels call its rounds on a tile's GLOBAL indices.
 
 
 def dropout_keep_mask(key_words, bh, seq_q, seq_k, keep, xp=jnp,
@@ -266,13 +222,6 @@ def dropout_keep_mask(key_words, bh, seq_q, seq_k, keep, xp=jnp,
         u32(bh, (bh, 1, 1)) + xp.asarray(first_head, dtype=xp.uint32),
         key_words[1])
     return _hash_bits(h_qk, h_head) < _keep_threshold(keep)
-
-
-def _key_words(rng_key):
-    """The first two uint32 words of a PRNG key, typed or raw."""
-    if jnp.issubdtype(rng_key.dtype, jax.dtypes.prng_key):
-        rng_key = jax.random.key_data(rng_key)
-    return rng_key.reshape(-1)[:2].astype(jnp.uint32)
 
 
 def _attention_with_prob_dropout(q, k, v, mask, scale, p, rng_key,

@@ -20,6 +20,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from .dropout_mask import inverted_dropout
 from .registry import register_op
 
 _GATES = {"rnn_relu": 1, "rnn_tanh": 1, "lstm": 4, "gru": 3}
@@ -166,9 +167,7 @@ def _rnn(data, parameters, state=None, state_cell=None, key=None,
             idx += 1
         x = jnp.concatenate(ys_dirs, axis=-1) if dirs > 1 else ys_dirs[0]
         if p > 0 and _train and layer < num_layers - 1 and key is not None:
-            sub = jax.random.fold_in(key, layer)
-            mask = jax.random.bernoulli(sub, 1 - p, x.shape).astype(x.dtype)
-            x = x * mask / (1 - p)
+            x = inverted_dropout(x, jax.random.fold_in(key, layer), p)
     if not state_outputs:
         return x
     h_stack = jnp.stack(h_outs)

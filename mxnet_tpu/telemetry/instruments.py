@@ -30,7 +30,7 @@ from typing import Dict, NamedTuple, Tuple
 from .metrics import MetricFamily, get_registry
 
 __all__ = [
-    "op_dispatch_total", "attention_route_total",
+    "op_dispatch_total", "attention_route_total", "dropout_sites_total",
     "remat_kept_bytes_total",
     "training_phase_seconds", "training_steps_total",
     "fused_step_total", "fused_compile_seconds",
@@ -162,6 +162,19 @@ _spec("mx_rotary_route_total", "counter",
 
 def rotary_route_total(route: str):
     return _child("mx_rotary_route_total", (route,))
+
+
+_spec("mx_dropout_sites_total", "counter",
+      "Dropout sites TRACED (the op Dropout, the RNN op's dropout between "
+      "layers), by the generator their mask is drawn from (hash = "
+      "ops/dropout_mask.py's integer hash of key words and element index, "
+      "the one attention's dropout uses; ops.dropout_mask.site_counts() "
+      "also gives the elements the masks cover): counted once a compiled "
+      "program, never per step.", ("generator",))
+
+
+def dropout_sites_total(generator: str):
+    return _child("mx_dropout_sites_total", (generator,))
 
 
 _spec("mx_remat_kept_bytes_total", "counter",

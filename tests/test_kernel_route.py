@@ -163,8 +163,9 @@ def test_one_store_behind_the_four_public_views():
 @pytest.mark.parametrize("family,metric,route", [
     ("attention", "mx_attention_route_total", "latent_xla"),
     ("rotary", "mx_rotary_route_total", "xla"),
+    ("dropout", "mx_dropout_sites_total", "hash"),
 ])
-def test_the_two_exported_families_reach_telemetry(family, metric, route):
+def test_the_exported_families_reach_telemetry(family, metric, route):
     telemetry.enable()
     try:
         fam = lambda: telemetry.get_registry().get(metric)
